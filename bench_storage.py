@@ -37,6 +37,7 @@ def _emit(backend: str, op: str, n: int, dt: float) -> dict:
         "n": n,
         "backend": backend,
         "op": op,
+        "platform": "host",  # pure host CPU: no JAX, no device
     }
     print(json.dumps(rec), flush=True)
     return rec

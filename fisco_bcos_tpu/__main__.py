@@ -178,9 +178,13 @@ def main(argv: list[str] | None = None) -> int:
         format="%(asctime)s %(levelname)s %(name)s %(message)s",
     )
 
+    from .utils.jaxenv import configure_compile_cache
+
+    cache_dir = configure_compile_cache()
     node, gw, server, ws, runtime, stop = build_node(opts)
     _log.info(
-        "node %s | chain %s group %s | p2p %s:%d rpc %s:%d | sealer=%s",
+        "node %s | chain %s group %s | p2p %s:%d rpc %s:%d | sealer=%s | "
+        "compile cache %s",
         node.node_id.hex()[:16],
         opts.node.chain_id,
         opts.node.group_id,
@@ -189,6 +193,7 @@ def main(argv: list[str] | None = None) -> int:
         opts.rpc_listen_ip,
         opts.rpc_listen_port,
         node.is_sealer(),
+        cache_dir,
     )
 
     if args.warmup:

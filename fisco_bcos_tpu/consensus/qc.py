@@ -50,7 +50,7 @@ from ..utils.metrics import REGISTRY
 _log = get_logger("qc")
 
 # fisco_qc_verify_ms bucket contract: sub-ms host ed25519 batches up to
-# multi-hundred-ms first-compile / tunneled pairing checks
+# multi-hundred-ms first-compile / host-reference pairing checks
 QC_VERIFY_BUCKETS_MS = (0.5, 1.0, 2.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 1000.0)
 # certificate sizes: ed25519 concatenated certs grow with the committee,
 # BLS certs stay near 100 B — the split these buckets make visible

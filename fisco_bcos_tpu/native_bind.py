@@ -52,9 +52,15 @@ def _host_isa() -> str:
 
 def _build() -> bool:
     # -fopenmp parallelizes the batch loops across host cores; a toolchain
-    # without libgomp still gets the single-threaded library
+    # without libgomp still gets the single-threaded library.
+    # Built under a per-process temp name and renamed into place: several
+    # processes starting from a fresh checkout (four node processes, the
+    # chip smoke's children) each compile their own copy, and whichever
+    # rename lands last wins whole — nobody ever dlopens a half-written
+    # file, which g++ writing straight to _LIB allowed.
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
     base = ["g++", "-O3", "-march=native", "-funroll-loops", "-shared",
-            "-fPIC", "-o", _LIB, _SRC]
+            "-fPIC", "-o", tmp, _SRC]
     try:
         res = subprocess.run(
             base[:1] + ["-fopenmp"] + base[1:],
@@ -71,10 +77,16 @@ def _build() -> bool:
         _log.warning("native build failed:\n%s", res.stderr[-2000:])
         return False
     try:
-        with open(_ISA_TAG, "w") as f:
+        os.replace(tmp, _LIB)
+    except OSError as e:
+        _log.warning("native build could not be moved into place: %s", e)
+        return False
+    try:
+        with open(tmp, "w") as f:
             f.write(_host_isa())
+        os.replace(tmp, _ISA_TAG)
     except OSError:
-        pass
+        pass  # untagged build: the next process rebuilds instead of trusting it
     return True
 
 

@@ -329,8 +329,15 @@ class Node:
         from ..device.plane import get_plane, plane_enabled
 
         if plane_enabled():
+            from ..utils.jaxenv import device_identity
+
             get_plane()
-            HEALTH.ok("device-plane", "coalescing scheduler up")
+            ident = device_identity()
+            HEALTH.ok(
+                "device-plane",
+                f"coalescing scheduler up on platform={ident['platform']} "
+                f"device_kind={ident['device_kind']} count={ident['count']}",
+            )
         # pipeline observatory (ISSUE 9): backpressure watermark probes at
         # every inter-stage boundary, sampled by one background thread into
         # bounded timelines (GET /pipeline + Chrome-trace counter events).
@@ -468,20 +475,39 @@ class Node:
         return drained
 
     def warmup(self, batch_sizes: tuple[int, ...] = (8,)) -> None:
-        """Pre-compile the batch admission kernels for the given bucket
-        sizes so the first live proposal doesn't pay XLA compile latency
-        inside the consensus timeout window."""
+        """Pre-compile (or load from the persistent cache) the batch
+        admission program for the ladder bucket each batch size implies, so
+        the first live proposal doesn't pay XLA compile latency inside the
+        consensus timeout window. The warm batch takes the same route a
+        live batch of that size takes; where that route is the native host
+        loop (below the cutover, or any size on a CPU backend) there is no
+        program to compile, and the log says so instead of claiming warm
+        kernels. Payloads are padded into the two-block keccak bucket that
+        precompiled calls land in — the shape key is (bucket, blocks)."""
+        from ..crypto.suite import use_native_batch
+        from ..ops.hash_common import bucket_batch
         from ..protocol.transaction import Transaction
         from ..txpool.validator import batch_admit
 
         for b in batch_sizes:
+            if use_native_batch(b):
+                _log.info(
+                    "warmup: a batch of %d rides the native host loop on "
+                    "this backend — no device program to compile", b
+                )
+                continue
+            bucket = bucket_batch(b)
             txs = []
-            for i in range(b):
-                tx = Transaction(chain_id=self.config.chain_id, nonce=f"warm{i}")
+            for i in range(bucket):
+                tx = Transaction(
+                    chain_id=self.config.chain_id,
+                    nonce=f"warm{i}",
+                    input=b"\x00" * 132,
+                )
                 tx.signature = b"\x01" * self.suite.signature_impl.sig_len
                 txs.append(tx)
             batch_admit(txs, self.suite)  # validity is irrelevant; shapes compile
-        _log.info("crypto kernels warm for batch sizes %s", batch_sizes)
+            _log.info("warmup: admission program ready for bucket %d", bucket)
 
     @property
     def node_id(self) -> bytes:

@@ -66,8 +66,12 @@ from collections import deque
 
 from ..ops.hash_common import bucket_batch, bucket_ladder
 from ..utils import metrics as _metrics
+from ..utils.jaxenv import device_identity
+from ..utils.log import get_logger
 from .histogram import BATCH_BUCKETS, LATENCY_BUCKETS_MS
 from .tracer import TRACER
+
+_log = get_logger("device")
 
 # in-plane phase segments: queue waits are sub-ms..100ms, transfers ms-class,
 # execute up to block-scale seconds
@@ -189,6 +193,9 @@ class CompileLedger:
         # bookkeeping wall spent in observatory accounting (device_span
         # exit paths add to it) — the measured-overhead artifact input
         self._overhead_s = 0.0
+        # op -> {"count", "last_error"}: device programs that raised and
+        # were answered by the host loop (crypto.suite._device_or_host)
+        self._failures: dict[str, dict] = {}
 
     # -- attribution frames (device_span drives these) -----------------------
 
@@ -417,6 +424,30 @@ class CompileLedger:
                 for (a, b), n in sorted(self._adjacency.items())
             }
 
+    def note_failure(self, op: str, exc: BaseException) -> None:
+        """A device program for ``op`` raised and the host loop answered in
+        its place. The product keeps serving (that is the breaker's job);
+        this is where the failure stays visible: ``GET /device`` →
+        ``failures``, ``fisco_device_program_failures_total{op}`` and one
+        warning line. Recorded regardless of ``FISCO_DEVICE_OBS`` — a
+        hidden device failure is what the counter exists to prevent."""
+        err = f"{type(exc).__name__}: {str(exc)[:300]}"
+        with self._lock:
+            rec = self._failures.setdefault(op, {"count": 0, "last_error": ""})
+            rec["count"] += 1
+            rec["last_error"] = err
+        _metrics.REGISTRY.counter_add(
+            f'fisco_device_program_failures_total{{op="{op}"}}',
+            1.0,
+            help="device programs that raised and were answered by the "
+            "host loop instead",
+        )
+        _log.warning("device program %s failed, host loop answered: %s", op, err)
+
+    def failures(self) -> dict[str, dict]:
+        with self._lock:
+            return {op: dict(rec) for op, rec in self._failures.items()}
+
     def add_overhead(self, secs: float) -> None:
         with self._lock:
             self._overhead_s += secs
@@ -447,6 +478,12 @@ class CompileLedger:
                 out[op] = out.get(op, 0) + 1
         return out
 
+    def max_batches(self) -> dict[str, int]:
+        """Largest batch each op was dispatched with — what tells a native
+        dispatch of a QC list from one of a whole block."""
+        with self._lock:
+            return dict(self._max_batch)
+
     def cold_compile_count(self) -> int:
         with self._lock:
             return sum(e["cold_compiles"] for e in self._entries.values())
@@ -473,6 +510,7 @@ class CompileLedger:
             self._adjacency.clear()
             self._last_adj_op = None
             self._overhead_s = 0.0
+            self._failures.clear()
 
 
 # process-wide ledger (ops wrappers and the jax listeners feed it directly,
@@ -585,7 +623,9 @@ def install_observatory() -> bool:
 
 
 def device_doc(tail: int = 64) -> dict:
-    """Everything the device observatory knows, one JSON: the compile
+    """Everything the device observatory knows, one JSON: which device
+    the process is on (platform / device_kind / count, as JAX reports it),
+    device programs that failed over to the host loop, the compile
     ledger (cold vs persistent-cache attribution), per-op phase totals,
     the first-shape heuristic counters for cross-checking, storm state,
     live-buffer bytes + their watermark rings, and the plane's scheduler
@@ -596,6 +636,9 @@ def device_doc(tail: int = 64) -> dict:
         "enabled": enabled,
         "ts": time.time(),
         "epoch": TRACER.epoch,
+        "device": device_identity(),
+        "failures": LEDGER.failures(),
+        "max_batch": LEDGER.max_batches() if enabled else {},
         "ledger": LEDGER.snapshot() if enabled else [],
         "phase_ms": LEDGER.phase_totals() if enabled else {},
         "compile_counts": compile_counts(),

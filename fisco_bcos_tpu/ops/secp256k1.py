@@ -170,15 +170,15 @@ _PALLAS_BROKEN = False
 
 
 def _use_pallas() -> bool:
-    """Pallas is OPT-IN (FISCO_FORCE_PALLAS=1, TPU only): the round-5
-    hardware qualification (tool/tpu_probe.py, v5e, 2026-08-01) measured the
-    plain-XLA paths FASTER than the Mosaic kernels everywhere — secp verify
-    0.14 ms vs 3.77 ms at B=256, sm2 verify 0.31 ms vs 6.07 ms — because XLA
-    already keeps the [16, T] limb chains vreg-resident and fuses them; the
-    hand-tiled kernel only adds scheduling overhead. The kernels stay (they
-    compile clean on hardware and are the bit-identity cross-check) but the
-    hot path is XLA on every backend. FISCO_NO_PALLAS still wins over the
-    force flag so one switch can pin the XLA leg in any process."""
+    """Pallas is OPT-IN (FISCO_FORCE_PALLAS=1, TPU only): on hardware the
+    plain-XLA paths beat the Mosaic kernels (tool/tpu_probe.py is the probe;
+    its readings are not of today's code — docs/crypto_plane_perf.md),
+    because XLA already keeps the [16, T] limb chains vreg-resident and
+    fuses them; the hand-tiled kernel only adds scheduling overhead. The
+    kernels stay as the bit-identity cross-check until ROADMAP Queue 3
+    removes them, but the hot path is XLA on every backend. FISCO_NO_PALLAS
+    still wins over the force flag so one switch can pin the XLA leg in any
+    process."""
     import os
 
     if _PALLAS_BROKEN or os.environ.get("FISCO_NO_PALLAS"):

@@ -421,8 +421,8 @@ class Scheduler:
 
         header = block.header
         header.gas_used = sum(rc.gas_used for rc in block.receipts)
-        # dispatch all three root programs before syncing any — on a
-        # tunneled device each forced sync is a round trip, and the three
+        # dispatch all three root programs before syncing any — each forced
+        # sync parks the host until the device answers, and the three
         # computations are independent
         get_hash_async = getattr(self.executor, "get_hash_async", None)
         state_f = (

@@ -24,6 +24,8 @@ import shutil
 import stat
 import sys
 
+from ..utils.jaxenv import CHECKOUT
+
 
 def _genesis_text(nodeids: list[str], chain_id: str, group_id: str) -> str:
     nodes = "\n".join(
@@ -108,13 +110,26 @@ def _config_text(
 """
 
 
+# The package is not installed: every generated script exports PYTHONPATH
+# for the checkout that generated it. JAX_COMPILATION_CACHE_DIR is left to
+# the environment (unset: <checkout>/.jax_cache, utils/jaxenv.py).
 _START_SH = """#!/bin/bash
 cd "$(dirname "$0")"
+export PYTHONPATH="{checkout}${{PYTHONPATH:+:$PYTHONPATH}}"
 nohup {python} -m fisco_bcos_tpu -c config.ini -g config.genesis \\
     >> node.log 2>&1 &
 echo $! > node.pid
 echo "started node (pid $(cat node.pid))"
 """
+
+# One process per chip: a node process takes the accelerator JAX finds and
+# holds it until it exits. On one chip, run ONE node process (a 4-node
+# committee on one chip is the in-process mapping, see README).
+_START_ALL_NOTE = (
+    "# one node process per chip: each ./nodeN/start.sh below claims the\n"
+    "# accelerator JAX finds. Set JAX_PLATFORMS=cpu for every node that has\n"
+    "# no chip of its own.\n"
+)
 
 _STOP_SH = """#!/bin/bash
 cd "$(dirname "$0")"
@@ -197,14 +212,15 @@ def build_chain(
                     sm_ca, conf, f"node{i}", node_id=keypairs[i].pub
                 )
         _write_exec(
-            os.path.join(ndir, "start.sh"), _START_SH.format(python=sys.executable)
+            os.path.join(ndir, "start.sh"),
+            _START_SH.format(python=sys.executable, checkout=CHECKOUT),
         )
         _write_exec(os.path.join(ndir, "stop.sh"), _STOP_SH)
         node_dirs.append(ndir)
 
     _write_exec(
         os.path.join(out_dir, "start_all.sh"),
-        "#!/bin/bash\ncd \"$(dirname \"$0\")\"\n"
+        "#!/bin/bash\n" + _START_ALL_NOTE + "cd \"$(dirname \"$0\")\"\n"
         + "".join(f"./node{i}/start.sh\n" for i in range(count)),
     )
     _write_exec(
@@ -221,9 +237,19 @@ def build_chain(
 
 _PRO_SVC_SH = """#!/bin/bash
 cd "$(dirname "$0")"
+export PYTHONPATH="{checkout}${{PYTHONPATH:+:$PYTHONPATH}}"
 nohup {python} -m {module} {args} > {name}.log 2>&1 &
 echo $! > {name}.pid
 """
+
+
+
+def _pro_svc_sh(module: str, args: str, name: str) -> str:
+    return _PRO_SVC_SH.format(
+        python=sys.executable, checkout=CHECKOUT, module=module, args=args,
+        name=name,
+    )
+
 
 _PRO_STOP_SH = """#!/bin/bash
 cd "$(dirname "$0")"
@@ -317,9 +343,7 @@ def build_pro_chain(
         for name, module, svc_args in svcs:
             _write_exec(
                 os.path.join(ndir, f"start_{name}.sh"),
-                _PRO_SVC_SH.format(
-                    python=sys.executable, module=module, args=svc_args, name=name
-                ),
+                _pro_svc_sh(module, svc_args, name),
             )
         _write_exec(
             os.path.join(ndir, "start.sh"),
@@ -383,11 +407,10 @@ def build_max_chain(
     storage_port = port_base
     _write_exec(
         os.path.join(out_dir, "start_storage.sh"),
-        _PRO_SVC_SH.format(
-            python=sys.executable,
-            module="fisco_bcos_tpu.service",
-            args=f"storage --db max_chain.db --port {storage_port}",
-            name="storage",
+        _pro_svc_sh(
+            "fisco_bcos_tpu.service",
+            f"storage --db max_chain.db --port {storage_port}",
+            "storage",
         ),
     )
 
@@ -446,9 +469,7 @@ def build_max_chain(
         for name, module, svc_args in svcs:
             _write_exec(
                 os.path.join(ndir, f"start_{name}.sh"),
-                _PRO_SVC_SH.format(
-                    python=sys.executable, module=module, args=svc_args, name=name
-                ),
+                _pro_svc_sh(module, svc_args, name),
             )
         exec_starts = "".join(
             f"./start_executor{e}.sh\n" for e in range(executors)

@@ -203,8 +203,6 @@ def _rpc_up(port):
 
 
 _BOOT = (
-    "import jax\n"
-    "jax.config.update('jax_platforms', 'cpu')\n"
     "import fisco_bcos_tpu.__main__ as m\n"
     "m.main(['-c', 'config.ini', '-g', 'config.genesis'])\n"
 )
@@ -227,7 +225,6 @@ def test_four_process_chain(tmp_path):
 
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(REPO, ".jax_cache"))
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
 
     procs = []

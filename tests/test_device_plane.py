@@ -314,7 +314,7 @@ def test_device_min_batch_env(monkeypatch):
     from fisco_bcos_tpu.crypto import suite as suite_mod
 
     # pretend the backend is an accelerator so the threshold is decisive
-    monkeypatch.setattr(suite_mod, "_BACKEND_IS_CPU", False)
+    monkeypatch.setattr(suite_mod, "device_backend_is_cpu", lambda: False)
     monkeypatch.delenv("FISCO_DEVICE_MIN_BATCH", raising=False)
     assert suite_mod.device_min_batch() == suite_mod._SMALL_BATCH
     assert suite_mod.use_native_batch(10)

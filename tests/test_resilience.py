@@ -195,7 +195,7 @@ def test_breaker_probe_released_when_both_paths_fail():
             raise RuntimeError("path down")
 
         with pytest.raises(RuntimeError):
-            _device_or_host(boom, boom)  # both legs fail: data error
+            _device_or_host("test", boom, boom)  # both legs fail: data error
         assert br.allow()  # probe slot free again — NOT wedged
         br.release_probe()
         # and an unclassified escape through CircuitBreaker.call too

@@ -1,8 +1,8 @@
 """Wall-clock sanity check for the TPU EC throughput numbers.
 
-The timed-repetition probes showed numbers good enough to distrust
-(~11M recovers/s at B=10240). This feeds K DISTINCT batches (fresh host
-data every call, so no conceivable caching can help), validates every
+Timed-repetition probes can show numbers good enough to distrust. This
+feeds K DISTINCT batches (fresh host data every call, so no conceivable
+caching can help), validates every
 output against known-good pubkeys, and reports end-to-end wall time
 including host->device transfer of each batch.
 
@@ -17,18 +17,15 @@ import time
 
 import numpy as np
 
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
-)
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main(batch: int = 10240, calls: int = 20) -> int:
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    from fisco_bcos_tpu.utils.jaxenv import configure_compile_cache
+
+    configure_compile_cache()
     from fisco_bcos_tpu.crypto import suite as cs
     from fisco_bcos_tpu.ops import secp256k1 as k1
     from fisco_bcos_tpu.ops.bigint import bytes_be_to_limbs

@@ -1,9 +1,8 @@
 """Batch-size scaling + component profile for the EC XLA paths on TPU.
 
-The 256-lane probe showed verify at 0.14 ms but recover at 36 ms — this
-breaks recover into its stages (inv, sqrt leg, ladder, finish) and times
-verify/recover/sm2 at growing batch sizes to find where the VPU saturates
-and which stage recover loses its time in.
+Breaks recover into its stages (inv, sqrt leg, ladder, finish) and times
+verify/recover/sm2 at growing batch sizes, warm, to find where the VPU
+saturates and which stage recover loses its time in.
 
 Usage: python -m tool.tpu_scale_probe
 """
@@ -16,10 +15,7 @@ import time
 
 import numpy as np
 
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"),
-)
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 _T0 = time.monotonic()
 
@@ -44,10 +40,10 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
 
-    jax.config.update("jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    from fisco_bcos_tpu.utils.jaxenv import configure_compile_cache
+
+    configure_compile_cache()
     _log(f"backend={jax.default_backend()}")
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     from fisco_bcos_tpu.crypto import suite as cs
     from fisco_bcos_tpu.ops import secp256k1 as k1
     from fisco_bcos_tpu.ops.bigint import bytes_be_to_limbs

@@ -8,7 +8,8 @@ compiler).
 Walks the SAME jit inventory the static analyzers use
 (``python -m fisco_bcos_tpu.analysis --list-jit``): every inventoried
 program is either warmed — its host wrapper is driven with shape-bucketed
-dummy inputs, compiling it into ``JAX_COMPILATION_CACHE_DIR`` — or listed
+dummy inputs, compiling it into the persistent cache
+(``fisco_bcos_tpu.utils.jaxenv.configure_compile_cache``) — or listed
 as skipped with a reason (pallas kernels off-TPU, sharded variants on a
 single-device host, BLS on CPU backends where the crypto seam routes to
 the host reference anyway; ``--include-bls`` forces it). The compile
@@ -42,21 +43,6 @@ import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR", os.path.join(_REPO, ".jax_cache")
-)
-
-
-def _init_jax() -> str:
-    import jax
-
-    jax.config.update(
-        "jax_compilation_cache_dir", os.environ["JAX_COMPILATION_CACHE_DIR"]
-    )
-    # every program counts: the whole point is that the SECOND process
-    # never compiles, so even fast programs belong in the cache
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    return jax.default_backend()
 
 
 # ---------------------------------------------------------------------------
@@ -206,14 +192,19 @@ def run_warm(
     """Drive the warmers over the jit inventory; returns (and optionally
     writes) the manifest. Importable — tests and boot scripts call this
     directly."""
-    backend = _init_jax()
     from fisco_bcos_tpu.analysis import jitmap
     from fisco_bcos_tpu.crypto.suite import device_backend_is_cpu
     from fisco_bcos_tpu.observability.device import (
         LEDGER,
         install_jax_hooks,
     )
+    from fisco_bcos_tpu.utils.jaxenv import (
+        configure_compile_cache,
+        device_identity,
+    )
 
+    cache_dir = configure_compile_cache()
+    backend = device_identity()["platform"]
     hooks = install_jax_hooks()
     LEDGER.reset()
     inventory = jitmap.inventory()
@@ -264,7 +255,7 @@ def run_warm(
     manifest = {
         "ts": time.time(),
         "backend": backend,
-        "cache_dir": os.environ["JAX_COMPILATION_CACHE_DIR"],
+        "cache_dir": cache_dir,
         "bucket": bucket,
         "jax_hooks": hooks,
         "wall_s": round(time.perf_counter() - t_start, 3),
