@@ -1154,14 +1154,16 @@ def _dump_device_artifact(tag: str, window_s: float, warm_ledger) -> None:
         5.0 / max(overhead_pct, 1e-6),
         error=None if overhead_pct < 5.0 else "device observatory >= 5%",
     )
-    execs = {
-        op: phases.get("execute", 0.0)
+    # measured phases only (observability/device.py): `sync` is the host
+    # waiting for the device's result
+    syncs = {
+        op: phases.get("sync", 0.0)
         for op, phases in doc["op_phase_ms"].items()
     }
-    top = max(execs.items(), key=lambda kv: kv[1], default=(None, 0.0))
+    top = max(syncs.items(), key=lambda kv: kv[1], default=(None, 0.0))
     print(
         f"# device: {doc['cold_compiles']} cold compile(s) in the measured "
-        f"window, {doc['cache_hits']} cache load(s), top execute "
+        f"window, {doc['cache_hits']} cache load(s), top sync "
         f"op={top[0]} ({top[1]:.0f}ms) -> {path}",
         flush=True,
     )

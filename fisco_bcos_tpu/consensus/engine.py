@@ -537,7 +537,7 @@ class PBFTEngine:
         # the leader's own pre-prepare (and, single-node, the whole phase
         # chain down to commit) runs here, not through handle_message —
         # same consensus-stage accounting either way
-        with PIPELINE.busy("consensus"), self._lock:
+        with TRACER.span("pbft.propose"), PIPELINE.busy("consensus"), self._lock:
             number = block.header.number
             if self.timeout_state:
                 return False
@@ -635,7 +635,11 @@ class PBFTEngine:
         in-proc deliveries have unwound (off-lock double-gate)."""
         self._enter_dispatch()
         try:
-            self._handle_message(msg, src)
+            # one span per message: authentication, vote bookkeeping and
+            # whatever the handler goes on to do (the execute and commit
+            # legs have spans of their own inside it)
+            with TRACER.span("pbft.message", type=msg.packet_type.name):
+                self._handle_message(msg, src)
         finally:
             self._exit_dispatch()
 
@@ -1221,6 +1225,7 @@ class PBFTEngine:
                 cache.t_accept,
                 cache.t_prepared - cache.t_accept,
                 parent_ctx=cache.trace_ctx,
+                derived=True,  # a gap between quorum events
                 block=number,
             )
         if self.cstore is not None and cache.block_data:
@@ -1278,6 +1283,7 @@ class PBFTEngine:
                 cache.t_prepared,
                 cache.t_committed - cache.t_prepared,
                 parent_ctx=cache.trace_ctx,
+                derived=True,  # a gap between quorum events
                 block=number,
             )
         self._execute_and_checkpoint(number, cache)
@@ -1435,6 +1441,7 @@ class PBFTEngine:
                 cache.t_committed,
                 now - cache.t_committed,
                 parent_ctx=cache.trace_ctx,
+                derived=True,  # a gap between quorum events
                 block=number,
             )
         self.roundlog.note(number, self.view, "stable", t=now)

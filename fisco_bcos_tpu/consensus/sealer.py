@@ -141,7 +141,9 @@ class Sealer:
                 self._prebuild(number + 1, cfg.tx_count_limit)
             return None
         t0 = time.perf_counter()
-        with PIPELINE.busy("sealer"):
+        # a live span, so a profiler capture shows it; it opens the BLOCK's
+        # trace (a root unless the caller carries a context)
+        with TRACER.span("seal", block=number) as sp, PIPELINE.busy("sealer"):
             prebuilt = self._take_prebuilt(number)
             if prebuilt is not None:
                 _n, txs, hashes, root_f = prebuilt
@@ -155,6 +157,7 @@ class Sealer:
             if len(txs) < self.min_seal_txs:
                 self.txpool.unseal(hashes)
                 PIPELINE.mark_idle("sealer")
+                sp.discard()  # a tick that sealed nothing leaves no record
                 return None
             suite = self.config.suite
             header = BlockHeader(
@@ -174,29 +177,26 @@ class Sealer:
                 else block.calculate_txs_root(suite)
             )
             header.clear_hash_cache()
-        dur = time.perf_counter() - t0
+            if TRACER.enabled:
+                from ..observability import critical_path
+
+                # close each absorbed tx's pool-wait gap in ITS trace; the
+                # seal span links back to every admission span it picked
+                # up (the same fan-in shape the device-plane merged batch
+                # uses) and its trace is the block's
+                sp.link(critical_path.note_sealed(hashes, number))
+                sp.set(txs=len(txs))
+                critical_path.note_block_trace(
+                    number, sp.ctx.trace_id if sp.ctx is not None else None
+                )
         REGISTRY.observe(
             "fisco_sealer_seal_latency_ms",
-            dur * 1e3,
+            (time.perf_counter() - t0) * 1e3,
             help="proposal generation wall latency (fetch + tx-root merkle)",
         )
         REGISTRY.counter_add(
             "fisco_sealer_proposals_total", help="block proposals generated"
         )
-        if TRACER.enabled:
-            from ..observability import critical_path
-
-            # close each absorbed tx's pool-wait gap in ITS trace, then
-            # open the BLOCK's trace with the seal span linking back to
-            # every admission span it picked up (the same fan-in shape the
-            # device-plane merged batch uses)
-            tx_ctxs = critical_path.note_sealed(hashes, number)
-            ctx = TRACER.record(
-                "seal", t0, dur, block=number, txs=len(txs), links=tx_ctxs
-            )
-            critical_path.note_block_trace(
-                number, ctx.trace_id if ctx is not None else None
-            )
         return block
 
     def seal_and_submit(self) -> bool:

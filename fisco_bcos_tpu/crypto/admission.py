@@ -153,28 +153,38 @@ def _admit_batch_device(
     transfer. `allow_shard=True` (plane dispatches only) fans the bucketed
     batch out over the local device mesh when it clears _shard_min."""
     from ..observability.device import device_span
+    from ..ops.hash_common import bucket_batch
 
     bsz = len(payloads)
-    # pad_keccak buckets the batch dim itself (empty-message pad rows);
-    # r/s/v follow the blocks tensor's bucket by construction
-    blocks, nblocks = pad_keccak(list(payloads))
-    bb = blocks.shape[0]
+    payloads = list(payloads)
+    # the shape the program compiles for, known before any marshalling:
+    # pad_keccak buckets the batch dim (empty-message pad rows) and the
+    # message-block dim; r/s/v follow the blocks tensor's bucket
+    bb = bucket_batch(max(bsz, 1))
+    mblocks = bucket_batch(max(map(len, payloads), default=0) // 136 + 1)
     step = _maybe_sharded_step(bb) if allow_shard else None
     op = "admission" if step is None else "admission_sharded"
-    with device_span(op, bsz, shape_key=(bb, blocks.shape[1])):
-        sigs65 = np.asarray(sigs65, dtype=np.uint8)
-        r = pad_rows(bytes_be_to_limbs(sigs65[:, :32]), bb)
-        s = pad_rows(bytes_be_to_limbs(sigs65[:, 32:64]), bb)
-        v = pad_rows(sigs65[:, 64].astype(np.int32), bb)
-        if step is None:
-            step = admission_step_packed
-        packed = np.asarray(step(blocks, nblocks, r, s, v))[:bsz]
-        return (
-            packed[:, :20],
-            packed[:, 20] != 0,
-            packed[:, 21:85],
-            packed[:, 85:117],
-        )
+    if step is None:
+        step = admission_step_packed
+    with device_span(op, bsz, shape_key=(bb, mblocks)) as sp:
+        with sp.phase("marshal"):
+            blocks, nblocks = pad_keccak(payloads)
+            sigs65 = np.asarray(sigs65, dtype=np.uint8)
+            r = pad_rows(bytes_be_to_limbs(sigs65[:, :32]), bb)
+            s = pad_rows(bytes_be_to_limbs(sigs65[:, 32:64]), bb)
+            v = pad_rows(sigs65[:, 64].astype(np.int32), bb)
+        with sp.phase("enqueue"):  # a shape's first call traces + compiles here
+            dev = step(blocks, nblocks, r, s, v)
+        with sp.phase("sync"):  # waits for the device, brings the result over
+            packed = np.asarray(dev)
+        with sp.phase("unpack"):
+            packed = packed[:bsz]
+            return (
+                packed[:, :20],
+                packed[:, 20] != 0,
+                packed[:, 21:85],
+                packed[:, 85:117],
+            )
 
 
 def _try_native(payloads, sigs65):

@@ -144,8 +144,9 @@ def device_lane(name: str):
 class PlaneRequest:
     """One queued batch: op key, op-specific payload, item count, lane.
     ``ctx`` is the submitting caller's trace context — the merged dispatch
-    span links back to it, and the caller's trace gets a retroactive
-    ``device.plane.wait`` span carrying the batch's span id."""
+    span links back to it, and the caller's trace gets a derived
+    ``device.plane.queue`` record (submit to dispatch) carrying the batch's
+    span id; the caller's own blocked time is ``device.plane.wait``."""
 
     op: str
     payload: object
@@ -506,18 +507,19 @@ class DevicePlane:
             for r in reqs:
                 if r.ctx is not None and r.ctx.sampled:
                     TRACER.record(
-                        "device.plane.wait",
+                        "device.plane.queue",
                         t0=r.t_enq,
                         dur=now - r.t_enq,
                         parent_ctx=r.ctx,
+                        derived=True,  # submit -> dispatch, seen from here
                         op=op,
                         lane=r.lane,
                         batch_span=f"{batch_ctx.span_id:016x}",
                     )
         from ..observability.device import (
-            DEVICE_PHASE_BUCKETS_MS,
             LEDGER,
             device_obs_enabled,
+            observe_phase,
         )
 
         # ledger attribution rides FISCO_DEVICE_OBS alone — it must keep
@@ -549,15 +551,7 @@ class DevicePlane:
                 lane=r.lane,
             )
             if obs:
-                REGISTRY.observe(
-                    "fisco_device_phase_ms",
-                    wait_ms,
-                    buckets=DEVICE_PHASE_BUCKETS_MS,
-                    help="device-plane time attribution per op: "
-                    "queue / compile / transfer / execute segments",
-                    op=op,
-                    phase="queue",
-                )
+                observe_phase(op, "queue", wait_ms)
         REGISTRY.counter_add(
             f'fisco_device_plane_dispatch_total{{op="{op}"}}',
             1.0,
@@ -668,10 +662,13 @@ def plane_wait(fut: Future):
     thread's ambient pipeline stage (``<stage> blocked_on=device_plane`` —
     the edge that says the admission/consensus/execute worker was parked
     behind the shared crypto engine, not doing its own work). Every crypto
-    seam that queues into the plane resolves its future through here."""
+    seam that queues into the plane resolves its future through here.
+    The ``device.plane.wait`` span is the caller's side of it, start to end
+    on the caller's thread: queue, coalescing window, dispatch and result."""
     from ..observability.pipeline import PIPELINE
+    from ..observability.tracer import TRACER
 
-    with PIPELINE.blocked("device_plane"):
+    with TRACER.span("device.plane.wait"), PIPELINE.blocked("device_plane"):
         return fut.result()
 
 
@@ -682,8 +679,9 @@ def plane_wait_deferred(fut: Future):
     blocked attribution — otherwise the sync (the expensive half: it waits
     for the device) would count as the caller's busy time."""
     from ..observability.pipeline import PIPELINE
+    from ..observability.tracer import TRACER
 
-    with PIPELINE.blocked("device_plane"):
+    with TRACER.span("device.plane.wait"), PIPELINE.blocked("device_plane"):
         return fut.result()()
 
 

@@ -18,7 +18,8 @@ Three pieces (ISSUE 1 tentpole):
 - :mod:`.device` — the device observatory (ISSUE 13 on top of the ISSUE 1
   signal bundle): per-op batch/latency/items metrics, the measured compile
   ledger (cold compile vs persistent-cache load via JAX's monitoring
-  hooks), queue/compile/transfer/execute phase attribution, device memory
+  hooks), measured phase attribution (marshal/enqueue/sync/unpack, the
+  ledger's compile, the plane's queue), device memory
   watermarks and the recompile-storm detector, served at ``GET /device``.
   Imported directly as ``from ..observability.device import device_span``
   by the ops wrappers (kept out of this namespace so importing the package

@@ -157,6 +157,7 @@ def note_sealed(tx_hashes, number: int) -> list[TraceContext]:
             t0=t_admit,
             dur=now - t_admit,
             parent_ctx=ctx,
+            derived=True,  # admitted -> sealed: a gap, nobody's work
             block=number,
         )
     return list(ctxs.values())

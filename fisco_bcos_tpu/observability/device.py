@@ -30,15 +30,21 @@ on; ``=0`` turns every one into a shared noop):
   ``(unattributed)``. This is what finally distinguishes the QC
   subsystem's hour-class BLS pairing cold compile from its ~50 ms
   persistent-cache load.
-- **Phase attribution**: every :class:`device_span` decomposes its wall
-  into compile (measured by the hooks), transfer (regions the wrapper
-  marks with ``span.phase("transfer")`` around host↔device staging) and
-  execute (the remainder: device run + result sync), emitted as
-  ``fisco_device_phase_ms{op,phase}`` on :data:`DEVICE_PHASE_BUCKETS_MS`
-  and recorded as retroactive child spans in the trace ring. The
-  DevicePlane adds the queue segment per dispatch (phase="queue", labeled
-  with the plane's dispatch op), so ``blocked_on=device_plane`` decomposes
-  one level deeper.
+- **Phase attribution**: a wrapper marks each real segment of its
+  :class:`device_span` with ``span.phase(name)`` (or :func:`device_phase`
+  from a helper that does not hold the span): ``marshal`` (padding, limb
+  split), ``enqueue`` (the jitted call returning; holds the jaxpr trace and
+  the compile on a shape's first call), ``sync`` (the ``np.asarray`` that
+  waits for the device and brings the result over), ``unpack``, and
+  ``transfer`` in the stand-alone verify wrappers. Every phase is measured
+  start to end on the clock: a live tracer span ``device.<op>.<phase>`` (so
+  it shows on a profiler capture too), ``fisco_device_phase_ms{op,phase}`` on
+  :data:`DEVICE_PHASE_BUCKETS_MS` and ``phase_ms`` in ``/device``. Beside
+  them stand ``compile`` (measured by the ledger's hooks; it lies inside
+  ``enqueue``) and the plane's ``queue`` segment per dispatch (labeled with
+  the plane's dispatch op), so ``blocked_on=device_plane`` decomposes one
+  level deeper. Nothing is a remainder and no timestamp is made up: time of
+  a span that no phase covers is in no phase.
 - **Memory watermarks**: :func:`device_memory_bytes` sums live-buffer
   bytes per jax device; :func:`install_observatory` registers it as the
   ``device_mem`` probe in the PR 9 watermark sampler, so per-device live
@@ -69,7 +75,7 @@ from ..utils import metrics as _metrics
 from ..utils.jaxenv import device_identity
 from ..utils.log import get_logger
 from .histogram import BATCH_BUCKETS, LATENCY_BUCKETS_MS
-from .tracer import TRACER
+from .tracer import TRACER, install_gc_spans
 
 _log = get_logger("device")
 
@@ -205,11 +211,12 @@ class CompileLedger:
             stack = self._tls.stack = []
         return stack
 
-    def push(self, op: str, shape_key, batch: int) -> dict:
+    def push(self, op: str, shape_key, batch: int, span=None) -> dict:
         frame = {
             "op": op,
             "shape": shape_key,
             "batch": int(batch),
+            "span": span,  # the device_span, for device_phase()
             "compile_ms": 0.0,
             "pending": None,  # cache verdict awaiting its backend_compile
             "pending_lowering_ms": 0.0,
@@ -599,9 +606,11 @@ def _memory_probe() -> dict[str, float]:
 
 
 def install_observatory() -> bool:
-    """Boot-time wiring: jax compile hooks + the ``device_mem`` watermark
-    probe (PR 9 sampler). Idempotent; refuses entirely under
-    ``FISCO_DEVICE_OBS=0``."""
+    """Boot-time wiring: the collector's pause spans (they follow the
+    tracer's switch, not this module's), then jax compile hooks + the
+    ``device_mem`` watermark probe (PR 9 sampler). Idempotent; the device
+    part refuses under ``FISCO_DEVICE_OBS=0``."""
+    install_gc_spans()
     if not device_obs_enabled():
         return False
     installed = install_jax_hooks()
@@ -699,21 +708,66 @@ _NOOP_PHASE = _NoopPhase()
 
 
 class _Phase:
-    __slots__ = ("_span", "_name", "_t0")
+    """One measured segment: a live tracer span ``device.<op>.<name>`` (real
+    timestamps, on a profiler capture too) whose duration feeds the phase
+    accounting — through its :class:`device_span` at that span's exit, or at
+    once where the segment runs after the span has closed (the resolver of a
+    dispatch-now/sync-later wrapper, on whichever thread resolves it)."""
 
-    def __init__(self, span: "device_span", name: str):
-        self._span = span
+    __slots__ = ("_op", "_name", "_span", "_trace", "_t0")
+
+    def __init__(self, op: str, name: str, span: "device_span | None" = None):
+        self._op = op
         self._name = name
+        self._span = span
 
     def __enter__(self):
+        self._trace = TRACER.span(f"device.{self._op}.{self._name}")
+        self._trace.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
-        self._span._phases.append(
-            (self._name, self._t0, time.perf_counter() - self._t0)
-        )
+        dur = time.perf_counter() - self._t0
+        self._trace.__exit__(*exc)
+        if self._span is not None:
+            self._span._phases.append((self._name, self._t0, dur))
+        else:
+            observe_phase(self._op, self._name, dur * 1e3)
+            LEDGER.note_phases(self._op, {self._name: dur * 1e3})
         return False
+
+
+def observe_phase(op: str, phase: str, ms: float) -> None:
+    """One ``fisco_device_phase_ms{op,phase}`` observation (the plane's
+    ``queue`` segment comes through here too)."""
+    reg = _metrics.REGISTRY
+    if reg.enabled and ms > 0.0:
+        reg.observe(
+            "fisco_device_phase_ms",
+            ms,
+            buckets=DEVICE_PHASE_BUCKETS_MS,
+            help="device-plane time attribution per op: measured marshal / "
+            "enqueue / sync / unpack / transfer segments, the ledger's "
+            "compile, the plane's queue",
+            op=op,
+            phase=phase,
+        )
+
+
+def device_phase(name: str, op: str | None = None):
+    """``span.phase(name)`` of the innermost :class:`device_span` open on
+    this thread, for helpers that run inside a wrapper's span without
+    holding it (``keccak256_batch_async`` under the hash-plane executor).
+    Outside any span: a segment of its own under ``op`` where one is given
+    (a deferred resolver's sync), else a no-op."""
+    stack = LEDGER._stack()
+    span = stack[-1]["span"] if stack else None
+    if span is not None:
+        return span.phase(name)
+    if op is not None and device_obs_enabled():
+        return _Phase(op, name)
+    return _NOOP_PHASE
 
 
 class device_span:
@@ -725,38 +779,31 @@ class device_span:
     whenever a caller skipped bucketing (ISSUE 13 satellite); wrappers
     with extra shape dims still pass their full key explicitly.
 
-    ``queue_ms`` lets a caller that measured an upstream queue wait itself
-    pre-load the queue segment (the DevicePlane does NOT use it — it
-    records its queue segment directly at dispatch under its own op label,
-    so passing queue_ms for plane-routed work would double-count);
-    ``with span.phase("transfer"): ...`` marks host↔device staging.
-    Compile time comes from the ledger's measured episodes during the
-    span; execute is the remainder.
+    ``with span.phase("marshal"): ...`` marks a real segment (module
+    docstring); compile time comes from the ledger's measured episodes
+    during the span. The plane records its queue segment itself at
+    dispatch, under its own op label.
     """
 
-    __slots__ = (
-        "op", "batch", "key", "queue_ms", "_t0", "_span", "_phases",
-        "_frame", "_obs_s",
-    )
+    __slots__ = ("op", "batch", "key", "_t0", "_span", "_phases", "_frame", "_obs_s")
 
-    def __init__(self, op: str, batch: int, shape_key=None,
-                 queue_ms: float | None = None):
+    def __init__(self, op: str, batch: int, shape_key=None):
         self.op = op
         self.batch = int(batch)
         self.key = (
             shape_key if shape_key is not None
             else bucket_batch(max(int(batch), 1))
         )
-        self.queue_ms = queue_ms
         self._phases: list[tuple[str, float, float]] = []
         self._frame: dict | None = None
         self._obs_s = 0.0  # this span's own observatory bookkeeping wall
 
     def phase(self, name: str):
-        """Mark a sub-segment (e.g. ``transfer``) of this span's wall."""
+        """Mark a real segment (``marshal``/``enqueue``/``sync``/``unpack``/
+        ``transfer``) of this span's wall."""
         if self._frame is None:
             return _NOOP_PHASE
-        return _Phase(self, name)
+        return _Phase(self.op, name, self)
 
     def __enter__(self):
         reg = _metrics.REGISTRY
@@ -771,7 +818,7 @@ class device_span:
             _count_shape(self.op, self.key)
         if device_obs_enabled():
             t_obs = time.perf_counter()
-            self._frame = LEDGER.push(self.op, self.key, self.batch)
+            self._frame = LEDGER.push(self.op, self.key, self.batch, span=self)
             self._obs_s += time.perf_counter() - t_obs
         else:
             self._frame = None
@@ -816,55 +863,15 @@ class device_span:
         return False
 
     def _emit_phases(self, dt: float) -> None:
-        total_ms = dt * 1e3
-        compile_ms = self._frame["compile_ms"]
-        # marked sub-segments aggregate under their OWN names (transfer is
-        # the common one, but a wrapper may mark others) — the histogram
-        # must agree with the trace child spans
-        marked: dict[str, float] = {}
+        """The measured segments under their own names, plus the ledger's
+        measured compile (part of ``enqueue`` where that is marked). The
+        trace already holds each phase as a live span."""
+        phases: dict[str, float] = {}
         for name, _t, d in self._phases:
-            marked[name] = marked.get(name, 0.0) + d * 1e3
-        execute_ms = max(
-            total_ms - compile_ms - sum(marked.values()), 0.0
-        )
-        phases = dict(
-            marked, compile=compile_ms, execute=execute_ms
-        )
-        if self.queue_ms is not None:
-            phases["queue"] = float(self.queue_ms)
-        reg = _metrics.REGISTRY
-        if reg.enabled:
-            for phase, ms in phases.items():
-                if ms > 0.0 or phase == "execute":
-                    reg.observe(
-                        "fisco_device_phase_ms",
-                        ms,
-                        buckets=DEVICE_PHASE_BUCKETS_MS,
-                        help="device-plane time attribution per op: "
-                        "queue / compile / transfer / execute segments",
-                        op=self.op,
-                        phase=phase,
-                    )
+            phases[name] = phases.get(name, 0.0) + d * 1e3
+        compile_ms = self._frame["compile_ms"]
+        if compile_ms > 0.0:
+            phases["compile"] = compile_ms
+        for phase, ms in phases.items():
+            observe_phase(self.op, phase, ms)
         LEDGER.note_phases(self.op, phases, t0=self._t0, dur=dt)
-        # retroactive trace children: the dispatch timeline readable in
-        # GET /trace (transfer segments keep their real timestamps; the
-        # compile/execute splits anchor at the span start)
-        ctx = getattr(self._span, "ctx", None)
-        if ctx is not None and ctx.sampled:
-            for name, t0, d in self._phases:
-                TRACER.record(
-                    f"device.{self.op}.{name}", t0=t0, dur=d, parent_ctx=ctx
-                )
-            if compile_ms > 0.0:
-                TRACER.record(
-                    f"device.{self.op}.compile",
-                    t0=self._t0,
-                    dur=compile_ms / 1e3,
-                    parent_ctx=ctx,
-                )
-            TRACER.record(
-                f"device.{self.op}.execute",
-                t0=self._t0 + (compile_ms + sum(marked.values())) / 1e3,
-                dur=execute_ms / 1e3,
-                parent_ctx=ctx,
-            )

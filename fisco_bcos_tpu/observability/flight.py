@@ -35,6 +35,10 @@ from .roundlog import fleet_obs_enabled
 _log = get_logger("flight")
 
 FLIGHT_CAP = 512
+# ``slow_span`` witnesses (observability/tracer.py) are kept apart from the
+# last-events ring: a stall early in a long run must still be in the dump
+# at ``Node.stop``, thousands of 2PC and round events later
+WITNESS_CAP = 64
 
 
 def flight_dir() -> str:
@@ -60,14 +64,19 @@ class FlightRecorder:
         # (t_mono, scope, category, name, detail) — appended without a lock
         # (GIL-atomic deque.append); maxlen gives the bounded ring
         self._ring: deque[tuple] = deque(maxlen=cap)
+        self._witnesses: deque[tuple] = deque(maxlen=WITNESS_CAP)
         self._flush_lock = threading.Lock()
 
     def record(self, category: str, name: str, scope: str = "", **detail) -> None:
         if not self.enabled:
             return
-        self._ring.append((self.clock(), scope, category, name, detail))
+        ring = self._witnesses if category == "slow_span" else self._ring
+        ring.append((self.clock(), scope, category, name, detail))
 
     def snapshot(self) -> list[dict]:
+        events = sorted(
+            list(self._witnesses) + list(self._ring), key=lambda ev: ev[0]
+        )
         return [
             {
                 "t": t,
@@ -76,7 +85,7 @@ class FlightRecorder:
                 "name": name,
                 "detail": detail,
             }
-            for (t, scope, category, name, detail) in list(self._ring)
+            for (t, scope, category, name, detail) in events
         ]
 
     def flush(

@@ -32,7 +32,19 @@ Trace model (ISSUE 4 tentpole):
 
 Completed spans from other timelines (e.g. PBFT phase gaps measured between
 message arrivals) are added retroactively via :meth:`Tracer.record`, with
-an explicit ``parent_ctx`` placing them in the right trace.
+an explicit ``parent_ctx`` placing them in the right trace. A record whose
+interval is a gap between two events, and not the lifetime of work on the
+thread that wrote it, is passed ``derived=True``: it overlaps the real spans
+of whatever ran meanwhile, so a reader that sums time leaves it out.
+
+One clock with the device trace: a live span enters a
+``jax.profiler.TraceAnnotation`` of its own name (``TraceMe`` is inactive
+outside a profiler session: one object per span), so any profiler capture
+of the process shows the node's spans on the host lines beside the device's
+programs. A process that never imported JAX skips it.
+
+A span that ran longer than :data:`SLOW_SPAN_S` hands the flight recorder one
+``slow_span`` event naming what else the ring saw during its interval.
 """
 
 from __future__ import annotations
@@ -41,10 +53,12 @@ import contextvars
 import json
 import os
 import random
+import sys
 import threading
 import time
+import types
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 # the current trace context: None outside any span. Survives everything
 # that runs on the same thread/context; worker threads start empty and are
@@ -106,7 +120,20 @@ def trace_hex(ctx: TraceContext | None) -> str | None:
     return f"{ctx.trace_id:032x}" if ctx is not None and ctx.sampled else None
 
 
-@dataclass
+# a span that ran longer than this leaves a ``slow_span`` flight event,
+# which names at most this many (span name, thread) witnesses
+SLOW_SPAN_S = 1.0
+SLOW_SPAN_WITNESSES = 32
+# the ring holds a benchmark window with a margin of two (PERF.md §6, PR 24):
+# the busiest cell writes ~26k records in 51 s; ~0.35 KB a record
+DEFAULT_CAPACITY = 65536
+
+# the attrs of a record that has none: one shared read-only mapping, so a
+# ring of tens of thousands of records does not hold as many empty dicts
+_NO_ATTRS: types.MappingProxyType = types.MappingProxyType({})
+
+
+@dataclass(slots=True)
 class SpanRecord:
     name: str
     ts: float  # perf_counter at span start (seconds)
@@ -114,11 +141,33 @@ class SpanRecord:
     tid: int
     depth: int = 0
     parent: str | None = None  # display label only; parent_id is the truth
-    attrs: dict = field(default_factory=dict)
+    attrs: "dict | types.MappingProxyType" = _NO_ATTRS
     trace_id: int = 0
     span_id: int = 0
     parent_id: int | None = None
     links: tuple = ()  # ((trace_id, span_id), ...)
+    derived: bool = False  # a gap between events, not work on this thread
+
+
+_ANNOTATION = None  # jax.profiler.TraceAnnotation once JAX is in the process
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, or None while this process has not
+    imported JAX (the tracer never drags it in) or has one without it."""
+    global _ANNOTATION
+    # analysis: allow(atomicity, racing first spans import the same class
+    # object — there is no second instance to hold)
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:
+            return None
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _ANNOTATION = TraceAnnotation
+        except Exception:  # a partial or stripped JAX: spans work without
+            _ANNOTATION = False
+    return _ANNOTATION or None
 
 
 class _NoopSpan:
@@ -141,6 +190,12 @@ class _NoopSpan:
     def set(self, **kv) -> "_NoopSpan":
         return self
 
+    def link(self, ctxs) -> None:
+        pass
+
+    def discard(self) -> None:
+        pass
+
     def __enter__(self):
         return self
 
@@ -154,7 +209,7 @@ _NOOP = _NoopSpan()
 class _Span:
     __slots__ = (
         "_tracer", "name", "attrs", "_t0", "depth", "parent",
-        "ctx", "_parent_ctx", "links", "_token",
+        "ctx", "_parent_ctx", "links", "_token", "_annotation", "_discard",
     )
 
     def __init__(
@@ -170,6 +225,17 @@ class _Span:
         self.attrs = attrs
         self._parent_ctx = parent_ctx
         self.links = tuple(links)
+        self._discard = False
+
+    def link(self, ctxs) -> None:
+        """Add links found while the span was open (the seal span learns
+        which admission spans it absorbed only once it has fetched)."""
+        self.links += tuple(ctxs)
+
+    def discard(self) -> None:
+        """Leave no record: the work this span was opened for did not
+        happen (a sealer tick that found nothing to seal)."""
+        self._discard = True
 
     def set(self, **kv) -> "_Span":
         """Attach attributes (the only supported mutation API — item
@@ -196,12 +262,22 @@ class _Span:
         self.parent = pctx.name or None if pctx is not None else None
         self.depth = self.ctx.depth
         self._token = _CURRENT.set(self.ctx)
+        annotation = _trace_annotation()
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
+        else:
+            self._annotation = None
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         _CURRENT.reset(self._token)
+        if self._discard:
+            return False
         self._tracer.record(
             self.name,
             t0=self._t0,
@@ -221,7 +297,7 @@ class Tracer:
 
     def __init__(
         self,
-        capacity: int = 8192,
+        capacity: int = DEFAULT_CAPACITY,
         enabled: bool = True,
         sample_rate: float | None = None,
     ):
@@ -239,6 +315,11 @@ class Tracer:
         # drop accounting: plain ints (GIL-cheap on the hot path), mirrored
         # into the metrics registry lazily (flush_drop_metrics)
         self._dropped = {"sampled": 0, "ring_evict": 0}
+        # tid -> thread name, noted at a thread's first record: a witness
+        # has often exited by the time a slow span names it
+        self._thread_names: dict[int, str] = {}
+        # the process tracer alone takes in the collector's pauses (below)
+        self._takes_gc = False
         self._dropped_pushed = {"sampled": 0, "ring_evict": 0}
         # wall-clock anchor: rec.ts (perf_counter) + epoch ≈ time.time() at
         # span start — what cross-process stitching orders by
@@ -324,12 +405,24 @@ class Tracer:
                 if delta > 0:
                     self._dropped_pushed[reason] = n
                     deltas.append((reason, delta))
+            gc_deltas = _claim_gc_deltas() if self._takes_gc else ()
         for reason, delta in deltas:
             REGISTRY.counter_add(
                 f'fisco_trace_spans_dropped_total{{reason="{reason}"}}',
                 float(delta),
                 help="spans not recorded, by reason (sampled = head "
                 "sampling, ring_evict = ring overwrote them)",
+            )
+        for gen, n, secs in gc_deltas:
+            REGISTRY.counter_add(
+                f'fisco_gc_collections_total{{gen="{gen}"}}',
+                float(n),
+                help="cyclic-collector passes by generation",
+            )
+            REGISTRY.counter_add(
+                f'fisco_gc_pause_seconds_total{{gen="{gen}"}}',
+                secs,
+                help="seconds the cyclic collector held the process, by generation",
             )
 
     # -- span creation --------------------------------------------------------
@@ -373,11 +466,14 @@ class Tracer:
         ctx: TraceContext | None = None,
         parent_ctx: TraceContext | None = None,
         links: tuple = (),
+        derived: bool = False,
         **attrs,
     ) -> TraceContext | None:
         """Append a COMPLETED span with explicit timing — the retroactive
         path for phase gaps measured between events (PBFT quorum waits,
-        pool-wait). ``parent_ctx`` places it in a trace; without one the
+        pool-wait: those pass ``derived=True``) and for intervals measured
+        start to end by the caller (collector pauses, a DAG batch).
+        ``parent_ctx`` places it in a trace; without one the
         ambient context applies, else it becomes a sampled-on-its-own root.
         Returns the recorded span's context (None when dropped)."""
         if not self.enabled:
@@ -402,6 +498,10 @@ class Tracer:
             return None
         if parent is None and parent_ctx is not None:
             parent = parent_ctx.name or None
+        tid = getattr(self._tls, "tid", None)
+        if tid is None:  # this thread's first record (idents are reused)
+            tid = self._tls.tid = threading.get_ident()
+            self._thread_names[tid] = threading.current_thread().name
         if not depth:
             depth = ctx.depth
         if links:
@@ -413,27 +513,104 @@ class Tracer:
             name,
             t0,
             max(dur, 0.0),
-            threading.get_ident(),
+            tid,
             depth,
             parent,
-            attrs,
+            attrs or _NO_ATTRS,
             trace_id=ctx.trace_id,
             span_id=ctx.span_id,
             parent_id=parent_ctx.span_id if parent_ctx is not None else None,
             links=links,
+            derived=derived,
         )
+        if _GC_PENDING and self._takes_gc:
+            self._drain_gc()
+        self._append(rec)
+        return ctx
+
+    def _append(self, rec: SpanRecord) -> None:
         if self.capacity <= 0:
             # FISCO_TRACE_CAPACITY=0: keep nothing, count everything
             self._drop("ring_evict")
-            return ctx
+            return
         with self._lock:
             if len(self._buf) >= self.capacity:
                 self._buf.popleft()
                 self._dropped["ring_evict"] += 1
             self._buf.append(rec)
-        return ctx
+        if rec.dur >= SLOW_SPAN_S and not rec.derived:
+            self._note_slow(rec)
+
+    def _drain_gc(self) -> None:
+        """Move the collector pauses noted by :func:`_on_gc` into the ring.
+        Runs at a safe point (a record or a read), never in the collector's
+        callback: a collection can start between two bytecodes of a thread
+        that holds the ring's lock."""
+        while True:
+            try:
+                gen, t0, dur, tid, collected = _GC_PENDING.popleft()
+            except IndexError:
+                return
+            name = f"gc.gen{gen}"
+            ctx = self._new_root(name)
+            if not ctx.sampled:
+                self._drop("sampled")
+                continue
+            self._append(
+                SpanRecord(
+                    name, t0, dur, tid, attrs={"collected": collected},
+                    trace_id=ctx.trace_id, span_id=ctx.span_id,
+                )
+            )
+
+    def _note_slow(self, rec: SpanRecord) -> None:
+        """One ``slow_span`` flight event: the slow span, and for its
+        interval the time by span name and thread of every other measured
+        record in the ring that overlaps it (collector pauses included) —
+        what a watchdog dump would have shown, taken after the fact from
+        data the process already holds. Nested spans each count their own
+        whole overlap (inclusive time, as a profile gives it)."""
+        try:
+            from .flight import FLIGHT
+
+            if not FLIGHT.enabled:
+                return
+            lo, hi = rec.ts, rec.ts + rec.dur
+            by: dict[tuple[str, int], list] = {}
+            for r in self.spans():
+                if r is rec or r.derived:
+                    continue
+                overlap = min(hi, r.ts + r.dur) - max(lo, r.ts)
+                if overlap > 0.0:
+                    slot = by.setdefault((r.name, r.tid), [0.0, 0])
+                    slot[0] += overlap
+                    slot[1] += 1
+            threads = self._thread_names
+            top = sorted(by.items(), key=lambda kv: -kv[1][0])[:SLOW_SPAN_WITNESSES]
+            FLIGHT.record(
+                "slow_span",
+                rec.name,
+                dur_ms=round(rec.dur * 1e3, 3),
+                t0=rec.ts,
+                thread=threads.get(rec.tid, str(rec.tid)),
+                overlaps=[
+                    {
+                        "name": name,
+                        "thread": threads.get(tid, str(tid)),
+                        "ms": round(secs * 1e3, 3),
+                        "n": n,
+                    }
+                    for (name, tid), (secs, n) in top
+                ],
+            )
+        except Exception as e:  # the witness must never break the span
+            from ..utils.log import note_swallowed
+
+            note_swallowed("tracer.slow_span", e)
 
     def spans(self) -> list[SpanRecord]:
+        if _GC_PENDING and self._takes_gc:
+            self._drain_gc()
         with self._lock:
             return list(self._buf)
 
@@ -453,6 +630,8 @@ class Tracer:
         events = []
         for rec in self.spans():
             args = {k: v for k, v in rec.attrs.items()}
+            if rec.derived:
+                args["derived"] = True
             if rec.parent is not None:
                 args["parent"] = rec.parent
             args["trace_id"] = f"{rec.trace_id:032x}"
@@ -496,6 +675,62 @@ class Tracer:
         return json.dumps(self.export_chrome(), default=str)
 
 
+# -- collector pauses ---------------------------------------------------------
+#
+# The cyclic collector stops every thread of the process while it runs. Its
+# pauses are measured start to end by a ``gc.callbacks`` hook and land in the
+# ring as ``gc.gen<n>`` records on the thread that triggered the collection.
+# The hook itself takes no lock (a collection may start inside a thread that
+# holds the ring's or the registry's): it reads the clock, bumps plain
+# tallies and appends to a deque; the ring and ``/metrics`` pick both up at
+# their next safe point (``Tracer._drain_gc``, ``flush_drop_metrics``).
+
+GC_GEN0_RECORD_S = 1e-3  # a generation-0 pass leaves a record only over this
+_GC_PENDING: deque = deque()  # (gen, t0, dur, tid, collected)
+_GC_TOTALS = {gen: [0, 0.0] for gen in range(3)}  # gen -> [collections, seconds]
+_GC_PUSHED = {gen: [0, 0.0] for gen in range(3)}
+_gc_t0 = 0.0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_t0
+    if not TRACER.enabled:
+        return
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    dur = time.perf_counter() - _gc_t0
+    gen = info["generation"]
+    tally = _GC_TOTALS[gen]
+    tally[0] += 1
+    tally[1] += dur
+    if gen or dur > GC_GEN0_RECORD_S:
+        _GC_PENDING.append(
+            (gen, _gc_t0, dur, threading.get_ident(), info["collected"])
+        )
+
+
+def _claim_gc_deltas() -> list[tuple[int, int, float]]:
+    """(gen, collections, seconds) not yet pushed to the registry; the caller
+    holds the ring's lock, so two scrapes never claim the same delta."""
+    out = []
+    for gen, (n, secs) in _GC_TOTALS.items():
+        pushed = _GC_PUSHED[gen]
+        if n > pushed[0]:
+            out.append((gen, n - pushed[0], secs - pushed[1]))
+            pushed[0], pushed[1] = n, secs
+    return out
+
+
+def install_gc_spans() -> None:
+    """Hook the collector (idempotent; process-wide). Follows the tracer's
+    switch: with telemetry off the hook returns at once."""
+    import gc
+
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
 class _Attach:
     __slots__ = ("_ctx", "_token")
 
@@ -515,6 +750,7 @@ class _Attach:
 # process-wide default tracer (modules import and use directly, like
 # utils.metrics.REGISTRY); FISCO_TELEMETRY=0 starts it disabled
 TRACER = Tracer(
-    capacity=int(os.environ.get("FISCO_TRACE_CAPACITY", "8192")),
+    capacity=int(os.environ.get("FISCO_TRACE_CAPACITY", str(DEFAULT_CAPACITY))),
     enabled=os.environ.get("FISCO_TELEMETRY", "1") != "0",
 )
+TRACER._takes_gc = True

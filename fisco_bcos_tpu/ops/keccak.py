@@ -190,13 +190,28 @@ def keccak256_batch_async(msgs):
     """Dispatch the device batch and defer the sync: returns a resolver
     () -> [B, 32] uint8. Lets callers queue several hash programs (tx
     root, receipts root, state root) before paying any device round
-    trip."""
+    trip. The segments are marked on the enclosing device_span (the
+    wrapper above, or the hash-plane executor's); a resolver called after
+    that span has closed, on the caller's thread inside its
+    ``device.plane.wait``, marks its sync and unpack as segments of their
+    own."""
+    from ..observability.device import device_phase
+
     n = len(msgs)
-    blocks, nblocks = pad_keccak(msgs)  # batch dim bucketed; slice below
-    words = keccak256_blocks(jnp.asarray(blocks), jnp.asarray(nblocks))
-    # analysis: allow(host-sync, deferred resolver — the sync happens when
-    # the caller RESOLVES the plane future, not at dispatch)
-    return lambda: digest_words_to_bytes_le(np.asarray(words))[:n]
+    with device_phase("marshal"):
+        blocks, nblocks = pad_keccak(msgs)  # batch dim bucketed; slice below
+    with device_phase("enqueue"):
+        words = keccak256_blocks(jnp.asarray(blocks), jnp.asarray(nblocks))
+
+    def resolve():
+        with device_phase("sync", op="keccak256"):
+            # analysis: allow(host-sync, deferred resolver — the sync happens
+            # when the caller RESOLVES the plane future, not at dispatch)
+            host = np.asarray(words)
+        with device_phase("unpack", op="keccak256"):
+            return digest_words_to_bytes_le(host)[:n]
+
+    return resolve
 
 
 # -- progaudit shape spec (analysis/progaudit: canonical audited bucket) -----

@@ -354,7 +354,7 @@ def merkle_root_async(
     resolve on call (letting the sealing path queue tx root, receipts root
     and state root before paying any device round trip); proofs, small
     trees and other hashers compute eagerly inside this call."""
-    from ..observability.device import device_span
+    from ..observability.device import device_phase, device_span
 
     if not isinstance(leaves, jax.Array):
         leaves = np.asarray(leaves, dtype=np.uint8)
@@ -365,11 +365,12 @@ def merkle_root_async(
         raise ValueError("width must be >= 2")
     # the span lives HERE (not in the merkle_root sync wrapper) so the
     # sealing path's suite.merkle_root_async calls are attributed too; it
-    # covers the dispatch only — the resolver's sync is the caller's wait,
-    # same contract as the hash-plane executor
+    # covers the dispatch only — the resolver's sync is the caller's wait
+    # and a segment of its own (``device.merkle_root.sync``): this program
+    # is dispatched on the caller's thread and not through the plane
     n = len(leaves)
     key = (hasher, width, bucket_leaves(max(n, 1)))
-    with device_span("merkle_root", n, shape_key=key):
+    with device_span("merkle_root", n, shape_key=key) as sp:
         if (
             hasher == "keccak256"
             and len(leaves) >= 256
@@ -382,11 +383,18 @@ def merkle_root_async(
             # program's input shape (and hence its compilation) is shared
             # by every block size in the bucket.
             b = bucket_leaves(n)
-            arr = jnp.asarray(leaves).astype(jnp.uint8)
-            if b > n:
-                arr = jnp.concatenate([arr, jnp.zeros((b - n, 32), jnp.uint8)])
-            dev = _device_root_fn(b, width)(arr)
-            return lambda: bind_root(bytes(np.asarray(dev)), n, hasher)
+            with sp.phase("marshal"):
+                arr = jnp.asarray(leaves).astype(jnp.uint8)
+                if b > n:
+                    arr = jnp.concatenate([arr, jnp.zeros((b - n, 32), jnp.uint8)])
+            with sp.phase("enqueue"):
+                dev = _device_root_fn(b, width)(arr)
+
+            def resolve() -> bytes:
+                with device_phase("sync", op="merkle_root"):
+                    return bind_root(bytes(np.asarray(dev)), n, hasher)
+
+            return resolve
         root = MerkleTree(
             np.asarray(leaves, dtype=np.uint8), width=width, hasher=hasher
         ).root

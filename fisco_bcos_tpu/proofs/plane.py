@@ -430,26 +430,31 @@ class ProofPlane:
             if not txs:
                 return
             t0 = time.perf_counter()
-            tx_hashes = block.tx_hashes(self.suite)
-            block_hash = block.header.hash(self.suite)
-            ents = {
-                (number, KIND_TX): self._freeze(
-                    tx_hashes, tx_hashes, block_hash, KIND_TX, "commit"
-                )
-            }
-            if len(block.receipts) == len(txs):
-                rc_hashes = [rc.hash(self.suite) for rc in block.receipts]
-                ents[(number, KIND_RECEIPT)] = self._freeze(
-                    tx_hashes, rc_hashes, block_hash, KIND_RECEIPT, "commit"
-                )
-            with self._lock:
-                for key, ent in ents.items():
-                    self._insert_locked(key, ent)
-                    self.builds_commit += 1
-                for h in tx_hashes:  # warm the locator for the new head
-                    self._hash2num[h] = number
-                while len(self._hash2num) > self._hash2num_cap:
-                    self._hash2num.popitem(last=False)
+            # everything this listener does is under one span: a reader
+            # tells the background build from the block path by its thread
+            with TRACER.span(
+                "proof.build", block=number, kind="both", source="commit"
+            ):
+                tx_hashes = block.tx_hashes(self.suite)
+                block_hash = block.header.hash(self.suite)
+                ents = {
+                    (number, KIND_TX): self._freeze(
+                        tx_hashes, tx_hashes, block_hash, KIND_TX, "commit"
+                    )
+                }
+                if len(block.receipts) == len(txs):
+                    rc_hashes = [rc.hash(self.suite) for rc in block.receipts]
+                    ents[(number, KIND_RECEIPT)] = self._freeze(
+                        tx_hashes, rc_hashes, block_hash, KIND_RECEIPT, "commit"
+                    )
+                with self._lock:
+                    for key, ent in ents.items():
+                        self._insert_locked(key, ent)
+                        self.builds_commit += 1
+                    for h in tx_hashes:  # warm the locator for the new head
+                        self._hash2num[h] = number
+                    while len(self._hash2num) > self._hash2num_cap:
+                        self._hash2num.popitem(last=False)
             if REGISTRY.enabled:
                 REGISTRY.observe(
                     "fisco_proof_build_latency_ms",

@@ -200,6 +200,16 @@ class Scheduler:
                 self._commit_done.wait(min(remaining, 0.5))
         return True
 
+    def drain_notifications(self, timeout: float = 30.0) -> bool:
+        """Block until every commit notification posted so far has been
+        delivered (the proof plane's commit-time tree build rides that
+        worker, beside the next block). ``drain_commits`` does not wait for
+        it, on purpose: listeners are off the block path. Returns False on
+        timeout."""
+        done = threading.Event()
+        self._notify.post(done.set)  # one FIFO worker: a barrier
+        return done.wait(timeout)
+
     def staged_state(self, number: int):
         """Post-state overlay of a block whose commit has not landed yet —
         lets the engine read block-derived state (committee membership)

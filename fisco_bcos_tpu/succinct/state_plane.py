@@ -491,6 +491,10 @@ class StatePlane:
         """Commit landed: the height's preview becomes the new base and a
         served height. Runs on the commit path (cheap: dict swaps) — must
         never throw into it."""
+        with TRACER.span("succinct.promote", block=number):
+            self._promote(number, block_hash)
+
+    def _promote(self, number: int, block_hash: bytes) -> None:
         try:
             with self._lock:
                 snap = self._previews.pop(number, None)

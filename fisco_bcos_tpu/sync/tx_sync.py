@@ -13,6 +13,7 @@ from enum import IntEnum
 
 from ..codec.flat import FlatReader, FlatWriter
 from ..front.front import FrontService, ModuleID
+from ..observability import TRACER
 from ..protocol.transaction import Transaction
 from ..txpool import TxPool
 from ..utils.log import get_logger, note_swallowed
@@ -57,19 +58,23 @@ class TransactionSync:
     def maintain(self) -> None:
         """Broadcast txs not yet gossiped (called on a timer / after RPC
         submissions)."""
-        to_send: list[bytes] = []
-        with self._lock:
-            with self.txpool._lock:
-                items = list(self.txpool._txs.items())
-            for h, tx in items:
-                if h not in self._broadcasted:
-                    self._broadcasted.add(h)
-                    to_send.append(tx.encode())
-            # forget hashes that already left the pool
-            if len(self._broadcasted) > 4 * max(1, len(items)):
-                live = {h for h, _ in items}
-                self._broadcasted &= live
-        if to_send:
+        with TRACER.span("txsync.maintain") as sp:
+            to_send: list[bytes] = []
+            with self._lock:
+                with self.txpool._lock:
+                    items = list(self.txpool._txs.items())
+                for h, tx in items:
+                    if h not in self._broadcasted:
+                        self._broadcasted.add(h)
+                        to_send.append(tx.encode())
+                # forget hashes that already left the pool
+                if len(self._broadcasted) > 4 * max(1, len(items)):
+                    live = {h for h, _ in items}
+                    self._broadcasted &= live
+            if not to_send:
+                sp.discard()  # an idle timer tick leaves no record
+                return
+            sp.set(txs=len(to_send))
             self.front.broadcast(
                 ModuleID.TXS_SYNC, _encode_txs(TxsPacket.PUSH, to_send)
             )

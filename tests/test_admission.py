@@ -114,3 +114,36 @@ def test_graft_entry_multichip():
     import __graft_entry__ as ge
 
     ge.dryrun_multichip(8)
+
+
+def test_device_leg_phases_are_measured_and_tile_the_span(monkeypatch):
+    """The admission call's phases are ``marshal`` / ``enqueue`` / ``sync`` /
+    ``unpack``, live spans with timestamps read from the clock, in that
+    order, that tile the ``device.admission`` span to within 5 %; ``/device``
+    ``phase_ms`` holds only them (and the ledger's compile on a first call):
+    no remainder, no ``execute``."""
+    from fisco_bcos_tpu.observability import TRACER
+    from fisco_bcos_tpu.observability.device import LEDGER
+
+    monkeypatch.setenv("FISCO_FORCE_DEVICE_ADMISSION", "1")
+    payloads = [b"phase-%d " % i + b"q" * (i * 11 % 90) for i in range(6)]
+    sigs, _ = _signed(payloads)
+    admission.admit_batch(payloads, sigs)  # the shape's first call may compile
+    mark = len(TRACER.spans())
+    _addr, ok, _pubs, _hashes = admission.admit_batch(payloads, sigs)
+    assert ok.all()
+    mine = [r for r in TRACER.spans()[mark:] if r.name.startswith("device.admission")]
+    by_name = {r.name: r for r in mine}
+    order = ["marshal", "enqueue", "sync", "unpack"]
+    assert set(by_name) == {"device.admission"} | {f"device.admission.{p}" for p in order}
+    span = by_name["device.admission"]
+    phases = [by_name[f"device.admission.{p}"] for p in order]
+    cursor = span.ts
+    for ph in phases:
+        assert ph.parent_id == span.span_id and not ph.derived
+        assert cursor <= ph.ts <= ph.ts + ph.dur <= span.ts + span.dur
+        cursor = ph.ts + ph.dur
+    assert sum(ph.dur for ph in phases) >= 0.95 * span.dur
+    # the plane's dispatch op is "admission" too: its queue segment sits beside
+    totals = LEDGER.phase_totals()["admission"]
+    assert set(order) <= set(totals) <= set(order) | {"compile", "queue"}

@@ -46,7 +46,7 @@ def test_ledger_cold_vs_cache_attribution_with_injected_hook():
     led.note_duration("backend_compile_duration", 3.25)
     frame = led.pop()
     # the span-side accumulator saw compile + lowering (what device_span
-    # subtracts from its execute remainder)
+    # reports as its ``compile`` phase)
     assert frame["compile_ms"] == 3252.0
 
     led.push("qc_pairing", (64, "g2"), 64)
@@ -134,37 +134,58 @@ def test_real_jax_compile_lands_in_ledger():
 
 
 def test_phase_histogram_shape_and_op_phase_labels():
+    """Only measured phases: the marked segments under their own names and
+    the ledger's compile. Time of the span that no phase covers is in no
+    phase — nothing is a remainder."""
     op = "phase_shape_test_op"
-    with device_span(op, 16, queue_ms=1.25) as sp:
-        with sp.phase("transfer"):
+    with device_span(op, 16) as sp:
+        with sp.phase("marshal"):
             time.sleep(0.002)
-        LEDGER.note_event("cache_miss")
-        LEDGER.note_duration("backend_compile_duration", 0.004)
+        with sp.phase("enqueue"):
+            LEDGER.note_event("cache_miss")
+            LEDGER.note_duration("backend_compile_duration", 0.004)
+        time.sleep(0.003)  # unmarked: must appear under no phase
+        with sp.phase("sync"):
+            time.sleep(0.001)
     h = REGISTRY.histogram("fisco_device_phase_ms")
     assert h.buckets == tuple(sorted(DEVICE_PHASE_BUCKETS_MS))
-    labels = set(h.snapshot())
-    for phase in ("queue", "compile", "transfer", "execute"):
-        key = (("op", op), ("phase", phase))
-        assert key in labels, (phase, sorted(labels))
+    labels = {key for key in h.snapshot() if ("op", op) in key}
+    assert labels == {
+        (("op", op), ("phase", phase))
+        for phase in ("marshal", "enqueue", "sync", "compile")
+    }
     totals = LEDGER.phase_totals()[op]
-    assert totals["queue"] == 1.25
+    assert set(totals) == {"marshal", "enqueue", "sync", "compile"}
     assert totals["compile"] == 4.0
-    assert totals["transfer"] >= 1.0
-    # execute is the remainder; the injected 4 ms compile exceeds the
-    # actual wall so it clamps to >= 0 instead of going negative
-    assert totals.get("execute", 0.0) >= 0.0
+    assert totals["marshal"] >= 2.0 and totals["sync"] >= 1.0
+    (_op, _t0, dur, _ph) = [d for d in LEDGER.dispatches() if d[0] == op][-1]
+    assert totals["marshal"] + totals["enqueue"] + totals["sync"] <= dur * 1e3 - 3.0
 
 
 def test_phase_child_spans_reach_the_trace_ring():
+    """Each phase is a live child span of ``device.<op>`` with timestamps
+    read from the clock: inside its parent, in the order it ran."""
     from fisco_bcos_tpu.observability import TRACER
+    from fisco_bcos_tpu.observability.device import device_phase
 
     op = "phase_trace_test_op"
     with device_span(op, 4) as sp:
-        with sp.phase("transfer"):
-            pass
-    names = {s.name for s in TRACER.spans()}
-    assert f"device.{op}.transfer" in names
-    assert f"device.{op}.execute" in names
+        with sp.phase("marshal"):
+            time.sleep(0.001)
+        with device_phase("enqueue"):  # a helper that does not hold the span
+            time.sleep(0.001)
+    with device_phase("sync", op=op):  # a deferred resolver, span closed
+        time.sleep(0.001)
+    mine = {s.name: s for s in TRACER.spans() if s.name.startswith(f"device.{op}")}
+    assert set(mine) == {f"device.{op}", f"device.{op}.marshal",
+                         f"device.{op}.enqueue", f"device.{op}.sync"}
+    parent = mine[f"device.{op}"]
+    marshal, enqueue, sync = (mine[f"device.{op}.{p}"] for p in ("marshal", "enqueue", "sync"))
+    assert marshal.parent_id == enqueue.parent_id == parent.span_id
+    assert parent.ts <= marshal.ts < marshal.ts + marshal.dur <= enqueue.ts
+    assert enqueue.ts + enqueue.dur <= parent.ts + parent.dur <= sync.ts
+    assert not any(s.derived for s in mine.values())
+    assert LEDGER.phase_totals()[op]["sync"] >= 1.0
 
 
 def test_plane_dispatch_emits_queue_phase():

@@ -289,6 +289,11 @@ def live_chain():
         leader = leader_of(nodes, height)
         submit_txs(leader, 3, start=height * 10)
         assert leader.sealer.seal_and_submit()
+    # the commit-notify worker freezes each head's trees in the background:
+    # the chain is ready for a test once its notifications have been delivered
+    for nd in nodes:
+        assert nd.scheduler.drain_commits(30.0)
+        assert nd.scheduler.drain_notifications(30.0)
     return nodes, gw
 
 

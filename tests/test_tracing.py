@@ -384,14 +384,17 @@ def test_device_plane_merged_batch_links_concurrent_callers():
     assert {caller_ctx[0].span_id, caller_ctx[1].span_id} <= linked
     # the batch span lives in the FIRST absorbed caller's trace
     assert d.trace_id in {caller_ctx[0].trace_id, caller_ctx[1].trace_id}
-    # ...and each caller's trace records its wait, naming the batch span
+    # ...and each caller's trace records its queue wait (submit to
+    # dispatch, written from the worker: a derived record), naming the
+    # batch span
     for i in range(2):
         wait = next(
             r
             for r in TRACER.spans()
-            if r.name == "device.plane.wait"
+            if r.name == "device.plane.queue"
             and r.trace_id == caller_ctx[i].trace_id
         )
+        assert wait.derived
         assert wait.parent_id == caller_ctx[i].span_id
         assert wait.attrs["batch_span"] == f"{d.span_id:016x}"
 
@@ -516,3 +519,146 @@ def test_tx_lifecycle_trace_over_pro_split():
         rpc.stop()
         facade.stop()
         storage_svc.stop()
+
+
+# ---------------------------------------------------------------------------
+# the tracer as the measurement (ISSUE 24): one clock with the profiler,
+# collector pauses, slow-span witnesses, a ring that holds a window
+# ---------------------------------------------------------------------------
+
+
+def test_span_is_on_a_host_line_of_a_profiler_capture(tmp_path):
+    """A span opened under ``jax.profiler.start_trace`` is found by name on a
+    host line of the ``.xplane.pb``, its duration within 1 ms of the ring's:
+    the node's spans and the device's programs share one capture."""
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with TRACER.span("xplane.probe_outer"):
+            with TRACER.span("xplane.probe_inner"):
+                time.sleep(0.02)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("xplane.probe_"):
+                    seen[ev.name] = (plane.name, ev.duration_ns / 1e9)
+    ring = {r.name: r.dur for r in TRACER.spans() if r.name.startswith("xplane.probe_")}
+    assert set(seen) == set(ring) == {"xplane.probe_outer", "xplane.probe_inner"}
+    for name, (plane_name, dur) in seen.items():
+        assert plane_name.startswith("/host:")
+        assert abs(dur - ring[name]) < 1e-3
+    # outside a capture the annotation is one inert object: the span still records
+    with TRACER.span("xplane.probe_after"):
+        pass
+    assert any(r.name == "xplane.probe_after" for r in TRACER.spans())
+
+
+def test_forced_collection_leaves_one_record_and_moves_both_counters():
+    import gc
+
+    from fisco_bcos_tpu.observability.tracer import install_gc_spans
+    from fisco_bcos_tpu.utils.metrics import REGISTRY
+
+    def counters():
+        TRACER.flush_drop_metrics()
+        got = REGISTRY.counters_matching("fisco_gc_")
+        return (got.get('fisco_gc_collections_total{gen="2"}', 0.0),
+                got.get('fisco_gc_pause_seconds_total{gen="2"}', 0.0))
+
+    install_gc_spans()
+    install_gc_spans()  # idempotent: one hook, one record a collection
+    assert sum(cb.__name__ == "_on_gc" for cb in gc.callbacks) == 1
+    before = [r for r in TRACER.spans() if r.name == "gc.gen2"]
+    n0, s0 = counters()
+    gc.collect(2)
+    after = [r for r in TRACER.spans() if r.name == "gc.gen2"]
+    (new,) = after[len(before):]
+    assert new.dur > 0.0 and new.tid == threading.get_ident() and not new.derived
+    assert "collected" in new.attrs
+    n1, s1 = counters()
+    assert n1 == n0 + 1 and s1 == pytest.approx(s0 + new.dur)
+
+
+def test_slow_span_keeps_its_witnesses():
+    """A span over a second hands the flight recorder one ``slow_span``
+    event: itself, and what else the ring saw during its interval, by span
+    name and thread — derived records left out."""
+    import time
+
+    from fisco_bcos_tpu.observability.flight import FLIGHT
+    from fisco_bcos_tpu.observability.tracer import SLOW_SPAN_S
+
+    started = threading.Event()
+
+    def witness():
+        started.wait(5)
+        with TRACER.span("slowtest.witness"):
+            time.sleep(0.3)
+
+    t = threading.Thread(target=witness, name="slowtest-thread")
+    t.start()
+    with TRACER.span("slowtest.stalled"):
+        t_inside = time.perf_counter()
+        started.set()
+        time.sleep(SLOW_SPAN_S + 0.1)
+        TRACER.record("slowtest.gap", t_inside, 0.5, derived=True)
+    t.join(5)
+    events = [e for e in FLIGHT.snapshot()
+              if e["category"] == "slow_span" and e["name"] == "slowtest.stalled"]
+    (ev,) = events
+    assert ev["detail"]["dur_ms"] >= SLOW_SPAN_S * 1e3
+    assert ev["detail"]["thread"] == threading.current_thread().name
+    by_name = {o["name"]: o for o in ev["detail"]["overlaps"]}
+    assert by_name["slowtest.witness"]["thread"] == "slowtest-thread"
+    assert 250.0 <= by_name["slowtest.witness"]["ms"] <= 1000.0
+    assert "slowtest.gap" not in by_name and "slowtest.stalled" not in by_name
+    # a quick span leaves no event
+    with TRACER.span("slowtest.quick"):
+        pass
+    assert not any(e["name"] == "slowtest.quick" for e in FLIGHT.snapshot())
+
+
+def test_ring_holds_a_window_and_a_record_stays_small():
+    """The default ring holds a 51 s window of the busiest cell with a margin
+    of two (PERF.md §6, PR 24); a record without attributes shares one empty
+    mapping and has no ``__dict__``."""
+    from fisco_bcos_tpu.observability.tracer import DEFAULT_CAPACITY, SpanRecord
+
+    assert Tracer().capacity == DEFAULT_CAPACITY >= 2 * 26000
+    tr = Tracer(capacity=4)
+    for i in range(6):
+        tr.record("r", float(i), 0.5)
+    a, b = tr.spans()[-2:]
+    assert a.attrs is b.attrs and not a.attrs and a.attrs.get("x") is None
+    assert not hasattr(a, "__dict__") and "derived" in SpanRecord.__slots__
+    assert tr.drop_counts()["ring_evict"] == 2
+    tr.record("gap", 1.0, 0.5, derived=True, block=3)
+    ev = [e for e in tr.export_chrome()["traceEvents"] if e["name"] == "gap"][0]
+    assert ev["args"]["derived"] is True and ev["args"]["block"] == 3
+
+
+def test_slow_span_witness_outlives_the_last_events_ring():
+    """A stall early in a long run is still in the dump: ``slow_span`` events
+    sit apart from the 512 last events (on the chip one was evicted by forty
+    later blocks' 2PC and round events before ``Node.stop`` flushed)."""
+    from fisco_bcos_tpu.observability.flight import FLIGHT_CAP, FlightRecorder
+
+    fl = FlightRecorder(enabled=True)
+    fl.record("slow_span", "txpool.submit_batch", dur_ms=4236.0)
+    for i in range(FLIGHT_CAP + 10):
+        fl.record("2pc", "prepare", height=i)
+    snap = fl.snapshot()
+    assert len(snap) == FLIGHT_CAP + 1
+    assert snap[0]["category"] == "slow_span" and snap[0]["detail"]["dur_ms"] == 4236.0
+    assert [e["t"] for e in snap] == sorted(e["t"] for e in snap)

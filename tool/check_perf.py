@@ -6,7 +6,7 @@ throughput campaign's "each win proved per stage" acceptance.
 per round: flood TPS plus the per-stage self-time vector aggregated across
 every sampled tx in the flood window (``stage_self_ms``). Since ISSUE 13
 it also writes ``bench_telemetry.flood.device.json``: the device
-observatory's per-op queue/compile/transfer/execute phase vector
+observatory's per-op measured phase vector
 (``op_phase_ms``). Since ISSUE 16 it also writes
 ``bench_telemetry.flood.rounds.json``: the fleet observatory's aligned
 consensus-round view — per-phase span p95 across every replica and round
@@ -114,17 +114,22 @@ def diff(
         "stage", "self time",
         old.get("stage_self_ms") or {}, new.get("stage_self_ms") or {},
     )
-    # device artifacts: gate on the EXECUTE phase per op (compile variance
-    # is cache state, not kernel speed — it has its own cold_compiles row)
+    # device artifacts: gate on the measured SYNC phase per op, the host
+    # waiting for the device's result (compile variance is cache state, not
+    # kernel speed — it has its own cold_compiles row). An artifact from
+    # before the phases were measured has no such row: its ops read as
+    # added, not compared with the old remainder `execute`.
     diff_series(
-        "device op", "execute time",
+        "device op", "sync time",
         {
-            op: ph.get("execute", 0.0)
+            op: ph["sync"]
             for op, ph in (old.get("op_phase_ms") or {}).items()
+            if "sync" in ph
         },
         {
-            op: ph.get("execute", 0.0)
+            op: ph["sync"]
             for op, ph in (new.get("op_phase_ms") or {}).items()
+            if "sync" in ph
         },
     )
     # fleet-round artifacts: per-consensus-phase span p95 across every
