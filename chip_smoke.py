@@ -126,7 +126,7 @@ def check_identity(ident: dict, want_platform: str) -> list[str]:
 
 def check_health(health: dict) -> list[str]:
     """No degraded row — in particular none of device-crypto (breaker
-    tripped), device-pallas (kernel latched off), device-recompile (storm)."""
+    tripped), device-recompile (storm)."""
     return [
         f"/health row {name} is {row.get('status')}: {row.get('reason')}"
         for name, row in sorted((health or {}).get("components", {}).items())
@@ -266,8 +266,6 @@ def check_air4(doc: dict, want_platform: str, sizes: dict) -> list[str]:
             f"full-width block ran as {got_op!r}; with {count} device(s) "
             f"visible it takes {want_op!r}"
         )
-    if doc.get("pallas_loaded"):
-        why.append("a Pallas kernel module was loaded (Pallas is off this path)")
     return why
 
 
@@ -648,10 +646,6 @@ def child_air4(args) -> int:
         device_doc=device_doc(),
         health=HEALTH.snapshot(),
         breaker_state=_device_breaker().state,
-        pallas_loaded=any(
-            m.endswith("ops.pallas_ec") or m.startswith("jax.experimental.pallas")
-            for m in sys.modules
-        ),
         wall_s=round(time.monotonic() - t_start, 1),
     )
     _write_json(args.out, doc)
@@ -717,9 +711,6 @@ class Smoke:
         env["PYTHONPATH"] = HERE + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
         )
-        # Pallas is opt-in and off this path
-        for k in ("FISCO_FORCE_PALLAS", "FISCO_USE_PALLAS"):
-            env.pop(k, None)
         env.setdefault("FISCO_FLIGHT_DIR", OUT)
         if self.rehearse and platform == "cpu":
             # the CPU rehearsal forces the device leg (a CPU backend routes

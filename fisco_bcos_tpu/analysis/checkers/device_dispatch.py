@@ -11,7 +11,7 @@ ad-hoc batch shapes re-open the recompile churn the bucket ladder closed.
 
 Rule: importing a device-kernel module (``ops.keccak``, ``ops.secp256k1``,
 ``ops.sm2``, ``ops.sm3``, ``ops.sha256``, ``ops.ed25519``, ``ops.merkle``,
-``ops.address``, ``ops.pallas_ec``) — or any *device entry* name from one —
+``ops.address``) — or any *device entry* name from one —
 outside the seam allowlist is a finding. Host-side helpers are exempt:
 ``ops.hash_common``/``ops.bigint``/``ops.limb`` everywhere, and the named
 host-tree classes from ``ops.merkle`` (``MerkleTree``/``MerkleProofItem``,
@@ -27,7 +27,7 @@ from ..core import Checker, Finding, Source, qualnames
 # device-kernel modules: importing these implies device dispatch
 DEVICE_MODULES = {
     "keccak", "sha256", "sm3", "sm2", "secp256k1", "ed25519",
-    "merkle", "address", "pallas_ec", "bls12_381", "poseidon",
+    "merkle", "address", "bls12_381", "poseidon",
 }
 # names importable from device modules that are host-side only
 HOST_SAFE_NAMES = {

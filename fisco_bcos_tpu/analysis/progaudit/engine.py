@@ -15,7 +15,7 @@ qualname → either an input-shape declaration or a skip reason::
             "call": lambda b: _device_root_fn(b, 16),
             "inputs": lambda b: [((b, 32), "uint8")],
         },
-        "maybe.run": {"skip": "pallas kernels are TPU-only"},
+        "sharded_verify.local": {"skip": "needs a multi-device mesh"},
     }
 
 ``bucket`` is the canonical batch the program is audited at — an explicit

@@ -3,8 +3,9 @@
 Number layout: a 256-bit value is 16 little-endian 16-bit limbs. Host-side
 (numpy) arrays here are **batch-major** ``[B, 16]`` — the stable public
 layout of the crypto suite APIs; the device math core
-(:mod:`fisco_bcos_tpu.ops.limb`) is **limb-major** ``[16, T]`` for full VPU
-lane utilization and transposes at its entry points.
+(:mod:`fisco_bcos_tpu.ops.limb`) keeps the limb index on the leading axis
+and the batch lane-dense behind it (``[16, S, 128]``), converting once at
+its entry points.
 
 The device-side converters keep hash → EC pipelines fused on device (the
 reference round-trips through CPU byte buffers between OpenSSL EVP hashing

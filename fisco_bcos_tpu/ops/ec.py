@@ -4,18 +4,19 @@ Replaces the reference's per-signature CPU EC stack (wedpr-crypto Rust FFI
 behind bcos-crypto — `wedpr_secp256k1_verify` at
 bcos-crypto/bcos-crypto/signature/secp256k1/Secp256k1Crypto.cpp:57, SM2 at
 signature/sm2/SM2Crypto.cpp:29-91) with batch complete-projective kernels
-over the limb-major field arithmetic in :mod:`fisco_bcos_tpu.ops.limb`.
+over the limb-leading field arithmetic in :mod:`fisco_bcos_tpu.ops.limb`.
 
 TPU-first design:
-- A point is a homogeneous (X : Y : Z) tuple of ``[16, T]`` limb-major
-  arrays in the curve's field domain (plain for the pseudo-Mersenne fast
-  path, Montgomery for SM2); (0 : 1 : 0) is the identity. The batch lives
-  in the minor axis so every op runs at full VPU lane utilization.
+- A point is a homogeneous (X : Y : Z) tuple of ``[16, ...]`` limb arrays
+  (limb index leading, batch in the trailing dimensions: lane-dense
+  ``[16, S, 128]`` from the secp256k1 and SM2 entry points, see
+  :mod:`fisco_bcos_tpu.ops.limb`) in the curve's field domain (plain for
+  the pseudo-Mersenne fast path, Montgomery for SM2); (0 : 1 : 0) is the
+  identity.
 - The group law is the Renes–Costello–Batina COMPLETE addition (section
   comment below): exceptional cases (identity operands, P == Q, P == -Q)
   are covered by the algebra itself — no per-lane select chains and no
-  shadow doubling per add, which trims ~25% of the ladder's field muls
-  and shrinks the Pallas kernel's live set.
+  shadow doubling per add, which trims ~25% of the ladder's field muls.
 - ``dual_mul_windowed`` computes u1*G + u2*Q with 4-bit windows and one
   shared doubling chain (Shamir): a 15-entry runtime projective table for
   Q, and a host-precomputed affine table {c*G} so G contributions are
@@ -23,9 +24,8 @@ TPU-first design:
 - The whole ladder is a ``lax.scan`` over 64 window steps; table selects
   are 15-way masked chains (schedule identical on every lane).
 
-The same functions run inside the Pallas TPU kernels (see
-:mod:`fisco_bcos_tpu.ops.pallas_ec`) and under plain XLA on CPU; integer
-semantics make both paths bit-identical — mandatory for consensus.
+Plain XLA on every backend; integer semantics make every backend
+bit-identical — mandatory for consensus.
 """
 
 from __future__ import annotations
@@ -436,48 +436,17 @@ def g_comb_table(name: str) -> np.ndarray:
     return tab
 
 
-LIMBS_PER_SCALAR = 16
-
-
-def window_at(k: jax.Array, wi: jax.Array) -> jax.Array:
-    """4-bit window ``wi`` (traced scalar, 0 = LSB) of [16, T] plain limbs ->
-    [T] uint32 in 0..15.
-
-    Row fetch is a 16-way masked chain on the static limb index and the
-    sub-limb shift is by a traced broadcast scalar — no gather, no
-    dynamic_slice, so the same code lowers under Mosaic (Pallas TPU), where
-    ``lax.scan`` over a precomputed [64, T] window array would not (its xs
-    slicing needs dynamic_slice)."""
-    wi = jnp.asarray(wi)  # plain int under eager fori_loop (disable_jit)
-    li = wi // (16 // WINDOW)  # limb index 0..15
-    sh = (wi % (16 // WINDOW)).astype(jnp.uint32) * WINDOW
-    r = limb.row(k, 0)
-    for j in range(1, LIMBS_PER_SCALAR):
-        r = jnp.where(li == j, limb.row(k, j), r)
-    return (r >> sh) & np.uint32(0xF)
-
-
 def scalar_windows(k: jax.Array) -> jax.Array:
     """[16, T] plain limbs -> [64, T] 4-bit windows, LSB-first order (the
-    scan-shape window precompute; plain-XLA path only)."""
-    rep = jnp.repeat(k, 16 // WINDOW, axis=0)  # [64, T]
+    window precompute the ladders scan over)."""
+    rep = jnp.repeat(k, 16 // WINDOW, axis=0)  # [64, ...]
     shifts = limb.dev_vec((np.arange(N_WINDOWS) % (16 // WINDOW)) * WINDOW)
-    return (rep >> shifts[:, None]) & np.uint32(0xF)
-
-
-def _point_table_list(t1, C: CurveOps):
-    """Window table of k*P for k = 1..15 as a 15-entry Python list of
-    (x, y, z) tuples — 14 unrolled additions (Mosaic shape: no scan-stacking,
-    Pallas TPU has no dynamic_update_slice for scan ys outputs)."""
-    tab = [t1]
-    for _ in range(14):
-        tab.append(pt_add(tab[-1], t1, C))
-    return tab
+    return (rep >> shifts.reshape((-1,) + (1,) * (k.ndim - 1))) & np.uint32(0xF)
 
 
 def _point_table_scan(t1, C: CurveOps):
-    """Same table as three stacked [15, 16, T] arrays via a 14-step scan —
-    the compact HLO shape for plain XLA (fast CPU compiles)."""
+    """Window table of k*P for k = 1..15 as three stacked [15, 16, T] arrays
+    via a 14-step scan (compact HLO: fast CPU compiles)."""
 
     def step(prev, _):
         nxt = pt_add(prev, t1, C)
@@ -488,6 +457,12 @@ def _point_table_scan(t1, C: CurveOps):
     tq_y = jnp.concatenate([t1[1][None], rest[1]], axis=0)
     tq_z = jnp.concatenate([t1[2][None], rest[2]], axis=0)
     return tq_x, tq_y, tq_z
+
+
+def _per_lane(tab: jax.Array, w: jax.Array) -> jax.Array:
+    """[15, 16] table of constants -> [15, 16, 1, ...]: broadcastable against
+    limb arrays whose batch dimensions are those of the window array w."""
+    return tab.reshape(tab.shape + (1,) * w.ndim)
 
 
 def _select15(tab, w: jax.Array):
@@ -508,45 +483,12 @@ def dual_mul_windowed(k1, k2, Q, C: CurveOps, g_table: jax.Array):
     g_table: device copy of :func:`g_comb_table` ([30, 16]).
 
     Schedule: 64 window steps, each 4 doublings + one full addition (runtime
-    Q table) + one mixed addition (affine G table), all lane-uniform. The
-    loop/table trace shape follows :func:`limb.is_mosaic_trace` (fori +
-    where-chains under Pallas, compact scans under plain XLA) — outputs are
-    bit-identical either way.
+    Q table) + one mixed addition (affine G table), all lane-uniform.
     """
     F = C.F
     one = F.one(k1)
     t1 = (Q[0], Q[1], one)
     acc0 = pt_infinity(k1, C)
-
-    if limb.is_mosaic_trace():
-        tq = _point_table_list(t1, C)
-        # G table as 15-entry lists of [16, 1] columns (affine x, y) —
-        # static slices + reshape, not g_table[c] (no dynamic_slice in Mosaic)
-        tg_x = [
-            lax.slice_in_dim(g_table, c, c + 1, axis=0).reshape(16, 1)
-            for c in range(15)
-        ]
-        tg_y = [
-            lax.slice_in_dim(g_table, 15 + c, 16 + c, axis=0).reshape(16, 1)
-            for c in range(15)
-        ]
-
-        def step(i, acc):
-            wi = 63 - i  # MSB-first
-            w1_i = window_at(k1, wi)
-            w2_i = window_at(k2, wi)
-            for _ in range(WINDOW):
-                acc = pt_double(acc, C)
-            qx, qy, qz = _select15(tq, w2_i)
-            added = pt_add(acc, (qx, qy, qz), C)
-            acc = select(w2_i == 0, acc, added)
-            gx = _select15(tg_x, w1_i)  # [16, T]
-            gy = _select15(tg_y, w1_i)
-            madded = pt_add_mixed(acc, (gx, gy), C)
-            acc = select(w1_i == 0, acc, madded)
-            return acc
-
-        return lax.fori_loop(0, N_WINDOWS, step, acc0)
 
     tq_x, tq_y, tq_z = _point_table_scan(t1, C)
     w1 = scalar_windows(k1)[::-1]  # MSB-first [64, T]
@@ -560,8 +502,8 @@ def dual_mul_windowed(k1, k2, Q, C: CurveOps, g_table: jax.Array):
             acc, (_select15(tq_x, w2_i), _select15(tq_y, w2_i), _select15(tq_z, w2_i)), C
         )
         acc = select(w2_i == 0, acc, added)
-        gx = _select15(g_table[:15][:, :, None], w1_i)  # [16, T]
-        gy = _select15(g_table[15:][:, :, None], w1_i)
+        gx = _select15(_per_lane(g_table[:15], w1_i), w1_i)  # [16, ...]
+        gy = _select15(_per_lane(g_table[15:], w1_i), w1_i)
         madded = pt_add_mixed(acc, (gx, gy), C)
         acc = select(w1_i == 0, acc, madded)
         return acc, None
@@ -585,11 +527,9 @@ def lane_inv(F, x: jax.Array) -> jax.Array:
     lane — the inverse is unique mod m, so the result is bit-identical to
     ``F.inv`` per lane (0 maps to 0, as Fermat gives). T is padded to a
     power of two with ones.
-
-    Plain-XLA only (lane slicing below the 128-lane vreg width does not
-    lower on Mosaic) — callers run it before/after a Pallas kernel, not
-    inside one.
     """
+    shape = x.shape
+    x = x.reshape(shape[0], -1)  # the tree halves the lanes: one flat axis
     t = x.shape[1]
     nz = ~is_zero(x)
     cur = select(nz, x, F.one(x))
@@ -609,7 +549,7 @@ def lane_inv(F, x: jax.Array) -> jax.Array:
         inv = jnp.concatenate([F.mul(inv, b), F.mul(inv, a)], axis=1)
     if pw != t:
         inv = inv[:, :t]
-    return select(nz, inv, jnp.zeros_like(x))
+    return select(nz, inv, jnp.zeros_like(x)).reshape(shape)
 
 
 def pt_to_affine_batch(P, C: CurveOps):
@@ -726,16 +666,14 @@ def glv_decompose(u2: jax.Array, C: CurveOps):
 
     Rounding is plain floor Barrett (error ≤ 2 per coefficient — the
     congruence holds for ANY rounding, slop only costs ladder-bound bits,
-    and N_QWINDOWS covers it). Elementwise + carry ops only: traces under
-    both Mosaic and plain XLA."""
+    and N_QWINDOWS covers it)."""
     P = glv_params(C.name)
-    t = u2.shape[1]
     # c_i = floor(u2 * g_i / 2^448): 16x21-limb product, drop 28 limbs
     c1 = _shr_limbs(_mul_c(u2, P.g1, 37), 28, 9)
     c2 = _shr_limbs(_mul_c(u2, P.g2, 37), 28, 9)
     # ka = u2 - c1*a1 - c2*a2 (signed)
-    s_a = limb.add_widen(_mul_c(c1, P.a1, 17), _mul_c(c2, P.a2, 17))  # [18,T]
-    u2p = jnp.concatenate([u2, jnp.zeros((2, t), jnp.uint32)], axis=0)
+    s_a = limb.add_widen(_mul_c(c1, P.a1, 17), _mul_c(c2, P.a2, 17))  # 18 limbs
+    u2p = limb._placed(u2, 0, 18)
     ka, sa = _abs_diff(u2p, s_a)
     # kb = c1*|b1| - c2*b2 (signed)
     kb, sb = _abs_diff(_mul_c(c1, P.b1_abs, 17), _mul_c(c2, P.b2, 17))
@@ -762,11 +700,9 @@ def g_comb_table_glv(name: str) -> np.ndarray:
 
 
 def _split_u1(u1: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """[16, T] -> 128-bit halves, each widened back to [16, T]."""
-    t = u1.shape[1]
-    zeros = jnp.zeros((8, t), jnp.uint32)
-    lo = jnp.concatenate([lax.slice_in_dim(u1, 0, 8, axis=0), zeros], axis=0)
-    hi = jnp.concatenate([lax.slice_in_dim(u1, 8, 16, axis=0), zeros], axis=0)
+    """[16, ...] -> 128-bit halves, each widened back to [16, ...]."""
+    lo = limb._placed(lax.slice_in_dim(u1, 0, 8, axis=0), 0, 16)
+    hi = limb._placed(lax.slice_in_dim(u1, 8, 16, axis=0), 0, 16)
     return lo, hi
 
 
@@ -787,8 +723,7 @@ def quad_mul_windowed(
     Q: field-domain affine; g_table2: :func:`g_comb_table_glv` on device.
 
     33 window steps of 4 doublings + 2 complete adds (runtime Q table and
-    its on-the-fly β-scaled λQ view) + 2 mixed adds (G combs). Same
-    Mosaic/scan dual shape as :func:`dual_mul_windowed`.
+    its on-the-fly β-scaled λQ view) + 2 mixed adds (G combs).
     """
     F = C.F
     P = glv_params(C.name)
@@ -797,56 +732,6 @@ def quad_mul_windowed(
     acc0 = pt_infinity(u1, C)
     u1lo, u1hi = _split_u1(u1)
     beta_c = const_rows(P.beta_enc, Q[0])
-
-    if limb.is_mosaic_trace():
-        ta = _point_table_list(t1, C)
-        ta_x = [e[0] for e in ta]
-        ta_y = [e[1] for e in ta]
-        ta_z = [e[2] for e in ta]
-        tb_x = [F.mul(x, beta_c) for x in ta_x]  # λ(X:Y:Z) = (βX:Y:Z)
-        tg = []
-        for base in (0, 30):
-            tg.append(
-                (
-                    [
-                        lax.slice_in_dim(
-                            g_table2, base + c, base + c + 1, axis=0
-                        ).reshape(16, 1)
-                        for c in range(15)
-                    ],
-                    [
-                        lax.slice_in_dim(
-                            g_table2, base + 15 + c, base + 16 + c, axis=0
-                        ).reshape(16, 1)
-                        for c in range(15)
-                    ],
-                )
-            )
-
-        def step(i, acc):
-            wi = N_QWINDOWS - 1 - i  # MSB-first
-            wa = window_at(ka, wi)
-            wb = window_at(kb, wi)
-            for _ in range(WINDOW):
-                acc = pt_double(acc, C)
-            xa = _select15(ta_x, wa)
-            ya = _select15(ta_y, wa)
-            za = _select15(ta_z, wa)
-            ya = select(sa, F.neg(ya), ya)
-            acc = select(wa == 0, acc, pt_add(acc, (xa, ya, za), C))
-            xb = _select15(tb_x, wb)
-            yb = _select15(ta_y, wb)
-            zb = _select15(ta_z, wb)
-            yb = select(sb, F.neg(yb), yb)
-            acc = select(wb == 0, acc, pt_add(acc, (xb, yb, zb), C))
-            for k1c, (tgx, tgy) in zip((u1lo, u1hi), tg):
-                w = window_at(k1c, wi)
-                gx = _select15(tgx, w)
-                gy = _select15(tgy, w)
-                acc = select(w == 0, acc, pt_add_mixed(acc, (gx, gy), C))
-            return acc
-
-        return lax.fori_loop(0, N_QWINDOWS, step, acc0)
 
     ta_x, ta_y, ta_z = _point_table_scan(t1, C)
     tb_x = jnp.stack([F.mul(ta_x[i], beta_c) for i in range(15)], axis=0)
@@ -868,8 +753,8 @@ def quad_mul_windowed(
         added = pt_add(acc, (_select15(tb_x, wb), yb, _select15(ta_z, wb)), C)
         acc = select(wb == 0, acc, added)
         for w, base in ((wlo, 0), (whi, 30)):
-            gx = _select15(g_table2[base : base + 15][:, :, None], w)
-            gy = _select15(g_table2[base + 15 : base + 30][:, :, None], w)
+            gx = _select15(_per_lane(g_table2[base : base + 15], w), w)
+            gy = _select15(_per_lane(g_table2[base + 15 : base + 30], w), w)
             madded = pt_add_mixed(acc, (gx, gy), C)
             acc = select(w == 0, acc, madded)
         return acc, None
@@ -886,18 +771,6 @@ def scalar_mul(k, P, C: CurveOps):
     F = C.F
     one = F.one(k)
     t1 = (P[0], P[1], one)
-
-    if limb.is_mosaic_trace():
-        tq = _point_table_list(t1, C)
-
-        def step(i, acc):
-            w_i = window_at(k, 63 - i)
-            for _ in range(WINDOW):
-                acc = pt_double(acc, C)
-            added = pt_add(acc, _select15(tq, w_i), C)
-            return select(w_i == 0, acc, added)
-
-        return lax.fori_loop(0, N_WINDOWS, step, pt_infinity(k, C))
 
     tq_x, tq_y, tq_z = _point_table_scan(t1, C)
     w = scalar_windows(k)[::-1]
@@ -943,7 +816,6 @@ __all__ = [
     "lane_inv",
     "pt_to_affine_batch",
     "quad_mul_windowed",
-    "window_at",
     "dual_mul_windowed",
     "scalar_mul",
     "generator_affine",

@@ -237,7 +237,7 @@ def verify_core(s, k_neg, a_y, a_sign, r_y, r_sign, b_table):
     valid = ok_a & ok_r
     valid &= lt(s, const_rows(_L_LIMBS, s))  # malleability guard (s < L)
 
-    # 15-entry runtime table for A (unified adds; list form is Mosaic-safe)
+    # 15-entry runtime table for A (unified adds)
     ta = [A]
     for _ in range(14):
         ta.append(ed_add(ta[-1], A))

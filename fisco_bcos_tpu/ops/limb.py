@@ -1,28 +1,50 @@
-"""Limb-major 256-bit modular arithmetic — the TPU-native bignum core.
+"""256-bit modular arithmetic with the limb index off the tiled axes — the
+TPU-native bignum core.
 
 Replaces the reference's CPU bignum (wedpr-crypto Rust FFI / OpenSSL BN behind
 bcos-crypto's secp256k1/SM2 paths) with a formulation shaped for the TPU VPU:
 
-- A 256-bit number is 16 little-endian 16-bit limbs in a uint32 array of
-  shape ``[L, T]`` — **limb-major**: the minor (lane) axis is the batch, so
-  every elementwise op runs at full 128-lane VPU utilization. (The round-1
-  layout ``[B, 16]`` put the 16-limb axis in the lanes — 12.5% utilization —
-  and was the single biggest cost of the 1.36× bench result.)
-- Multiplication is 16 unrolled rows of vector MACs with 16-bit lo/hi
-  splitting (every partial product and column sum stays inside uint32);
-  there are no matmuls — int32 matmul does not map to the MXU.
-- Carry propagation is Kogge–Stone over the limb axis
-  (``lax.associative_scan``, log₂ depth), never a sequential scan.
+- A 256-bit number is 16 little-endian 16-bit limbs in a uint32 array whose
+  **leading axis is the limb index** and whose trailing dimensions are the
+  batch. The secp256k1 and SM2 entry points make the batch **lane-dense**
+  (:func:`lane_dense`, which pads it to a multiple of 128 lanes): a batch in
+  which a limb is more than one vreg (over 1,024 lanes) becomes
+  ``[L, S, 128]`` with ``S = lanes / 128``, so the batch fills both tiled
+  dimensions (sublanes × lanes) and the limb index is on neither; up to
+  1,024 lanes it stays ``[L, T]``, limbs sharing vregs along the sublanes
+  (which form wins at which size was measured: :func:`lane_dense`). Every
+  function here is written against ``[L, ...]`` and never looks at the
+  trailing shape; programs that have not moved yet (ed25519, BLS12-381,
+  Poseidon, bn128) still pass ``[L, T]``.
+- Taking a limb, shifting by limbs, placing a partial product at a column,
+  dropping the top limb and widening by a zero limb are each written as ONE
+  ``lax.pad`` (:func:`_shift_limbs`; negative padding drops), never slice +
+  concatenate: address arithmetic on an untiled axis in ``[L, S, 128]``, a
+  sublane shift inside the consuming fusion in ``[L, T]``. The pads and the
+  carry lookahead below are most of what PR 25 gained (394 -> 167 ms at
+  10,240 lanes in ``[L, T]`` alone); the layout adds the rest (-> 149 ms). History, because two layouts were called "full utilisation"
+  before a chip said otherwise: ``[B, 16]`` put the limbs in the lanes
+  (12.5 %); ``[16, T]`` put them in the sublanes and built every shift and
+  carry from slices and concatenates at sublane offsets, and the chip ran a
+  1,024-lane admission call as 483,211 device ops — 175,505 of them bare
+  slices, 99,400 the levels of the Kogge–Stone carry networks, 10,175 the
+  fusions that multiply (PERF.md §6, PR 25).
+- Multiplication is 16 unrolled limb MACs with 16-bit lo/hi splitting (every
+  partial product and column sum stays inside uint32); there are no matmuls
+  — int32 matmul does not map to the MXU.
+- Carry propagation is a carry-lookahead by ONE machine addition
+  (:func:`_carry_in`): generate and propagate bits are packed along the limb
+  axis into 32-bit words, and ``(G | P) + G`` ripples through propagate runs
+  in the adder. Never a sequential scan over limbs, and no longer a
+  log-depth network whose every level was a device op of its own.
 - Modular reduction is **pseudo-Mersenne folding** (``FoldField``) for
   moduli of the form 2^256 − c with small c — secp256k1's p and n both
   qualify — and word Montgomery (``MontField``) for arbitrary odd moduli
   (SM2). Both present the same field-ops protocol so the EC layer in
   :mod:`fisco_bcos_tpu.ops.ec` is generic over them.
 
-Everything here is plain ``jnp`` on values — the same functions run inside a
-Pallas TPU kernel (VMEM-resident, the fast path) and under ordinary XLA on
-CPU (the portable/correctness path); integer semantics make the two
-bit-identical by construction, which is what consensus code requires.
+Everything here is plain ``jnp`` on values; integer semantics make every
+backend bit-identical by construction, which is what consensus code requires.
 
 Host-side byte/int conversions stay in :mod:`fisco_bcos_tpu.ops.bigint`.
 """
@@ -39,9 +61,7 @@ from jax import lax
 
 LIMBS = 16
 LIMB_BITS = 16
-# numpy scalar, not jnp: a module-level jax.Array would be a captured
-# constant inside Pallas kernel traces (Mosaic rejects those); np scalars
-# stay jaxpr literals.
+# numpy scalar, not jnp: it stays a jaxpr literal, never a captured constant
 _MASK = np.uint32(0xFFFF)
 _R = 1 << 256
 
@@ -58,6 +78,7 @@ def int_to_rows(x: int, width: int = LIMBS) -> np.ndarray:
 def rows_to_ints(a) -> list[int]:
     """[L, T] limbs -> list of T Python ints (host-side, for tests)."""
     a = np.asarray(a)
+    a = a.reshape(a.shape[0], -1)
     return [
         sum(int(a[i, j]) << (LIMB_BITS * i) for i in range(a.shape[0]))
         for j in range(a.shape[1])
@@ -65,84 +86,148 @@ def rows_to_ints(a) -> list[int]:
 
 
 def dev_vec(arr, dtype=jnp.uint32) -> jax.Array:
-    """1-D host constant -> device vector assembled from scalar constants.
-
-    Pallas kernel bodies may not capture array constants (only scalars), so
-    every host-side table/constant that flows into the shared field code is
-    built this way; XLA constant-folds the stack outside Pallas."""
+    """1-D host constant -> device vector assembled from scalar constants
+    (XLA constant-folds the stack)."""
     return jnp.stack([jnp.array(int(v), dtype) for v in arr])
 
 
 def const_rows(limbs_np: np.ndarray, t: int | jax.Array) -> jax.Array:
-    """[L] host constant -> [L, T] broadcast (T from an int or a like-array).
-
-    Plain XLA: one embedded constant + one broadcast. Mosaic trace: built
-    from scalar literals (Pallas kernels may not capture array constants) —
-    L fulls + a stack, which Mosaic constant-folds."""
-    if not isinstance(t, int):
-        t = t.shape[-1]
-    if is_mosaic_trace():
-        return jnp.stack([jnp.full((t,), int(v), jnp.uint32) for v in limbs_np])
+    """[L] host constant -> [L, ...] broadcast (the batch dimensions from an
+    int T or from a like-array's trailing dimensions): one embedded constant
+    + one broadcast."""
+    tail = (t,) if isinstance(t, int) else t.shape[1:]
     arr = np.asarray(limbs_np, dtype=np.uint32)
-    return jnp.broadcast_to(jnp.asarray(arr)[:, None], (arr.shape[0], t))
+    return jnp.broadcast_to(
+        jnp.asarray(arr).reshape((-1,) + (1,) * len(tail)), (arr.shape[0],) + tail
+    )
 
 
 # ---------------------------------------------------------------------------
-# Carry machinery (Kogge–Stone along the limb axis = axis 0)
+# Entry conversions: batch-major [B, L] <-> lane-dense [L, S, 128]
+# ---------------------------------------------------------------------------
+
+LANES = 128  # the minor tiled dimension: one vreg row
+_VREG = 8 * LANES  # 32-bit elements in one vreg (8 sublanes x 128 lanes)
+
+
+def lane_dense(x: jax.Array) -> jax.Array:
+    """Batch-major [B, L] limbs -> limb-leading, the batch padded to a
+    multiple of 128 lanes. The one relayout on the way in.
+
+    A batch in which a limb is more than one vreg (over 1,024 lanes) becomes
+    ``[L, S, 128]``: the batch fills both tiled dimensions and the limb index
+    is the leading, untiled axis. Up to 1,024 lanes it stays ``[L, T]``,
+    limbs sharing vregs along the sublanes. The rule is the admission
+    program's device time in both forms on a TPU v5e (PERF.md §6, PR 25),
+    ``[L, T]`` / ``[L, S, 128]``: 512 lanes 43.9 / 58 ms (four sublanes pad
+    to eight: the call pays for 1,024 lanes), 1,024 lanes 53.1 / 55.8 ms,
+    2,560 lanes 99.6 / 84.7 ms, 10,240 lanes 166.7 / 148.7 ms: while a limb
+    fits one vreg a device op costs its fixed ≈ 300 ns whatever it moves, and
+    past that the sublane shifts of ``[L, T]`` show. Padding lanes hold zero:
+    an invalid scalar, so they lower their validity bit like any other bad
+    lane and never raise."""
+    b, width = x.shape
+    t = b + (-b % LANES)
+    xt = jnp.pad(x.T, ((0, 0), (0, t - b)))
+    return xt.reshape(width, -1, LANES) if t > _VREG else xt
+
+
+def lane_mask(v: jax.Array, like: jax.Array) -> jax.Array:
+    """[B] per-lane vector -> the batch shape of `like` (a :func:`lane_dense`
+    limb array), zero in the padding lanes."""
+    shape = like.shape[1:]
+    return jnp.pad(v, (0, int(np.prod(shape)) - v.shape[0])).reshape(shape)
+
+
+def batch_major(x: jax.Array, b: int) -> jax.Array:
+    """Limb-leading [L, ...] -> [B, L]: the one relayout on the way out,
+    padding lanes dropped."""
+    return x.reshape(x.shape[0], -1).T[:b]
+
+
+def batch_lanes(mask: jax.Array, b: int) -> jax.Array:
+    """A per-lane result of batch shape [...] -> [B], padding lanes dropped."""
+    return mask.reshape(-1)[:b]
+
+
+# ---------------------------------------------------------------------------
+# Carry machinery (lookahead along the limb axis = axis 0)
 # ---------------------------------------------------------------------------
 
 
-def _gp_combine(x, y):
-    gx, px = x
-    gy, py = y
-    return gy | (py & gx), py & px
+def _shift_limbs(x: jax.Array, k: int, fill: int = 0, grow: int = 0) -> jax.Array:
+    """[L, ...] -> [L + grow, ...] shifted k limbs toward the high end along
+    the leading axis: `fill` enters below, the top k - grow limbs drop off.
+
+    ONE ``lax.pad`` (negative high padding drops), never slice + concatenate:
+    the limb axis is the leading, untiled axis of a lane-dense ``[L, S, 128]``
+    batch, so the shift is address arithmetic inside whatever fusion consumes
+    it. The concatenate form ended a fusion at every shift, and its slices
+    ran as device ops of their own (PERF.md §6, PR 25)."""
+    cfg = [(k, grow - k, 0)] + [(0, 0, 0)] * (x.ndim - 1)
+    return lax.pad(x, jnp.array(fill, x.dtype), cfg)
 
 
 def _shift_up(x: jax.Array) -> jax.Array:
-    """[L, T] -> [L, T] shifted one limb toward the high end (axis 0)."""
-    return jnp.concatenate([jnp.zeros_like(x[:1]), x[:-1]], axis=0)
+    """[L, ...] -> [L, ...] shifted one limb toward the high end (axis 0)."""
+    return _shift_limbs(x, 1)
 
 
 def row(x: jax.Array, i: int) -> jax.Array:
-    """Static row i of [L, T] -> [T] via a static slice + squeeze.
-
-    NEVER ``x[i]``: jnp integer indexing lowers through dynamic_slice even
-    for constant indices, and Mosaic (Pallas TPU) has no dynamic_slice."""
+    """Static row i of [L, ...] -> [...] via a static slice + squeeze
+    (``x[i]`` lowers through dynamic_slice even for a constant index)."""
     return jnp.squeeze(lax.slice_in_dim(x, i, i + 1, axis=0), axis=0)
 
 
-def _carry_in(g: jax.Array, p: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Per-position carry/borrow-in from generate/propagate; also returns the
-    final carry-out row (both bool [T]).
+_WORD = 32
 
-    Explicit Kogge–Stone doubling loop rather than ``lax.associative_scan``:
-    the scan's recursive odd/even decomposition emits zero-length slices,
-    which Mosaic (Pallas TPU) rejects as 0-sized vectors; this loop is the
-    same log₂-depth circuit with every slice non-empty. Bits ride int32
-    lanes, not bool — Mosaic cannot concatenate i1 (mask-register) vectors
-    ("Invalid vector register cast")."""
-    G = g.astype(jnp.int32)
-    P = p.astype(jnp.int32)
-    shift = 1
+
+def _carry_in(g: jax.Array, p: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Per-limb carry/borrow-in from generate/propagate (bool [L, ...], never
+    both set in one limb); also returns the final carry-out (bool [...]).
+
+    Carry lookahead by one machine addition: the limbs' bits are packed into
+    32-bit words along the leading axis (G = Σ g_k·2^k, P likewise), and
+    (G | P) + G carries through a run of propagate bits exactly as the limb
+    chain does, so the carry into limb k is bit k of ((G | P) + G) ^ P. Two
+    reductions over the limb axis and a dozen elementwise operations, in
+    place of a Kogge–Stone network of log₂ L levels, each of which reads its
+    input shifted and unshifted and so ended a fusion on the device (six
+    fusions a carry chain, four chains a field multiplication: PERF.md §6,
+    PR 25). The carries are the same bits."""
     n = g.shape[0]
-    while shift < n:
-        # segment ending at i-shift, shifted into position i; out-of-range
-        # rows get the combine identity (g=0, p=1)
-        Gs = jnp.concatenate([jnp.zeros_like(G[:shift]), G[:-shift]], axis=0)
-        Ps = jnp.concatenate([jnp.ones_like(P[:shift]), P[:-shift]], axis=0)
-        G, P = _gp_combine((Gs, Ps), (G, P))
-        shift *= 2
-    cin = jnp.concatenate([jnp.zeros_like(G[:1]), G[:-1]], axis=0)
-    return cin != 0, row(G, n - 1) != 0
+    bit = lax.broadcasted_iota(jnp.uint32, (n,) + (1,) * (g.ndim - 1), 0) % _WORD
+    gb = g.astype(jnp.uint32) << bit
+    pb = p.astype(jnp.uint32) << bit
+    carry = None  # carry out of the word below
+    words = []
+    for lo in range(0, n, _WORD):
+        G = jnp.sum(gb[lo : lo + _WORD], axis=0, dtype=jnp.uint32)
+        P = jnp.sum(pb[lo : lo + _WORD], axis=0, dtype=jnp.uint32)
+        a = G | P  # a & G == G, a ^ G == P: G generates, P propagates
+        s = a + G if carry is None else a + G + carry
+        words.append(s ^ P)  # bit k: the carry into limb lo + k
+        carry = ((a & G) | ((a | G) & ~s)) >> (_WORD - 1)  # out of bit 31
+    if n % _WORD:
+        cout = (words[-1] >> np.uint32(n % _WORD)) & 1
+    else:
+        cout = carry
+    per_limb = jnp.concatenate(
+        [jnp.broadcast_to(w, (min(_WORD, n - i * _WORD),) + w.shape)
+         for i, w in enumerate(words)],
+        axis=0,
+    ) if len(words) > 1 else jnp.broadcast_to(words[0], (n,) + words[0].shape)
+    cin = (per_limb >> bit) & 1
+    return cin != 0, cout != 0
 
 
 def carry_norm(cols: jax.Array) -> jax.Array:
-    """Carry-propagate column sums: [L, T] uint32 (any uint32 value: the
+    """Carry-propagate column sums: [L, ...] uint32 (any uint32 value: the
     two-pass split bounds s = lo16 + prev_hi16 < 2^17 and t ≤ 2^16 before
-    the Kogge–Stone increment pass, so no intermediate can overflow —
+    the lookahead's increment pass, so no intermediate can overflow —
     mul_cols feeds columns < 2^22, mul_small up to ~2^31) ->
-    [L+1, T] normalized 16-bit limbs (top row = final carry-out)."""
-    cols = jnp.concatenate([cols, jnp.zeros_like(cols[:1])], axis=0)
+    [L+1, ...] normalized 16-bit limbs (top limb = final carry-out)."""
+    cols = _shift_limbs(cols, 0, grow=1)
     s = (cols & _MASK) + _shift_up(cols >> LIMB_BITS)  # < 2^16 + 2^11
     t = (s & _MASK) + _shift_up(s >> LIMB_BITS)  # ≤ 2^16; increments {0,1}
     g = t > _MASK
@@ -161,16 +246,10 @@ def sub_borrow(a: jax.Array, b: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 
 def _or_fold(x: jax.Array) -> jax.Array:
-    """Bitwise-OR all rows of [L, T] -> [T] via a log-depth halving tree
-    (no jnp.all/jnp.any: Mosaic lacks those reductions for integer input,
-    and this shape serves both backends identically)."""
-    while x.shape[0] > 1:
-        half = x.shape[0] // 2
-        rest = x[2 * half :]  # odd leftover row, if any
-        x = x[:half] | x[half : 2 * half]
-        if rest.shape[0]:
-            x = jnp.concatenate([x[:1] | rest, x[1:]], axis=0)
-    return row(x, 0)
+    """Nonzero where any limb of [L, ...] is nonzero -> [...]: one reduction
+    over the leading axis (the maximum of unsigned limbs is zero exactly
+    when all are)."""
+    return jnp.max(x, axis=0)
 
 
 def is_zero(a: jax.Array) -> jax.Array:
@@ -195,8 +274,7 @@ def select(cond: jax.Array, a, b):
     """cond [T] -> cond ? a : b over [..., T] operands (or tuples of them)."""
     if isinstance(a, tuple):
         return tuple(select(cond, x, y) for x, y in zip(a, b))
-    shape = (1,) * (a.ndim - 1) + cond.shape
-    return jnp.where(cond.reshape(shape), a, b)
+    return jnp.where(jnp.expand_dims(cond, tuple(range(a.ndim - cond.ndim))), a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -205,41 +283,26 @@ def select(cond: jax.Array, a, b):
 
 
 def _placed(x: jax.Array, offset: int, out: int) -> jax.Array:
-    """[n, T] rows placed at row `offset` of an [out, T] zero canvas —
-    zeros‖x‖zeros concat (2 broadcasts + 1 concat). NEVER `.at[...].add`:
-    a static-slice scatter is the single most expensive op for XLA to
-    compile (round-2 lesson: ~11k scatters made one EC program a >10-minute
-    CPU compile), and Mosaic cannot lower scatter at all."""
-    n = min(x.shape[0], out - offset)
-    if n <= 0:
-        return jnp.zeros((out, x.shape[1]), x.dtype)
-    parts = []
-    if offset:
-        parts.append(jnp.zeros((offset, x.shape[1]), x.dtype))
-    parts.append(x[:n])
-    if offset + n < out:
-        parts.append(jnp.zeros((out - offset - n, x.shape[1]), x.dtype))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+    """[n, ...] limbs placed at limb `offset` of an [out, ...] zero canvas
+    (what does not fit is dropped) — one pad. NEVER `.at[...].add`: a
+    static-slice scatter is the single most expensive op for XLA to compile
+    (round-2 lesson: ~11k scatters made one EC program a >10-minute CPU
+    compile)."""
+    if offset >= out:
+        return jnp.zeros((out,) + x.shape[1:], x.dtype)
+    return _shift_limbs(x, offset, grow=out - x.shape[0])
 
 
 def _add_rows(x: jax.Array) -> jax.Array:
-    """Sum the rows of [L, T] -> [1, T] via a log-depth halving tree (no
-    jnp.sum: Mosaic lacks integer reductions). Caller bounds the values so
+    """Sum the limbs of [L, ...] -> [1, ...]. Caller bounds the values so
     sums cannot overflow uint32."""
-    while x.shape[0] > 1:
-        half = x.shape[0] // 2
-        rest = x[2 * half :]
-        x = x[:half] + x[half : 2 * half]
-        if rest.shape[0]:
-            x = jnp.concatenate([x[:1] + rest, x[1:]], axis=0)
-    return x
+    return jnp.sum(x, axis=0, keepdims=True, dtype=jnp.uint32)
 
 
 def _sum_terms(terms: list[jax.Array]) -> jax.Array:
-    """Balanced tree-add of equal-shape u32 arrays.
-
-    Mosaic has no unsigned reductions, so no stack+jnp.sum; a log-depth add
-    tree is equally fusable under XLA and trivially lowerable under Mosaic."""
+    """Balanced tree-add of equal-shape u32 arrays: elementwise adds of
+    shifted operands, which XLA fuses into the consumer (a stack + sum would
+    materialise the stack)."""
     while len(terms) > 1:
         nxt = [
             terms[i] + terms[i + 1] if i + 1 < len(terms) else terms[i]
@@ -272,17 +335,13 @@ def sqr_cols(a: jax.Array, out: int = 2 * LIMBS) -> jax.Array:
     product rows instead of :func:`mul_cols`'s 256, with the same 32-term
     add tree. Doubling happens after the lo/hi split (terms < 2^17), so
     column sums stay < 32 * 2^17 < 2^23, inside carry_norm's budget."""
-    t = a.shape[1]
-    d = a * a  # [16, T] diagonal products a_i^2, column 2i
-    zero = jnp.zeros((LIMBS, 1, t), jnp.uint32)
-    # interleave rows with zeros: (d0, 0, d1, 0, ...) -> columns 0,2,4,...
-    d_lo = jnp.concatenate(
-        [(d & _MASK)[:, None], zero], axis=1
-    ).reshape(2 * LIMBS, t)
-    # (0, h0, 0, h1, ...) -> columns 1,3,5,...
-    d_hi = jnp.concatenate(
-        [zero, (d >> LIMB_BITS)[:, None]], axis=1
-    ).reshape(2 * LIMBS, t)
+    d = a * a  # [16, ...] diagonal products a_i^2, column 2i
+    # interleave limbs with zeros — interior padding of the leading axis:
+    # (d0, 0, d1, 0, ...) -> columns 0,2,4,...; (0, h0, 0, h1, ...) -> 1,3,5,...
+    zero = jnp.array(0, jnp.uint32)
+    rest = [(0, 0, 0)] * (a.ndim - 1)
+    d_lo = lax.pad(d & _MASK, zero, [(0, 1, 1)] + rest)
+    d_hi = lax.pad(d >> LIMB_BITS, zero, [(1, 0, 1)] + rest)
     terms = [_placed(d_lo, 0, out), _placed(d_hi, 0, out)]
     for i in range(LIMBS - 1):
         ai = lax.slice_in_dim(a, i, i + 1, axis=0)  # [1, T]
@@ -298,7 +357,7 @@ def mul_const_cols(
 ) -> jax.Array:
     """Column sums of hi * c for a small host constant c: [H, T] x [C] ->
     [out, T] raw columns (same lo/hi splitting as :func:`mul_cols`)."""
-    terms = [jnp.zeros((out, hi.shape[1]), jnp.uint32)]
+    terms = [jnp.zeros((out,) + hi.shape[1:], jnp.uint32)]
     for k, cval in enumerate(np.asarray(c_limbs, dtype=np.uint64)):
         cval = int(cval)
         if cval == 0:
@@ -313,16 +372,7 @@ def add_widen(a: jax.Array, b: jax.Array) -> jax.Array:
     """Exact add of two normalized arrays (equal or different widths) ->
     [max(L)+1, T] normalized."""
     w = max(a.shape[0], b.shape[0])
-    t = a.shape[1]
-
-    def pad(x):
-        if x.shape[0] == w:
-            return x
-        return jnp.concatenate(
-            [x, jnp.zeros((w - x.shape[0], t), jnp.uint32)], axis=0
-        )
-
-    return carry_norm(pad(a) + pad(b))
+    return carry_norm(_placed(a, 0, w) + _placed(b, 0, w))
 
 
 def cond_sub(x: jax.Array, m_limbs: np.ndarray) -> jax.Array:
@@ -490,7 +540,7 @@ class SparseFoldField(FoldField):
         width = 18  # value < 2^256 + 16·2^16·m < 2^277
         terms = [_placed(lo, 0, width)]
         for j in range(LIMBS):
-            tj = dev_vec(tab[:, j]).reshape(h, 1)  # column constants
+            tj = dev_vec(tab[:, j]).reshape((h,) + (1,) * (hi.ndim - 1))  # column constants
             prod = hi * tj  # [h, T], products < 2^32
             terms.append(_placed(_add_rows(prod & _MASK), j, width))
             terms.append(_placed(_add_rows(prod >> LIMB_BITS), j + 1, width))
@@ -593,8 +643,7 @@ class MontField:
         return self.mul(x, const_rows(self.r2, x))
 
     def to_plain(self, x: jax.Array) -> jax.Array:
-        pad = jnp.zeros((LIMBS, x.shape[1]), jnp.uint32)
-        return self.redc(jnp.concatenate([x, pad], axis=0))
+        return self.redc(_placed(x, 0, 2 * LIMBS))
 
     def mul(self, a: jax.Array, b: jax.Array) -> jax.Array:
         return self.redc(carry_norm(mul_cols(a, b))[: 2 * LIMBS])
@@ -668,80 +717,16 @@ def _exp_windows(e: int) -> np.ndarray:
     )
 
 
-# When set, shared field/EC code traces in its Mosaic-safe shape (fori
-# loops, masked where-chains, unrolled tables — no scan xs/ys, whose
-# dynamic_slice/dynamic_update_slice lowering Pallas TPU lacks). Otherwise
-# (plain XLA: CPU tests, virtual meshes, fallback), the same math traces as
-# compact lax.scan programs — ~15x smaller HLO, which is the difference
-# between seconds and tens of minutes of XLA-CPU compile on a 1-core host.
-# Integer semantics are identical element-for-element, so both shapes are
-# bit-identical in output — the consensus requirement.
-# A ContextVar, not a module global: a Pallas kernel trace on one thread
-# must not leak the Mosaic shape into a concurrent plain-XLA trace.
-import contextvars as _contextvars
-
-_MOSAIC_TRACE: _contextvars.ContextVar[bool] = _contextvars.ContextVar(
-    "mosaic_trace", default=False
-)
-
-
-def is_mosaic_trace() -> bool:
-    return _MOSAIC_TRACE.get()
-
-
-class mosaic_trace:
-    """Context manager scoping the Mosaic trace shape to this thread."""
-
-    def __enter__(self):
-        self._token = _MOSAIC_TRACE.set(True)
-
-    def __exit__(self, *exc):
-        _MOSAIC_TRACE.reset(self._token)
-
-
-def static_lookup(vals: np.ndarray, i: jax.Array) -> jax.Array:
-    """vals[i] for a static host table and a traced scalar index — a masked
-    where-chain (no gather/dynamic_slice; Mosaic supports neither)."""
-    out = jnp.full((), int(vals[0]), jnp.int32)
-    for j in range(1, len(vals)):
-        out = jnp.where(i == j, np.int32(int(vals[j])), out)
-    return out
-
-
 def pow_static(F, a: jax.Array, e: int) -> jax.Array:
     """a^e in field F for a fixed Python-int exponent.
 
     4-bit windows, MSB first: per window 4 squarings + one table multiply
-    selected branch-free from the 15 precomputed powers; the loop/table
-    shape follows :func:`is_mosaic_trace` (see its comment).
+    selected branch-free from the 15 precomputed powers, as compact
+    ``lax.scan`` programs (an unrolled form is ~15x the HLO, which is the
+    difference between seconds and tens of minutes of XLA-CPU compile).
     """
     wins = _exp_windows(e)
 
-    if is_mosaic_trace():
-        # table[c-1] = a^c for c in 1..15 — 14 unrolled sequential muls
-        tab = [a]
-        for _ in range(14):
-            tab.append(F.mul(tab[-1], a))
-        first = int(wins[0])
-        assert first != 0
-        acc0 = tab[first - 1]
-        if len(wins) == 1:
-            return acc0
-        rest = wins[1:]
-
-        def body(i, acc):
-            c = static_lookup(rest, i)
-            for _ in range(_POW_W):
-                acc = F.sqr(acc)
-            sel = tab[0]
-            for k in range(2, 16):
-                sel = jnp.where(c == k, tab[k - 1], sel)
-            with_mul = F.mul(acc, sel)
-            return jnp.where(c == 0, acc, with_mul)
-
-        return lax.fori_loop(0, len(rest), body, acc0)
-
-    # compact scan shape (plain XLA)
     def _tab_step(prev, _):
         nxt = F.mul(prev, a)
         return nxt, nxt

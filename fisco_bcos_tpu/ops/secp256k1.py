@@ -8,12 +8,9 @@ and the PBFT/BlockSync signature-list check
 (bcos-pbft/bcos-pbft/core/BlockValidator.cpp:141-177) invoke one tx at a time on
 CPU threads. Here a whole block's signatures are one device program.
 
-Two execution paths share one body (bit-identical by integer semantics):
-- **Pallas TPU kernel** (:mod:`fisco_bcos_tpu.ops.pallas_ec`): the entire
-  recover/verify program — field folds, windowed ladder, comb table — runs
-  VMEM-resident over batch tiles. This is the fast path.
-- **Plain XLA**: the same ``*_core`` functions jitted directly; used on CPU
-  (tests, the virtual multi-chip mesh) and as fallback.
+One execution path: the ``*_core`` functions jitted as plain XLA on every
+backend (the hand-tiled Pallas kernels that once traced the same bodies lost
+to it on hardware, were never on a deployment's path, and went in PR 25).
 
 Semantics match the reference:
 - 65-byte signature r‖s‖v; v ∈ {0..3} or {27, 28} (Secp256k1Crypto.cpp:106-108).
@@ -56,7 +53,7 @@ def _g_table() -> jnp.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Batched scalar inversion (runs OUTSIDE the Pallas kernel, plain XLA)
+# Batched scalar inversion
 # ---------------------------------------------------------------------------
 
 
@@ -68,12 +65,13 @@ def inv_mod_n(x):
 
 
 # ---------------------------------------------------------------------------
-# Core bodies (limb-major [16, T]; run under Pallas or plain XLA)
+# Core bodies (limb-leading [16, ...]; the jitted entry points pass a
+# lane-dense batch, limb.lane_dense)
 # ---------------------------------------------------------------------------
 
 
 def verify_core(z, r, s, qx, qy, sinv, g_table):
-    """Batch ECDSA verify. z/r/s/qx/qy: [16, T] plain-domain limb-major;
+    """Batch ECDSA verify. z/r/s/qx/qy: [16, ...] plain-domain limbs;
     sinv = :func:`inv_mod_n`(s) computed outside (batched — garbage on
     s ≡ 0 lanes, which `valid` masks).
 
@@ -105,14 +103,14 @@ def verify_core(z, r, s, qx, qy, sinv, g_table):
 
 
 def recover_project_core(z, r, s, v, rinv, g_table):
-    """Batch ECDSA public-key recovery, projective part (Pallas-resident).
+    """Batch ECDSA public-key recovery, projective part.
 
-    z, r, s: [16, T] plain limb-major; v: [T] int32 recovery id (0..3 or
+    z, r, s: [16, ...] plain limbs; v: [...] int32 recovery id (0..3 or
     27/28, exactly the reference's accepted encodings —
     Secp256k1Crypto.cpp:106; 29/30 must NOT alias to 2/3);
     rinv = :func:`inv_mod_n`(r) computed outside.
     Returns (X, Y, Z [16, T] field-domain projective Q, ok bool[T]);
-    :func:`recover_finish` converts to plain affine outside the kernel.
+    :func:`recover_finish` converts to plain affine.
     """
     C = _C
     F, Fn = C.F, C.Fn
@@ -144,7 +142,7 @@ def recover_project_core(z, r, s, v, rinv, g_table):
 
 def recover_finish(X, Y, Z, valid):
     """Projective Q -> plain affine (qx, qy, ok), Z inversion batched
-    across lanes (plain XLA, runs after the kernel)."""
+    across lanes."""
     C = _C
     qx_e, qy_e, inf = pt_to_affine_batch((X, Y, Z), C)
     valid &= ~inf
@@ -162,94 +160,36 @@ def recover_core(z, r, s, v, g_table):
 
 
 # ---------------------------------------------------------------------------
-# Device entry points ([B, 16] batch-major public API, kept from round 1)
+# Device entry points ([B, 16] batch-major public API; converted to the
+# lane-dense [16, S, 128] once on the way in and back once on the way out)
 # ---------------------------------------------------------------------------
-
-
-_PALLAS_BROKEN = False
-
-
-def _use_pallas() -> bool:
-    """Pallas is OPT-IN (FISCO_FORCE_PALLAS=1, TPU only): on hardware the
-    plain-XLA paths beat the Mosaic kernels (tool/tpu_probe.py is the probe;
-    its readings are not of today's code — docs/crypto_plane_perf.md),
-    because XLA already keeps the [16, T] limb chains vreg-resident and
-    fuses them; the hand-tiled kernel only adds scheduling overhead. The
-    kernels stay as the bit-identity cross-check until ROADMAP Queue 3
-    removes them, but the hot path is XLA on every backend. FISCO_NO_PALLAS
-    still wins over the force flag so one switch can pin the XLA leg in any
-    process."""
-    import os
-
-    if _PALLAS_BROKEN or os.environ.get("FISCO_NO_PALLAS"):
-        return False
-    return os.environ.get("FISCO_FORCE_PALLAS") == "1" and jax.default_backend() == "tpu"
-
-
-def pallas_or_xla(fn_pallas, fn_xla, *args):
-    """Run the Pallas kernel; on a KERNEL failure (Mosaic rejects constructs
-    the CPU interpreter accepts — the kernels' first hardware compile happens
-    in the field) degrade PERMANENTLY to the bit-identical XLA path instead
-    of killing the caller (a bench run or a live node).
-
-    The latch only sticks when the XLA retry of the SAME args succeeds —
-    proving the kernel, not the data, was at fault. A data error (bad
-    shape/dtype) re-raises from the XLA path WITHOUT latching, so one
-    malformed batch can't silently demote a healthy TPU to the slow path."""
-    global _PALLAS_BROKEN
-    try:
-        return fn_pallas(*args)
-    except Exception as e:  # Mosaic/lowering/compile failures have no
-        # common base class
-        out = fn_xla(*args)  # data errors raise here, latch untouched
-        _PALLAS_BROKEN = True
-        from ..resilience import HEALTH
-        from ..utils.log import get_logger
-
-        get_logger("ops").warning(
-            "Pallas kernel failed on this backend (%s: %s) but the XLA path "
-            "succeeded; using XLA for the rest of this process",
-            type(e).__name__, str(e)[:300],
-        )
-        # the latch is permanent for this process: report it so /health
-        # shows the node running on the (slower) XLA leg — informational
-        # (critical=False), the node serves correctly throughout
-        HEALTH.degrade(
-            "device-pallas", f"kernel latched off ({type(e).__name__})",
-            critical=False,
-        )
-        return out
 
 
 @jax.jit
 def _verify_xla(z, r, s, qx, qy):
-    sT = s.T
-    return verify_core(z.T, r.T, sT, qx.T, qy.T, inv_mod_n(sT), _g_table())
+    b = z.shape[0]
+    z, r, s, qx, qy = (limb.lane_dense(a) for a in (z, r, s, qx, qy))
+    ok = verify_core(z, r, s, qx, qy, inv_mod_n(s), _g_table())
+    return limb.batch_lanes(ok, b)
 
 
 @jax.jit
 def _recover_xla(z, r, s, v):
-    qx, qy, ok = recover_core(z.T, r.T, s.T, v, _g_table())
-    return qx.T, qy.T, ok
+    b = z.shape[0]
+    z, r, s = (limb.lane_dense(a) for a in (z, r, s))
+    qx, qy, ok = recover_core(z, r, s, limb.lane_mask(v, z), _g_table())
+    return limb.batch_major(qx, b), limb.batch_major(qy, b), limb.batch_lanes(ok, b)
 
 
 def verify_device(z, r, s, qx, qy):
     """Batch ECDSA verify. All inputs [B, 16] plain-domain limbs (batch
     major); returns bool[B]."""
-    if _use_pallas():
-        from .pallas_ec import verify_pallas
-
-        return pallas_or_xla(verify_pallas, _verify_xla, z, r, s, qx, qy)
     return _verify_xla(z, r, s, qx, qy)
 
 
 def recover_device(z, r, s, v):
     """Batch ECDSA recover. z/r/s: [B, 16] limbs; v: [B] int32.
     Returns (qx, qy [B, 16] plain limbs, ok bool[B])."""
-    if _use_pallas():
-        from .pallas_ec import recover_pallas
-
-        return pallas_or_xla(recover_pallas, _recover_xla, z, r, s, v)
     return _recover_xla(z, r, s, v)
 
 

@@ -13,7 +13,7 @@ messages, so e-derivation itself runs on the batch SM3 kernel.
 Verification: t = (r + s) mod n (t ≠ 0); (x1, y1) = s*G + t*Q;
 valid iff (e + x1) mod n == r.
 
-The EC plane is the limb-major windowed ladder shared with secp256k1
+The EC plane is the lane-dense windowed ladder shared with secp256k1
 (:mod:`fisco_bcos_tpu.ops.ec`); SM2's prime has a 225-bit complement, so
 the field is the generic Montgomery path (``limb.MontField``) by default.
 The prime is also a Solinas prime (2^256 − p = 2^224 + 2^96 − 2^64 + 1),
@@ -42,6 +42,7 @@ from .ec import (
 )
 from .hash_common import bucket_batch as _bucket
 from .hash_common import pad_rows as _pad_rows
+from . import limb
 from .limb import const_rows, eq, is_zero, lt
 from .sm3 import sm3_batch_async
 
@@ -49,10 +50,10 @@ _C = SM2_OPS
 
 
 def verify_project_core(e, r, s, qx, qy, g_table):
-    """Batch SM2 verify, projective part — Mosaic-compatible (runs inside
-    the Pallas kernel on TPU, or plain XLA on CPU).
+    """Batch SM2 verify, projective part.
 
-    Limb-major [16, T] plain-domain inputs: e = SM3(ZA ‖ M) digest as an
+    Limb-leading [16, ...] plain-domain inputs (lane-dense [16, S, 128] from
+    the jitted entry): e = SM3(ZA ‖ M) digest as an
     integer; (r, s): signature; (qx, qy): affine public key.
     Returns (X, Z [16, T] Montgomery-domain projective coords of
     s*G + t*Q, valid bool[T]) — the final comparison needs the affine x1
@@ -94,17 +95,13 @@ def verify_core(e, r, s, qx, qy, g_table):
 @jax.jit
 def _verify_xla(e, r, s, qx, qy):
     gt = jnp.asarray(g_comb_table(_C.name))
-    return verify_core(e.T, r.T, s.T, qx.T, qy.T, gt)
+    b = e.shape[0]
+    ok = verify_core(*(limb.lane_dense(a) for a in (e, r, s, qx, qy)), gt)
+    return limb.batch_lanes(ok, b)
 
 
 def verify_device(e, r, s, qx, qy):
     """Batch SM2 verify. All inputs [B, 16] plain-domain batch-major limbs."""
-    from .secp256k1 import _use_pallas, pallas_or_xla
-
-    if _use_pallas():
-        from .pallas_ec import sm2_verify_pallas
-
-        return pallas_or_xla(sm2_verify_pallas, _verify_xla, e, r, s, qx, qy)
     return _verify_xla(e, r, s, qx, qy)
 
 

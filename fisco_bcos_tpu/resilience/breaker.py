@@ -48,7 +48,7 @@ class HealthRegistry:
         (e.g. a storage shard is gone — 2PC cannot commit) and /health
         answers 503 so probes take it out of rotation. ``critical=False``:
         the node KEEPS serving through a fallback (survivor executors, the
-        host crypto loop, the XLA leg after a Pallas latch) — /health stays
+        host crypto loop) — /health stays
         200 with the degradation in the JSON body, because evicting a
         correctly-serving node over a permanent informational latch would
         turn one slow path into an outage."""

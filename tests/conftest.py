@@ -156,21 +156,3 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers", "slow: multi-process / long-wall-clock end-to-end tests"
     )
-    config.addinivalue_line(
-        "markers",
-        "pallas_interpret: numeric Pallas-interpreter cases (10+ min XLA-CPU "
-        "compile per kernel on this host) — deselected unless "
-        "FISCO_PALLAS_INTERPRET=1; kernel-body rot is covered default-on by "
-        "test_pallas_trace.py",
-    )
-
-
-def pytest_collection_modifyitems(config, items):
-    if os.environ.get("FISCO_PALLAS_INTERPRET"):
-        return
-    keep, drop = [], []
-    for item in items:
-        (drop if item.get_closest_marker("pallas_interpret") else keep).append(item)
-    if drop:
-        config.hook.pytest_deselected(items=drop)
-        items[:] = keep

@@ -200,7 +200,6 @@ def _air4_doc():
         "health": _health(),
         "breaker_state": "closed",
         "compiles_after_first_block": 0, "compiled_in_window": {},
-        "pallas_loaded": False,
     }
 
 

@@ -1,0 +1,271 @@
+"""The limb arithmetic on lane-dense batches, against Python integers.
+
+``ops/limb.py`` keeps the limb index on the leading axis and the batch in the
+trailing dimensions — ``[L, S, 128]`` in the admission program over 1,024
+lanes (a limb is more than one vreg), ``[L, T]`` up to there.
+Every case here runs the public batch-major ``[B, L]`` -> ``lane_dense`` ->
+field operation -> ``batch_major`` path at a lane count that pads (24), at
+whole multiples of 128 in ``[L, T]`` and at 1,152 lanes in ``[L, 9, 128]``, on
+random and edge values, for secp256k1's p and n (``FoldField``) and SM2's p
+(``MontField``).
+
+The structural guards at the end pin what the chip's op profile said costs
+the time (PERF.md §6, PR 25): limb-axis slices and concatenates in a field
+multiplication, and the size of one window step of the GLV ladder.
+"""
+
+import random
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from fisco_bcos_tpu.ops import ec, limb
+
+_R = 1 << 256
+_P = ec.SECP256K1_OPS.curve.p
+_N = ec.SECP256K1_OPS.curve.n
+_SM2P = ec.SM2_OPS.curve.p
+
+FIELDS = {
+    "fold-p": limb.make_fold_field(_P),
+    "fold-n": limb.make_fold_field(_N),
+    "mont-sm2p": limb.make_mont_field(_SM2P),
+}
+LANES = [24, 128, 512, 1024, 1152]
+
+
+def _edge(m: int) -> list[int]:
+    return [0, 1, m - 1, m % m, (_R - 1) % m, 0xFFFF, (1 << 128) - 1, m - 2]
+
+
+def _values(m: int, n: int, seed: int) -> list[int]:
+    rng = random.Random(seed)
+    vals = _edge(m) + [rng.randrange(m) for _ in range(n)]
+    rng.shuffle(vals)
+    return vals[:n]
+
+
+def _dense(xs, width=limb.LIMBS):
+    """Python ints -> batch-major [B, width] -> lane-dense limbs."""
+    rows = np.stack([limb.int_to_rows(x, width) for x in xs])
+    out = limb.lane_dense(jnp.asarray(rows))
+    lanes = -(-len(xs) // 128) * 128
+    # a limb over one vreg: [L, S, 128]; up to 1,024 lanes limbs share vregs, [L, T]
+    assert out.shape == ((width, lanes // 128, 128) if lanes > 1024 else (width, lanes))
+    return out
+
+
+def _ints(x, n):
+    """Lane-dense limbs -> the first n lanes as Python ints, through the
+    public way out (padding lanes dropped)."""
+    rows = np.asarray(limb.batch_major(x, n))
+    assert rows.shape == (n, x.shape[0])
+    return [sum(int(v) << (16 * i) for i, v in enumerate(r)) for r in rows]
+
+
+def _codec(F):
+    m = F.m_int
+    if isinstance(F, limb.MontField):
+        rinv = pow(_R, -1, m)
+        return (lambda xs: _dense([x * _R % m for x in xs])), (
+            lambda a, n: [v * rinv % m for v in _ints(a, n)]
+        )
+    return _dense, _ints
+
+
+BINARY = {
+    "mul": (lambda F, a, b: F.mul(a, b), lambda m, x, y: x * y % m),
+    "add": (lambda F, a, b: F.add(a, b), lambda m, x, y: (x + y) % m),
+    "sub": (lambda F, a, b: F.sub(a, b), lambda m, x, y: (x - y) % m),
+}
+UNARY = {
+    "sqr": (lambda F, a: F.sqr(a), lambda m, x: x * x % m),
+    "neg": (lambda F, a: F.neg(a), lambda m, x: -x % m),
+    "mul_small": (lambda F, a: F.mul_small(a, 21), lambda m, x: 21 * x % m),
+}
+
+
+@pytest.mark.parametrize("lanes", LANES)
+@pytest.mark.parametrize("op", sorted(BINARY))
+@pytest.mark.parametrize("field", sorted(FIELDS))
+def test_field_binary_ops_match_python_ints(field, op, lanes):
+    F = FIELDS[field]
+    m = F.m_int
+    enc, dec = _codec(F)
+    xs, ys = _values(m, lanes, 1), _values(m, lanes, 2)
+    dev, ref = BINARY[op]
+    assert dec(dev(F, enc(xs), enc(ys)), lanes) == [ref(m, x, y) for x, y in zip(xs, ys)]
+
+
+@pytest.mark.parametrize("lanes", LANES)
+@pytest.mark.parametrize("op", sorted(UNARY))
+@pytest.mark.parametrize("field", sorted(FIELDS))
+def test_field_unary_ops_match_python_ints(field, op, lanes):
+    F = FIELDS[field]
+    m = F.m_int
+    enc, dec = _codec(F)
+    xs = _values(m, lanes, 3)
+    dev, ref = UNARY[op]
+    assert dec(dev(F, enc(xs)), lanes) == [ref(m, x) for x in xs]
+
+
+@pytest.mark.parametrize("lanes", LANES)
+@pytest.mark.parametrize("field", ["fold-p", "fold-n"])
+def test_reduce_wide_folds_a_full_product(field, lanes):
+    F = FIELDS[field]
+    rng = random.Random(4)
+    wide = [(_R - 1) ** 2, 0, _R, F.m_int * F.m_int] + [
+        rng.randrange((_R - 1) ** 2 + 1) for _ in range(lanes)
+    ]
+    wide = wide[:lanes]
+    got = F.reduce_wide(_dense(wide, 32), (_R - 1) ** 2 + 1)
+    assert _ints(got, lanes) == [w % F.m_int for w in wide]
+
+
+@pytest.mark.parametrize("lanes", LANES)
+@pytest.mark.parametrize("limbs", [16, 33, 48])
+def test_carry_norm_and_sub_borrow_chains(limbs, lanes):
+    """Carry chains of every length the programs use (33: a product; 48:
+    BLS, two lookahead words), with the all-0xFFFF runs that carry end to
+    end."""
+    top = (1 << (16 * limbs)) - 1
+    rng = random.Random(5)
+    xs = ([top, top, 0, 1, top - 1] + [rng.randrange(top + 1) for _ in range(lanes)])[:lanes]
+    ys = ([1, top, 0, top, 1] + [rng.randrange(top + 1) for _ in range(lanes)])[:lanes]
+    a, b = _dense(xs, limbs), _dense(ys, limbs)
+    assert _ints(limb.add_widen(a, b), lanes) == [x + y for x, y in zip(xs, ys)]
+    diff, borrow = limb.sub_borrow(a, b)
+    assert _ints(diff, lanes) == [(x - y) % (top + 1) for x, y in zip(xs, ys)]
+    assert list(np.asarray(limb.batch_lanes(borrow, lanes))) == [x < y for x, y in zip(xs, ys)]
+    # raw column sums far above 16 bits (what mul_small feeds: < 2^31)
+    cols = a * np.uint32(0x7FFF)
+    assert _ints(limb.carry_norm(cols), lanes) == [x * 0x7FFF for x in xs]
+
+
+@pytest.mark.parametrize("lanes", LANES)
+def test_compare_and_select(lanes):
+    xs, ys = _values(_P, lanes, 6), _values(_P, lanes, 7)
+    ys[:4] = xs[:4]  # equal lanes
+    a, b = _dense(xs), _dense(ys)
+
+    def lanes_of(mask):
+        return list(np.asarray(limb.batch_lanes(mask, lanes)))
+
+    assert lanes_of(limb.lt(a, b)) == [x < y for x, y in zip(xs, ys)]
+    assert lanes_of(limb.geq(a, b)) == [x >= y for x, y in zip(xs, ys)]
+    assert lanes_of(limb.eq(a, b)) == [x == y for x, y in zip(xs, ys)]
+    assert lanes_of(limb.is_zero(a)) == [x == 0 for x in xs]
+    picked = limb.select(limb.lt(a, b), a, b)
+    assert _ints(picked, lanes) == [min(x, y) for x, y in zip(xs, ys)]
+    pair = limb.select(limb.lt(a, b), (a, b), (b, a))
+    assert _ints(pair[1], lanes) == [max(x, y) for x, y in zip(xs, ys)]
+
+
+def test_mul_cols_is_the_plain_product():
+    xs, ys = _values(_R, 128, 8), _values(_R, 128, 9)
+    xs[0] = ys[0] = _R - 1
+    wide = limb.carry_norm(limb.mul_cols(_dense(xs), _dense(ys)))
+    assert _ints(wide, 128) == [x * y for x, y in zip(xs, ys)]
+    sq = limb.carry_norm(limb.sqr_cols(_dense(xs)))
+    assert _ints(sq, 128) == [x * x for x in xs]
+
+
+# -- the admission program's recover against the benchmark's plain reference --
+
+
+@pytest.mark.parametrize("n", [128, 1152])
+def test_recover_matches_refcrypto_with_corrupted_lanes(n):
+    """128 lanes (``[16, 128]``) and 1,152 (``[16, 9, 128]``, the layout of
+    the full-block program): every sound lane recovers the signer's key bit
+    for bit; r = 0, s >= n, v = 29, a non-residue x and r >= n each lower
+    their lane's bit, leak no key and never raise."""
+    from benchmark import refcrypto
+    from fisco_bcos_tpu.ops import secp256k1
+    from fisco_bcos_tpu.ops.bigint import bytes_be_to_limbs, limbs_to_bytes_be
+
+    rng = random.Random(2500)
+    secrets = [rng.randrange(1, refcrypto.N) for _ in range(8)]
+    digests = [refcrypto.keccak256(b"lane %d" % i) for i in range(n)]
+    sigs = np.frombuffer(
+        b"".join(refcrypto.sign(d, secrets[i % 8]) for i, d in enumerate(digests)), np.uint8
+    ).reshape(n, 65).copy()
+    pubs = [refcrypto.pubkey_bytes(s) for s in secrets]
+    order = np.frombuffer(refcrypto.N.to_bytes(32, "big"), np.uint8)
+    sigs[3, :32] = 0  # r = 0
+    sigs[17, 32:64] = order  # s = n
+    sigs[40, 64] = 29  # v = 29 must not alias to 2
+    x = next(v for v in range(2, 99) if pow(v**3 + 7, (_P - 1) // 2, _P) != 1)
+    sigs[77, :32] = np.frombuffer(x.to_bytes(32, "big"), np.uint8)  # x^3 + 7 is no square
+    sigs[127, :32] = np.frombuffer((_P - 1).to_bytes(32, "big"), np.uint8)  # r >= n
+    rejected = {3, 17, 40, 77, 127}
+
+    z = jnp.asarray(bytes_be_to_limbs(np.frombuffer(b"".join(digests), np.uint8).reshape(n, 32)))
+    r = jnp.asarray(bytes_be_to_limbs(sigs[:, :32]))
+    s = jnp.asarray(bytes_be_to_limbs(sigs[:, 32:64]))
+    v = jnp.asarray(sigs[:, 64].astype(np.int32))
+    qx, qy, ok = secp256k1.recover_device(z, r, s, v)
+    ok = np.asarray(ok)
+    got = np.concatenate(
+        [limbs_to_bytes_be(np.asarray(qx)), limbs_to_bytes_be(np.asarray(qy))], axis=-1
+    )
+    for i in range(n):
+        if i in rejected:
+            assert not ok[i], f"lane {i} admitted"
+            assert not got[i].any(), f"lane {i} leaked a key"
+        else:
+            assert ok[i] and bytes(got[i]) == pubs[i % 8], f"lane {i}"
+
+
+# -- structural guards (tracing only: seconds on the CPU) ---------------------
+
+
+def _count(jaxpr, names) -> int:
+    """Equations named in `names`, nested jaxprs multiplied out once each."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in names:
+            total += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            total += _count(sub, names)
+    return total
+
+
+_DYNAMIC_SHUFFLES = ("dynamic_slice", "gather", "scatter")
+
+
+def test_field_mul_shuffles_no_limbs():
+    """A field multiplication holds no concatenate at all (97 before PR 25),
+    at most 40 limb-axis slices (135 before: the operand's 16 limbs, the
+    fold's lo/hi halves, a dropped top limb), and every limb shift or
+    placement is one pad. The op count cannot creep back unseen."""
+    a = jax.ShapeDtypeStruct((16, 8, 128), jnp.uint32)
+    # equations: 337 for p (597 before); n's nine-limb complement folds wider
+    for F, eqns in ((FIELDS["fold-p"], 400), (FIELDS["fold-n"], 650)):
+        jaxpr = jax.make_jaxpr(F.mul)(a, a).jaxpr
+        assert _count(jaxpr, ("concatenate",)) <= 8
+        assert _count(jaxpr, ("slice",)) <= 40
+        assert _count(jaxpr, _DYNAMIC_SHUFFLES) == 0
+        assert len(jaxpr.eqns) <= eqns
+
+
+def test_glv_window_step_stays_small():
+    """One window step of quad_mul_windowed (4 doublings, 2 complete and 2
+    mixed additions): at most 1,000 concatenates (10,554 before PR 25)."""
+    C = ec.SECP256K1_OPS
+    a = jax.ShapeDtypeStruct((16, 8, 128), jnp.uint32)
+
+    def step(x, y, z, qx, qy):
+        acc = (x, y, z)
+        for _ in range(ec.WINDOW):
+            acc = ec.pt_double(acc, C)
+        for _ in range(2):
+            acc = ec.pt_add(acc, (qx, qy, z), C)
+        for _ in range(2):
+            acc = ec.pt_add_mixed(acc, (qx, qy), C)
+        return acc
+
+    jaxpr = jax.make_jaxpr(step)(a, a, a, a, a).jaxpr
+    assert _count(jaxpr, ("concatenate",)) <= 1000
+    assert len(jaxpr.eqns) <= 45_000  # 67,298 before

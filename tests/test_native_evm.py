@@ -310,28 +310,6 @@ def test_sm_suite_frames_stay_on_python():
     assert res.output == sm3(b"\x00" * 32)
 
 
-def test_pallas_latch_not_set_by_data_errors():
-    """A data error (XLA retry fails too) must re-raise WITHOUT latching;
-    only a kernel-specific failure (XLA succeeds) sticks the latch."""
-    from fisco_bcos_tpu.ops import secp256k1 as s
-
-    s._PALLAS_BROKEN = False
-
-    def broken(*a):
-        raise RuntimeError("mosaic lowering")
-
-    def xla_also_fails(*a):
-        raise ValueError("bad shape")
-
-    with pytest.raises(ValueError):
-        s.pallas_or_xla(broken, xla_also_fails, 1)
-    assert s._PALLAS_BROKEN is False  # data error: no latch
-
-    assert s.pallas_or_xla(broken, lambda *a: "ok", 1) == "ok"
-    assert s._PALLAS_BROKEN is True  # kernel error: latched
-    s._PALLAS_BROKEN = False
-
-
 class TestDifferentialFuzz:
     """Seeded random-program fuzz: both engines must agree on EVERY program,
     including ones that trip errors mid-stream or escape at a CALL and

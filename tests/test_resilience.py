@@ -419,11 +419,11 @@ def test_health_endpoint_transitions():
         reg.ok("storage")
         # a non-critical (serving-through-fallback) degradation stays 200:
         # probes must not evict a node that is answering correctly
-        reg.degrade("device-pallas", "latched to XLA", critical=False)
+        reg.degrade("device-crypto", "serving from the host loop", critical=False)
         with urllib.request.urlopen(url, timeout=5) as resp:
             body = json.loads(resp.read())
             assert resp.status == 200 and body["status"] == "degraded"
-        reg.ok("device-pallas")
+        reg.ok("device-crypto")
         with urllib.request.urlopen(url, timeout=5) as resp:
             assert json.loads(resp.read())["status"] == "ok"
     finally:

@@ -10,7 +10,7 @@ Walks the SAME jit inventory the static analyzers use
 program is either warmed — its host wrapper is driven with shape-bucketed
 dummy inputs, compiling it into the persistent cache
 (``fisco_bcos_tpu.utils.jaxenv.configure_compile_cache``) — or listed
-as skipped with a reason (pallas kernels off-TPU, sharded variants on a
+as skipped with a reason (sharded variants on a
 single-device host, BLS on CPU backends where the crypto seam routes to
 the host reference anyway; ``--include-bls`` forces it). The compile
 ledger (observability/device.py) measures every program: the manifest
@@ -160,10 +160,6 @@ def _skip_sharded(_bucket: int):
     )
 
 
-def _skip_pallas(_bucket: int):
-    return "pallas kernels are TPU-only (FISCO_USE_PALLAS gates them)"
-
-
 # file (as jitmap.inventory reports it) -> (op label, warmer).  A warmer
 # returns None (warmed) or a skip-reason string; raising marks it failed.
 WARMERS = {
@@ -177,7 +173,6 @@ WARMERS = {
     "fisco_bcos_tpu/ops/merkle.py": ("merkle", _warm_merkle),
     "fisco_bcos_tpu/ops/bls12_381.py": ("bls12_381", _warm_bls),
     "fisco_bcos_tpu/ops/poseidon.py": ("poseidon", _warm_poseidon),
-    "fisco_bcos_tpu/ops/pallas_ec.py": ("pallas_ec", _skip_pallas),
     "fisco_bcos_tpu/parallel/sharding.py": ("sharding", _skip_sharded),
     "fisco_bcos_tpu/crypto/admission.py": ("admission", _warm_admission),
 }
