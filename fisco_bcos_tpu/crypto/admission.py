@@ -12,20 +12,37 @@ tensors, and leaves as (sender addresses, validity bitmap, recovered pubkeys).
 Invalid lanes never raise — they lower a validity bit (consensus code must be
 total). See also the #1 batch-verify hot loop in the reference,
 bcos-txpool/sync/TransactionSync.cpp:521-553 (tbb::parallel_for over verify).
+
+A national-crypto chain (``sm_crypto=true``) runs the same step as
+
+    tx hash (SM3)  →  e = SM3(ZA ‖ hash)  →  SM2 verify of the carried key
+    →  sender = right160(SM3(pub))
+
+(:func:`sm_admission_core`; the 128-byte signature r ‖ s ‖ pub carries the
+key, bcos-crypto signature/sm2/SM2Crypto.cpp:29-91). What differs between the
+two suites is one :class:`_Body`; marshalling, the plane executor, the breaker,
+the mesh fan-out and the packed result are shared.
 """
 
 from __future__ import annotations
 
+import functools
 import os
+from collections.abc import Callable
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import keccak, secp256k1
-from ..ops.address import sender_address_device
-from ..ops.bigint import bytes_be_to_limbs, digest_words_le_to_limbs
-from ..ops.hash_common import pad_keccak, pad_rows
+from ..ops import keccak, secp256k1, sm2, sm3
+from ..ops.address import sender_address_device, sm_sender_address_device
+from ..ops.bigint import (
+    bytes_be_to_limbs,
+    digest_words_be_to_limbs,
+    digest_words_le_to_limbs,
+)
+from ..ops.hash_common import pad_keccak, pad_md64, pad_rows
 
 
 def admission_core(blocks, nblocks, r, s, v):
@@ -78,6 +95,36 @@ def _admission_packed(blocks, nblocks, r, s, v):
 admission_step_packed = jax.jit(_admission_packed)
 
 
+def sm_admission_core(blocks, nblocks, r, s, qx, qy):
+    """The fused SM2/SM3 admission body, unjitted like :func:`admission_core`
+    and shared the same way by the single-chip jit and the sharded wrapper.
+
+    blocks [B, M, 16] + nblocks [B] are the pre-padded SM3 form of each tx's
+    signed payload (``hash_common.pad_md64``); r, s and the carried public key
+    (qx, qy) are [B, 16] limbs split from the 128-byte signature. ZA, e and
+    the address input are assembled from the limbs here, on the device.
+
+    Returns the tuple :func:`admission_core` returns. A rejected lane (range
+    check, carried key not a point of the curve, or not the signer's) comes
+    back with address and key zeroed; z is always the payload's digest."""
+    h = sm3.sm3_blocks(blocks, nblocks)
+    e = digest_words_be_to_limbs(sm2.e_device(h, qx, qy))
+    ok = sm2.verify_rows(e, r, s, qx, qy)
+    keep = ok[:, None]
+    addr = jnp.where(keep, sm_sender_address_device(qx, qy), 0)
+    qx, qy = (jnp.where(keep, q, 0) for q in (qx, qy))
+    return addr, ok, qx, qy, digest_words_be_to_limbs(h)
+
+
+def _sm_admission_packed(blocks, nblocks, r, s, qx, qy):
+    return pack_admission_device(
+        *sm_admission_core(blocks, nblocks, r, s, qx, qy)
+    )
+
+
+sm_admission_step_packed = jax.jit(_sm_admission_packed)
+
+
 def _admit_batch_native(payloads, sigs65):
     """Host-loop admission through the native C core (keccak → recover →
     address), bit-identical to the device program on valid lanes
@@ -111,9 +158,107 @@ def _admit_batch_native(payloads, sigs65):
     return senders, ok, pubs, digests
 
 
+def _admit_batch_host_sm(payloads, sigs128, native: bool):
+    """SM admission on the host (SM3 → SM2 verify of the carried key →
+    address), lane for lane what the device program answers. ``native``
+    verifies through the C core's batch loop and gives None where the library
+    is missing; otherwise the per-item loop, which always answers (the
+    breaker's fallback)."""
+    from .suite import SM3, SM2Crypto
+
+    sm3_hash, impl = SM3().hash, SM2Crypto()
+    n = len(payloads)
+    digests = np.frombuffer(
+        b"".join(sm3_hash(p) for p in payloads), dtype=np.uint8
+    ).reshape(n, 32)
+    rs, ss, pubs = sigs128[:, :32], sigs128[:, 32:64], sigs128[:, 64:128]
+    if native:
+        ok = impl._native_batch_verify(digests, pubs, rs, ss)
+        if ok is None:
+            return None
+    else:
+        ok = impl._host_verify_loop(digests, rs, ss, pubs)
+    pubs = np.where(ok[:, None], pubs, 0).astype(np.uint8)
+    senders = np.zeros((n, 20), dtype=np.uint8)
+    for i in np.flatnonzero(ok):
+        senders[i] = np.frombuffer(sm3_hash(pubs[i].tobytes())[12:], dtype=np.uint8)
+    return senders, ok, pubs, digests
+
+
+# -- the two suites' bodies ----------------------------------------------------
+
+
+def _marshal_secp(payloads, sigs65, bb):
+    blocks, nblocks = pad_keccak(payloads)
+    return (
+        blocks,
+        nblocks,
+        pad_rows(bytes_be_to_limbs(sigs65[:, :32]), bb),
+        pad_rows(bytes_be_to_limbs(sigs65[:, 32:64]), bb),
+        pad_rows(sigs65[:, 64].astype(np.int32), bb),
+    )
+
+
+def _marshal_sm(payloads, sigs128, bb):
+    blocks, nblocks = pad_md64(payloads)
+    return (blocks, nblocks) + tuple(
+        pad_rows(bytes_be_to_limbs(sigs128[:, lo : lo + 32]), bb)
+        for lo in (0, 32, 64, 96)  # r, s, Px, Py
+    )
+
+
+def _host_native_or_raise(payloads, sigs65):
+    out = _admit_batch_native(payloads, sigs65)
+    if out is None:
+        raise RuntimeError("native admission unavailable for host fallback")
+    return out
+
+
+@dataclass(frozen=True)
+class _Body:
+    """What one suite's fused admission is made of; everything below the
+    table is written once against it."""
+
+    op: str  # device_span op: spans, phases, items, compile-ledger episodes
+    plane_op: str  # the plane merges requests of one op only
+    sig_len: int
+    packed: Callable  # the unjitted program -> [B, 117]; the sharded wrapper's body
+    step: Callable  # jax.jit(packed), through the module's name for it
+    mblocks: Callable[[int], int]  # longest payload's bytes -> message blocks
+    marshal: Callable  # (payloads, sigs, bucket) -> the program's operands
+    native: Callable  # (payloads, sigs) -> result, or None without the library
+    host: Callable  # (payloads, sigs) -> result: the breaker's fallback
+
+
+_BODIES = {
+    ("secp256k1", "keccak256"): _Body(
+        "admission", "admission", 65, _admission_packed,
+        lambda *operands: admission_step_packed(*operands),
+        lambda n: n // 136 + 1, _marshal_secp, _admit_batch_native,
+        _host_native_or_raise,
+    ),
+    ("sm2", "sm3"): _Body(
+        "admission_sm", "admission.sm", 128, _sm_admission_packed,
+        lambda *operands: sm_admission_step_packed(*operands),
+        lambda n: (n + 8) // 64 + 1, _marshal_sm,
+        functools.partial(_admit_batch_host_sm, native=True),
+        functools.partial(_admit_batch_host_sm, native=False),
+    ),
+}
+_SECP = _BODIES["secp256k1", "keccak256"]
+
+
+def _body_of(suite) -> _Body | None:
+    """The fused body of a suite (crypto.suite.CryptoSuite), None where it
+    has none (ed25519); no suite means the default one."""
+    if suite is None:
+        return _SECP
+    return _BODIES.get((suite.signature_impl.name, suite.hash_impl.name))
+
+
 # -- multi-device fan-out -----------------------------------------------------
 
-_SHARD_CACHE: dict[int, object] = {}
+_SHARD_CACHE: dict[tuple[str, int], object] = {}
 
 
 def _shard_min() -> int:
@@ -127,7 +272,7 @@ def _shard_min() -> int:
         return 4096
 
 
-def _maybe_sharded_step(bb: int):
+def _maybe_sharded_step(body: _Body, bb: int):
     """The cached sharded admission program when the bucketed batch `bb`
     clears the fan-out threshold on a multi-device mesh; None otherwise
     (single-chip jit). Nothing is caught here: a mesh or program that fails
@@ -138,43 +283,43 @@ def _maybe_sharded_step(bb: int):
     ndev = len(jax.devices())
     if ndev <= 1 or bb < max(_shard_min(), ndev) or bb % ndev:
         return None
-    step = _SHARD_CACHE.get(ndev)
+    step = _SHARD_CACHE.get((body.op, ndev))
     if step is None:
         from ..parallel.sharding import make_mesh, sharded_admission_packed
 
-        step = _SHARD_CACHE[ndev] = sharded_admission_packed(make_mesh(ndev))
+        step = _SHARD_CACHE[body.op, ndev] = sharded_admission_packed(
+            make_mesh(ndev), body=body.packed
+        )
     return step
 
 
 def _admit_batch_device(
-    payloads, sigs65, allow_shard: bool = False
+    payloads, sigs, allow_shard: bool = False, body: _Body = _SECP
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """The fused device program (keccak → recover → address), one result
-    transfer. `allow_shard=True` (plane dispatches only) fans the bucketed
-    batch out over the local device mesh when it clears _shard_min."""
+    """The fused device program of `body` (hash → recover or verify →
+    address), one result transfer. `allow_shard=True` (plane dispatches only)
+    fans the bucketed batch out over the local device mesh when it clears
+    _shard_min."""
     from ..observability.device import device_span
     from ..ops.hash_common import bucket_batch
 
     bsz = len(payloads)
     payloads = list(payloads)
-    # the shape the program compiles for, known before any marshalling:
-    # pad_keccak buckets the batch dim (empty-message pad rows) and the
-    # message-block dim; r/s/v follow the blocks tensor's bucket
+    # the shape the program compiles for, known before any marshalling: the
+    # padders bucket the batch dim (empty-message pad rows) and the
+    # message-block dim; the signature operands follow the blocks tensor's
+    # bucket
     bb = bucket_batch(max(bsz, 1))
-    mblocks = bucket_batch(max(map(len, payloads), default=0) // 136 + 1)
-    step = _maybe_sharded_step(bb) if allow_shard else None
-    op = "admission" if step is None else "admission_sharded"
+    mblocks = bucket_batch(body.mblocks(max(map(len, payloads), default=0)))
+    step = _maybe_sharded_step(body, bb) if allow_shard else None
+    op = body.op if step is None else body.op + "_sharded"
     if step is None:
-        step = admission_step_packed
+        step = body.step
     with device_span(op, bsz, shape_key=(bb, mblocks)) as sp:
         with sp.phase("marshal"):
-            blocks, nblocks = pad_keccak(payloads)
-            sigs65 = np.asarray(sigs65, dtype=np.uint8)
-            r = pad_rows(bytes_be_to_limbs(sigs65[:, :32]), bb)
-            s = pad_rows(bytes_be_to_limbs(sigs65[:, 32:64]), bb)
-            v = pad_rows(sigs65[:, 64].astype(np.int32), bb)
+            operands = body.marshal(payloads, np.asarray(sigs, dtype=np.uint8), bb)
         with sp.phase("enqueue"):  # a shape's first call traces + compiles here
-            dev = step(blocks, nblocks, r, s, v)
+            dev = step(*operands)
         with sp.phase("sync"):  # waits for the device, brings the result over
             packed = np.asarray(dev)
         with sp.phase("unpack"):
@@ -187,7 +332,7 @@ def _admit_batch_device(
             )
 
 
-def _try_native(payloads, sigs65):
+def _try_native(body: _Body, payloads, sigs):
     """The native-host-loop leg when policy picks it; None to use device."""
     from ..observability.device import device_span
     from .suite import use_native_batch
@@ -199,79 +344,85 @@ def _try_native(payloads, sigs65):
     # native host loop — shape_key pinned so it never reads as
     # a compile; the op label keeps the dispatch split visible
     with device_span("admission_native", len(payloads), shape_key="native"):
-        return _admit_batch_native(payloads, np.asarray(sigs65, dtype=np.uint8))
+        return body.native(payloads, np.asarray(sigs, dtype=np.uint8))
 
 
-def _admit_direct(payloads, sigs65):
+def _admit_direct(body: _Body, payloads, sigs):
     """Pre-plane per-caller dispatch (the FISCO_DEVICE_PLANE=0 path):
     native-vs-device decided for THIS call alone — no coalescing, no
     fan-out, no breaker."""
     from .suite import _note_dispatch_path
 
-    out = _try_native(payloads, sigs65)
+    out = _try_native(body, payloads, sigs)
     if out is not None:
         _note_dispatch_path("admission", "native")
         return out
     _note_dispatch_path("admission", "device")
-    return _admit_batch_device(payloads, sigs65, allow_shard=False)
+    return _admit_batch_device(payloads, sigs, allow_shard=False, body=body)
 
 
-def _admit_merged(payloads, sigs65):
+def _admit_merged(body: _Body, payloads, sigs):
     """Plane-executor body: the same native-vs-device policy applied to the
     MERGED batch, with multi-device fan-out allowed and the device leg under
     the resilience breaker (host-loop fallback keeps admission serving when
-    the device plane is degraded)."""
+    the device plane is degraded). Either suite's dispatch is noted as the
+    node's ``admission``."""
     from .suite import _device_or_host, _note_dispatch_path
 
-    out = _try_native(payloads, sigs65)
+    out = _try_native(body, payloads, sigs)
     if out is not None:
         _note_dispatch_path("admission", "native")
         return out
-
-    def _host(p, s):
-        host_out = _admit_batch_native(p, np.asarray(s, dtype=np.uint8))
-        if host_out is None:
-            raise RuntimeError("native admission unavailable for host fallback")
-        return host_out
-
     return _device_or_host(
         "admission",
-        lambda p, s: _admit_batch_device(p, s, allow_shard=True),
-        _host,
+        lambda p, s: _admit_batch_device(p, s, allow_shard=True, body=body),
+        lambda p, s: body.host(p, np.asarray(s, dtype=np.uint8)),
         payloads,
-        sigs65,
+        sigs,
     )
 
 
-def _admission_plane_exec(reqs):
-    """DevicePlane executor: merge every queued admission request (txpool
-    RPC batches, consensus proposal re-verification, sync imports) into one
-    policy decision + one device program, then slice results per request."""
-    payloads: list[bytes] = []
-    rows = []
-    for r in reqs:
-        payloads.extend(r.payload[0])
-        rows.append(r.payload[1])
-    sigs65 = np.concatenate(rows, axis=0)
-    senders, ok, pubs, digests = _admit_merged(payloads, sigs65)
-    senders, ok = np.asarray(senders), np.asarray(ok)
-    pubs, digests = np.asarray(pubs), np.asarray(digests)
-    out, lo = [], 0
-    for r in reqs:
-        hi = lo + r.n
-        out.append((senders[lo:hi], ok[lo:hi], pubs[lo:hi], digests[lo:hi]))
-        lo = hi
-    return out
+@functools.cache
+def _plane_exec(body: _Body):
+    """DevicePlane executor of one body: merge every queued admission request
+    (txpool RPC batches, consensus proposal re-verification, sync imports)
+    into one policy decision + one device program, then slice results per
+    request."""
+
+    def run(reqs):
+        payloads: list[bytes] = []
+        rows = []
+        for r in reqs:
+            payloads.extend(r.payload[0])
+            rows.append(r.payload[1])
+        sigs = np.concatenate(rows, axis=0)
+        senders, ok, pubs, digests = _admit_merged(body, payloads, sigs)
+        senders, ok = np.asarray(senders), np.asarray(ok)
+        pubs, digests = np.asarray(pubs), np.asarray(digests)
+        out, lo = [], 0
+        for r in reqs:
+            hi = lo + r.n
+            out.append((senders[lo:hi], ok[lo:hi], pubs[lo:hi], digests[lo:hi]))
+            lo = hi
+        return out
+
+    return run
 
 
 def admit_batch(
-    payloads, sigs65
+    payloads, sigs, suite=None
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
-    """Host API: list[bytes] signed payloads + [B, 65] r‖s‖v signatures ->
+    """Host API: list[bytes] signed payloads + [B, L] signatures ->
     (senders [B, 20] uint8, ok bool[B], pubkeys [B, 64] uint8,
     tx hashes [B, 32] uint8). One device program, ONE result transfer —
     or the native host loop when that wins (small batch / CPU-only backend;
     crypto.suite.use_native_batch holds the policy).
+
+    `suite` (a crypto.suite.CryptoSuite) picks the body: 65-byte r‖s‖v under
+    secp256k1 + keccak256, the default; 128-byte r‖s‖pub under SM2 + SM3. A
+    suite without a fused body, or signatures of another width than the
+    suite's, raise ValueError (``CryptoSuite.fused_admission`` answers None
+    for the former instead).
 
     Routed through the shared DevicePlane: concurrent callers' batches
     coalesce into one program, shapes ride the bucket ladder, and oversized
@@ -281,17 +432,26 @@ def admit_batch(
     cover the device path on CPU hosts)."""
     from ..device.plane import get_plane, plane_route, plane_wait
 
+    body = _body_of(suite)
+    if body is None:
+        raise ValueError("admit_batch: this suite has no fused admission")
     bsz = len(payloads)
+    sigs_arr = np.asarray(sigs, dtype=np.uint8)
+    if bsz and sigs_arr.shape[-1] != body.sig_len:
+        raise ValueError(
+            f"admit_batch: {sigs_arr.shape[-1]}-byte signatures under a suite "
+            f"that signs {body.sig_len} bytes"
+        )
     if plane_route() and bsz:
-        sigs_arr = np.asarray(sigs65, dtype=np.uint8)
         return plane_wait(get_plane().submit(
-            "admission", (list(payloads), sigs_arr), bsz, _admission_plane_exec
+            body.plane_op, (list(payloads), sigs_arr), bsz, _plane_exec(body)
         ))
-    return _admit_direct(payloads, sigs65)
+    return _admit_direct(body, payloads, sigs_arr)
 
 
 # -- progaudit shape spec: M=2 message-block dim (the short-payload bucket
-# the flood pads to); both the raw core and the packed wrapper audit.
+# the flood pads to); both the raw core and the packed wrapper audit, and the
+# SM body's packed wrapper.
 PROGSPEC = {
     "admission_core": {
         "bucket": 256,
@@ -306,5 +466,10 @@ PROGSPEC = {
             ((b, 2, 17, 2), "uint32"), ((b,), "int32"),
             ((b, 16), "uint32"), ((b, 16), "uint32"), ((b,), "int32"),
         ],
+    },
+    "_sm_admission_packed": {
+        "bucket": 256,
+        "inputs": lambda b: [((b, 2, 16), "uint32"), ((b,), "int32")]
+        + [((b, 16), "uint32")] * 4,
     },
 }

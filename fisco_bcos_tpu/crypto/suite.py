@@ -17,6 +17,7 @@ any divergence would fork a chain.
 
 from __future__ import annotations
 
+import functools
 import os
 import secrets
 import threading
@@ -941,6 +942,23 @@ class CryptoSuite:
     def calculate_address_batch(self, pubs: np.ndarray) -> np.ndarray:
         digests = self.hash_impl.hash_batch([bytes(p) for p in np.asarray(pubs)])
         return digests[:, 12:]
+
+    def fused_admission(self):
+        """This suite's fused tx admission — ``(payloads, sigs) -> (senders,
+        ok, pubkeys, tx hashes)``, one device program with one result
+        transfer (crypto.admission.admit_batch) — or None for a suite that
+        has none (ed25519), whose batches take hash_batch → batch_recover →
+        calculate_address_batch instead."""
+        from . import admission
+
+        body = admission._body_of(self)
+        if body is None:
+            return None
+        if body is admission._SECP:
+            # the default suite is admit_batch's own default: the call stays
+            # the two-argument one its other callers make
+            return admission.admit_batch
+        return functools.partial(admission.admit_batch, suite=self)
 
     def merkle_root_async(self, leaves: np.ndarray):
         """Dispatch-now, sync-later (() -> bytes) wide device merkle over

@@ -13,8 +13,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .bigint import limbs_to_bytes_device
+from .bigint import limbs_to_bytes_device, limbs_to_words_be_device
 from .keccak import keccak256_blocks
+from .sm3 import sm3_of_word_pair
 
 _RATE_BYTES = 136
 _RATE_LANES = 17
@@ -47,6 +48,18 @@ def sender_address_device(qx: jax.Array, qy: jax.Array) -> jax.Array:
     )  # [B, 8] little-endian digest words
     idx = jnp.arange(12, 32)
     return (words[:, idx // 4] >> (8 * (idx % 4))) & 0xFF
+
+
+def sm_sender_address_device(qx: jax.Array, qy: jax.Array) -> jax.Array:
+    """The national-crypto suite's address, right160(SM3(Px ‖ Py)): affine
+    pubkey limbs ([B, 16] each, plain domain) -> [B, 20] uint32 address byte
+    values. Unjitted: it only runs inside the fused SM admission program
+    (crypto.admission.sm_admission_core)."""
+    words = sm3_of_word_pair(
+        limbs_to_words_be_device(qx), limbs_to_words_be_device(qy)
+    )  # [B, 8] big-endian digest words
+    idx = jnp.arange(12, 32)
+    return (words[:, idx // 4] >> (8 * (3 - idx % 4))) & 0xFF
 
 
 # -- progaudit shape spec (analysis/progaudit: canonical audited bucket) -----

@@ -96,6 +96,14 @@ def digest_words_be_to_limbs(words: jax.Array) -> jax.Array:
     return _chunks32_be_to_limbs(words.astype(jnp.uint32))
 
 
+def limbs_to_words_be_device(limbs: jax.Array) -> jax.Array:
+    """[..., 16] limbs -> [..., 8] uint32 big-endian words (the value's 32
+    bytes as SHA-256/SM3 block words), on device: the inverse of
+    :func:`digest_words_be_to_limbs`."""
+    rev = limbs[..., ::-1].astype(jnp.uint32)  # the most significant limb first
+    return (rev[..., 0::2] << 16) | rev[..., 1::2]
+
+
 def limbs_to_bytes_device(limbs: jax.Array) -> jax.Array:
     """[..., 16] limbs -> [..., 32] big-endian bytes (uint32 lanes), on device."""
     rev = limbs[..., ::-1].astype(jnp.uint32)

@@ -8,7 +8,7 @@ uncompressed public key appended, and "recover" = parse-pubkey-then-verify
 
 The digest is e = SM3(ZA ‖ M) with ZA = SM3(ENTL ‖ ID ‖ a ‖ b ‖ Gx ‖ Gy ‖
 Px ‖ Py) and the default user id "1234567812345678"; both are fixed-length
-messages, so e-derivation itself runs on the batch SM3 kernel.
+messages, assembled from limbs and hashed on the device (:func:`e_device`).
 
 Verification: t = (r + s) mod n (t ≠ 0); (x1, y1) = s*G + t*Q;
 valid iff (e + x1) mod n == r.
@@ -29,7 +29,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..crypto.ref.ecdsa import SM2_DEFAULT_ID
-from .bigint import bytes_be_to_limbs
+from .bigint import (
+    bytes_be_to_limbs,
+    digest_words_be_to_limbs,
+    limbs_to_bytes_be,
+    limbs_to_words_be_device,
+)
 from .ec import (
     SM2_OPS,
     add_mod_n,
@@ -44,7 +49,7 @@ from .hash_common import bucket_batch as _bucket
 from .hash_common import pad_rows as _pad_rows
 from . import limb
 from .limb import const_rows, eq, is_zero, lt
-from .sm3 import sm3_batch_async
+from .sm3 import sm3_fixed, sm3_of_word_pair
 
 _C = SM2_OPS
 
@@ -92,12 +97,19 @@ def verify_core(e, r, s, qx, qy, g_table):
     return verify_finish(e, r, X, Z, valid)
 
 
-@jax.jit
-def _verify_xla(e, r, s, qx, qy):
+def verify_rows(e, r, s, qx, qy):
+    """Batch SM2 verify over batch-major [B, 16] plain limbs -> bool[B]:
+    the body of :func:`_verify_xla` and of the fused SM admission program
+    (crypto.admission.sm_admission_core), unjitted."""
     gt = jnp.asarray(g_comb_table(_C.name))
     b = e.shape[0]
     ok = verify_core(*(limb.lane_dense(a) for a in (e, r, s, qx, qy)), gt)
     return limb.batch_lanes(ok, b)
+
+
+@jax.jit
+def _verify_xla(e, r, s, qx, qy):
+    return verify_rows(e, r, s, qx, qy)
 
 
 def verify_device(e, r, s, qx, qy):
@@ -106,40 +118,75 @@ def verify_device(e, r, s, qx, qy):
 
 
 # ---------------------------------------------------------------------------
+# e = SM3(ZA ‖ M) on the device
+# ---------------------------------------------------------------------------
+
+
+def _za_frame() -> tuple[np.ndarray, np.ndarray]:
+    """What surrounds Px ‖ Py in ZA's padded SM3 input under the default user
+    id, as big-endian 16-bit halfwords (a limb is one): the constant ENTL ‖ ID
+    ‖ a ‖ b ‖ Gx ‖ Gy before it (146 bytes), the Merkle–Damgård padding after
+    it; with the key's 64 bytes, four blocks."""
+    c = _C.curve
+    head = (len(SM2_DEFAULT_ID) * 8).to_bytes(2, "big") + SM2_DEFAULT_ID + b"".join(
+        v.to_bytes(32, "big") for v in (c.a, c.b, c.gx, c.gy)
+    )
+    n = len(head) + 64
+    total = ((n + 8) // 64 + 1) * 64
+    tail = b"\x80" + bytes(total - n - 9) + (n * 8).to_bytes(8, "big")
+    return tuple(np.frombuffer(b, dtype=">u2").astype(np.uint32) for b in (head, tail))
+
+
+_ZA_FRAME = _za_frame()
+
+
+def e_device(m_words, qx, qy):
+    """e = SM3(ZA ‖ M) with ZA = SM3(ENTL ‖ ID ‖ a ‖ b ‖ Gx ‖ Gy ‖ Px ‖ Py)
+    under the default user id, assembled from limbs and hashed on the device.
+    m_words [B, 8]: the 32-byte message (the tx hash) as big-endian words;
+    qx, qy [B, 16] plain limbs -> e as [B, 8] big-endian words."""
+    head, tail = _ZA_FRAME
+    b = qx.shape[0]
+    hw = jnp.concatenate(
+        [
+            jnp.broadcast_to(jnp.asarray(head), (b, head.size)),
+            qx[:, ::-1].astype(jnp.uint32),  # limbs, most significant first
+            qy[:, ::-1].astype(jnp.uint32),
+            jnp.broadcast_to(jnp.asarray(tail), (b, tail.size)),
+        ],
+        axis=1,
+    )
+    words = (hw[:, 0::2] << 16) | hw[:, 1::2]
+    za = sm3_fixed(words.reshape(b, -1, 16))
+    return sm3_of_word_pair(za, m_words)
+
+
+@jax.jit
+def _e_xla(m, qx, qy):
+    return digest_words_be_to_limbs(e_device(limbs_to_words_be_device(m), qx, qy))
+
+
+# ---------------------------------------------------------------------------
 # Host wrappers
 # ---------------------------------------------------------------------------
 
 
-def sm2_e_batch(
-    msg_hashes: np.ndarray, pubkeys: np.ndarray, user_id: bytes = SM2_DEFAULT_ID
-) -> np.ndarray:
-    """e = SM3(ZA ‖ M) for a batch: [B,32] hashes + [B,64] pubkeys -> [B,32].
-
-    ZA inputs are fixed-length, so both SM3 passes run on the device kernel."""
+def sm2_e_batch(msg_hashes: np.ndarray, pubkeys: np.ndarray) -> np.ndarray:
+    """e = SM3(ZA ‖ M) for a batch: [B,32] hashes + [B,64] pubkeys -> [B,32],
+    one device program (:func:`e_device`), rows bucketed like every batch."""
     msg_hashes = np.asarray(msg_hashes, dtype=np.uint8)
     pubkeys = np.asarray(pubkeys, dtype=np.uint8)
-    c = _C.curve
-    entl = (len(user_id) * 8).to_bytes(2, "big")
-    prefix = np.frombuffer(
-        entl
-        + user_id
-        + c.a.to_bytes(32, "big")
-        + c.b.to_bytes(32, "big")
-        + c.gx.to_bytes(32, "big")
-        + c.gy.to_bytes(32, "big"),
-        dtype=np.uint8,
-    )
     bsz = len(msg_hashes)
-    za_in = np.concatenate(
-        [np.broadcast_to(prefix, (bsz, len(prefix))), pubkeys], axis=1
+    bb = _bucket(max(bsz, 1))
+    e = _e_xla(
+        *(
+            _pad_rows(bytes_be_to_limbs(a), bb)
+            for a in (msg_hashes, pubkeys[:, :32], pubkeys[:, 32:])
+        )
     )
-    # the span-less async entry: sm2_e_batch runs INSIDE the caller's
-    # sm2_verify device_span — a nested sm3 span would double-count the
-    # SM3 wall (and misfile its compiles as sm2 execute remainder); the
-    # e-derivation is part of sm2's own phase decomposition
-    za = sm3_batch_async([bytes(row) for row in za_in])()
-    e_in = np.concatenate([za, msg_hashes], axis=1)
-    return sm3_batch_async([bytes(row) for row in e_in])()
+    # analysis: allow(host-sync, wrapper-boundary materialization — the
+    # caller marshals e into the verify program's operands)
+    return limbs_to_bytes_be(np.asarray(e))[:bsz]
 
 
 def verify_batch(
@@ -147,7 +194,6 @@ def verify_batch(
     rs: np.ndarray,
     ss: np.ndarray,
     pubkeys: np.ndarray,
-    user_id: bytes = SM2_DEFAULT_ID,
 ) -> np.ndarray:
     """Host API: [B,32] tx hash, [B,32] r, [B,32] s, [B,64] pubkey -> bool[B]."""
     from ..observability.device import device_span
@@ -156,7 +202,7 @@ def verify_batch(
     bb = _bucket(bsz)
     with device_span("sm2_verify", bsz, shape_key=bb) as sp:
         e = _pad_rows(
-            bytes_be_to_limbs(sm2_e_batch(msg_hashes, pubkeys, user_id)), bb
+            bytes_be_to_limbs(sm2_e_batch(msg_hashes, pubkeys)), bb
         )
         r = _pad_rows(bytes_be_to_limbs(rs), bb)
         s = _pad_rows(bytes_be_to_limbs(ss), bb)
@@ -193,5 +239,9 @@ PROGSPEC = {
     "_verify_xla": {
         "bucket": 256,
         "inputs": lambda b: [((b, 16), "uint32")] * 5,
+    },
+    "_e_xla": {
+        "bucket": 256,
+        "inputs": lambda b: [((b, 16), "uint32")] * 3,
     },
 }

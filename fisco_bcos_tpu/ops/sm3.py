@@ -107,6 +107,32 @@ def sm3_blocks(blocks: jax.Array, nblocks: jax.Array) -> jax.Array:
     return state
 
 
+# the padding block of any 64-byte message: 0x80, zeros, bit length 512
+_TAIL64 = np.array([0x80000000] + [0] * 14 + [512], dtype=np.uint32)
+
+
+# analysis: allow(shape-bucket) — runs INSIDE jit traces (the fused SM
+# admission, sm2._e_xla) whose batch their host wrappers already bucketed. It
+# calls the jitted sm3_blocks and not its body on purpose: as calls of one
+# function the program's five hashes ran 6.8 % faster on the chip than traced
+# inline (431 against 459 ms a 10,240-lane block: PERF.md §6, PR 26)
+def sm3_fixed(blocks: jax.Array) -> jax.Array:
+    """SM3 of messages assembled on the device, every lane as long as the
+    next: blocks [B, M, 16] padded big-endian words, all M absorbed ->
+    digests [B, 8] uint32."""
+    bsz, m = blocks.shape[:2]
+    return sm3_blocks(blocks, jnp.full((bsz,), m, jnp.int32))
+
+
+def sm3_of_word_pair(a: jax.Array, b: jax.Array) -> jax.Array:
+    """SM3(a ‖ b) for two [B, 8] big-endian word tensors: a 64-byte message
+    is one data block and one constant padding block. SM2's e = SM3(ZA ‖ M)
+    and the sender address SM3(Px ‖ Py) are hashed this way without leaving
+    the device."""
+    tail = jnp.broadcast_to(jnp.asarray(_TAIL64), (a.shape[0], 16))
+    return sm3_fixed(jnp.stack([jnp.concatenate([a, b], axis=1), tail], axis=1))
+
+
 def sm3_batch(msgs) -> np.ndarray:
     """Host convenience: list of bytes -> [B, 32] uint8 digests (device batch)."""
     from ..observability.device import device_span

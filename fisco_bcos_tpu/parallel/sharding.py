@@ -90,34 +90,33 @@ def sharded_admission(mesh: Mesh, axis_name: str = DATA_AXIS):
     return jax.jit(f)
 
 
-def sharded_admission_packed(mesh: Mesh, axis_name: str = DATA_AXIS):
-    """Fan-out form of the packed one-transfer admission program
-    (crypto.admission.admission_step_packed) — the DevicePlane's
-    multi-device leg for merged batches above its per-device threshold.
+def sharded_admission_packed(mesh: Mesh, axis_name: str = DATA_AXIS, body=None):
+    """Fan-out form of a packed one-transfer admission program — the
+    DevicePlane's multi-device leg for merged batches above its per-device
+    threshold. `body` is the unjitted packed program of one suite
+    (crypto.admission: ``_admission_packed``, the default, or
+    ``_sm_admission_packed``); every operand is batch-leading.
 
     Each device runs the fused admission body over its batch shard and
     packs locally; the [B, 117] uint8 result (addr ‖ ok ‖ pubkey ‖ tx_hash)
     rides ONE all_gather, so the host still pays a single transfer.
-    Bit-identical to the single-chip program lane-for-lane (the body is
-    admission_core verbatim; only the batch partitioning differs).
+    Bit-identical to the single-chip program lane-for-lane (the body is the
+    single-chip one verbatim; only the batch partitioning differs).
 
-    Returns a jitted fn (blocks, nblocks, r, s, v) -> [B, 117] uint8
-    replicated; B divisible by the mesh size (the bucket ladder guarantees
-    it for power-of-two meshes)."""
-    from ..crypto.admission import pack_admission_device
+    Returns a jitted fn (the body's operands) -> [B, 117] uint8 replicated;
+    B divisible by the mesh size (the bucket ladder guarantees it for
+    power-of-two meshes)."""
+    if body is None:
+        from ..crypto.admission import _admission_packed as body
 
-    def local(blocks, nblocks, r, s, v):
-        packed = pack_admission_device(
-            *admission_core(blocks, nblocks, r, s, v)
-        )
-        return jax.lax.all_gather(packed, axis_name, tiled=True)
+    def admission_shard(*operands):
+        return jax.lax.all_gather(body(*operands), axis_name, tiled=True)
 
-    spec = P(axis_name)
     f = jax.shard_map(
-        local,
+        admission_shard,
         mesh=mesh,
         check_vma=False,
-        in_specs=(spec, spec, spec, spec, spec),
+        in_specs=P(axis_name),
         out_specs=P(),
     )
     return jax.jit(f)
@@ -260,7 +259,7 @@ _SHARDED_SKIP = "needs a multi-device mesh (shapes depend on deployment fan-out)
 PROGSPEC = {
     "sharded_verify.local": {"skip": _SHARDED_SKIP},
     "sharded_admission.local": {"skip": _SHARDED_SKIP},
-    "sharded_admission_packed.local": {"skip": _SHARDED_SKIP},
+    "sharded_admission_packed.admission_shard": {"skip": _SHARDED_SKIP},
     "sharded_sm2_verify.local": {"skip": _SHARDED_SKIP},
     "sharded_ed25519_verify.local": {"skip": _SHARDED_SKIP},
     "sharded_merkle_root.local": {"skip": _SHARDED_SKIP},

@@ -4,10 +4,11 @@ Reference: bcos-txpool/txpool/validator/TxValidator.cpp:27-69 (group/chain
 check → nonce checkers → ``tx->verify()``), TxPoolNonceChecker.cpp (in-pool
 nonce dedup) and LedgerNonceChecker.cpp (committed-nonce window keyed by block
 number, pruned by block_limit). The signature step is the #1 hot loop; here
-`batch_admit` runs a whole batch through one device program — the fused
-keccak→recover→address kernel for the default suite, or the generic
-hash_batch→batch_recover pipeline for SM — instead of the reference's
-per-tx CPU call under tbb (TransactionSync.cpp:521-553).
+`batch_admit` runs a whole batch through one device program — the suite's
+fused admission (keccak→recover→address, or SM3→SM2 verify→address on a
+national-crypto chain), or the generic hash_batch→batch_recover pipeline for
+a suite that has none — instead of the reference's per-tx CPU call under tbb
+(TransactionSync.cpp:521-553).
 """
 
 from __future__ import annotations
@@ -116,9 +117,10 @@ def batch_admit(txs: list[Transaction], suite: CryptoSuite) -> np.ndarray:
     """Signature-verify + sender-recover a whole batch in one device pipeline,
     filling each tx's sender cache. Returns ok bool[B] (lanes, not exceptions).
 
-    Dispatch: the default suite (keccak256+secp256k1) takes the fully-fused
-    admission kernel; any other suite takes hash_batch → batch_recover →
-    address-batch (still three device programs, not B CPU calls).
+    Dispatch: the suite's fused admission where it has one (secp256k1 +
+    keccak256, SM2 + SM3); any other suite (ed25519) takes hash_batch →
+    batch_recover → address-batch (still three device programs, not B CPU
+    calls).
     """
     if not txs:
         return np.zeros(0, dtype=bool)
@@ -131,9 +133,8 @@ def batch_admit(txs: list[Transaction], suite: CryptoSuite) -> np.ndarray:
         else:
             well_formed[i] = False
 
-    if suite.signature_impl.name == "secp256k1" and suite.hash_impl.name == "keccak256":
-        from ..crypto.admission import admit_batch as fused
-
+    fused = suite.fused_admission()
+    if fused is not None:
         payloads = [t.encode_data() for t in txs]
         senders, ok, _pubs, digests = fused(payloads, sigs)
         # the fused program computed the tx hashes; fill caches from them

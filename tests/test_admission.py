@@ -1,5 +1,7 @@
 """Fused admission step + device address derivation + sharded verification."""
 
+import time
+
 import numpy as np
 import pytest
 
@@ -129,10 +131,13 @@ def test_device_leg_phases_are_measured_and_tile_the_span(monkeypatch):
     payloads = [b"phase-%d " % i + b"q" * (i * 11 % 90) for i in range(6)]
     sigs, _ = _signed(payloads)
     admission.admit_batch(payloads, sigs)  # the shape's first call may compile
-    mark = len(TRACER.spans())
+    # by the spans' own clock and not by the ring's length: a worker whose
+    # earlier files filled the ring (65,536 records) keeps that length
+    t_mark = time.perf_counter()
     _addr, ok, _pubs, _hashes = admission.admit_batch(payloads, sigs)
     assert ok.all()
-    mine = [r for r in TRACER.spans()[mark:] if r.name.startswith("device.admission")]
+    mine = [r for r in TRACER.spans()
+            if r.ts >= t_mark and r.name.startswith("device.admission")]
     by_name = {r.name: r for r in mine}
     order = ["marshal", "enqueue", "sync", "unpack"]
     assert set(by_name) == {"device.admission"} | {f"device.admission.{p}" for p in order}

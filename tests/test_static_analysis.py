@@ -295,6 +295,7 @@ def test_repo_jit_inventory_is_substantial():
 # entry (progaudit) AND a tool/warm_cache.py warmer.
 PINNED_JIT_PROGRAMS = [
     "fisco_bcos_tpu/crypto/admission.py:_admission_packed",
+    "fisco_bcos_tpu/crypto/admission.py:_sm_admission_packed",
     "fisco_bcos_tpu/crypto/admission.py:admission_core",
     "fisco_bcos_tpu/ops/address.py:sender_address_device",
     "fisco_bcos_tpu/ops/bls12_381.py:_multi_pairing_xla",
@@ -306,10 +307,11 @@ PINNED_JIT_PROGRAMS = [
     "fisco_bcos_tpu/ops/secp256k1.py:_recover_xla",
     "fisco_bcos_tpu/ops/secp256k1.py:_verify_xla",
     "fisco_bcos_tpu/ops/sha256.py:sha256_blocks",
+    "fisco_bcos_tpu/ops/sm2.py:_e_xla",
     "fisco_bcos_tpu/ops/sm2.py:_verify_xla",
     "fisco_bcos_tpu/ops/sm3.py:sm3_blocks",
     "fisco_bcos_tpu/parallel/sharding.py:sharded_admission.local",
-    "fisco_bcos_tpu/parallel/sharding.py:sharded_admission_packed.local",
+    "fisco_bcos_tpu/parallel/sharding.py:sharded_admission_packed.admission_shard",
     "fisco_bcos_tpu/parallel/sharding.py:sharded_ed25519_verify.local",
     "fisco_bcos_tpu/parallel/sharding.py:sharded_merkle_root.local",
     "fisco_bcos_tpu/parallel/sharding.py:sharded_qc_check.local",

@@ -113,10 +113,13 @@ def _warm_address(bucket: int) -> None:
 def _warm_admission(bucket: int) -> None:
     import numpy as np
 
-    from fisco_bcos_tpu.crypto.admission import _admit_batch_device
+    from fisco_bcos_tpu.crypto.admission import _BODIES, _admit_batch_device
 
     payloads = [b"warm-cache admission %d" % i for i in range(bucket)]
-    _admit_batch_device(payloads, np.ones((bucket, 65), np.uint8))
+    for body in _BODIES.values():  # secp256k1 + keccak256, SM2 + SM3
+        _admit_batch_device(
+            payloads, np.ones((bucket, body.sig_len), np.uint8), body=body
+        )
 
 
 def _warm_merkle(bucket: int):
