@@ -95,8 +95,8 @@ def _cond_sub24(x: jax.Array) -> jax.Array:
 
 def _mul_cols24(a: jax.Array, b: jax.Array, out: int) -> jax.Array:
     """Column sums of a*b for 24-limb rows ([24, T] x [24, T] -> [out, T]).
-    48 sub-2^16 terms per column keeps sums inside carry_norm's 2^22
-    two-pass budget."""
+    48 sub-2^16 terms per column keeps sums under 2^22, far inside what
+    carry_norm takes."""
     terms = []
     for i in range(NL):
         prod = lax.slice_in_dim(a, i, i + 1, axis=0) * b  # [24, T] < 2^32

@@ -36,7 +36,12 @@ bcos-crypto's secp256k1/SM2 paths) with a formulation shaped for the TPU VPU:
   (:func:`_carry_in`): generate and propagate bits are packed along the limb
   axis into 32-bit words, and ``(G | P) + G`` ripples through propagate runs
   in the adder. Never a sequential scan over limbs, and no longer a
-  log-depth network whose every level was a device op of its own.
+  log-depth network whose every level was a device op of its own. It runs
+  only where canonical limbs are consumed — a product of two variables, a
+  comparison, a field operation's result: between a product and its last
+  fold the limbs stay loose (:func:`carry_loose`: split passes alone, their
+  bound carried statically), which the constant multiplications and column
+  additions there take as well (PERF.md §6, PR 27).
 - Modular reduction is **pseudo-Mersenne folding** (``FoldField``) for
   moduli of the form 2^256 − c with small c — secp256k1's p and n both
   qualify — and word Montgomery (``MontField``) for arbitrary odd moduli
@@ -64,6 +69,7 @@ LIMB_BITS = 16
 # numpy scalar, not jnp: it stays a jaxpr literal, never a captured constant
 _MASK = np.uint32(0xFFFF)
 _R = 1 << 256
+_ONES = (_R - 1) // 0xFFFF  # Σ 2^(16k), k < 16: 16 limbs ≤ b hold at most b · _ONES
 
 
 def int_to_rows(x: int, width: int = LIMBS) -> np.ndarray:
@@ -221,19 +227,50 @@ def _carry_in(g: jax.Array, p: jax.Array) -> tuple[jax.Array, jax.Array]:
     return cin != 0, cout != 0
 
 
+def _split(cols: jax.Array, grow: int = 1) -> jax.Array:
+    """One pass of a normalisation: [L, ...] uint32, any value -> [L + grow,
+    ...] of the same value, limb k = lo16(col k) + hi16(col k-1) ≤ 0xFFFF +
+    (the largest column >> 16) ≤ 2^17 − 2. `grow` = 0 where the top column
+    is known to be a limb already."""
+    if grow:
+        cols = _shift_limbs(cols, 0, grow=grow)
+    return (cols & _MASK) + _shift_up(cols >> LIMB_BITS)
+
+
 def carry_norm(cols: jax.Array) -> jax.Array:
-    """Carry-propagate column sums: [L, ...] uint32 (any uint32 value: the
-    two-pass split bounds s = lo16 + prev_hi16 < 2^17 and t ≤ 2^16 before
-    the lookahead's increment pass, so no intermediate can overflow —
-    mul_cols feeds columns < 2^22, mul_small up to ~2^31) ->
-    [L+1, ...] normalized 16-bit limbs (top limb = final carry-out)."""
-    cols = _shift_limbs(cols, 0, grow=1)
-    s = (cols & _MASK) + _shift_up(cols >> LIMB_BITS)  # < 2^16 + 2^11
-    t = (s & _MASK) + _shift_up(s >> LIMB_BITS)  # ≤ 2^16; increments {0,1}
-    g = t > _MASK
-    p = t == _MASK
-    cin, _ = _carry_in(g, p)
+    """Carry-propagate column sums, exactly: [L, ...] uint32 (mul_cols feeds
+    columns < 2^22, mul_small up to ~2^31; the value must fit L + 1 limbs)
+    -> [L+1, ...] normalized 16-bit limbs (top limb = final carry-out).
+
+    One split pass, then the lookahead: a limb ≤ 2^17 − 2 with its carry-in
+    stays under 2^17, so the carry out is one bit, generated at t > 0xFFFF
+    and propagated at t = 0xFFFF."""
+    t = _split(cols)
+    cin, _ = _carry_in(t > _MASK, t == _MASK)
     return (t + cin.astype(jnp.uint32)) & _MASK
+
+
+def carry_loose(
+    cols: jax.Array, col_max: int = 0xFFFFFFFF, limb_max: int = 1 << LIMB_BITS
+) -> tuple[jax.Array, int]:
+    """The loose normalisation: [L, ...] column sums ≤ col_max -> ([L+1, ...]
+    limbs of the same value, their static bound ≤ limb_max). Split passes
+    only (one where col_max allows it, never more than two: limb_max ≥ 2^16),
+    no reduction over the limb axis, no lookahead.
+
+    Enough for a consumer that multiplies the limbs by constant limbs c only
+    (limb_max · c < 2^32) or adds them into columns that are normalised
+    again; a limb product of two variables, a comparison and a field
+    operation's result take :func:`carry_norm`. Limbs are non-negative, so a
+    nonzero limb k still implies value ≥ 2^(16k): what a static bound on the
+    value says is zero may be sliced off a loose array as off a canonical
+    one."""
+    if limb_max < 1 << LIMB_BITS:
+        raise ValueError("carry_loose cannot promise limbs under 2^16")
+    out, bound = _split(cols), int(_MASK) + (col_max >> LIMB_BITS)
+    while bound > limb_max:
+        out, bound = _split(out, grow=0), int(_MASK) + (bound >> LIMB_BITS)
+    return out, bound
 
 
 def sub_borrow(a: jax.Array, b: jax.Array) -> tuple[jax.Array, jax.Array]:
@@ -310,6 +347,10 @@ def _sum_terms(terms: list[jax.Array]) -> jax.Array:
         ]
         terms = nxt
     return terms[0]
+
+
+_MUL_COL_MAX = 32 * 0xFFFF  # a column of mul_cols: 16 low and 16 high halves
+_SQR_COL_MAX = 64 * 0xFFFF  # of sqr_cols: the off-diagonal halves doubled
 
 
 def mul_cols(a: jax.Array, b: jax.Array, out: int = 2 * LIMBS) -> jax.Array:
@@ -425,25 +466,48 @@ class FoldField:
         return const_rows(int_to_rows(1), t)
 
     # -- reduction --
-    def reduce_wide(self, x: jax.Array, bound: int) -> jax.Array:
-        """x (normalized limbs, value < bound, bound exclusive) -> x mod m.
+    @property
+    def _hi_limb_max(self) -> int:
+        """The largest limb `hi * c` takes inside uint32."""
+        return 0xFFFFFFFF // int(max(self.c_limbs))
+
+    def reduce_wide(
+        self, x: jax.Array, bound: int, limb_max: int = 0xFFFF
+    ) -> jax.Array:
+        """x (limbs ≤ limb_max, value < bound, bound exclusive) -> x mod m,
+        canonical. x may be loose (:func:`carry_loose`, limb_max its bound)
+        where bound > 2m, so that a fold runs.
 
         Folds value = lo + hi*2^256 ≡ lo + hi*c (mod m) until the static
-        value bound drops below 2m, then one conditional subtract. Any
-        contribution the static column clamp drops is provably zero (a
-        nonzero write at column k implies value ≥ 2^(16k) > bound).
+        value bound drops below 2m, then one conditional subtract. Only the
+        last fold is normalised exactly (the subtract compares); the folds
+        before it feed `hi * c` and an addition of columns, which take loose
+        limbs, and the bound carries a loose low half's slack (limb_max in
+        every limb, not 0xFFFF). Any contribution the static column clamp
+        drops is provably zero (a nonzero write at column k implies value ≥
+        2^(16k) > bound: limbs and columns are non-negative).
         """
         c_int = _R - self.m_int
+        # a column of a fold: a low and a high half for every limb of c, and lo
+        fold_cols = 2 * 0xFFFF * int(np.count_nonzero(self.c_limbs))
         while bound > 2 * self.m_int:
             lo, hi = x[:LIMBS], x[LIMBS:]
             if hi.shape[0] == 0:
                 break
+            if limb_max > self._hi_limb_max:
+                raise ValueError("reduce_wide: hi * c would leave uint32")
             hi_max = (bound - 1) >> 256
-            bound = (_R - 1) + hi_max * c_int + 1
+            bound = limb_max * _ONES + hi_max * c_int + 1
             width = max((bound - 1).bit_length() + 15, 17 * 16) // 16
             cols = mul_const_cols(hi, self.c_limbs, width)
             cols = cols + _placed(lo, 0, width)
-            x = carry_norm(cols)[:width]
+            if bound > 2 * self.m_int:
+                x, limb_max = carry_loose(
+                    cols, fold_cols + limb_max, self._hi_limb_max
+                )
+            else:
+                x, limb_max = carry_norm(cols), 0xFFFF
+            x = x[:width]
         return cond_sub(x, self.m_limbs)
 
     def reduce1(self, x: jax.Array) -> jax.Array:
@@ -451,13 +515,20 @@ class FoldField:
         return cond_sub(x, self.m_limbs)
 
     # -- field ops --
+    def _reduce_cols(self, cols: jax.Array, col_max: int, bound: int) -> jax.Array:
+        """Raw columns ≤ col_max of a value < bound -> the value mod m."""
+        # limbs the bound says are zero go: a product is 32, not 33
+        width = ((bound - 1).bit_length() + 15) // 16
+        if bound <= 2 * self.m_int:  # no fold will run: this normalisation is the last
+            return cond_sub(carry_norm(cols)[:width], self.m_limbs)
+        wide, limb_max = carry_loose(cols, col_max, self._hi_limb_max)
+        return self.reduce_wide(wide[:width], bound, limb_max)
+
     def mul(self, a: jax.Array, b: jax.Array) -> jax.Array:
-        wide = carry_norm(mul_cols(a, b))[: 2 * LIMBS]
-        return self.reduce_wide(wide, (_R - 1) ** 2 + 1)
+        return self._reduce_cols(mul_cols(a, b), _MUL_COL_MAX, (_R - 1) ** 2 + 1)
 
     def sqr(self, a: jax.Array) -> jax.Array:
-        wide = carry_norm(sqr_cols(a))[: 2 * LIMBS]
-        return self.reduce_wide(wide, (_R - 1) ** 2 + 1)
+        return self._reduce_cols(sqr_cols(a), _SQR_COL_MAX, (_R - 1) ** 2 + 1)
 
     def mul_small(self, a: jax.Array, c: int) -> jax.Array:
         """a * c for a small host constant c < 2^15 — one scalar-broadcast
@@ -466,8 +537,7 @@ class FoldField:
         if not 0 < c < 1 << 15:
             raise ValueError("mul_small needs 0 < c < 2^15")
         cols = a * np.uint32(c)  # limbs < 2^16 * 2^15 = 2^31: no overflow
-        wide = carry_norm(cols)[: LIMBS + 1]
-        return self.reduce_wide(wide, (_R - 1) * c + 1)
+        return self._reduce_cols(cols, 0xFFFF * c, (_R - 1) * c + 1)
 
     def add(self, a: jax.Array, b: jax.Array) -> jax.Array:
         return cond_sub(add_widen(a, b), self.m_limbs)
@@ -528,8 +598,14 @@ class SparseFoldField(FoldField):
     def _c_pos(self) -> int:
         return sum(1 << (16 * o) for o in self.pos_offsets)
 
-    def _table_fold(self, lo: jax.Array, hi: jax.Array) -> tuple[jax.Array, int]:
-        """lo [16,T] + hi [H≤16,T] -> normalized limbs of
+    @property
+    def _hi_limb_max(self) -> int:
+        return 0xFFFFFFFF // 0xFFFF  # hi meets the fold table's limbs, any of them
+
+    def _table_fold(
+        self, lo: jax.Array, hi: jax.Array, limb_max: int
+    ) -> tuple[jax.Array, int]:
+        """lo [16,T] + hi [H≤16,T] (limbs ≤ limb_max) -> normalized limbs of
         lo + Σ_k hi_k · (2^(256+16k) mod m), with its exclusive bound.
 
         One output column j sums h_k·T[k][j] over k: a single broadcast
@@ -537,18 +613,22 @@ class SparseFoldField(FoldField):
         after the lo/hi split, so sums stay < 2^20 — far inside uint32)."""
         h = hi.shape[0]
         tab = self.fold_rows[:h]  # [h, 16]
-        width = 18  # value < 2^256 + 16·2^16·m < 2^277
+        width = 18  # value < limb_max·(R/65,535 + 16·m) < 2^277
         terms = [_placed(lo, 0, width)]
         for j in range(LIMBS):
             tj = dev_vec(tab[:, j]).reshape((h,) + (1,) * (hi.ndim - 1))  # column constants
             prod = hi * tj  # [h, T], products < 2^32
             terms.append(_placed(_add_rows(prod & _MASK), j, width))
             terms.append(_placed(_add_rows(prod >> LIMB_BITS), j + 1, width))
-        bound = _R + (LIMBS * ((1 << LIMB_BITS) - 1)) * self.m_int
+        bound = limb_max * (_ONES + LIMBS * self.m_int) + 1
         return carry_norm(_sum_terms(terms))[:width], bound
 
-    def reduce_wide(self, x: jax.Array, bound: int) -> jax.Array:
-        """x (normalized limbs, value < bound) -> x mod m.
+    def reduce_wide(
+        self, x: jax.Array, bound: int, limb_max: int = 0xFFFF
+    ) -> jax.Array:
+        """x (limbs ≤ limb_max ≤ 65,537, canonical or loose as FoldField's;
+        value < bound) -> x mod m. Every chain here stays exact: no cell runs
+        this field.
 
         Wide inputs (a full product) take ONE dense table fold
         (lo + Σ hi_k·(2^(256+16k) mod m)), leaving a ~2^21 hi that a single
@@ -556,14 +636,17 @@ class SparseFoldField(FoldField):
         which cannot go negative) folds under 2m. Narrow inputs skip
         straight to shift-add rounds."""
         c_pos = self._c_pos
+        if limb_max > self._hi_limb_max:
+            raise ValueError("reduce_wide: hi * table limb would leave uint32")
         if x.shape[0] > LIMBS + 2 and bound > 2 * self.m_int:
-            x, bound = self._table_fold(x[:LIMBS], x[LIMBS:])
+            x, bound = self._table_fold(x[:LIMBS], x[LIMBS:], limb_max)
+            limb_max = 0xFFFF
         while bound > 2 * self.m_int:
             lo, hi = x[:LIMBS], x[LIMBS:]
             if hi.shape[0] == 0:
                 break
             hi_max = (bound - 1) >> 256
-            bound = (_R - 1) + hi_max * c_pos + 1
+            bound = limb_max * _ONES + hi_max * c_pos + 1
             width = max((bound - 1).bit_length() + 15 + 16, 17 * 16) // 16
             cols = _placed(lo, 0, width)
             for o in self.pos_offsets:
@@ -574,7 +657,7 @@ class SparseFoldField(FoldField):
                 neg_cols = neg_cols + _placed(hi, o, width)
             neg_n = carry_norm(neg_cols)[:width]
             diff, _borrow = sub_borrow(pos_n, neg_n)  # value ≥ 0: no borrow
-            x = diff
+            x, limb_max = diff, 0xFFFF
         return cond_sub(x, self.m_limbs)
 
 
@@ -629,14 +712,20 @@ class MontField:
         return const_rows(self.r1, t)
 
     def redc(self, t: jax.Array) -> jax.Array:
-        """t [32, T] (t < m*R) -> t*R^-1 mod m, [16, T]."""
+        """t [32, ...] (limbs ≤ 2^16, canonical or loose; t < m*R) ->
+        t*R^-1 mod m, [16, ...] canonical.
+
+        Two exact chains and the conditional subtract. t's limbs meet only
+        constant limbs (`· m'`: 2^16 · 0xFFFF < 2^32, and the columns past R
+        drop, so any low half congruent to t mod R serves) and an addition of
+        columns, so they may be loose; m_val multiplies m and has to be < R
+        for t + m_val·m < 2mR, so it is exact; m_val·m is never normalised on
+        its own: its raw columns (< 2^22) join t's."""
         m_val = carry_norm(
             mul_cols(t[:LIMBS], const_rows(self.mprime, t), out=LIMBS)
         )[:LIMBS]
-        mm = carry_norm(mul_cols(m_val, const_rows(self.m_limbs, t)))[
-            : 2 * LIMBS
-        ]
-        s = add_widen(t, mm)  # [33, T]; low 16 limbs are zero
+        mm = mul_cols(m_val, const_rows(self.m_limbs, t))
+        s = carry_norm(t + mm)  # [33, ...]; low 16 limbs are zero
         return cond_sub(s[LIMBS:], self.m_limbs)
 
     def from_plain(self, x: jax.Array) -> jax.Array:
@@ -646,10 +735,10 @@ class MontField:
         return self.redc(_placed(x, 0, 2 * LIMBS))
 
     def mul(self, a: jax.Array, b: jax.Array) -> jax.Array:
-        return self.redc(carry_norm(mul_cols(a, b))[: 2 * LIMBS])
+        return self.redc(carry_loose(mul_cols(a, b), _MUL_COL_MAX)[0][: 2 * LIMBS])
 
     def sqr(self, a: jax.Array) -> jax.Array:
-        return self.redc(carry_norm(sqr_cols(a))[: 2 * LIMBS])
+        return self.redc(carry_loose(sqr_cols(a), _SQR_COL_MAX)[0][: 2 * LIMBS])
 
     def mul_small(self, a: jax.Array, c: int) -> jax.Array:
         """a * c for tiny c via an addition chain (scaling commutes with the

@@ -7,7 +7,13 @@ Every case here runs the public batch-major ``[B, L]`` -> ``lane_dense`` ->
 field operation -> ``batch_major`` path at a lane count that pads (24), at
 whole multiples of 128 in ``[L, T]`` and at 1,152 lanes in ``[L, 9, 128]``, on
 random and edge values, for secp256k1's p and n (``FoldField``) and SM2's p
-(``MontField``).
+(``MontField``); ed25519's 2p, SM2's n and the Solinas form of SM2's p at the
+lane counts that pad and that fill ``[L, 9, 128]``.
+
+A field operation normalises exactly only where canonical limbs are consumed
+(PR 27): the adversarial cases below drive the loose limbs ≤ 2^16 that run
+between a product and its last fold, on raw operands that random draws do
+not reach.
 
 The structural guards at the end pin what the chip's op profile said costs
 the time (PERF.md §6, PR 25): limb-axis slices and concatenates in a field
@@ -15,6 +21,7 @@ multiplication, and the size of one window step of the GLV ladder.
 """
 
 import random
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -27,13 +34,26 @@ _R = 1 << 256
 _P = ec.SECP256K1_OPS.curve.p
 _N = ec.SECP256K1_OPS.curve.n
 _SM2P = ec.SM2_OPS.curve.p
+_SM2N = ec.SM2_OPS.curve.n
+_ED2P = 2 * ((1 << 255) - 19)  # ed25519 keeps its values mod 2p
 
 FIELDS = {
     "fold-p": limb.make_fold_field(_P),
     "fold-n": limb.make_fold_field(_N),
     "mont-sm2p": limb.make_mont_field(_SM2P),
+    "fold-ed2p": limb.make_fold_field(_ED2P),
+    "mont-sm2n": limb.make_mont_field(_SM2N),
+    "sparse-sm2p": limb.make_sparse_fold_field(_SM2P),
 }
 LANES = [24, 128, 512, 1024, 1152]
+# the fields of the admission programs at every lane count, the others where
+# the batch pads and where a limb is more than one vreg
+FIELD_LANES = [
+    (f, n)
+    for f in sorted(FIELDS)
+    for n in LANES
+    if f in ("fold-p", "fold-n", "mont-sm2p") or n in (24, 1152)
+]
 
 
 def _edge(m: int) -> list[int]:
@@ -87,9 +107,8 @@ UNARY = {
 }
 
 
-@pytest.mark.parametrize("lanes", LANES)
 @pytest.mark.parametrize("op", sorted(BINARY))
-@pytest.mark.parametrize("field", sorted(FIELDS))
+@pytest.mark.parametrize("field,lanes", FIELD_LANES)
 def test_field_binary_ops_match_python_ints(field, op, lanes):
     F = FIELDS[field]
     m = F.m_int
@@ -99,9 +118,8 @@ def test_field_binary_ops_match_python_ints(field, op, lanes):
     assert dec(dev(F, enc(xs), enc(ys)), lanes) == [ref(m, x, y) for x, y in zip(xs, ys)]
 
 
-@pytest.mark.parametrize("lanes", LANES)
 @pytest.mark.parametrize("op", sorted(UNARY))
-@pytest.mark.parametrize("field", sorted(FIELDS))
+@pytest.mark.parametrize("field,lanes", FIELD_LANES)
 def test_field_unary_ops_match_python_ints(field, op, lanes):
     F = FIELDS[field]
     m = F.m_int
@@ -122,6 +140,127 @@ def test_reduce_wide_folds_a_full_product(field, lanes):
     wide = wide[:lanes]
     got = F.reduce_wide(_dense(wide, 32), (_R - 1) ** 2 + 1)
     assert _ints(got, lanes) == [w % F.m_int for w in wide]
+
+
+# -- loose limbs between a product and its last fold (PR 27) ------------------
+
+# a pair whose product leaves two limbs of the loose normalisation at exactly
+# 2^16 (found by search: about one structured draw in a thousand leaves one)
+_LOOSE_A = 0xFFFFFFFFFFFF80010001000000FF80017FFF00FFFFFEDAEA0000000080000001
+_LOOSE_B = 0x2000000018001FFFE0000FFFFFFFE000000FF4A3780017FFF1096FF00FFFF
+_POOL = (0, 1, 2, 0xFFFF, 0xFFFE, 0x8000, 0x7FFF, 0x8001, 0x00FF, 0xFF00)
+
+
+def _adversarial(top: int, n: int, seed: int, head=()) -> list[int]:
+    """n raw operands < top: `head`, the edges, then limbs drawn from the
+    values that make products carry (0xFFFF, 0x8000, ...)."""
+    rng = random.Random(seed)
+    vals = [v for v in head if v < top] + [0, 1, top - 1, top - 2, (_R - 1) % top]
+    while len(vals) < n:
+        x = sum(rng.choice(_POOL) << (16 * i) for i in range(limb.LIMBS))
+        if x < top:
+            vals.append(x)
+    return vals[:n]
+
+
+def _raw(F) -> tuple[int, int]:
+    """(r, rinv): on raw limbs a Montgomery field's product carries R^-1 and
+    its conversions R^±1; a plain-domain field's carry 1."""
+    if isinstance(F, limb.MontField):
+        return _R % F.m_int, pow(_R, -1, F.m_int)
+    return 1, 1
+
+
+def _wide_cases(F, n):
+    """32-limb inputs of the reduction with their exclusive bound: a full
+    product for the fold, t < m·R for REDC (its low half 0 in every third)."""
+    m = F.m_int
+    if isinstance(F, limb.MontField):
+        top = m * _R
+        his = _adversarial(m, n, 14)
+        los = _adversarial(_R, n, 15)
+        return [h * _R + (0 if i % 3 == 0 else l) for i, (h, l) in enumerate(zip(his, los))], top
+    top = (_R - 1) ** 2 + 1
+    his = _adversarial(_R - 2, n, 14)  # hi ≤ R − 3 leaves the low half free
+    los = _adversarial(_R, n, 15, head=(_R - 1,))
+    return [top - 1, _R * _R - 3 * _R + 1] + [h * _R + l for h, l in zip(his, los)][: n - 2], top
+
+
+ADVERSARIAL_OPS = ("mul", "sqr", "mul_small", "reduce", "plain")
+
+
+@pytest.mark.parametrize("op", ADVERSARIAL_OPS)
+@pytest.mark.parametrize("field", sorted(FIELDS))
+def test_field_ops_on_adversarial_raw_operands(field, op):
+    """mul, sqr, mul_small, the wide reduction (reduce_wide / redc) and the
+    domain conversions against Python integers on raw canonical operands:
+    0, 1, m − 1, limbs of 0xFFFF, the pair whose product's loose limbs reach
+    2^16, a REDC input whose low half is 0."""
+    F = FIELDS[field]
+    m = F.m_int
+    r, rinv = _raw(F)
+    n = 128
+    xs = _adversarial(m, n, 11, head=(_LOOSE_A, 1 << 128))
+    ys = _adversarial(m, n, 12, head=(_LOOSE_B, 1 << 128))
+    ys[5:10] = xs[5:10][::-1]  # the edges against each other
+    a, b = _dense(xs), _dense(ys)
+    if op == "mul":
+        assert _ints(F.mul(a, b), n) == [x * y * rinv % m for x, y in zip(xs, ys)]
+    elif op == "sqr":
+        assert _ints(F.sqr(a), n) == [x * x * rinv % m for x in xs]
+    elif op == "mul_small":
+        for c in (1, 3, 21):
+            assert _ints(F.mul_small(a, c), n) == [c * x % m for x in xs]
+    elif op == "reduce":
+        wide, top = _wide_cases(F, n)
+        assert max(wide) < top
+        if isinstance(F, limb.MontField):
+            assert _ints(F.redc(_dense(wide, 32)), n) == [w * rinv % m for w in wide]
+        else:
+            assert _ints(F.reduce_wide(_dense(wide, 32), top), n) == [w % m for w in wide]
+    else:
+        assert _ints(F.from_plain(a), n) == [x * r % m for x in xs]
+        assert _ints(F.to_plain(a), n) == [x * rinv % m for x in xs]
+
+
+@pytest.mark.parametrize("limbs", [16, 33, 48])
+def test_carry_loose_keeps_the_value_and_bounds_the_limbs(limbs):
+    """The loose normalisation on columns up to 2^32 − 1: the same value, one
+    limb more, every limb ≤ 2^16 (and = 2^16 somewhere), under the bound it
+    returns; one pass where the columns' bound allows the limbs asked for.
+    The exact one agrees on the same columns, also where one generate at
+    limb 2 runs through propagates to the top limb."""
+    rng = random.Random(27)
+    full = 0xFFFFFFFF
+    run = [full, 0xFFFF] + [0xFFFF] * (limbs - 2)  # loose: 2^16 at limb 2, 0xFFFF above
+    cols = [[full] * limbs, run, [0] * limbs, [full, 0] * (limbs // 2) + [full] * (limbs % 2)]
+    cols += [[rng.choice((0, 1, 0xFFFF, 0x10000, 0xFFFF0000, full, rng.randrange(full + 1)))
+              for _ in range(limbs)] for _ in range(124)]
+    n = len(cols)
+    dense = limb.lane_dense(jnp.asarray(np.array(cols, dtype=np.uint32)))
+    value = [sum(c << (16 * i) for i, c in enumerate(col)) for col in cols]
+    loose, bound = limb.carry_loose(dense)
+    assert loose.shape[0] == limbs + 1
+    assert int(jnp.max(loose)) == bound == 1 << 16
+    assert _ints(loose, n) == value
+    got = np.asarray(limb.batch_major(loose, n))[1]
+    assert got[2] == 1 << 16 and (got[3:limbs] == 0xFFFF).all()
+    # columns of a product (< 2^21): one pass where 0xFFFF + 31 will do, two for 2^16
+    small = dense & np.uint32(limb._MUL_COL_MAX)
+    for limb_max, passes_bound in ((0xFFFF + 31, 0xFFFF + 31), (1 << 16, 1 << 16)):
+        once, bound = limb.carry_loose(small, limb._MUL_COL_MAX, limb_max)
+        assert bound == passes_bound and int(jnp.max(once)) <= bound
+        assert _ints(once, n) == _ints(small, n)
+    with pytest.raises(ValueError):
+        limb.carry_loose(dense, full, 0xFFFF)
+    # L + 1 canonical limbs hold the value once the top column is a limb
+    fits = dense.at[limbs - 1].set(dense[limbs - 1] & 0xFFFF)
+    exact = limb.carry_norm(fits)
+    assert int(jnp.max(exact)) <= 0xFFFF
+    assert _ints(exact, n) == _ints(limb.carry_loose(fits)[0], n)
+    # the product pair: the loose limbs of a·b reach 2^16 twice
+    prod, _ = limb.carry_loose(limb.mul_cols(_dense([_LOOSE_A]), _dense([_LOOSE_B])))
+    assert int((np.asarray(limb.batch_major(prod, 1)) == 1 << 16).sum()) == 2
 
 
 @pytest.mark.parametrize("lanes", LANES)
@@ -235,19 +374,59 @@ def _count(jaxpr, names) -> int:
 _DYNAMIC_SHUFFLES = ("dynamic_slice", "gather", "scatter")
 
 
-def test_field_mul_shuffles_no_limbs():
+def _tool():
+    """tool/admission_op_profile.py: the chain tally is its chip-free half."""
+    if "tool" not in sys.path:
+        sys.path.insert(0, "tool")
+    import admission_op_profile
+
+    return admission_op_profile
+
+
+# field -> (equations a multiplication may trace, limb-axis slices, the limbs
+# of its exact carry chains); before PR 27: 337 equations for p, 542 for n
+# (its nine-limb complement folds wider), 636 for REDC (three limb products:
+# 48 operand limbs), chains of 33, 20, 18, 17 limbs and 33, 17, 33, 33, 17
+MUL_CEILINGS = {
+    "fold-p": (265, 40, [17, 18]),
+    "fold-n": (440, 40, [17, 18]),
+    "mont-sm2p": (540, 64, [17, 17, 33]),
+}
+
+@pytest.mark.parametrize("field", sorted(MUL_CEILINGS))
+def test_field_mul_shuffles_no_limbs(field):
     """A field multiplication holds no concatenate at all (97 before PR 25),
     at most 40 limb-axis slices (135 before: the operand's 16 limbs, the
     fold's lo/hi halves, a dropped top limb), and every limb shift or
-    placement is one pad. The op count cannot creep back unseen."""
+    placement is one pad. The op count cannot creep back unseen. Exact
+    carry chains (``_carry_in``): the last fold and the conditional subtract
+    of a fold (four before PR 27); m_val, t + m_val·m and the subtract of a
+    REDC (five before)."""
+    eqns, slices, chains = MUL_CEILINGS[field]
     a = jax.ShapeDtypeStruct((16, 8, 128), jnp.uint32)
-    # equations: 337 for p (597 before); n's nine-limb complement folds wider
-    for F, eqns in ((FIELDS["fold-p"], 400), (FIELDS["fold-n"], 650)):
-        jaxpr = jax.make_jaxpr(F.mul)(a, a).jaxpr
-        assert _count(jaxpr, ("concatenate",)) <= 8
-        assert _count(jaxpr, ("slice",)) <= 40
-        assert _count(jaxpr, _DYNAMIC_SHUFFLES) == 0
-        assert len(jaxpr.eqns) <= eqns
+    jaxpr = jax.make_jaxpr(FIELDS[field].mul)(a, a).jaxpr
+    assert _count(jaxpr, ("concatenate",)) <= 8
+    assert _count(jaxpr, ("slice",)) <= slices
+    assert _count(jaxpr, _DYNAMIC_SHUFFLES) == 0
+    assert len(jaxpr.eqns) <= eqns
+    tally = _tool().chain_tally(jaxpr)
+    assert sorted(limbs for (_, limbs), n in tally.items() for _ in range(n)) == chains, tally
+
+
+@pytest.mark.parametrize("program,ceiling", [("secp", 16_500), ("sm", 43_500)])
+def test_admission_program_runs_few_exact_chains(program, ceiling):
+    """Executed exact carry chains a call of the two admission programs,
+    traced at 1,024 lanes (24,164 and 48,717 before PR 27), and their packed
+    lookahead words, Σ ⌈limbs / 32⌉ (27,977 and 65,573): a later edit that
+    puts a lookahead back where nothing consumes canonical limbs is seen here,
+    without a chip. Tracing only: nothing compiles."""
+    from fisco_bcos_tpu.observability.device import LEDGER
+
+    tool = _tool()
+    LEDGER.reset()
+    totals = tool.chain_totals(tool.program_chains(program))
+    assert LEDGER.cold_compile_count() == 0 and LEDGER.snapshot() == []
+    assert 0 < totals["chains"] <= totals["words"] <= ceiling
 
 
 def test_glv_window_step_stays_small():

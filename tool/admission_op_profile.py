@@ -1,17 +1,28 @@
 #!/usr/bin/env python3
-"""Device-op profile of the fused admission program, one call a shape.
+"""Device-op profile of the fused admission programs, one call a shape, and
+the chip-free tally of their exact carry chains.
 
 Runs ``crypto.admission._admit_batch_device`` (the program every benchmark
-cell waits for) at 1,000 lanes (bucket 1,024) and 10,000 lanes (bucket
-10,240) under ``jax.profiler`` with the device's ops line on, and reduces
-the capture to: device ops a call, the op kinds by count and by time, the
-program's device time. The mechanism counter of PERF.md §6 (PR 25).
+cell waits for) at every benchmark shape — 500 lanes (bucket 512), 1,000
+(1,024) and 10,000 (10,240) of secp256k1 + keccak256, 10,000 of SM2 + SM3
+(``sm:10000``) — under ``jax.profiler`` with the device's ops line on, and
+reduces the capture to: device ops a call, the op kinds by count and by time,
+the program's device time. The mechanism counter of PERF.md §6 (PR 25).
 
-    python tool/admission_op_profile.py --label parent [--lanes 1000,10000]
+    python tool/admission_op_profile.py --label parent [--lanes 1000,sm:10000]
 
 Refuses to run off the chip (exit 4): an op count of the CPU backend says
 nothing about the TPU's fusions. Writes ``chiprun_out/op_profile/<label>.json``
 and prints the same JSON as its last line.
+
+    python tool/admission_op_profile.py --chains
+
+needs no chip and compiles nothing: it traces ``admission_core`` and
+``sm_admission_core`` at 1,024 lanes and counts the exact carry chains a call
+executes (``limb._carry_in``; ``lax.scan`` bodies weighted by their trip
+counts) and their packed lookahead words (Σ ⌈limbs / 32⌉), by the field
+operation that runs them. The count is static, the same on every backend
+(PERF.md §6, PR 27); ``tests/test_limb_lane_dense.py`` pins the totals.
 """
 
 from __future__ import annotations
@@ -86,12 +97,113 @@ def reduce_ops(path: str) -> dict:
     return out
 
 
+# -- the chain tally (tracing only) -------------------------------------------
+
+# frames of ops/limb.py between a field operation and _carry_in
+_CHAIN_HELPERS = ("_reduce_cols", "reduce_wide", "redc", "_table_fold")
+
+
+def _chain_caller(frames) -> str:
+    """The user frames of a ``_carry_in`` equation, innermost first -> the
+    field operation that runs the chain and the helpers between them
+    (``FoldField.mul>reduce_wide>carry_norm``); for a chain no field
+    operation runs, the function outside ``ops/limb.py`` that asked for it."""
+    path = []
+    for f in frames[1:]:
+        name = f.function_name
+        if not f.file_name.endswith(os.path.join("ops", "limb.py")):
+            path.append(f"{os.path.basename(f.file_name)}:{name}")
+            break
+        if "Field." in name and name.rpartition(".")[2] not in _CHAIN_HELPERS:
+            path.append(name)
+            break
+        path.append(name.rpartition(".")[2])
+    return ">".join(reversed(path))
+
+
+def chain_tally(jaxpr, weight: int = 1, out=None) -> collections.Counter:
+    """Executed ``limb._carry_in`` calls of a jaxpr -> {(caller, limbs): n},
+    bodies of ``scan`` multiplied by their trip counts. A call is known by its
+    one ``iota`` equation (the bit index along the limb axis), whose shape
+    gives the chain's limbs."""
+    import jax
+    from jax._src import source_info_util
+
+    out = collections.Counter() if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "iota":
+            frames = list(source_info_util.user_frames(eqn.source_info.traceback))
+            if frames and frames[0].function_name == "_carry_in":
+                out[_chain_caller(frames), eqn.params["shape"][0]] += weight
+        if eqn.primitive.name == "while":
+            raise ValueError("chain tally: a while loop has no static trip count")
+        inner = weight * eqn.params["length"] if eqn.primitive.name == "scan" else weight
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            chain_tally(sub, inner, out)
+    return out
+
+
+def chain_totals(tally) -> dict:
+    return {
+        "chains": sum(tally.values()),
+        "words": sum(n * -(-limbs // 32) for (_, limbs), n in tally.items()),
+    }
+
+
+CHAIN_PROGRAMS = {  # name -> (the unjitted body, the PROGSPEC entry with its operands)
+    "secp": ("admission_core", "admission_core"),
+    "sm": ("sm_admission_core", "_sm_admission_packed"),
+}
+
+
+def program_chains(program: str, lanes: int = 1024) -> collections.Counter:
+    """The chain tally of one admission program, traced at `lanes` lanes."""
+    import jax
+
+    from fisco_bcos_tpu.crypto import admission
+
+    body, spec = CHAIN_PROGRAMS[program]
+    operands = [
+        jax.ShapeDtypeStruct(shape, dtype)
+        for shape, dtype in admission.PROGSPEC[spec]["inputs"](lanes)
+    ]
+    return chain_tally(jax.make_jaxpr(getattr(admission, body))(*operands).jaxpr)
+
+
+def chains_main() -> int:
+    result = {}
+    for program in CHAIN_PROGRAMS:
+        tally = program_chains(program)
+        result[program] = dict(
+            chain_totals(tally),
+            by_caller=[
+                {"caller": caller, "limbs": limbs, "chains": n}
+                for (caller, limbs), n in sorted(tally.items(), key=lambda kv: -kv[1])
+            ],
+        )
+        print(f"{program}: {result[program]['chains']} exact chains a call, "
+              f"{result[program]['words']} lookahead words")
+        for row in result[program]["by_caller"]:
+            print(f"  {row['chains']:>7}  {row['caller']} ({row['limbs']})")
+    print(json.dumps(result))
+    return 0
+
+
+# -- the device-op profile (chip only) ----------------------------------------
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--label", required=True)
-    ap.add_argument("--lanes", default="1000,10000")
+    ap.add_argument("--label")
+    ap.add_argument("--lanes", default="500,1000,10000,sm:10000")
     ap.add_argument("--seed", type=int, default=2500000001)
+    ap.add_argument("--chains", action="store_true",
+                    help="no chip: trace the two programs and count their exact carry chains")
     args = ap.parse_args()
+    if args.chains:
+        return chains_main()
+    if not args.label:
+        ap.error("--label is required for the device-op profile")
 
     from fisco_bcos_tpu.utils import jaxenv
 
@@ -103,28 +215,33 @@ def main() -> int:
         print(f"refused: op profile needs the chip, got {dev.platform}", file=sys.stderr)
         return 4
 
-    from benchmark.generators.signed_payloads import Corpus
+    from benchmark.generators import signed_payloads, sm_signed_payloads
     from fisco_bcos_tpu.crypto import admission
 
-    lanes = [int(x) for x in args.lanes.split(",")]
-    blocks = {
-        n: Corpus({"lanes": n, "signers": 64, "rotations": 1}, args.seed).blocks[0]
-        for n in lanes
+    suites = {
+        "secp": (signed_payloads.Corpus, admission._SECP),
+        "sm": (sm_signed_payloads.Corpus, admission._BODIES["sm2", "sm3"]),
     }
+    shapes = args.lanes.split(",")  # "1000" or "sm:10000"
+    blocks, bodies = {}, {}
+    for shape in shapes:
+        suite, _, n = shape.rpartition(":")
+        corpus, bodies[shape] = suites[suite or "secp"]
+        blocks[shape] = corpus({"lanes": int(n), "signers": 64, "rotations": 1}, args.seed).blocks[0]
 
-    def call(n):
-        b = blocks[n]
-        return admission._admit_batch_device(b["payloads"], b["sigs"])
+    def call(shape):
+        b = blocks[shape]
+        return admission._admit_batch_device(b["payloads"], b["sigs"], body=bodies[shape])
 
     # first call of a shape traces and compiles (or loads): the shapes side by side
     setup = {}
 
-    def warm(n):
+    def warm(shape):
         t = time.monotonic()
-        call(n)
-        setup[n] = time.monotonic() - t
+        call(shape)
+        setup[shape] = time.monotonic() - t
 
-    threads = [threading.Thread(target=warm, args=(n,)) for n in lanes]
+    threads = [threading.Thread(target=warm, args=(shape,)) for shape in shapes]
     for t in threads:
         t.start()
     for t in threads:
@@ -135,25 +252,26 @@ def main() -> int:
     result = {
         "label": args.label,
         "device": {"platform": dev.platform, "kind": dev.device_kind, "count": jax.device_count()},
-        "first_call_s": {str(n): round(s, 1) for n, s in setup.items()},
+        "first_call_s": {shape: round(s, 1) for shape, s in setup.items()},
         "shapes": {},
     }
-    for n in lanes:
-        call(n)  # warm, untimed
+    for shape in shapes:
+        call(shape)  # warm, untimed
         t = time.perf_counter()
-        out = call(n)
+        out = call(shape)
         wall_ms = (time.perf_counter() - t) * 1e3
-        trace_dir = tempfile.mkdtemp(prefix=f"op_profile_{n}_")  # hundreds of MB: not under chiprun_out/
+        # hundreds of MB: not under chiprun_out/
+        trace_dir = tempfile.mkdtemp(prefix=f"op_profile_{shape.replace(':', '_')}_")
         jax.profiler.start_trace(trace_dir)
         try:
-            call(n)
+            call(shape)
         finally:
             jax.profiler.stop_trace()
         (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
         red = reduce_ops(path)
         red["wall_ms_untraced"] = wall_ms
         red["lanes_ok"] = int(out[1].sum())
-        result["shapes"][str(n)] = red
+        result["shapes"][shape] = red
         shutil.rmtree(trace_dir, ignore_errors=True)
     with open(os.path.join(out_dir, f"{args.label}.json"), "w") as f:
         json.dump(result, f, indent=1)
