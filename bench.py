@@ -531,7 +531,7 @@ def bench_flood() -> None:
     # recompile counts ride along so the next BENCH round can attribute the
     # e2e gap: with the plane on, a ragged flood must stay within the bucket
     # ladder instead of compiling one program per batch size
-    from fisco_bcos_tpu.device.plane import get_plane, plane_enabled
+    from fisco_bcos_tpu.device.plane import get_plane
     from fisco_bcos_tpu.observability.device import compile_counts
 
     print(
@@ -557,21 +557,15 @@ def bench_flood() -> None:
     # (noop and placeholder-emitting with FISCO_STORAGE_OBS=0)
     _dump_storage_artifact(dt, alloc_top)
     _gate_flood_round(prev_round_doc, tps)
-    if plane_enabled():
-        plane = get_plane()
-        plane.drain(10.0)
-        ratio = plane.coalesce_ratio()
-        print(
-            f"# device plane: {plane.stats()} wait_p99_ms="
-            f"{plane.wait_p99_ms():.2f}",
-            flush=True,
-        )
-        _emit(M_COALESCE[0], ratio, M_COALESCE[1], ratio)
-    else:
-        _emit(
-            M_COALESCE[0], 1.0, M_COALESCE[1], 1.0,
-            error="device plane disabled (FISCO_DEVICE_PLANE=0)",
-        )
+    plane = get_plane()
+    plane.drain(10.0)
+    ratio = plane.coalesce_ratio()
+    print(
+        f"# device plane: {plane.stats()} wait_p99_ms="
+        f"{plane.wait_p99_ms():.2f}",
+        flush=True,
+    )
+    _emit(M_COALESCE[0], ratio, M_COALESCE[1], ratio)
 
 
 def bench_scenario(name: str) -> None:

@@ -394,12 +394,9 @@ def child_air4(args) -> int:
 
     from fisco_bcos_tpu.codec.abi import ABICodec
     from fisco_bcos_tpu.crypto import admission
-    from fisco_bcos_tpu.crypto.suite import (
-        _device_breaker,
-        device_min_batch,
-        ecdsa_suite,
-    )
+    from fisco_bcos_tpu.crypto.suite import ecdsa_suite
     from fisco_bcos_tpu.crypto.testvec import signed_payload_vectors
+    from fisco_bcos_tpu.device.dispatch import device_breaker, device_min_batch
     from fisco_bcos_tpu.executor.precompiled import DAG_TRANSFER_ADDRESS
     from fisco_bcos_tpu.front import InprocGateway
     from fisco_bcos_tpu.ledger import ConsensusNode, GenesisConfig
@@ -645,7 +642,7 @@ def child_air4(args) -> int:
         ),
         device_doc=device_doc(),
         health=HEALTH.snapshot(),
-        breaker_state=_device_breaker().state,
+        breaker_state=device_breaker().state,
         wall_s=round(time.monotonic() - t_start, 1),
     )
     _write_json(args.out, doc)

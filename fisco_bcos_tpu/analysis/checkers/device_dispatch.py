@@ -16,6 +16,14 @@ outside the seam allowlist is a finding. Host-side helpers are exempt:
 ``ops.hash_common``/``ops.bigint``/``ops.limb`` everywhere, and the named
 host-tree classes from ``ops.merkle`` (``MerkleTree``/``MerkleProofItem``,
 which ledger/lightnode legitimately use for proofs).
+
+Second rule, the layering under the seams: ``crypto/`` imports ``device/``,
+``ops/`` and ``parallel/``, never the reverse. A module in one of those
+three directories that imports ``fisco_bcos_tpu.crypto`` points an arrow
+up (it is how the dispatch policy came to be known by five modules).
+``crypto.ref`` is exempt: the plain reference is a leaf of pure Python that
+imports nothing of the package, and ``ops/`` takes its curve constants and
+host hashes from it.
 """
 
 from __future__ import annotations
@@ -41,6 +49,34 @@ SEAM_PREFIXES = (
     "fisco_bcos_tpu/parallel/",
     "fisco_bcos_tpu/analysis/",  # the checkers read, never dispatch
 )
+
+
+# directories below the crypto seams: nothing in them imports crypto/
+LOWER_LAYERS = {"device", "ops", "parallel"}
+
+
+def _imported_crypto(node: ast.AST) -> str | None:
+    """The ``crypto`` module an import names (``crypto.suite``, ``crypto``),
+    None for anything else and for the exempt ``crypto.ref``."""
+    names: list[str] = []
+    if isinstance(node, ast.ImportFrom):
+        mod = node.module or ""
+        if node.level == 0 and not mod.startswith("fisco_bcos_tpu"):
+            return None
+        parts = [p for p in mod.split(".") if p != "fisco_bcos_tpu" and p]
+        # from .. import crypto / from ..crypto import suite: the names count
+        names = [".".join(parts + [a.name]) for a in node.names]
+    elif isinstance(node, ast.Import):
+        names = [
+            a.name.removeprefix("fisco_bcos_tpu.")
+            for a in node.names
+            if a.name.startswith("fisco_bcos_tpu.")
+        ]
+    for name in names:
+        parts = name.split(".")
+        if parts[0] == "crypto" and parts[1:2] != ["ref"]:
+            return ".".join(parts[:2])
+    return None
 
 
 def _imported_device_module(node: ast.AST) -> tuple[str, list[str]] | None:
@@ -72,6 +108,8 @@ class DeviceDispatchChecker(Checker):
     def run(self, sources: list[Source]) -> list[Finding]:
         out: list[Finding] = []
         for src in sources:
+            if LOWER_LAYERS.intersection(src.relpath.split("/")[:-1]):
+                out.extend(self._upward_imports(src))
             if src.relpath.startswith(SEAM_PREFIXES):
                 continue
             qn = qualnames(src.tree)
@@ -100,4 +138,25 @@ class DeviceDispatchChecker(Checker):
                         "through the plane",
                     )
                 )
+        return out
+
+    def _upward_imports(self, src: Source) -> list[Finding]:
+        out: list[Finding] = []
+        qn = qualnames(src.tree)
+        for node in ast.walk(src.tree):
+            mod = _imported_crypto(node)
+            if mod is None or src.waived(node.lineno, self.name):
+                continue
+            out.append(
+                self.finding(
+                    src,
+                    node,
+                    qn.get(node, ""),
+                    f"imports-up-{mod}",
+                    f"`{mod}` imported from below the crypto seams: device/, "
+                    "ops/ and parallel/ import nothing of crypto/ but the "
+                    "plain reference (crypto.ref) — hand the value down as "
+                    "an argument, or move it to where both can reach it",
+                )
+            )
         return out

@@ -20,8 +20,9 @@ A national-crypto chain (``sm_crypto=true``) runs the same step as
 
 (:func:`sm_admission_core`; the 128-byte signature r ‖ s ‖ pub carries the
 key, bcos-crypto signature/sm2/SM2Crypto.cpp:29-91). What differs between the
-two suites is one :class:`_Body`; marshalling, the plane executor, the breaker,
-the mesh fan-out and the packed result are shared.
+two suites is one :class:`_Body`; the device leg below (marshal, mesh fan-out,
+packed result) is written once against it, and where a batch runs — plane,
+policy, breaker — is device/dispatch.py's.
 """
 
 from __future__ import annotations
@@ -35,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..device.dispatch import BatchOp, dispatch
 from ..ops import keccak, secp256k1, sm2, sm3
 from ..ops.address import sender_address_device, sm_sender_address_device
 from ..ops.bigint import (
@@ -131,7 +133,7 @@ def _admit_batch_native(payloads, sigs65):
     (tests/test_admission.py pins it). None when the native library is
     unavailable. ~0.3ms/sig — beats the DEVICE path outright when the jax
     backend is CPU XLA, and a device dispatch's fixed cost for small
-    batches (crypto.suite._SMALL_BATCH)."""
+    batches (device/dispatch.py holds the cutover)."""
     from .. import native_bind
 
     if native_bind.load() is None:
@@ -171,13 +173,13 @@ def _admit_batch_host_sm(payloads, sigs128, native: bool):
     digests = np.frombuffer(
         b"".join(sm3_hash(p) for p in payloads), dtype=np.uint8
     ).reshape(n, 32)
-    rs, ss, pubs = sigs128[:, :32], sigs128[:, 32:64], sigs128[:, 64:128]
+    pubs = sigs128[:, 64:128]
     if native:
-        ok = impl._native_batch_verify(digests, pubs, rs, ss)
+        ok = impl._native_verify(digests, pubs, sigs128)
         if ok is None:
             return None
     else:
-        ok = impl._host_verify_loop(digests, rs, ss, pubs)
+        ok = impl._host_verify_loop(digests, pubs, sigs128)
     pubs = np.where(ok[:, None], pubs, 0).astype(np.uint8)
     senders = np.zeros((n, 20), dtype=np.uint8)
     for i in np.flatnonzero(ok):
@@ -214,38 +216,57 @@ def _host_native_or_raise(payloads, sigs65):
     return out
 
 
-@dataclass(frozen=True)
-class _Body:
-    """What one suite's fused admission is made of; everything below the
-    table is written once against it."""
+def _native_leg(native):
+    """A body's native host loop as a leg: under the ``admission_native``
+    span, shape_key pinned so it never reads as a compile; the op label keeps
+    the dispatch split visible."""
+
+    def run(payloads, sigs):
+        from ..observability.device import device_span
+
+        with device_span("admission_native", len(payloads), shape_key="native"):
+            return native(payloads, sigs)
+
+    return run
+
+
+@dataclass(frozen=True, kw_only=True)
+class _Body(BatchOp):
+    """What one suite's fused admission is made of: the seam's description
+    (label ``admission`` for either suite, the plane op, the three legs) and
+    what the device leg, written once below the table, is built from."""
 
     op: str  # device_span op: spans, phases, items, compile-ledger episodes
-    plane_op: str  # the plane merges requests of one op only
     sig_len: int
     packed: Callable  # the unjitted program -> [B, 117]; the sharded wrapper's body
     step: Callable  # jax.jit(packed), through the module's name for it
     mblocks: Callable[[int], int]  # longest payload's bytes -> message blocks
     marshal: Callable  # (payloads, sigs, bucket) -> the program's operands
-    native: Callable  # (payloads, sigs) -> result, or None without the library
-    host: Callable  # (payloads, sigs) -> result: the breaker's fallback
 
 
+# the device leg may fan out over the mesh; it names its body through the
+# module, as `step` names its jit
 _BODIES = {
     ("secp256k1", "keccak256"): _Body(
-        "admission", "admission", 65, _admission_packed,
-        lambda *operands: admission_step_packed(*operands),
-        lambda n: n // 136 + 1, _marshal_secp, _admit_batch_native,
-        _host_native_or_raise,
+        "admission", "admission",
+        lambda p, s: _admit_batch_device(p, s, allow_shard=True, body=_SECP),
+        _native_leg(_admit_batch_native), _host_native_or_raise,
+        op="admission", sig_len=65, packed=_admission_packed,
+        step=lambda *operands: admission_step_packed(*operands),
+        mblocks=lambda n: n // 136 + 1, marshal=_marshal_secp,
     ),
     ("sm2", "sm3"): _Body(
-        "admission_sm", "admission.sm", 128, _sm_admission_packed,
-        lambda *operands: sm_admission_step_packed(*operands),
-        lambda n: (n + 8) // 64 + 1, _marshal_sm,
-        functools.partial(_admit_batch_host_sm, native=True),
+        "admission", "admission.sm",
+        lambda p, s: _admit_batch_device(p, s, allow_shard=True, body=_SM),
+        _native_leg(functools.partial(_admit_batch_host_sm, native=True)),
         functools.partial(_admit_batch_host_sm, native=False),
+        op="admission_sm", sig_len=128, packed=_sm_admission_packed,
+        step=lambda *operands: sm_admission_step_packed(*operands),
+        mblocks=lambda n: (n + 8) // 64 + 1, marshal=_marshal_sm,
     ),
 }
 _SECP = _BODIES["secp256k1", "keccak256"]
+_SM = _BODIES["sm2", "sm3"]
 
 
 def _body_of(suite) -> _Body | None:
@@ -276,8 +297,8 @@ def _maybe_sharded_step(body: _Body, bb: int):
     """The cached sharded admission program when the bucketed batch `bb`
     clears the fan-out threshold on a multi-device mesh; None otherwise
     (single-chip jit). Nothing is caught here: a mesh or program that fails
-    raises into the caller's device leg, where crypto.suite._device_or_host
-    counts the failure, answers from the host loop and feeds the breaker —
+    raises into the caller's device leg, where the dispatch seam counts the
+    failure, answers from the host loop and feeds the breaker —
     fan-out stays an optimization, never a liveness dependency, without
     hiding that it broke."""
     ndev = len(jax.devices())
@@ -297,7 +318,7 @@ def _admit_batch_device(
     payloads, sigs, allow_shard: bool = False, body: _Body = _SECP
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """The fused device program of `body` (hash → recover or verify →
-    address), one result transfer. `allow_shard=True` (plane dispatches only)
+    address), one result transfer. `allow_shard=True` (the seam's device leg)
     fans the bucketed batch out over the local device mesh when it clears
     _shard_min."""
     from ..observability.device import device_span
@@ -332,83 +353,6 @@ def _admit_batch_device(
             )
 
 
-def _try_native(body: _Body, payloads, sigs):
-    """The native-host-loop leg when policy picks it; None to use device."""
-    from ..observability.device import device_span
-    from .suite import use_native_batch
-
-    if os.environ.get("FISCO_FORCE_DEVICE_ADMISSION"):
-        return None
-    if not use_native_batch(len(payloads)):
-        return None
-    # native host loop — shape_key pinned so it never reads as
-    # a compile; the op label keeps the dispatch split visible
-    with device_span("admission_native", len(payloads), shape_key="native"):
-        return body.native(payloads, np.asarray(sigs, dtype=np.uint8))
-
-
-def _admit_direct(body: _Body, payloads, sigs):
-    """Pre-plane per-caller dispatch (the FISCO_DEVICE_PLANE=0 path):
-    native-vs-device decided for THIS call alone — no coalescing, no
-    fan-out, no breaker."""
-    from .suite import _note_dispatch_path
-
-    out = _try_native(body, payloads, sigs)
-    if out is not None:
-        _note_dispatch_path("admission", "native")
-        return out
-    _note_dispatch_path("admission", "device")
-    return _admit_batch_device(payloads, sigs, allow_shard=False, body=body)
-
-
-def _admit_merged(body: _Body, payloads, sigs):
-    """Plane-executor body: the same native-vs-device policy applied to the
-    MERGED batch, with multi-device fan-out allowed and the device leg under
-    the resilience breaker (host-loop fallback keeps admission serving when
-    the device plane is degraded). Either suite's dispatch is noted as the
-    node's ``admission``."""
-    from .suite import _device_or_host, _note_dispatch_path
-
-    out = _try_native(body, payloads, sigs)
-    if out is not None:
-        _note_dispatch_path("admission", "native")
-        return out
-    return _device_or_host(
-        "admission",
-        lambda p, s: _admit_batch_device(p, s, allow_shard=True, body=body),
-        lambda p, s: body.host(p, np.asarray(s, dtype=np.uint8)),
-        payloads,
-        sigs,
-    )
-
-
-@functools.cache
-def _plane_exec(body: _Body):
-    """DevicePlane executor of one body: merge every queued admission request
-    (txpool RPC batches, consensus proposal re-verification, sync imports)
-    into one policy decision + one device program, then slice results per
-    request."""
-
-    def run(reqs):
-        payloads: list[bytes] = []
-        rows = []
-        for r in reqs:
-            payloads.extend(r.payload[0])
-            rows.append(r.payload[1])
-        sigs = np.concatenate(rows, axis=0)
-        senders, ok, pubs, digests = _admit_merged(body, payloads, sigs)
-        senders, ok = np.asarray(senders), np.asarray(ok)
-        pubs, digests = np.asarray(pubs), np.asarray(digests)
-        out, lo = [], 0
-        for r in reqs:
-            hi = lo + r.n
-            out.append((senders[lo:hi], ok[lo:hi], pubs[lo:hi], digests[lo:hi]))
-            lo = hi
-        return out
-
-    return run
-
-
 def admit_batch(
     payloads, sigs, suite=None
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
@@ -416,7 +360,7 @@ def admit_batch(
     (senders [B, 20] uint8, ok bool[B], pubkeys [B, 64] uint8,
     tx hashes [B, 32] uint8). One device program, ONE result transfer —
     or the native host loop when that wins (small batch / CPU-only backend;
-    crypto.suite.use_native_batch holds the policy).
+    device/dispatch.py holds the policy).
 
     `suite` (a crypto.suite.CryptoSuite) picks the body: 65-byte r‖s‖v under
     secp256k1 + keccak256, the default; 128-byte r‖s‖pub under SM2 + SM3. A
@@ -425,13 +369,11 @@ def admit_batch(
     for the former instead).
 
     Routed through the shared DevicePlane: concurrent callers' batches
-    coalesce into one program, shapes ride the bucket ladder, and oversized
-    merged batches fan out over the device mesh. ``FISCO_DEVICE_PLANE=0``
-    restores the per-caller direct dispatch exactly.
+    (txpool RPC batches, consensus proposal re-verification, sync imports)
+    coalesce into one policy decision and one program, shapes ride the bucket
+    ladder, and oversized merged batches fan out over the device mesh.
     FISCO_FORCE_DEVICE_ADMISSION=1 pins the device program (tests use it to
     cover the device path on CPU hosts)."""
-    from ..device.plane import get_plane, plane_route, plane_wait
-
     body = _body_of(suite)
     if body is None:
         raise ValueError("admit_batch: this suite has no fused admission")
@@ -442,11 +384,7 @@ def admit_batch(
             f"admit_batch: {sigs_arr.shape[-1]}-byte signatures under a suite "
             f"that signs {body.sig_len} bytes"
         )
-    if plane_route() and bsz:
-        return plane_wait(get_plane().submit(
-            body.plane_op, (list(payloads), sigs_arr), bsz, _plane_exec(body)
-        ))
-    return _admit_direct(body, payloads, sigs_arr)
+    return dispatch(body, (list(payloads), sigs_arr), bsz)
 
 
 # -- progaudit shape spec: M=2 message-block dim (the short-payload bucket

@@ -9,8 +9,8 @@ whole quorum — routes through the shared DevicePlane as the
 ``bls_aggregate_verify`` op on whatever lane the caller tagged (consensus
 for QC admission), merging concurrent certificate checks from block-sync /
 lightnode header storms into one jitted pairing program. CPU backends and
-sub-threshold batches take the bit-identical host pairing, exactly the
-``use_native_batch`` contract the other curves follow.
+sub-threshold batches take the bit-identical host pairing: the dispatch
+seam's policy (device/dispatch.py), as for the other curves.
 
 Key model: BLS keypairs are DERIVED (secret scalar mod r) from the node's
 main consensus secret, and the committee's BLS pubkeys are registered in
@@ -26,7 +26,8 @@ from functools import lru_cache
 import numpy as np
 
 from ..crypto.ref import bls12_381 as ref
-from .suite import SignatureCrypto, KeyPair, use_native_batch
+from ..device.dispatch import BatchOp, dispatch, run_legs
+from .suite import SignatureCrypto, KeyPair
 
 
 @lru_cache(maxsize=4096)
@@ -61,22 +62,58 @@ def _apk_point(pubs: tuple[bytes, ...]):
     return acc
 
 
-def _aggregate_plane_exec(impl: "BLSCrypto"):
-    """Plane executor: merge every queued request's checks into ONE
-    pairing batch; one result row per check, sliced back per request."""
+def _triples(checks):
+    """(aggregate pubkey, signature, H(m)) points per check, None where one
+    is malformed. Deserialization and hash-to-G2 are host-side (cached)."""
+    triples = []
+    for pubs, msg, agg in checks:
+        apk = _apk_point(pubs) if pubs else None
+        sig = _g2_point(agg)
+        hm = ref.hash_to_g2(msg) if apk is not None and sig is not None else None
+        triples.append((apk, sig, hm))
+    return triples
 
-    def run(reqs):
-        checks: list = []
-        for r in reqs:
-            checks.extend(r.payload)
-        ok = impl._aggregate_verify_merged(checks)
-        out, lo = [], 0
-        for r in reqs:
-            out.append(ok[lo : lo + r.n])
-            lo += r.n
-        return out
 
-    return run
+def _host_pairing_check(checks):
+    from ..ops import bls12_381 as bls_ops
+
+    return bls_ops.host_pairing_check_batch(_triples(checks))
+
+
+def _device_pairing_check(checks):
+    from ..observability.device import device_span
+    from ..ops import bls12_381 as bls_ops
+    from ..ops.hash_common import bucket_batch
+
+    n = len(checks)
+    with device_span("bls_aggregate_verify", n, shape_key=bucket_batch(max(n, 1))):
+        return bls_ops.pairing_check_batch(_triples(checks))
+
+
+def _host_multi_pairing(pairs):
+    from ..ops import bls12_381 as bls_ops
+
+    return bool(bls_ops.host_multi_pairing_check(pairs))
+
+
+def _device_multi_pairing(pairs):
+    from ..observability.device import device_span
+    from ..ops import bls12_381 as bls_ops
+
+    n = len(pairs)
+    with device_span("bls_multi_pairing", n, shape_key=bls_ops.multi_pairing_pad(n)):
+        return bool(bls_ops.multi_pairing_check(pairs))
+
+
+# the pairing runs on device for large merged batches on accelerator
+# backends, else on the bit-identical host reference
+_AGGREGATE_VERIFY = BatchOp(
+    "bls_aggregate_verify", "bls_aggregate_verify",
+    _device_pairing_check, native=_host_pairing_check,
+)
+_MULTI_PAIRING = BatchOp(
+    "bls_multi_pairing", None, _device_multi_pairing, native=_host_multi_pairing
+)
 
 
 class BLSCrypto(SignatureCrypto):
@@ -152,49 +189,7 @@ class BLSCrypto(SignatureCrypto):
             (tuple(bytes(p) for p in pubs), bytes(m), bytes(s))
             for pubs, m, s in checks
         ]
-        from ..device.plane import get_plane, plane_route, plane_wait
-
-        if plane_route() and checks:
-            return plane_wait(
-                get_plane().submit(
-                    "bls_aggregate_verify",
-                    checks,
-                    len(checks),
-                    _aggregate_plane_exec(self),
-                )
-            )
-        return self._aggregate_verify_merged(checks)
-
-    def _aggregate_verify_merged(self, checks) -> np.ndarray:
-        """The merged-batch body both dispatch modes share. Deserialization
-        and hash-to-G2 are host-side (cached); the pairing runs on device
-        for large merged batches on accelerator backends, else on the
-        bit-identical host reference."""
-        from ..observability.device import device_span
-        from ..ops.hash_common import bucket_batch
-
-        triples = []
-        for pubs, msg, agg in checks:
-            apk = _apk_point(pubs) if pubs else None
-            sig = _g2_point(agg)
-            hm = ref.hash_to_g2(msg) if apk is not None and sig is not None else None
-            triples.append((apk, sig, hm))
-        n = len(triples)
-        from ..ops import bls12_381 as bls_ops
-
-        if use_native_batch(n):
-            from .suite import _note_dispatch_path
-
-            _note_dispatch_path("bls_aggregate_verify", "native")
-            return bls_ops.host_pairing_check_batch(triples)
-        from .suite import _note_dispatch_path
-
-        _note_dispatch_path("bls_aggregate_verify", "device")
-        with device_span(
-            "bls_aggregate_verify", n, shape_key=bucket_batch(max(n, 1))
-        ):
-            return bls_ops.pairing_check_batch(triples)
-
+        return dispatch(_AGGREGATE_VERIFY, (checks,), len(checks))
 
     # -- succinct header sync (the multi-pairing surface) -------------------
 
@@ -259,19 +254,7 @@ class BLSCrypto(SignatureCrypto):
             pairs.append((ref.ec_mul(apk, r, ref.FP_OPS), hm))
         pairs.insert(0, (ref.ec_neg(ref.G1, ref.FP_OPS), sig_acc))
 
-        from ..observability.device import device_span
-        from ..ops import bls12_381 as bls_ops
-        from .suite import _note_dispatch_path
-
-        n = len(pairs)
-        if use_native_batch(n):
-            _note_dispatch_path("bls_multi_pairing", "native")
-            return bool(bls_ops.host_multi_pairing_check(pairs))
-        _note_dispatch_path("bls_multi_pairing", "device")
-        with device_span(
-            "bls_multi_pairing", n, shape_key=bls_ops.multi_pairing_pad(n)
-        ):
-            return bool(bls_ops.multi_pairing_check(pairs))
+        return run_legs(_MULTI_PAIRING, len(pairs), pairs)
 
 
 def bls_suite():

@@ -18,13 +18,14 @@ any divergence would fork a chain.
 from __future__ import annotations
 
 import functools
-import os
 import secrets
 import threading
 from dataclasses import dataclass
 
 import numpy as np
 
+from ..device.dispatch import BatchOp, dispatch, enqueue
+from ..device.plane import plane_wait, plane_wait_deferred
 from ..ops import keccak as keccak_ops
 from ..ops import merkle as merkle_ops
 from ..ops import secp256k1 as secp_ops
@@ -79,12 +80,11 @@ def _hash_plane_exec(name: str, batch_async_direct):
 class HashImpl:
     """Hash interface (reference: bcos-crypto Hash.h:37-60 + AnyHasher).
 
-    Batch calls route through the shared :class:`~..device.plane.DevicePlane`
-    (coalesced, bucket-padded, priority-laned); ``FISCO_DEVICE_PLANE=0``
-    restores the direct per-caller dispatch. Subclasses implement the
-    ``_batch_direct`` / ``_batch_async_direct`` pair; the plane executor and
-    the passthrough path both go through those, so the two modes cannot
-    diverge.
+    Batch calls enter the shared :class:`~..device.plane.DevicePlane`
+    through the dispatch seam (coalesced, bucket-padded, priority-laned).
+    Subclasses implement ``_batch_async_direct``: the plane executor
+    dispatches through it, and a call that must not queue
+    (device/dispatch.enqueue) runs it inline.
     """
 
     name: str = ""
@@ -92,47 +92,29 @@ class HashImpl:
     def hash(self, data: bytes) -> bytes:
         raise NotImplementedError
 
-    def _batch_direct(self, msgs) -> np.ndarray:
-        """Direct (non-plane) batch dispatch: one device program."""
-        raise NotImplementedError
-
     def _batch_async_direct(self, msgs):
-        """Direct deferred-sync dispatch: () -> [B, 32]. Default dispatches
-        eagerly; device-backed impls override with their ops *_batch_async
-        so the plane executor can defer the sync."""
-        out = self._batch_direct(msgs)
-        return lambda: out
+        """Direct deferred-sync dispatch, one device program: () -> [B, 32]
+        (an ops ``*_batch_async``, so the plane executor can defer the sync)."""
+        raise NotImplementedError
 
     def hash_batch(self, msgs) -> np.ndarray:
         """list[bytes] -> [B, 32] uint8 digests, one device program."""
-        msgs = list(msgs)
-        from ..device.plane import plane_route
-
-        if plane_route() and msgs:
-            return self.hash_batch_async(msgs)()
-        return self._batch_direct(msgs)
+        return self.hash_batch_async(msgs)()
 
     def hash_batch_async(self, msgs):
         """Dispatch the device batch, defer the sync: () -> [B, 32] uint8.
 
-        Routed through the device plane so concurrent callers' hash
-        programs coalesce AND overlap before the first sync (pre-plane,
-        this default ran eagerly — each caller synced before the next
-        could even dispatch)."""
+        Queued into the device plane so concurrent callers' hash programs
+        coalesce AND overlap before the first sync."""
         msgs = list(msgs)
-        from ..device.plane import get_plane, plane_route, plane_wait_deferred
-
-        if plane_route() and msgs:
-            fut = get_plane().submit(
-                f"hash.{self.name or type(self).__name__}",
-                msgs,
-                len(msgs),
-                _hash_plane_exec(
-                    self.name or type(self).__name__, self._batch_async_direct
-                ),
-            )
-            return lambda: plane_wait_deferred(fut)
-        return self._batch_async_direct(msgs)
+        name = self.name or type(self).__name__
+        fut = enqueue(
+            f"hash.{name}", msgs, len(msgs),
+            _hash_plane_exec(name, self._batch_async_direct),
+        )
+        if fut is None:
+            return self._batch_async_direct(msgs)
+        return lambda: plane_wait_deferred(fut)
 
 
 class Keccak256(HashImpl):
@@ -146,9 +128,6 @@ class Keccak256(HashImpl):
 
         return native_bind.keccak256(data) or ref_keccak256(data)
 
-    def _batch_direct(self, msgs) -> np.ndarray:
-        return keccak_ops.keccak256_batch(msgs)
-
     def _batch_async_direct(self, msgs):
         return keccak_ops.keccak256_batch_async(msgs)
 
@@ -161,9 +140,6 @@ class SM3(HashImpl):
 
         return native_bind.sm3(data) or ref_sm3(data)
 
-    def _batch_direct(self, msgs) -> np.ndarray:
-        return sm3_ops.sm3_batch(msgs)
-
     def _batch_async_direct(self, msgs):
         return sm3_ops.sm3_batch_async(msgs)
 
@@ -175,9 +151,6 @@ class Sha256(HashImpl):
         from .. import native_bind
 
         return native_bind.sha256(data) or ref_sha256(data)
-
-    def _batch_direct(self, msgs) -> np.ndarray:
-        return sha256_ops.sha256_batch(msgs)
 
     def _batch_async_direct(self, msgs):
         return sha256_ops.sha256_batch_async(msgs)
@@ -196,11 +169,6 @@ class Poseidon(HashImpl):
         from .ref.poseidon import poseidon_hash
 
         return poseidon_hash(data)
-
-    def _batch_direct(self, msgs) -> np.ndarray:
-        from ..ops import poseidon as poseidon_ops
-
-        return poseidon_ops.poseidon_batch(msgs)
 
     def _batch_async_direct(self, msgs):
         from ..ops import poseidon as poseidon_ops
@@ -258,196 +226,10 @@ def _make_keypair(curve: ref_ecdsa.Curve, secret: int | None) -> KeyPair:
 # Signature implementations
 # ---------------------------------------------------------------------------
 
-# Batches below this ride the native host loop instead of the device: a
-# device program pays a fixed dispatch + transfer + sync cost regardless of
-# batch size, while the native single-item path is ~0.3ms/sig, so there is
-# a break-even batch.  PBFT QC signature lists (3-4 sigs per block,
-# BlockValidator.cpp:141-177) and small-block admission are the
-# beneficiaries.  The value is inherited, NOT measured on a local chip —
-# deriving it from the device observatory is ROADMAP Queue 3.  Results are
-# bit-identical across both legs (tests/test_native_ec.py pins it).
-_SMALL_BATCH = 256
-
-
-def device_min_batch() -> int:
-    """Host-vs-device cutover: batches below this ride the native host loop.
-
-    ``FISCO_DEVICE_MIN_BATCH`` overrides the hardcoded default — the right
-    cutover depends on the device's fixed per-dispatch cost, which is not
-    measured on a local chip yet. Read per call (an env read, ~100ns
-    against a batch dispatch) so operators and tests can retune without a
-    restart."""
-    raw = os.environ.get("FISCO_DEVICE_MIN_BATCH")
-    if raw:
-        try:
-            return max(0, int(raw))
-        except ValueError:
-            pass
-    return _SMALL_BATCH
-
-
-def _note_dispatch_path(op: str, path: str) -> None:
-    """Labeled counter of which leg a batch actually took (native host loop
-    vs device program) — the observable form of the `use_native_batch`
-    policy, so a mistuned FISCO_DEVICE_MIN_BATCH shows up in /metrics
-    instead of as a silent latency cliff."""
-    from ..utils.metrics import REGISTRY
-
-    REGISTRY.counter_add(
-        f'fisco_device_dispatch_path_total{{op="{op}",path="{path}"}}',
-        1.0,
-        help="batch dispatches split by chosen leg (native host vs device)",
-    )
-
-
-def device_backend_is_cpu() -> bool:
-    """True when the jax device plane is CPU XLA (no accelerator): there the
-    native C loop beats the XLA program at EVERY batch size (~0.3ms/sig vs
-    4-16ms/sig of emulated 256-bit limb arithmetic), so batch dispatchers
-    should prefer the host path regardless of _SMALL_BATCH. The identity is
-    memoised in utils.jaxenv; a backend that fails to initialise raises —
-    "no chip" must never read as "CPU"."""
-    from ..utils.jaxenv import device_identity
-
-    return device_identity()["platform"] == "cpu"
-
-
-def use_native_batch(n: int) -> bool:
-    """Whether an n-item signature batch should ride the native host loop
-    instead of a device program (threshold: :func:`device_min_batch`)."""
-    return 0 < n and (n < device_min_batch() or device_backend_is_cpu())
-
-
-# -- device-path circuit breaker (resilience/) -------------------------------
-
-_DEVICE_BREAKER = None
-_DEVICE_BREAKER_LOCK = threading.Lock()
-
-
-def _device_breaker():
-    """Breaker over the compiled device batch plane. It can fail in the
-    field — a lost accelerator, device OOM on an oversized trace, a
-    driver hiccup — and consensus must keep verifying: each failure falls
-    back to the host loop for THAT batch, and repeated failures trip the
-    breaker so admission stops paying a doomed device dispatch before every
-    fallback. /health reports `device-crypto` degraded while tripped; a
-    half-open probe re-closes it when the device plane answers again."""
-    global _DEVICE_BREAKER
-    if _DEVICE_BREAKER is None:
-        from ..resilience import CircuitBreaker
-
-        # double-checked: two racing callers must end up sharing ONE breaker
-        # — split breakers would each see half the failures and never trip
-        with _DEVICE_BREAKER_LOCK:
-            if _DEVICE_BREAKER is None:
-                _DEVICE_BREAKER = CircuitBreaker(
-                    "device-crypto", failure_threshold=2, reset_timeout=60.0,
-                    critical=False,  # host loop keeps serving: slower, not down
-                )
-    return _DEVICE_BREAKER
-
-
-def _device_or_host(op: str, device_fn, host_fn, *args):
-    """Run the compiled device path for ``op`` under the breaker, degrading
-    to the bit-identical host loop. The failure only counts against the
-    breaker when the host retry of the SAME args succeeds — a data error
-    (bad shape/dtype) re-raises from the host path without tripping
-    anything, so one malformed batch cannot demote a healthy device plane.
-
-    Nothing here is silent: the leg taken lands in
-    ``fisco_device_dispatch_path_total{op,path}`` (``device``, or
-    ``host_fallback`` while the breaker is open), and every device-program
-    failure the host loop covered for is counted and kept with its error in
-    the device observatory (``GET /device`` → ``failures``)."""
-    breaker = _device_breaker()
-    if not breaker.allow():
-        _note_dispatch_path(op, "host_fallback")
-        return host_fn(*args)
-    _note_dispatch_path(op, "device")
-    try:
-        out = device_fn(*args)
-    except Exception as e:
-        try:
-            out = host_fn(*args)
-        except BaseException:
-            # both paths failed: a data error, not a device verdict — free
-            # the half-open probe slot or the breaker wedges
-            breaker.release_probe()
-            raise
-        from ..observability.device import LEDGER
-
-        LEDGER.note_failure(op, e)
-        breaker.record_failure(f"{type(e).__name__}: {str(e)[:200]}")
-        return out
-    breaker.record_success()
-    return out
-
-
-# -- device-plane executors ---------------------------------------------------
-#
-# One executor per (op, merge-convention): each merges every queued request
-# into one batch, runs the impl's merged-batch body (the SAME body the
-# passthrough path uses — the two modes cannot diverge), and slices the
-# result back per request. Executors run on the plane worker with routing
-# disabled, so nested seam calls (ed25519 recover → verify) take the direct
-# path instead of deadlocking the worker.
-
-
-def _verify_plane_exec(impl):
-    """(hashes [n,32], pubs [n,64], sigs [n,L]) ndarray triples -> ok[n]."""
-
-    def run(reqs):
-        hs = np.concatenate([r.payload[0] for r in reqs], axis=0)
-        ps = np.concatenate([r.payload[1] for r in reqs], axis=0)
-        sg = np.concatenate([r.payload[2] for r in reqs], axis=0)
-        ok = np.asarray(impl._verify_merged(hs, ps, sg))
-        out, lo = [], 0
-        for r in reqs:
-            out.append(ok[lo : lo + r.n])
-            lo += r.n
-        return out
-
-    return run
-
-
-def _verify_plane_exec_lists(impl):
-    """Same as :func:`_verify_plane_exec` for list-of-bytes payloads
-    (ed25519's variable-form signatures)."""
-
-    def run(reqs):
-        hs: list[bytes] = []
-        ps: list[bytes] = []
-        sg: list[bytes] = []
-        for r in reqs:
-            h, p, s = r.payload
-            hs += h
-            ps += p
-            sg += s
-        ok = np.asarray(impl._verify_merged(hs, ps, sg))
-        out, lo = [], 0
-        for r in reqs:
-            out.append(ok[lo : lo + r.n])
-            lo += r.n
-        return out
-
-    return run
-
-
-def _recover_plane_exec(impl):
-    """(hashes [n,32], sigs [n,L]) -> (pubs [n,64], ok[n]) per request."""
-
-    def run(reqs):
-        hs = np.concatenate([r.payload[0] for r in reqs], axis=0)
-        sg = np.concatenate([r.payload[1] for r in reqs], axis=0)
-        pubs, ok = impl._recover_merged(hs, sg)
-        pubs, ok = np.asarray(pubs), np.asarray(ok)
-        out, lo = [], 0
-        for r in reqs:
-            out.append((pubs[lo : lo + r.n], ok[lo : lo + r.n]))
-            lo += r.n
-        return out
-
-    return run
+# Where a batch runs — native host loop, device program, or the host loop
+# behind the breaker — is device/dispatch.py's decision. The classes below
+# normalise their arguments and describe each batch operation as a BatchOp;
+# every leg of one op takes the same fields and answers the same rows.
 
 
 class SignatureCrypto:
@@ -483,6 +265,54 @@ class SignatureCrypto:
     ) -> tuple[np.ndarray, np.ndarray]:
         raise NotImplementedError
 
+    def _host_verify_loop(self, hashes, pubs, sigs) -> np.ndarray:
+        """Degraded-mode fallback: per-item verify on the host (native C or
+        pure-Python ref) — slow but bit-identical in outcome."""
+        return np.array(
+            [
+                self.verify(bytes(pubs[i]), bytes(hashes[i]), bytes(sigs[i]))
+                for i in range(len(hashes))
+            ],
+            dtype=bool,
+        )
+
+
+class _DeviceCurveCrypto(SignatureCrypto):
+    """The two tx-signing curves of the device batch plane: ``[n, L]`` uint8
+    arrays in, one BatchOp per batch operation, the device legs the host
+    wrappers of ``_ops``. A subclass gives ``_native_verify``,
+    ``_native_recover`` (None without the library) and
+    ``_host_recover_loop``."""
+
+    _ops = None  # ops.secp256k1 / ops.sm2: verify_batch, recover_batch
+
+    def __init__(self):
+        self._verify_op = BatchOp(
+            f"{self.name}_verify", f"verify.{self.name}", self._device_verify,
+            self._native_verify, self._host_verify_loop,
+        )
+        self._recover_op = BatchOp(
+            f"{self.name}_recover", f"recover.{self.name}", self._device_recover,
+            self._native_recover, self._host_recover_loop,
+        )
+
+    def batch_verify(self, msg_hashes, pubs, sigs) -> np.ndarray:
+        sigs = np.asarray(sigs, dtype=np.uint8)
+        hashes = np.asarray(msg_hashes, dtype=np.uint8)
+        pubs = np.asarray(pubs, dtype=np.uint8)
+        return dispatch(self._verify_op, (hashes, pubs, sigs), len(sigs))
+
+    def batch_recover(self, msg_hashes, sigs):
+        sigs = np.asarray(sigs, dtype=np.uint8)
+        hashes = np.asarray(msg_hashes, dtype=np.uint8)
+        return dispatch(self._recover_op, (hashes, sigs), len(sigs))
+
+    def _device_verify(self, hashes, pubs, sigs):
+        return self._ops.verify_batch(hashes, sigs[:, :32], sigs[:, 32:64], pubs)
+
+    def _device_recover(self, hashes, sigs):
+        return self._ops.recover_batch(hashes, sigs)
+
 
 class Ed25519Crypto(SignatureCrypto):
     """Ed25519 (reference: signature/ed25519/Ed25519Crypto.cpp via wedpr).
@@ -497,6 +327,12 @@ class Ed25519Crypto(SignatureCrypto):
 
     name = "ed25519"
     sig_len = 96
+
+    def __init__(self):
+        self._verify_op = BatchOp(
+            "ed25519_verify", "verify.ed25519", self._device_verify,
+            self._native_verify, self._host_verify_loop,
+        )
 
     def generate_keypair(self, secret: int | None = None) -> KeyPair:
         from .. import native_bind
@@ -538,40 +374,33 @@ class Ed25519Crypto(SignatureCrypto):
         (decompression, dual ladder, cofactored identity check) on device;
         SHA-512 challenges on host (ops/ed25519.py module docstring).
         Small batches and CPU-only backends ride the native host loop like
-        the other curves (use_native_batch) — a QC list of 4 signatures
-        must never pay a device dispatch or emulated-XLA limb math.
-        Routed through the device plane (merged with concurrent callers;
-        the host-vs-device cutover applies to the MERGED size)."""
+        the other curves — a QC list of 4 signatures must never pay a
+        device dispatch or emulated-XLA limb math."""
         hashes = [bytes(h) for h in msg_hashes]
         pub_list = [bytes(p) for p in pubs]
         sig_list = [bytes(s) for s in sigs]
-        from ..device.plane import get_plane, plane_route, plane_wait
+        return dispatch(
+            self._verify_op, (hashes, pub_list, sig_list), len(sig_list)
+        )
 
-        if plane_route() and sig_list:
-            return plane_wait(get_plane().submit(
-                "verify.ed25519",
-                (hashes, pub_list, sig_list),
-                len(sig_list),
-                _verify_plane_exec_lists(self),
-            ))
-        return self._verify_merged(hashes, pub_list, sig_list)
+    @staticmethod
+    def _native_verify(hashes, pub_list, sig_list):
+        from .. import native_bind
 
-    def _verify_merged(self, hashes, pub_list, sig_list) -> np.ndarray:
-        if use_native_batch(len(sig_list)):
-            from .. import native_bind
+        if native_bind.load() is None:
+            return None
+        return np.array(
+            [
+                native_bind.ed25519_verify(p[:32], h, s[:64])
+                for h, p, s in zip(hashes, pub_list, sig_list)
+            ],
+            dtype=bool,
+        )
 
-            if native_bind.load() is not None:
-                _note_dispatch_path("ed25519_verify", "native")
-                return np.array(
-                    [
-                        native_bind.ed25519_verify(p[:32], h, s[:64])
-                        for h, p, s in zip(hashes, pub_list, sig_list)
-                    ],
-                    dtype=bool,
-                )
+    @staticmethod
+    def _device_verify(hashes, pub_list, sig_list):
         from ..ops import ed25519 as ed_ops
 
-        _note_dispatch_path("ed25519_verify", "device")
         return ed_ops.verify_batch(hashes, pub_list, sig_list)
 
     def batch_recover(self, msg_hashes, sigs):
@@ -598,7 +427,7 @@ class Ed25519Crypto(SignatureCrypto):
         return out, np.asarray(ok)
 
 
-class Secp256k1Crypto(SignatureCrypto):
+class Secp256k1Crypto(_DeviceCurveCrypto):
     """65-byte r‖s‖v signatures, v ∈ {0..3} ∪ {27, 28}
     (reference: Secp256k1Crypto.cpp:32-136).
 
@@ -609,6 +438,7 @@ class Secp256k1Crypto(SignatureCrypto):
 
     name = "secp256k1"
     sig_len = 65
+    _ops = secp_ops
 
     def generate_keypair(self, secret: int | None = None) -> KeyPair:
         if secret is None:
@@ -656,55 +486,38 @@ class Secp256k1Crypto(SignatureCrypto):
         x, y = pub
         return x.to_bytes(32, "big") + y.to_bytes(32, "big")
 
-    def batch_verify(self, msg_hashes, pubs, sigs) -> np.ndarray:
-        sigs = np.asarray(sigs, dtype=np.uint8)
-        hashes = np.asarray(msg_hashes, dtype=np.uint8)
-        pubs = np.asarray(pubs, dtype=np.uint8)
-        from ..device.plane import get_plane, plane_route, plane_wait
+    @staticmethod
+    def _native_verify(hashes, pubs, sigs):
+        from .. import native_bind
 
-        if plane_route() and len(sigs):
-            return plane_wait(get_plane().submit(
-                "verify.secp256k1",
-                (hashes, pubs, sigs),
-                len(sigs),
-                _verify_plane_exec(self),
-            ))
-        return self._verify_merged(hashes, pubs, sigs)
+        out = native_bind.secp256k1_verify_batch(
+            np.ascontiguousarray(hashes).tobytes(),
+            np.ascontiguousarray(sigs[:, :32]).tobytes(),
+            np.ascontiguousarray(sigs[:, 32:64]).tobytes(),
+            np.ascontiguousarray(pubs).tobytes(),
+            len(sigs),
+        )
+        return None if out is None else np.asarray(out, dtype=bool)
 
-    def _verify_merged(self, hashes, pubs, sigs) -> np.ndarray:
+    @staticmethod
+    def _native_recover(hashes, sigs):
+        from .. import native_bind
+
         n = len(sigs)
-        if use_native_batch(n):
-            from .. import native_bind
-
-            out = native_bind.secp256k1_verify_batch(
-                np.ascontiguousarray(hashes).tobytes(),
-                np.ascontiguousarray(sigs[:, :32]).tobytes(),
-                np.ascontiguousarray(sigs[:, 32:64]).tobytes(),
-                np.ascontiguousarray(pubs).tobytes(),
-                n,
-            )
-            if out is not None:
-                _note_dispatch_path("secp256k1_verify", "native")
-                return np.asarray(out, dtype=bool)
-        return _device_or_host(
-            "secp256k1_verify", secp_ops.verify_batch, self._host_verify_loop,
-            hashes, sigs[:, :32], sigs[:, 32:64], pubs,
+        out = native_bind.secp256k1_recover_batch(
+            np.ascontiguousarray(hashes).tobytes(),
+            np.ascontiguousarray(sigs[:, :32]).tobytes(),
+            np.ascontiguousarray(sigs[:, 32:64]).tobytes(),
+            np.ascontiguousarray(sigs[:, 64]).tobytes(),
+            n,
         )
-
-    def _host_verify_loop(self, hashes, rs, ss, pubs) -> np.ndarray:
-        """Degraded-mode fallback: per-item verify on the host (native C or
-        pure-Python ref) — slow but bit-identical in outcome."""
-        return np.array(
-            [
-                self.verify(
-                    bytes(pubs[i]),
-                    bytes(hashes[i]),
-                    bytes(rs[i]) + bytes(ss[i]) + b"\x00",
-                )
-                for i in range(len(hashes))
-            ],
-            dtype=bool,
-        )
+        if out is None:
+            return None
+        pubs_raw, oks = out
+        pubs = np.frombuffer(pubs_raw, np.uint8).reshape(n, 64).copy()
+        ok = np.asarray(oks, dtype=bool)
+        pubs[~ok] = 0
+        return pubs, ok
 
     def _host_recover_loop(self, hashes, sigs):
         n = len(sigs)
@@ -719,52 +532,15 @@ class Secp256k1Crypto(SignatureCrypto):
             ok[i] = True
         return pubs, ok
 
-    def batch_recover(self, msg_hashes, sigs):
-        sigs = np.asarray(sigs, dtype=np.uint8)
-        hashes = np.asarray(msg_hashes, dtype=np.uint8)
-        from ..device.plane import get_plane, plane_route, plane_wait
 
-        if plane_route() and len(sigs):
-            return plane_wait(get_plane().submit(
-                "recover.secp256k1",
-                (hashes, sigs),
-                len(sigs),
-                _recover_plane_exec(self),
-            ))
-        return self._recover_merged(hashes, sigs)
-
-    def _recover_merged(self, hashes, sigs):
-        n = len(sigs)
-        if use_native_batch(n):
-            from .. import native_bind
-
-            out = native_bind.secp256k1_recover_batch(
-                np.ascontiguousarray(hashes).tobytes(),
-                np.ascontiguousarray(sigs[:, :32]).tobytes(),
-                np.ascontiguousarray(sigs[:, 32:64]).tobytes(),
-                np.ascontiguousarray(sigs[:, 64]).tobytes(),
-                n,
-            )
-            if out is not None:
-                _note_dispatch_path("secp256k1_recover", "native")
-                pubs_raw, oks = out
-                pubs = np.frombuffer(pubs_raw, np.uint8).reshape(n, 64).copy()
-                ok = np.asarray(oks, dtype=bool)
-                pubs[~ok] = 0
-                return pubs, ok
-        return _device_or_host(
-            "secp256k1_recover", secp_ops.recover_batch,
-            self._host_recover_loop, hashes, sigs,
-        )
-
-
-class SM2Crypto(SignatureCrypto):
+class SM2Crypto(_DeviceCurveCrypto):
     """128-byte r‖s‖pubkey signatures; "recover" parses the carried pubkey and
     verifies (reference: SM2Crypto.cpp:29-91 — sign appends the pubkey,
     recover = parse-pub-then-verify)."""
 
     name = "sm2"
     sig_len = 128
+    _ops = sm2_ops
 
     @staticmethod
     def _e_bytes(pub: bytes, msg_hash: bytes) -> bytes:
@@ -815,7 +591,7 @@ class SM2Crypto(SignatureCrypto):
             raise ValueError("sm2 recover: carried pubkey fails verification")
         return pub
 
-    def _native_batch_verify(self, hashes, pubs, rs, ss):
+    def _native_verify(self, hashes, pubs, sigs):
         """Native host loop for sub-threshold batches (e computed with the
         native SM3); None when the native core is unavailable."""
         from .. import native_bind
@@ -828,90 +604,27 @@ class SM2Crypto(SignatureCrypto):
         )
         out = native_bind.sm2_verify_batch(
             es,
-            np.ascontiguousarray(rs).tobytes(),
-            np.ascontiguousarray(ss).tobytes(),
+            np.ascontiguousarray(sigs[:, :32]).tobytes(),
+            np.ascontiguousarray(sigs[:, 32:64]).tobytes(),
             np.ascontiguousarray(pubs).tobytes(),
             n,
         )
         return None if out is None else np.asarray(out, dtype=bool)
 
-    def batch_verify(self, msg_hashes, pubs, sigs) -> np.ndarray:
-        sigs = np.asarray(sigs, dtype=np.uint8)
-        hashes = np.asarray(msg_hashes, dtype=np.uint8)
-        pubs = np.asarray(pubs, dtype=np.uint8)
-        from ..device.plane import get_plane, plane_route, plane_wait
+    def _carried(self, verify, hashes, sigs):
+        """recover = verify the key each signature carries, zero the rows
+        that fail (None where ``verify`` cannot answer)."""
+        pubs = sigs[:, 64:128]
+        ok = verify(hashes, pubs, sigs)
+        if ok is None:
+            return None
+        return np.where(ok[:, None], pubs, np.zeros_like(pubs)), ok
 
-        if plane_route() and len(sigs):
-            return plane_wait(get_plane().submit(
-                "verify.sm2",
-                (hashes, pubs, sigs),
-                len(sigs),
-                _verify_plane_exec(self),
-            ))
-        return self._verify_merged(hashes, pubs, sigs)
+    def _native_recover(self, hashes, sigs):
+        return self._carried(self._native_verify, hashes, sigs)
 
-    def _verify_merged(self, hashes, pubs, sigs) -> np.ndarray:
-        if use_native_batch(len(sigs)):
-            out = self._native_batch_verify(
-                hashes, pubs, sigs[:, :32], sigs[:, 32:64]
-            )
-            if out is not None:
-                _note_dispatch_path("sm2_verify", "native")
-                return out
-        return _device_or_host(
-            "sm2_verify", sm2_ops.verify_batch, self._host_verify_loop,
-            hashes, sigs[:, :32], sigs[:, 32:64], pubs,
-        )
-
-    def _host_verify_loop(self, hashes, rs, ss, pubs) -> np.ndarray:
-        """Degraded-mode fallback: per-item SM2 verify on the host."""
-        return np.array(
-            [
-                self.verify(
-                    bytes(pubs[i]),
-                    bytes(hashes[i]),
-                    bytes(rs[i]) + bytes(ss[i]) + bytes(pubs[i]),
-                )
-                for i in range(len(hashes))
-            ],
-            dtype=bool,
-        )
-
-    def batch_recover(self, msg_hashes, sigs):
-        sigs = np.asarray(sigs, dtype=np.uint8)
-        hashes = np.asarray(msg_hashes, dtype=np.uint8)
-        from ..device.plane import get_plane, plane_route, plane_wait
-
-        if plane_route() and len(sigs):
-            return plane_wait(get_plane().submit(
-                "recover.sm2",
-                (hashes, sigs),
-                len(sigs),
-                _recover_plane_exec(self),
-            ))
-        return self._recover_merged(hashes, sigs)
-
-    def _recover_merged(self, hashes, sigs):
-        if use_native_batch(len(sigs)):
-            pubs = sigs[:, 64:128]
-            ok = self._native_batch_verify(
-                hashes, pubs, sigs[:, :32], sigs[:, 32:64]
-            )
-            if ok is not None:
-                _note_dispatch_path("sm2_recover", "native")
-                out = np.where(ok[:, None], pubs, np.zeros_like(pubs))
-                return out, ok
-
-        def _host_recover(hashes_, sigs_):
-            pubs_ = sigs_[:, 64:128]
-            ok_ = self._host_verify_loop(
-                hashes_, sigs_[:, :32], sigs_[:, 32:64], pubs_
-            )
-            return np.where(ok_[:, None], pubs_, np.zeros_like(pubs_)), ok_
-
-        return _device_or_host(
-            "sm2_recover", sm2_ops.recover_batch, _host_recover, hashes, sigs
-        )
+    def _host_recover_loop(self, hashes, sigs):
+        return self._carried(self._host_verify_loop, hashes, sigs)
 
 
 # ---------------------------------------------------------------------------
@@ -984,55 +697,36 @@ class CryptoSuite:
         ladder. Bit-identical to a direct ``MerkleTree(...)`` build by
         construction — both paths run the same constructor.
         """
-        from ..device.plane import get_plane, plane_route, plane_wait
-        from ..observability.device import device_span
-
         leaves = np.asarray(leaves, dtype=np.uint8)
-        if plane_route() and len(leaves) > 1:
+        hasher = self.hash_impl.name
+        if len(leaves) > 1:
             # op name carries the hasher (like `hash.<name>` / `sm2_verify`):
             # the plane binds ONE executor per op name process-wide, and a
             # multi-suite host (keccak + SM groups) must not have the first
             # suite's hasher capture every group's tree builds
-            return plane_wait(get_plane().submit(
-                f"merkle_tree.{self.hash_impl.name}",
-                leaves,
-                len(leaves),
-                _merkle_tree_plane_exec(self.hash_impl.name),
-            ))
-        # direct path gets the same span the plane executor wraps builds
-        # in — tree hashing stays attributed with the plane off too
-        with device_span(
-            "merkle_tree",
-            len(leaves),
-            shape_key=(
-                self.hash_impl.name,
-                merkle_ops.bucket_leaves(max(len(leaves), 1)),
-            ),
-        ):
-            return merkle_ops.MerkleTree(leaves, hasher=self.hash_impl.name)
+            fut = enqueue(
+                f"merkle_tree.{hasher}", leaves, len(leaves),
+                lambda reqs: [_build_tree(hasher, r.payload) for r in reqs],
+            )
+            if fut is not None:
+                return plane_wait(fut)
+        return _build_tree(hasher, leaves)
 
 
-def _merkle_tree_plane_exec(hasher: str):
-    """Plane executor for proof-tree builds: each request is its own tree
-    (different heights — there is nothing sound to merge across roots), but
-    dispatching them through one plane slot serializes read-path hashing
-    behind the priority lanes and shares the dispatch accounting."""
+def _build_tree(hasher: str, leaves) -> "merkle_ops.MerkleTree":
+    """One proof-tree build under its span. The plane executor runs this per
+    request (different heights — there is nothing sound to merge across
+    roots; one plane slot still serializes read-path hashing behind the
+    priority lanes and shares the dispatch accounting), and so does a build
+    that does not queue."""
+    from ..observability.device import device_span
 
-    def run(reqs):
-        from ..observability.device import device_span
-
-        out = []
-        for r in reqs:
-            leaves = r.payload
-            with device_span(
-                "merkle_tree",
-                len(leaves),
-                shape_key=(hasher, merkle_ops.bucket_leaves(max(len(leaves), 1))),
-            ):
-                out.append(merkle_ops.MerkleTree(leaves, hasher=hasher))
-        return out
-
-    return run
+    with device_span(
+        "merkle_tree",
+        len(leaves),
+        shape_key=(hasher, merkle_ops.bucket_leaves(max(len(leaves), 1))),
+    ):
+        return merkle_ops.MerkleTree(leaves, hasher=hasher)
 
 
 def ecdsa_suite() -> CryptoSuite:

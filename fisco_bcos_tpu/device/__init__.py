@@ -1,5 +1,6 @@
 """Device verification plane — shared, shape-bucketed batch scheduling for
-all device crypto (see :mod:`.plane` and docs/device_plane.md)."""
+all device crypto (see :mod:`.plane`, :mod:`.dispatch` — the one place
+that decides where a batch runs — and docs/device_plane.md)."""
 
 from .plane import (  # noqa: F401
     DEFAULT_LANE,
@@ -10,6 +11,4 @@ from .plane import (  # noqa: F401
     device_lane,
     get_plane,
     in_plane_executor,
-    plane_enabled,
-    plane_route,
 )

@@ -15,8 +15,8 @@ The plane is that engine's scheduler:
 
 - **Per-op request queue, future-based results.** Callers submit
   (op, payload, item-count, executor) and get a ``concurrent.futures.Future``
-  back; the crypto seams (crypto/suite.py batch methods,
-  crypto/admission.admit_batch) block on it, so caller APIs are unchanged.
+  back; the dispatch seam (device/dispatch.py, which every crypto batch
+  method enters through) blocks on it, so caller APIs are unchanged.
 - **Micro-batch coalescer.** A single worker drains each op's queue after a
   bounded window (``FISCO_DEVICE_WINDOW_MS``, default 2 ms) or when the
   queued item count crosses the high-water mark
@@ -46,17 +46,12 @@ The plane is that engine's scheduler:
   Deferred requests keep their enqueue time (aging still applies) and
   count into ``fisco_device_plane_deferred_total{op,group}``. Single-group
   queues take the exact pre-fairness path: everything merges, no cap.
-- **Passthrough mode.** ``FISCO_DEVICE_PLANE=0`` disables routing entirely:
-  every seam takes its exact pre-plane dispatch path (per-caller batches,
-  no coalescing, no fan-out) — the escape hatch the smoke tool exercises.
 
-Executors run ON the worker thread with a thread-local marker set;
-``plane_route()`` returns False there, so an executor calling back into a
-plane-routed seam (e.g. ed25519 batch_recover → batch_verify) takes the
-direct path instead of deadlocking the single worker against itself.
-Results are bit-identical to the direct path by construction: executors
-call the same merged-batch implementations the direct path uses, and
-invalid rows lower validity-lane bits — they never raise.
+Executors run ON the worker thread with a thread-local marker set
+(``in_plane_executor()``); the dispatch seam reads it, so an executor
+calling back into a seam (e.g. ed25519 batch_recover → batch_verify) runs
+that body inline instead of deadlocking the single worker against itself.
+Invalid rows lower validity-lane bits — they never raise.
 """
 
 from __future__ import annotations
@@ -81,22 +76,10 @@ DEFAULT_LANE = "admission"
 _tls = threading.local()
 
 
-def plane_enabled() -> bool:
-    """The master switch, read per call so tool/check_device_plane.py can
-    flip passthrough mode mid-process."""
-    return os.environ.get("FISCO_DEVICE_PLANE", "1") != "0"
-
-
 def in_plane_executor() -> bool:
+    """True on the plane worker while it runs an executor: a seam entered
+    from there must run inline, never queue (device/dispatch.enqueue)."""
     return bool(getattr(_tls, "in_exec", False))
-
-
-def plane_route() -> bool:
-    """True when a batch call should enqueue into the shared plane: the
-    plane is enabled AND this is not already a plane executor (an executor
-    re-entering the queue would deadlock the single worker, so nested seam
-    calls take the direct path)."""
-    return plane_enabled() and not in_plane_executor()
 
 
 def current_lane() -> str:
@@ -169,8 +152,8 @@ class DevicePlane:
     serves every crypto seam; standalone instances exist only in tests.
 
     Executors are bound lazily at submit time (first one registered per op
-    wins) — the plane imports nothing from the crypto layer, so there is no
-    import cycle and no registration ordering to get wrong.
+    wins) — the plane imports nothing from the crypto layer above it, so
+    there is no import cycle and no registration ordering to get wrong.
     An executor receives the request list and returns one result per
     request, in order; it runs with the in-executor marker set.
     """
@@ -242,7 +225,7 @@ class DevicePlane:
         sequential batch call for nothing — bursts still coalesce while the
         worker is busy. A backend that fails to initialise raises here, at
         plane construction, instead of reading as an accelerator."""
-        from ..crypto.suite import device_backend_is_cpu
+        from ..utils.jaxenv import device_backend_is_cpu
 
         return 0.0 if device_backend_is_cpu() else 2.0
 
@@ -661,8 +644,8 @@ def plane_wait(fut: Future):
     """Block on a plane future, attributing the wait to the calling
     thread's ambient pipeline stage (``<stage> blocked_on=device_plane`` —
     the edge that says the admission/consensus/execute worker was parked
-    behind the shared crypto engine, not doing its own work). Every crypto
-    seam that queues into the plane resolves its future through here.
+    behind the shared crypto engine, not doing its own work). Every batch
+    queued into the plane resolves its future through here.
     The ``device.plane.wait`` span is the caller's side of it, start to end
     on the caller's thread: queue, coalescing window, dispatch and result."""
     from ..observability.pipeline import PIPELINE

@@ -324,20 +324,17 @@ class Node:
         self.amop = AMOPService(self.front)
         # shared device-verification plane: spin the worker (and its queue
         # gauges) up BEFORE consensus traffic so the first proposal never
-        # races the thread start; FISCO_DEVICE_PLANE=0 = passthrough mode,
-        # every crypto seam keeps its per-caller direct dispatch
-        from ..device.plane import get_plane, plane_enabled
+        # races the thread start
+        from ..device.plane import get_plane
+        from ..utils.jaxenv import device_identity
 
-        if plane_enabled():
-            from ..utils.jaxenv import device_identity
-
-            get_plane()
-            ident = device_identity()
-            HEALTH.ok(
-                "device-plane",
-                f"coalescing scheduler up on platform={ident['platform']} "
-                f"device_kind={ident['device_kind']} count={ident['count']}",
-            )
+        get_plane()
+        ident = device_identity()
+        HEALTH.ok(
+            "device-plane",
+            f"coalescing scheduler up on platform={ident['platform']} "
+            f"device_kind={ident['device_kind']} count={ident['count']}",
+        )
         # pipeline observatory (ISSUE 9): backpressure watermark probes at
         # every inter-stage boundary, sampled by one background thread into
         # bounded timelines (GET /pipeline + Chrome-trace counter events).
@@ -358,8 +355,7 @@ class Node:
             PIPELINE.add_probe(
                 "scheduler.commit_queue", self.scheduler.commit_depth
             )
-            if plane_enabled():
-                PIPELINE.add_probe("device_plane", get_plane().lane_depths)
+            PIPELINE.add_probe("device_plane", get_plane().lane_depths)
             if self.proof_plane is not None:
                 PIPELINE.add_probe(
                     "proof_plane.pending", self.proof_plane.pending_builds
@@ -484,13 +480,13 @@ class Node:
         program to compile, and the log says so instead of claiming warm
         kernels. Payloads are padded into the two-block keccak bucket that
         precompiled calls land in — the shape key is (bucket, blocks)."""
-        from ..crypto.suite import use_native_batch
+        from ..device.dispatch import use_native_batch
         from ..ops.hash_common import bucket_batch
         from ..protocol.transaction import Transaction
         from ..txpool.validator import batch_admit
 
         for b in batch_sizes:
-            if use_native_batch(b):
+            if use_native_batch(b, "admission"):
                 _log.info(
                     "warmup: a batch of %d rides the native host loop on "
                     "this backend — no device program to compile", b

@@ -200,7 +200,7 @@ class CompileLedger:
         # exit paths add to it) — the measured-overhead artifact input
         self._overhead_s = 0.0
         # op -> {"count", "last_error"}: device programs that raised and
-        # were answered by the host loop (crypto.suite._device_or_host)
+        # were answered by the host loop (the breaker in device/dispatch.py)
         self._failures: dict[str, dict] = {}
 
     # -- attribution frames (device_span drives these) -----------------------
@@ -677,13 +677,10 @@ def device_doc(tail: int = 64) -> dict:
     else:
         doc["memory"] = {}
     try:
-        from ..device.plane import get_plane, plane_enabled
+        from ..device.plane import get_plane
 
-        if plane_enabled():
-            plane = get_plane()
-            doc["plane"] = dict(plane.stats(), lanes=plane.lane_depths())
-        else:
-            doc["plane"] = {"enabled": False}
+        plane = get_plane()
+        doc["plane"] = dict(plane.stats(), lanes=plane.lane_depths())
     except Exception:
         doc["plane"] = {}
     return doc

@@ -38,8 +38,8 @@ in the easy part uses the standard tower-norm descent.
 
 Compile cost is real (~an ed25519-sized scan body plus the final-exp
 scans) and paid once per shape bucket into the persistent jit cache;
-CPU backends never compile it — the crypto seam routes them to the
-bit-identical host reference (use_native_batch), exactly like the other
+CPU backends never compile it — the dispatch seam (device/dispatch.py)
+routes them to the bit-identical host reference, exactly like the other
 curves.
 """
 

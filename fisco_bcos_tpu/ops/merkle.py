@@ -118,7 +118,7 @@ def _prefer_host_tree() -> bool:
     backend-aware routing admit_batch applies to EC. Device backends keep
     the fused device tree (leaves are usually already device-resident)."""
     from .. import native_bind
-    from ..crypto.suite import device_backend_is_cpu
+    from ..utils.jaxenv import device_backend_is_cpu
 
     return device_backend_is_cpu() and native_bind.load() is not None
 

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..crypto.admission import admission_core
 from ..ops import secp256k1
 
 DATA_AXIS = "data"
@@ -62,16 +61,18 @@ def sharded_verify(mesh: Mesh, axis_name: str = DATA_AXIS):
     return jax.jit(f)
 
 
-def sharded_admission(mesh: Mesh, axis_name: str = DATA_AXIS):
+def sharded_admission(mesh: Mesh, core, axis_name: str = DATA_AXIS):
     """Batch-sharded fused admission (hash → recover → address), the sharded
-    form of crypto.admission.admission_step.
+    form of crypto.admission.admission_step; `core` is that module's unjitted
+    ``admission_core``, handed down by the caller (this layer sits under
+    crypto/ and imports nothing of it).
 
     Returns a jitted fn (blocks, nblocks, r, s, v) ->
     (addr [B, 20] replicated, ok bool[B] replicated, n_valid int32[]).
     """
 
     def local(blocks, nblocks, r, s, v):
-        addr, ok, _qx, _qy, _z = admission_core(blocks, nblocks, r, s, v)
+        addr, ok, _qx, _qy, _z = core(blocks, nblocks, r, s, v)
         n_valid = jax.lax.psum(jnp.sum(ok.astype(jnp.int32)), axis_name)
         return (
             jax.lax.all_gather(addr, axis_name, tiled=True),
@@ -90,12 +91,12 @@ def sharded_admission(mesh: Mesh, axis_name: str = DATA_AXIS):
     return jax.jit(f)
 
 
-def sharded_admission_packed(mesh: Mesh, axis_name: str = DATA_AXIS, body=None):
+def sharded_admission_packed(mesh: Mesh, body, axis_name: str = DATA_AXIS):
     """Fan-out form of a packed one-transfer admission program — the
     DevicePlane's multi-device leg for merged batches above its per-device
     threshold. `body` is the unjitted packed program of one suite
-    (crypto.admission: ``_admission_packed``, the default, or
-    ``_sm_admission_packed``); every operand is batch-leading.
+    (crypto.admission: ``_admission_packed`` or ``_sm_admission_packed``);
+    every operand is batch-leading.
 
     Each device runs the fused admission body over its batch shard and
     packs locally; the [B, 117] uint8 result (addr ‖ ok ‖ pubkey ‖ tx_hash)
@@ -106,8 +107,6 @@ def sharded_admission_packed(mesh: Mesh, axis_name: str = DATA_AXIS, body=None):
     Returns a jitted fn (the body's operands) -> [B, 117] uint8 replicated;
     B divisible by the mesh size (the bucket ladder guarantees it for
     power-of-two meshes)."""
-    if body is None:
-        from ..crypto.admission import _admission_packed as body
 
     def admission_shard(*operands):
         return jax.lax.all_gather(body(*operands), axis_name, tiled=True)

@@ -244,12 +244,11 @@ class ScenarioRunner:
             "faults_injected": plan.injected if plan is not None else 0,
             "determinism_digest": digest.hexdigest(),
         }
-        from ..device.plane import get_plane, plane_enabled
+        from ..device.plane import get_plane
 
-        if plane_enabled():
-            plane = get_plane()
-            plane.drain(10.0)
-            doc["device_plane"] = plane.stats()
+        plane = get_plane()
+        plane.drain(10.0)
+        doc["device_plane"] = plane.stats()
         if self.error:
             doc["error"] = self.error
         return doc

@@ -76,6 +76,16 @@ def device_identity() -> dict:
     return dict(_IDENTITY)
 
 
+def device_backend_is_cpu() -> bool:
+    """True when the jax device plane is CPU XLA (no accelerator): batch
+    dispatchers then prefer the native host loops at every size
+    (device/dispatch.py), tree levels hash on the host (ops/merkle.py) and
+    the plane keeps no coalescing window. Rides :func:`device_identity`'s
+    memo; a backend that fails to initialise raises — "no chip" must never
+    read as "CPU"."""
+    return device_identity()["platform"] == "cpu"
+
+
 def pin_cpu(virtual_devices: int | None = None) -> None:
     """Hold this process to the CPU backend for correctness runs (the test
     tier, ``tool/check_*.py``): CPU platform, fast-compile XLA flags, the

@@ -37,7 +37,7 @@ def test_digest_words_to_limbs_roundtrip():
 
 
 # admit_batch dispatches native-vs-device by batch size and backend
-# (crypto.suite.use_native_batch); both legs must satisfy the same contract
+# (device.dispatch.use_native_batch); both legs must satisfy the same contract
 @pytest.fixture(params=["native", "device"])
 def admit_path(request, monkeypatch):
     if request.param == "device":

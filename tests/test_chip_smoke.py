@@ -333,7 +333,7 @@ def test_forced_device_program_failure_fails_air4_by_name(monkeypatch, tmp_path)
     raise. The host loop answers every batch (the product's resilience), the
     chain commits — and phase air4 still fails, naming the program."""
     from fisco_bcos_tpu.crypto import admission
-    from fisco_bcos_tpu.crypto import suite as suite_mod
+    from fisco_bcos_tpu.device import dispatch as dispatch_mod
     from fisco_bcos_tpu.observability.device import LEDGER
     from fisco_bcos_tpu.resilience import CircuitBreaker
     from fisco_bcos_tpu.resilience.breaker import HealthRegistry
@@ -345,7 +345,7 @@ def test_forced_device_program_failure_fails_air4_by_name(monkeypatch, tmp_path)
     monkeypatch.setenv("FISCO_FORCE_DEVICE_ADMISSION", "1")
     # a private breaker + registry: the process-wide ones must not trip
     monkeypatch.setattr(
-        suite_mod, "_DEVICE_BREAKER",
+        dispatch_mod, "_DEVICE_BREAKER",
         CircuitBreaker("device-crypto", failure_threshold=2, reset_timeout=60.0,
                        critical=False, registry=HealthRegistry()),
     )

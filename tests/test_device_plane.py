@@ -1,159 +1,166 @@
-"""DevicePlane: coalescer mechanics, priority lanes, shape-bucket
-bit-identity, passthrough mode, and the host-vs-device cutover env.
+"""DevicePlane and the dispatch seam: coalescer mechanics, priority lanes,
+shape-bucket bit-identity, the seam's entry / policy / legs, and the
+host-vs-device cutover env.
 
 The bit-identity property (ISSUE 3 acceptance): routing a batch through the
 plane — merged with strangers, bucket-padded, sliced back — must produce
-byte-for-byte the same outputs as the pre-plane direct dispatch, across
-ragged batch sizes including all-invalid and empty batches. A divergence
-would fork a plane-routed node from a passthrough node.
+byte-for-byte what the plain reference (crypto/ref) says, across ragged
+batch sizes including all-invalid and empty batches. A divergence would
+fork a node from its peers.
 """
 
 from __future__ import annotations
 
-import os
+import functools
+import importlib
 import threading
-from contextlib import contextmanager
 
 import numpy as np
 import pytest
 
+from fisco_bcos_tpu import native_bind
 from fisco_bcos_tpu.crypto import admission
 from fisco_bcos_tpu.crypto.ref import ecdsa as ref
 from fisco_bcos_tpu.crypto.ref.keccak import keccak256
-from fisco_bcos_tpu.crypto.suite import ecdsa_suite, sm_suite
-from fisco_bcos_tpu.device.plane import (
-    DevicePlane,
-    device_lane,
-    get_plane,
-    plane_enabled,
-    plane_route,
-)
+from fisco_bcos_tpu.crypto.suite import Ed25519Crypto, ecdsa_suite, sm_suite
+from fisco_bcos_tpu.device import dispatch as dispatch_mod
+from fisco_bcos_tpu.device.plane import DevicePlane, device_lane, get_plane
+
+N_KEYS = 16  # distinct signers of a corpus: the reference derives each key once
 
 
-@contextmanager
-def _env(name: str, value: str | None):
-    old = os.environ.get(name)
-    if value is None:
-        os.environ.pop(name, None)
-    else:
-        os.environ[name] = value
-    try:
-        yield
-    finally:
-        if old is None:
-            os.environ.pop(name, None)
-        else:
-            os.environ[name] = old
+@functools.cache
+def _pub(d: int) -> bytes:
+    x, y = ref.privkey_to_pubkey(ref.SECP256K1, d)
+    return x.to_bytes(32, "big") + y.to_bytes(32, "big")
+
+
+def _secret(i: int, base: int) -> int:
+    return base + 31337 * (i % N_KEYS)
 
 
 def _signed(payloads, base=0xA11CE):
     sigs = []
     for i, p in enumerate(payloads):
-        d = base + 31337 * i
-        r, s, v = ref.ecdsa_sign(keccak256(p), d)
+        r, s, v = ref.ecdsa_sign(keccak256(p), _secret(i, base))
         sigs.append(r.to_bytes(32, "big") + s.to_bytes(32, "big") + bytes([v]))
     return np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(-1, 65).copy()
 
 
-def _admit_both_modes(payloads, sigs):
-    """(direct, planed) admit_batch outputs for the same inputs."""
-    with _env("FISCO_DEVICE_PLANE", "0"):
-        direct = admission.admit_batch(payloads, sigs)
-    with _env("FISCO_DEVICE_PLANE", None):
-        planed = admission.admit_batch(payloads, sigs)
-    return direct, planed
+def _rsv(sig):
+    sig = bytes(sig)
+    return int.from_bytes(sig[:32], "big"), int.from_bytes(sig[32:64], "big"), sig[64]
+
+
+def _assert_admitted(got, payloads, sigs, base=0xA11CE, broken=()):
+    """admit_batch's answer held to crypto/ref lane by lane: an intact lane
+    recovers its signer's key (derived by the reference from the secret) and
+    that key's address; a lane the test broke is one the reference's recover
+    refuses; every lane carries the reference's digest."""
+    senders, ok, pubs, digests = (np.asarray(a) for a in got)
+    assert len(ok) == len(payloads)
+    for i, p in enumerate(payloads):
+        assert bytes(digests[i]) == keccak256(p), i
+        if i in broken:
+            assert ref.ecdsa_recover(keccak256(p), *_rsv(sigs[i])) is None
+            assert not ok[i], i
+        else:
+            want = _pub(_secret(i, base))
+            assert ok[i] and bytes(pubs[i]) == want, i
+            assert bytes(senders[i]) == keccak256(want)[12:], i
 
 
 # -- bit-identity across ragged batch sizes ----------------------------------
 
 
 @pytest.mark.parametrize("n", [1, 7, 63, 100, 1000])
-def test_plane_matches_direct_admission_ragged(n):
+def test_plane_admission_matches_reference_ragged(n):
     payloads = [b"rag-%d " % i + b"x" * (i * 13 % 97) for i in range(n)]
     sigs = _signed(payloads)
-    if n >= 3:
-        sigs[2, :64] = 0  # one structurally-invalid lane
-    direct, planed = _admit_both_modes(payloads, sigs)
-    for a, b in zip(direct, planed):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert planed[1].sum() == (n - 1 if n >= 3 else n)
+    broken = (2,) if n >= 3 else ()
+    for lane in broken:
+        sigs[lane, :64] = 0  # one structurally-invalid lane
+    before = get_plane().stats()["requests"]
+    got = admission.admit_batch(payloads, sigs)
+    assert get_plane().stats()["requests"] == before + 1
+    _assert_admitted(got, payloads, sigs, broken=broken)
 
 
-def test_plane_matches_direct_all_invalid_and_empty():
+def test_plane_admission_all_invalid_and_empty():
     payloads = [b"inv-%d" % i for i in range(5)]
     sigs = np.zeros((5, 65), dtype=np.uint8)  # every lane garbage
-    direct, planed = _admit_both_modes(payloads, sigs)
-    for a, b in zip(direct, planed):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert not planed[1].any()
+    got = admission.admit_batch(payloads, sigs)
+    _assert_admitted(got, payloads, sigs, broken=range(5))
 
-    empty_sigs = np.zeros((0, 65), dtype=np.uint8)
-    direct, planed = _admit_both_modes([], empty_sigs)
-    for a, b in zip(direct, planed):
-        a, b = np.asarray(a), np.asarray(b)
-        assert a.shape == b.shape and a.dtype == b.dtype
+    # nothing to queue: the body runs inline and answers the empty shapes
+    before = get_plane().stats()["requests"]
+    got = admission.admit_batch([], np.zeros((0, 65), dtype=np.uint8))
+    assert get_plane().stats()["requests"] == before
+    assert [np.asarray(a).shape for a in got] == [(0, 20), (0,), (0, 64), (0, 32)]
+    assert np.asarray(got[1]).dtype == bool
+    assert {np.asarray(got[i]).dtype for i in (0, 2, 3)} == {np.dtype(np.uint8)}
 
 
-def test_plane_matches_direct_device_leg(monkeypatch):
-    """Force the device program on both legs (the bucketed/padded path the
-    plane exists for) — outputs must still match the direct dispatch."""
+def test_plane_admission_device_leg_matches_reference(monkeypatch):
+    """Force the device program (the bucketed/padded path the plane exists
+    for) — its lanes are still the reference's."""
     monkeypatch.setenv("FISCO_FORCE_DEVICE_ADMISSION", "1")
     for n in (3, 9):
         payloads = [b"dev-%d " % i + b"y" * (i * 7 % 50) for i in range(n)]
         sigs = _signed(payloads, base=0xBEEF)
-        if n > 4:
-            sigs[4, 32:64] = 0
-        direct, planed = _admit_both_modes(payloads, sigs)
-        for a, b in zip(direct, planed):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        broken = (4,) if n > 4 else ()
+        for lane in broken:
+            sigs[lane, 32:64] = 0
+        got = admission.admit_batch(payloads, sigs)
+        _assert_admitted(got, payloads, sigs, base=0xBEEF, broken=broken)
 
 
-def test_plane_matches_direct_batch_verify_and_recover():
-    suite = ecdsa_suite()
+def _signature_corpus(suite, secret, n, tag):
     impl = suite.signature_impl
-    kp = impl.generate_keypair(secret=0x5EED)
-    msgs = [b"verify-%d" % i for i in range(7)]
-    hashes = np.frombuffer(
-        b"".join(keccak256(m) for m in msgs), np.uint8
-    ).reshape(-1, 32)
-    sigs = np.frombuffer(
-        b"".join(impl.sign(kp, keccak256(m)) for m in msgs), np.uint8
-    ).reshape(-1, 65).copy()
-    pubs = np.frombuffer(kp.pub * len(msgs), np.uint8).reshape(-1, 64)
-    sigs[3, :32] = 0  # invalid lane lowers a bit, never raises
-
-    with _env("FISCO_DEVICE_PLANE", "0"):
-        ok_direct = impl.batch_verify(hashes, pubs, sigs)
-        rec_direct = impl.batch_recover(hashes, sigs)
-    ok_planed = impl.batch_verify(hashes, pubs, sigs)
-    rec_planed = impl.batch_recover(hashes, sigs)
-    np.testing.assert_array_equal(ok_direct, ok_planed)
-    np.testing.assert_array_equal(rec_direct[0], rec_planed[0])
-    np.testing.assert_array_equal(rec_direct[1], rec_planed[1])
-    assert ok_planed.sum() == len(msgs) - 1
-
-
-def test_plane_matches_direct_sm_suite():
-    suite = sm_suite()
-    impl = suite.signature_impl
-    kp = impl.generate_keypair(secret=0x51712)
-    msgs = [b"sm-%d" % i for i in range(4)]
+    kp = impl.generate_keypair(secret=secret)
+    msgs = [b"%s-%d" % (tag, i) for i in range(n)]
     hashes = np.frombuffer(
         b"".join(suite.hash(m) for m in msgs), np.uint8
     ).reshape(-1, 32)
     sigs = np.frombuffer(
         b"".join(impl.sign(kp, suite.hash(m)) for m in msgs), np.uint8
-    ).reshape(-1, 128).copy()
+    ).reshape(n, impl.sig_len).copy()
+    pubs = np.frombuffer(kp.pub * n, np.uint8).reshape(-1, 64)
+    return kp, hashes, pubs, sigs
+
+
+def test_plane_batch_verify_and_recover_match_reference():
+    impl = ecdsa_suite().signature_impl
+    kp, hashes, pubs, sigs = _signature_corpus(ecdsa_suite(), 0x5EED, 7, b"verify")
+    sigs[3, :32] = 0  # invalid lane lowers a bit, never raises
+    point = (kp.pub_x, kp.pub_y)
+    want = [
+        ref.ecdsa_verify(bytes(h), *_rsv(s)[:2], point) for h, s in zip(hashes, sigs)
+    ]
+    ok = impl.batch_verify(hashes, pubs, sigs)
+    rec_pubs, rec_ok = impl.batch_recover(hashes, sigs)
+    assert list(ok) == want == [i != 3 for i in range(7)]
+    for i, (h, s) in enumerate(zip(hashes, sigs)):
+        got = ref.ecdsa_recover(bytes(h), *_rsv(s))
+        assert bool(rec_ok[i]) == (got is not None), i
+        if got is not None:
+            assert bytes(rec_pubs[i]) == got[0].to_bytes(32, "big") + got[1].to_bytes(32, "big")
+    assert bytes(rec_pubs[0]) == kp.pub and not rec_pubs[3].any()
+
+
+def test_plane_sm_suite_matches_reference():
+    impl = sm_suite().signature_impl
+    kp, hashes, pubs, sigs = _signature_corpus(sm_suite(), 0x51712, 4, b"sm")
     sigs[1, :32] = 0
-    pubs = np.frombuffer(kp.pub * len(msgs), np.uint8).reshape(-1, 64)
-    with _env("FISCO_DEVICE_PLANE", "0"):
-        ok_direct = impl.batch_verify(hashes, pubs, sigs)
-        rec_direct = impl.batch_recover(hashes, sigs)
-    ok_planed = impl.batch_verify(hashes, pubs, sigs)
-    rec_planed = impl.batch_recover(hashes, sigs)
-    np.testing.assert_array_equal(ok_direct, ok_planed)
-    np.testing.assert_array_equal(rec_direct[0], rec_planed[0])
-    np.testing.assert_array_equal(rec_direct[1], rec_planed[1])
+    point = (kp.pub_x, kp.pub_y)
+    want = [
+        ref.sm2_verify(bytes(h), *_rsv(s)[:2], point) for h, s in zip(hashes, sigs)
+    ]
+    ok = impl.batch_verify(hashes, pubs, sigs)
+    rec_pubs, rec_ok = impl.batch_recover(hashes, sigs)
+    assert list(ok) == list(rec_ok) == want == [True, False, True, True]
+    for i in range(4):
+        assert bytes(rec_pubs[i]) == (kp.pub if want[i] else b"\x00" * 64)
 
 
 def test_plane_hash_matches_reference():
@@ -296,33 +303,217 @@ def test_concurrent_submitters_coalesce_and_stay_correct():
     assert sum(len(c) for c in calls) == 4  # every request dispatched once
 
 
-# -- passthrough + policy env -------------------------------------------------
+# -- the dispatch seam: entry, policy, legs -------------------------------------
 
 
-def test_plane_disabled_is_passthrough():
-    suite = ecdsa_suite()
-    with _env("FISCO_DEVICE_PLANE", "0"):
-        assert not plane_enabled() and not plane_route()
+def test_seam_call_on_the_plane_worker_runs_inline():
+    """The re-entry rule: an executor that calls back into a seam (ed25519
+    batch_recover → batch_verify) must not queue behind itself. The nested
+    call runs its body on the worker and the shared plane sees no request."""
+    impl = Ed25519Crypto()
+    kps = [impl.generate_keypair(secret=0xED00 + i) for i in range(3)]
+    hashes = [bytes([i + 1]) * 32 for i in range(3)]
+    sigs = [impl.sign(kp, h) for kp, h in zip(kps, hashes)]
+    sigs[1] = bytes([sigs[1][0] ^ 1]) + sigs[1][1:]
+    seen = []
+
+    def nested(reqs):
+        seen.append(threading.current_thread().name)
         before = get_plane().stats()["requests"]
-        suite.hash_batch([b"direct-1", b"direct-2"])
-        payloads = [b"direct-adm"]
-        admission.admit_batch(payloads, _signed(payloads))
+        out = [impl.batch_recover(*r.payload) for r in reqs]
         assert get_plane().stats()["requests"] == before  # nothing enqueued
+        return out
+
+    plane = DevicePlane(window_ms=0)
+    pubs, ok = plane.submit("nested", (hashes, sigs), 3, nested).result(timeout=60)
+    assert seen == ["device-plane"]
+    assert list(ok) == [True, False, True]
+    assert bytes(pubs[0]) == kps[0].pub and not pubs[1].any()
+    # the same call from a caller's thread does queue
+    before = get_plane().stats()["requests"]
+    assert list(impl.batch_recover(hashes, sigs)[1]) == [True, False, True]
+    assert get_plane().stats()["requests"] == before + 1
+
+
+def test_policy_is_asked_once_with_the_merged_size(monkeypatch):
+    """Two 200-item requests coalesced on a pretend accelerator ride the
+    device leg, though either alone is under the 256 cutover: the policy is
+    applied to the merged batch, once per dispatch."""
+    monkeypatch.setattr(dispatch_mod, "device_backend_is_cpu", lambda: False)
+    monkeypatch.delenv("FISCO_DEVICE_MIN_BATCH", raising=False)
+    asked = []
+    policy = dispatch_mod.use_native_batch
+
+    def recording(n, label=""):
+        asked.append((n, label))
+        return policy(n, label)
+
+    monkeypatch.setattr(dispatch_mod, "use_native_batch", recording)
+    op = dispatch_mod.BatchOp(
+        "pretend", "pretend",
+        device=lambda x, tags: (x * 3, x + 1),
+        native=lambda x, tags: (x * 2, x),
+    )
+    a, b = np.arange(200), np.arange(200, 400)
+    assert dispatch_mod.run_legs(op, 200, a, ["a"] * 200)[0][1] == 2  # alone: native
+    asked.clear()
+
+    plane = DevicePlane(window_ms=60_000, high_water=400, starvation_ms=60_000)
+    run = dispatch_mod._merge_exec(op)
+    f1 = plane.submit("pretend", (a, ["a"] * 200), 200, run)
+    f2 = plane.submit("pretend", (b, ["b"] * 200), 200, run)  # crosses high water
+    (a3, a1), (b3, b1) = f1.result(timeout=10), f2.result(timeout=10)
+    assert asked == [(400, "pretend")]
+    np.testing.assert_array_equal(a3, a * 3)
+    np.testing.assert_array_equal(b3, b * 3)
+    np.testing.assert_array_equal(b1, b + 1)
+    assert len(a1) == 200
+
+
+def _failing(calls):
+    """A device program that records the attempt and raises."""
+
+    def device(*_a, **_k):
+        calls.append("device")
+        raise RuntimeError("forced device failure")
+
+    return device
+
+
+def _case_signature(suite_fn, method, device_fn_name, native_fn_name):
+    """A signature batch op: its device program (an ops host wrapper) fails,
+    its native batch loop answers None."""
+
+    def build(monkeypatch, calls):
+        suite = suite_fn()
+        impl = suite.signature_impl
+        ops_mod = importlib.import_module(f"fisco_bcos_tpu.ops.{impl.name}")
+        monkeypatch.setattr(ops_mod, device_fn_name, _failing(calls))
+        monkeypatch.setattr(native_bind, native_fn_name, lambda *a, **k: None)
+        kp, hashes, pubs, sigs = _signature_corpus(suite, 0xFA11, 3, b"leg")
+        sigs[1, :32] = 0
+        if method == "batch_verify":
+            return lambda: np.asarray(impl.batch_verify(hashes, pubs, sigs))
+        return lambda: np.asarray(impl.batch_recover(hashes, sigs)[1])
+
+    return build
+
+
+def _case_ed25519(monkeypatch, calls):
+    from fisco_bcos_tpu.ops import ed25519 as ed_ops
+
+    impl = Ed25519Crypto()
+    kps = [impl.generate_keypair(secret=0xED10 + i) for i in range(3)]
+    hashes = [bytes([i + 7]) * 32 for i in range(3)]
+    sigs = [impl.sign(kp, h) for kp, h in zip(kps, hashes)]
+    sigs[1] = bytes([sigs[1][0] ^ 1]) + sigs[1][1:]
+    pubs = [kp.pub for kp in kps]
+    monkeypatch.setattr(ed_ops, "verify_batch", _failing(calls))
+    monkeypatch.setattr(native_bind, "load", lambda: None)  # the native leg: None
+    return lambda: np.asarray(impl.batch_verify(hashes, pubs, sigs))
+
+
+def _case_admission(suite_fn, step_name):
+    """Fused admission: the jitted step fails. The SM body's native loop
+    answers None (no library); the secp body's host fallback IS the native
+    loop, so there the policy override picks the device leg instead."""
+
+    def build(monkeypatch, calls):
+        suite = suite_fn()
+        impl = suite.signature_impl
+        monkeypatch.setattr(admission, step_name, _failing(calls))
+        if impl.name == "sm2":
+            monkeypatch.setattr(native_bind, "load", lambda: None)
+        else:
+            monkeypatch.setenv("FISCO_FORCE_DEVICE_ADMISSION", "1")
+        kp = impl.generate_keypair(secret=0xAD17)
+        payloads = [b"leg-%d" % i for i in range(3)]
+        sigs = np.frombuffer(
+            b"".join(impl.sign(kp, suite.hash(p)) for p in payloads), np.uint8
+        ).reshape(3, impl.sig_len).copy()
+        sigs[1, :32] = 0
+        admit = suite.fused_admission()
+        return lambda: np.asarray(admit(payloads, sigs)[1])
+
+    return build
+
+
+@pytest.mark.parametrize(
+    "label,build",
+    [
+        ("secp256k1_verify", _case_signature(ecdsa_suite, "batch_verify", "verify_batch", "secp256k1_verify_batch")),
+        ("secp256k1_recover", _case_signature(ecdsa_suite, "batch_recover", "recover_batch", "secp256k1_recover_batch")),
+        ("sm2_verify", _case_signature(sm_suite, "batch_verify", "verify_batch", "sm2_verify_batch")),
+        ("sm2_recover", _case_signature(sm_suite, "batch_recover", "recover_batch", "sm2_verify_batch")),
+        ("ed25519_verify", _case_ed25519),
+        ("admission", _case_admission(ecdsa_suite, "admission_step_packed")),
+        ("admission", _case_admission(sm_suite, "sm_admission_step_packed")),
+    ],
+    ids=["secp256k1_verify", "secp256k1_recover", "sm2_verify", "sm2_recover",
+         "ed25519_verify", "admission", "admission_sm"],
+)
+def test_failing_device_leg_is_answered_by_the_host_loop(label, build, monkeypatch):
+    """Every op with a host loop: a native leg that answers None falls
+    through to the device; a device program that raises is answered, lane
+    for lane, by the host loop and counted; after the breaker's two failures
+    the device is no longer tried and the leg reads ``host_fallback``."""
+    from fisco_bcos_tpu.observability.device import LEDGER
+    from fisco_bcos_tpu.resilience import CircuitBreaker
+    from fisco_bcos_tpu.resilience.breaker import HealthRegistry
+    from fisco_bcos_tpu.utils.metrics import REGISTRY
+
+    # a private breaker + registry + failure book: the process-wide ones
+    # must not trip
+    breaker = CircuitBreaker(
+        "device-crypto", failure_threshold=2, reset_timeout=60.0,
+        critical=False, registry=HealthRegistry(),
+    )
+    monkeypatch.setattr(dispatch_mod, "_DEVICE_BREAKER", breaker)
+    monkeypatch.setattr(LEDGER, "_failures", {})
+    calls: list[str] = []
+    call = build(monkeypatch, calls)
+
+    def legs():
+        series = REGISTRY.counters_matching(
+            f'fisco_device_dispatch_path_total{{op="{label}",'
+        )
+        return {
+            path: series.get(
+                f'fisco_device_dispatch_path_total{{op="{label}",path="{path}"}}', 0.0
+            )
+            for path in ("native", "device", "host_fallback")
+        }
+
+    before = legs()
+    for _ in range(3):
+        assert list(call()) == [True, False, True]
+    after = legs()
+    assert calls == ["device", "device"]  # the third call never tried it
+    assert breaker.state == "open"
+    assert {k: after[k] - before[k] for k in after} == {
+        "native": 0, "device": 2, "host_fallback": 1,
+    }
+    assert LEDGER.failures()[label]["count"] == 2
+    assert "forced device failure" in LEDGER.failures()[label]["last_error"]
 
 
 def test_device_min_batch_env(monkeypatch):
-    from fisco_bcos_tpu.crypto import suite as suite_mod
-
     # pretend the backend is an accelerator so the threshold is decisive
-    monkeypatch.setattr(suite_mod, "device_backend_is_cpu", lambda: False)
+    monkeypatch.setattr(dispatch_mod, "device_backend_is_cpu", lambda: False)
     monkeypatch.delenv("FISCO_DEVICE_MIN_BATCH", raising=False)
-    assert suite_mod.device_min_batch() == suite_mod._SMALL_BATCH
-    assert suite_mod.use_native_batch(10)
+    monkeypatch.delenv("FISCO_FORCE_DEVICE_ADMISSION", raising=False)
+    assert dispatch_mod.device_min_batch() == dispatch_mod._SMALL_BATCH == 256
+    assert dispatch_mod.use_native_batch(10)
     monkeypatch.setenv("FISCO_DEVICE_MIN_BATCH", "4")
-    assert not suite_mod.use_native_batch(10)
-    assert suite_mod.use_native_batch(3)
+    assert not dispatch_mod.use_native_batch(10)
+    assert dispatch_mod.use_native_batch(3)
+    assert not dispatch_mod.use_native_batch(0)
     monkeypatch.setenv("FISCO_DEVICE_MIN_BATCH", "not-a-number")
-    assert suite_mod.device_min_batch() == suite_mod._SMALL_BATCH
+    assert dispatch_mod.device_min_batch() == dispatch_mod._SMALL_BATCH
+    # the admission override is the admission ops' alone
+    monkeypatch.setenv("FISCO_FORCE_DEVICE_ADMISSION", "1")
+    assert not dispatch_mod.use_native_batch(10, "admission")
+    assert dispatch_mod.use_native_batch(10, "secp256k1_verify")
 
 
 def test_bucket_ladder_bounds_shapes():

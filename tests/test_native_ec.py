@@ -192,9 +192,9 @@ def test_ed25519_suite_single_item_uses_native():
 
 def test_ed25519_batch_routes_native_and_agrees():
     """QC-sized ed25519 batches must ride the native host loop on CPU
-    backends (use_native_batch — review r5: the XLA program re-introduced
-    per-block latency the routing was built to remove) and agree with the
-    device-path semantics."""
+    backends (device.dispatch.use_native_batch — review r5: the XLA program
+    re-introduced per-block latency the routing was built to remove) and
+    agree with the device-path semantics."""
     import numpy as np
 
     from fisco_bcos_tpu import native_bind

@@ -179,17 +179,17 @@ def test_breaker_probe_released_when_both_paths_fail():
     """Regression: an exception escaping the half-open probe (device AND
     host path both raise — a data error) must free the probe slot, not
     wedge the breaker in half-open forever."""
-    from fisco_bcos_tpu.crypto.suite import _device_or_host
+    from fisco_bcos_tpu.device.dispatch import _device_or_host
 
     reg = HealthRegistry()
     br = CircuitBreaker("dev2", failure_threshold=1, reset_timeout=0.05, registry=reg)
     br.record_failure("seed")  # open
     time.sleep(0.1)  # cooldown -> half-open
 
-    import fisco_bcos_tpu.crypto.suite as suite_mod
+    import fisco_bcos_tpu.device.dispatch as dispatch_mod
 
-    old = suite_mod._DEVICE_BREAKER
-    suite_mod._DEVICE_BREAKER = br
+    old = dispatch_mod._DEVICE_BREAKER
+    dispatch_mod._DEVICE_BREAKER = br
     try:
         def boom(*a):
             raise RuntimeError("path down")
@@ -205,7 +205,7 @@ def test_breaker_probe_released_when_both_paths_fail():
                     classify=(ValueError,))
         assert br.allow()
     finally:
-        suite_mod._DEVICE_BREAKER = old
+        dispatch_mod._DEVICE_BREAKER = old
 
 
 def test_health_snapshot_shape():

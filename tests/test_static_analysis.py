@@ -107,6 +107,26 @@ def test_fixture_device_dispatch(fixture_findings):
     )
 
 
+def test_fixture_layering_under_the_seams(fixture_findings):
+    """device/, ops/ and parallel/ import nothing of crypto/ but the plain
+    reference; the rule reads every spelling of the import."""
+    got = _keys(fixture_findings, "device-dispatch")
+    base = "device-dispatch:tests/fixtures/analysis/device/bad_layering.py::"
+    assert {k for k in got if k.startswith(base)} == {base + "imports-up-crypto.suite"}
+    for text in (
+        "from ..crypto.suite import device_backend_is_cpu\n",
+        "from ..crypto import admission\n",
+        "from .. import crypto\n",
+        "import fisco_bcos_tpu.crypto.bls\n",
+        "def f():\n    from fisco_bcos_tpu.crypto.admission import admission_core\n",
+    ):
+        for layer in ("device/plane.py", "ops/merkle.py", "parallel/sharding.py"):
+            assert DeviceDispatchChecker().run([_src(text, "fisco_bcos_tpu/" + layer)]), text
+        assert not DeviceDispatchChecker().run([_src(text, "fisco_bcos_tpu/crypto/x.py")])
+    leaf = "from ..crypto.ref.ecdsa import SECP256K1\nfrom ..crypto.ref import sm3\n"
+    assert not DeviceDispatchChecker().run([_src(leaf, "fisco_bcos_tpu/ops/ec.py")])
+
+
 def test_fixture_shape_bucket(fixture_findings):
     assert (
         "shape-bucket:tests/fixtures/analysis/bad_shape.py:feed:unbucketed-kernel"

@@ -1,15 +1,13 @@
 #!/usr/bin/env python
 """Device-plane smoke check (ISSUE 3 acceptance):
 
-1. With the plane enabled, flood one node with CONCURRENT ragged admission
-   batches, proposal verification (full-tx re-verification) and tx-sync
-   imports, then assert:
-   - the device compile counter stays ≤ the bucket-ladder size per op
-     (ragged shapes must converge onto the ladder, not compile per size);
-   - queue wait p99 is bounded (default 750 ms, --wait-p99-ms);
-   - every submitted tx was admitted exactly once (slices never crossed).
-2. With the plane force-disabled (``FISCO_DEVICE_PLANE=0`` passthrough), a
-   4-node PBFT chain still commits blocks — the escape hatch works.
+Flood one node with CONCURRENT ragged admission batches, proposal
+verification (full-tx re-verification) and tx-sync imports, then assert:
+
+- the device compile counter stays ≤ the bucket-ladder size per op
+  (ragged shapes must converge onto the ladder, not compile per size);
+- queue wait p99 is bounded (default 750 ms, --wait-p99-ms);
+- every submitted tx was admitted exactly once (slices never crossed).
 
 Runnable locally and from CI::
 
@@ -78,13 +76,11 @@ def _flood_txs(suite, tag: str, n: int):
 def check_plane_flood(n_txs: int, wait_p99_ms: float) -> None:
     """Concurrent ragged admission + proposal verification + sync imports
     through one shared plane."""
-    from fisco_bcos_tpu.device.plane import device_lane, get_plane, plane_enabled
+    from fisco_bcos_tpu.device.plane import device_lane, get_plane
     from fisco_bcos_tpu.observability.device import compile_counts
     from fisco_bcos_tpu.ops.hash_common import bucket_ladder
     from fisco_bcos_tpu.txpool.validator import batch_admit
 
-    if not plane_enabled():
-        fail("plane disabled at phase 1 — unset FISCO_DEVICE_PLANE")
     node = _make_node()
     suite = node.suite
 
@@ -169,68 +165,6 @@ def check_plane_flood(n_txs: int, wait_p99_ms: float) -> None:
     print("OK: plane flood (compile bound, wait p99, slice integrity)")
 
 
-def check_passthrough_chain() -> None:
-    """FISCO_DEVICE_PLANE=0: the 4-node chain must still seal + commit."""
-    os.environ["FISCO_DEVICE_PLANE"] = "0"
-    try:
-        from fisco_bcos_tpu.crypto.suite import ecdsa_suite
-        from fisco_bcos_tpu.device.plane import get_plane, plane_route
-        from fisco_bcos_tpu.front import InprocGateway
-        from fisco_bcos_tpu.ledger import ConsensusNode, GenesisConfig
-        from fisco_bcos_tpu.node import Node, NodeConfig
-
-        if plane_route():
-            fail("FISCO_DEVICE_PLANE=0 did not disable routing")
-        before = get_plane().stats()["requests"]
-        suite = ecdsa_suite()
-        keypairs = [
-            suite.signature_impl.generate_keypair(secret=0x0FF + i)
-            for i in range(4)
-        ]
-        cons = [ConsensusNode(kp.pub, weight=1) for kp in keypairs]
-        gw = InprocGateway(auto=True)
-        nodes = []
-        for kp in keypairs:
-            cfg = NodeConfig(
-                genesis=GenesisConfig(
-                    consensus_nodes=list(cons), tx_count_limit=500
-                )
-            )
-            node = Node(cfg, keypair=kp)
-            gw.connect(node.front)
-            nodes.append(node)
-        entry = nodes[0]
-        txs = _flood_txs(suite, "pass", 40)
-        results = entry.txpool.submit_batch(txs)
-        if any(r.status != 0 for r in results):
-            fail("passthrough admission rejected txs")
-        entry.tx_sync.maintain()
-        stalls = 0
-        while entry.txpool.pending_count() > 0 and stalls < 5:
-            idx = nodes[0].pbft_config.leader_index(
-                nodes[0].block_number() + 1, 0
-            )
-            target = nodes[0].pbft_config.nodes[idx].node_id
-            leader = next(nd for nd in nodes if nd.node_id == target)
-            if not leader.sealer.seal_and_submit():
-                stalls += 1
-        heights = {nd.block_number() for nd in nodes}
-        if heights != {nodes[0].block_number()} or nodes[0].block_number() < 1:
-            fail(f"passthrough chain did not commit: heights {sorted(heights)}")
-        if entry.txpool.pending_count():
-            fail(
-                f"passthrough left {entry.txpool.pending_count()} txs pending"
-            )
-        if get_plane().stats()["requests"] != before:
-            fail("passthrough mode still enqueued into the plane")
-        print(
-            f"OK: passthrough chain committed to height "
-            f"{nodes[0].block_number()} with the plane disabled"
-        )
-    finally:
-        os.environ.pop("FISCO_DEVICE_PLANE", None)
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--txs", type=int, default=100, help="max batch size")
@@ -242,7 +176,6 @@ def main() -> None:
     )
     args = ap.parse_args()
     check_plane_flood(args.txs, args.wait_p99_ms)
-    check_passthrough_chain()
     print("PASS: device plane smoke")
 
 

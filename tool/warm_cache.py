@@ -191,13 +191,13 @@ def run_warm(
     writes) the manifest. Importable — tests and boot scripts call this
     directly."""
     from fisco_bcos_tpu.analysis import jitmap
-    from fisco_bcos_tpu.crypto.suite import device_backend_is_cpu
     from fisco_bcos_tpu.observability.device import (
         LEDGER,
         install_jax_hooks,
     )
     from fisco_bcos_tpu.utils.jaxenv import (
         configure_compile_cache,
+        device_backend_is_cpu,
         device_identity,
     )
 
