@@ -39,12 +39,8 @@ import numpy as np
 from ..device.dispatch import BatchOp, dispatch
 from ..ops import keccak, secp256k1, sm2, sm3
 from ..ops.address import sender_address_device, sm_sender_address_device
-from ..ops.bigint import (
-    bytes_be_to_limbs,
-    digest_words_be_to_limbs,
-    digest_words_le_to_limbs,
-)
-from ..ops.hash_common import pad_keccak, pad_md64, pad_rows
+from ..ops.bigint import digest_words_be_to_limbs, digest_words_le_to_limbs
+from ..ops.hash_common import pad_keccak, pad_md64
 
 
 def admission_core(blocks, nblocks, r, s, v):
@@ -190,23 +186,27 @@ def _admit_batch_host_sm(payloads, sigs128, native: bool):
 # -- the two suites' bodies ----------------------------------------------------
 
 
+def _limb_operands(be: np.ndarray, bb: int) -> tuple[np.ndarray, ...]:
+    """[B, 32·k] uint8 rows of k big-endian 256-bit values -> k operands
+    [bb, 16] uint32 (16-bit limbs, least significant first, what
+    ``bytes_be_to_limbs`` gives each value), zero rows behind the batch. One
+    big-endian 16-bit view of the rows, written limb-reversed straight into
+    the zeroed bucket-sized block the k operands are slices of."""
+    n, k = be.shape[0], be.shape[1] // 32
+    out = np.zeros((k, bb, 16), dtype=np.uint32)
+    be16 = np.ascontiguousarray(be).view(">u2").reshape(n, k, 16)
+    out[:, :n] = be16[:, :, ::-1].transpose(1, 0, 2)
+    return tuple(out)
+
+
 def _marshal_secp(payloads, sigs65, bb):
-    blocks, nblocks = pad_keccak(payloads)
-    return (
-        blocks,
-        nblocks,
-        pad_rows(bytes_be_to_limbs(sigs65[:, :32]), bb),
-        pad_rows(bytes_be_to_limbs(sigs65[:, 32:64]), bb),
-        pad_rows(sigs65[:, 64].astype(np.int32), bb),
-    )
+    v = np.zeros(bb, dtype=np.int32)
+    v[: len(sigs65)] = sigs65[:, 64]
+    return pad_keccak(payloads) + _limb_operands(sigs65[:, :64], bb) + (v,)
 
 
 def _marshal_sm(payloads, sigs128, bb):
-    blocks, nblocks = pad_md64(payloads)
-    return (blocks, nblocks) + tuple(
-        pad_rows(bytes_be_to_limbs(sigs128[:, lo : lo + 32]), bb)
-        for lo in (0, 32, 64, 96)  # r, s, Px, Py
-    )
+    return pad_md64(payloads) + _limb_operands(sigs128, bb)  # r, s, Px, Py
 
 
 def _host_native_or_raise(payloads, sigs65):

@@ -7,6 +7,13 @@ a per-lane block count; the device kernel scans over the M block slots and
 masks inactive lanes. M is rounded up to a bounded shape schedule (powers of two, then multiples of
 2048) to bound the number of
 distinct compiled shapes (XLA needs static shapes).
+
+The tensor is built by whole-array operations (:func:`_padded_rows`: one join
+of the messages, one gather of the rows, the padding's bytes and length field
+by indexed stores), never by a turn of the interpreter per message: a 10,000-
+message block is a millisecond or two of host time beside the program it
+feeds, where the per-message loop was a fifth of the block (PERF.md §6, PR
+29). What comes out is byte for byte what padding each message alone gives.
 """
 
 from __future__ import annotations
@@ -66,6 +73,39 @@ def pad_rows(a: np.ndarray, rows: int) -> np.ndarray:
     return np.concatenate([a, pad], axis=0)
 
 
+def _padded_rows(
+    msgs: Sequence[bytes], rate: int, tail: int, first: bytes
+) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The byte rows both padders share: ``(rows [B', M·rate] uint8, lens [B']
+    int64, nblocks [B'] int32)`` with B' = _bucket(len(msgs)), a message
+    taking ``(len + tail) // rate + 1`` blocks and M the bucketed most of
+    them. Row i holds message i, then ``first`` (the padding's opening
+    byte), then zeros; the rows behind the batch are empty messages.
+
+    No turn of the interpreter per message: the messages are joined ONCE
+    with a separator of ``first`` and M·rate − 1 zeros, so that M·rate bytes
+    read from a message's first byte are exactly its row (a message is
+    shorter than its blocks, so the opening byte always falls inside), and
+    every row is cut from the joined bytes by one gather over a window view
+    whose row k starts at byte k. The joined bytes cost a second copy of the
+    tensor; the tensor itself is dense in M·rate whoever builds it."""
+    n = len(msgs)
+    b_pad = _bucket(max(n, 1))
+    lens = np.zeros(b_pad, dtype=np.int64)
+    lens[:n] = np.fromiter(map(len, msgs), dtype=np.int64, count=n)
+    nblocks = ((lens + tail) // rate + 1).astype(np.int32)
+    width = _bucket(int(nblocks.max())) * rate
+    sep = first + bytes(width - 1)
+    # one allocation: b_pad separators in all, one behind every message and
+    # one for every row behind the batch (an empty message each)
+    joined = sep.join([*msgs, *[b""] * (b_pad - n + 1)])
+    step = lens + width
+    windows = np.ndarray(
+        (len(joined) - width + 1, width), np.uint8, joined, strides=(1, 1)
+    )
+    return windows[np.cumsum(step) - step], lens, nblocks
+
+
 def pad_keccak(
     msgs: Sequence[bytes], rate: int = 136
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -78,25 +118,18 @@ def pad_keccak(
     dirty-set size (r5 flood profile). Padding rows are empty messages;
     callers that need exactly len(msgs) digests slice the result (the
     *_batch_async resolvers do).
+
+    The bytes are placed by :func:`_padded_rows` (message, 0x01, zeros) and
+    the closing 0x80 is or-ed into each row's last block byte by one indexed
+    store (it meets the 0x01 where a message ends one byte short of a
+    block). Value, shape, dtype and layout are those of padding one message
+    at a time (tests/test_hash_padding.py keeps that loop as the reference).
     """
-    b_pad = _bucket(max(len(msgs), 1))
-    nblocks = np.array(
-        [len(m) // rate + 1 for m in msgs] + [1] * (b_pad - len(msgs)),
-        dtype=np.int32,
-    )
-    m_max = _bucket(int(nblocks.max()))
-    lanes = rate // 8
-    buf = np.zeros((b_pad, m_max * rate), dtype=np.uint8)
-    for i, m in enumerate(msgs):
-        buf[i, : len(m)] = np.frombuffer(m, dtype=np.uint8)
-        end = nblocks[i] * rate
-        buf[i, len(m)] ^= 0x01
-        buf[i, end - 1] ^= 0x80
-    if b_pad > len(msgs):  # all pad rows are the padded empty message
-        buf[len(msgs):, 0] = 0x01
-        buf[len(msgs):, rate - 1] = 0x80
-    words = buf.view("<u4").reshape(b_pad, m_max, lanes, 2)
-    return words.astype(np.uint32), nblocks
+    rows, _, nblocks = _padded_rows(msgs, rate, 0, b"\x01")
+    b_pad = len(rows)
+    rows[np.arange(b_pad), nblocks * rate - 1] |= 0x80
+    words = rows.view("<u4").reshape(b_pad, -1, rate // 8, 2)
+    return words.astype(np.uint32, copy=False), nblocks
 
 
 def pad_md64(
@@ -105,25 +138,20 @@ def pad_md64(
     """Merkle–Damgård padding with 64-bit big-endian length (SHA-256 and SM3
     share it): 0x80, zeros, bitlen. Returns (blocks [B', M, 16] uint32
     big-endian words, nblocks [B'] int32); B' = _bucket(len(msgs)) with
-    empty-message padding rows, exactly like :func:`pad_keccak`."""
-    b_pad = _bucket(max(len(msgs), 1))
-    nblocks = np.array(
-        [(len(m) + 8) // 64 + 1 for m in msgs] + [1] * (b_pad - len(msgs)),
-        dtype=np.int32,
-    )
-    m_max = _bucket(int(nblocks.max()))
-    buf = np.zeros((b_pad, m_max * 64), dtype=np.uint8)
-    for i, m in enumerate(msgs):
-        buf[i, : len(m)] = np.frombuffer(m, dtype=np.uint8)
-        buf[i, len(m)] = 0x80
-        end = nblocks[i] * 64
-        buf[i, end - 8 : end] = np.frombuffer(
-            (len(m) * 8).to_bytes(8, "big"), dtype=np.uint8
-        )
-    if b_pad > len(msgs):  # pad rows: empty message = 0x80 + zero bitlen
-        buf[len(msgs):, 0] = 0x80
-    words = buf.view(">u4").reshape(b_pad, m_max, 16)
-    return words.astype(np.uint32), nblocks
+    empty-message padding rows, exactly like :func:`pad_keccak`.
+
+    The bytes are placed by :func:`_padded_rows` (message, 0x80, zeros); the
+    one pass that reads them as big-endian words is the only copy, and the
+    bit length goes into the last two words of each row's last block by two
+    indexed stores. Identical to padding one message at a time, like
+    :func:`pad_keccak`."""
+    rows, lens, nblocks = _padded_rows(msgs, 64, 8, b"\x80")
+    b_pad = len(rows)
+    words = rows.view(">u4").astype(np.uint32)
+    lanes, last, bits = np.arange(b_pad), nblocks * 16 - 1, lens * 8
+    words[lanes, last - 1] = bits >> 32
+    words[lanes, last] = bits & 0xFFFFFFFF
+    return words.reshape(b_pad, -1, 16), nblocks
 
 
 def digest_words_to_bytes_le(words: np.ndarray) -> np.ndarray:

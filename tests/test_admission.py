@@ -103,6 +103,39 @@ def test_admission_rejects_corruption():
     assert not ok[1]
 
 
+def _limb_operand_from_ints(rows, bb):
+    """What an operand is, said with Python integers: row i the 16 limbs of
+    the big-endian value, zero rows behind the batch."""
+    out = np.zeros((bb, 16), dtype=np.uint32)
+    out[: len(rows)] = bigint.ints_to_limbs(int.from_bytes(bytes(r), "big") for r in rows)
+    return out
+
+
+def test_marshal_builds_the_operands_python_integers_give():
+    """A batch that is no bucket size: blocks and nblocks are pad_keccak's, r
+    and s are the signature's two big-endian values as limbs and v its last
+    byte, each bucket-sized with zero rows behind the batch, dtype and shape
+    those the program was compiled for."""
+    from fisco_bcos_tpu.ops.hash_common import bucket_batch, pad_keccak
+
+    n = 5
+    bb = bucket_batch(n)
+    assert bb > n
+    sigs = np.random.default_rng(29).integers(0, 256, (n, 65), dtype=np.uint8)
+    sigs[0, :64] = 0xFF  # every limb at its largest
+    sigs[1, :64] = 0
+    payloads = [b"marshal %d " % i + b"m" * (i * 53) for i in range(n)]
+    blocks, nblocks, r, s, v = admission._marshal_secp(payloads, sigs, bb)
+    want_blocks, want_nblocks = pad_keccak(payloads)
+    np.testing.assert_array_equal(blocks, want_blocks)
+    np.testing.assert_array_equal(nblocks, want_nblocks)
+    for got, lo in ((r, 0), (s, 32)):
+        assert got.dtype == np.uint32 and got.shape == (bb, 16) and got.flags.c_contiguous
+        np.testing.assert_array_equal(got, _limb_operand_from_ints(sigs[:, lo : lo + 32], bb))
+    assert v.dtype == np.int32 and v.shape == (bb,)
+    assert v[:n].tolist() == [int(x) for x in sigs[:, 64]] and not v[n:].any()
+
+
 def test_graft_entry_single_chip():
     import __graft_entry__ as ge
 

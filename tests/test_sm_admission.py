@@ -132,6 +132,32 @@ def test_device_leg_and_host_leg_agree_bit_for_bit(corpus, device_leg, leg):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_marshal_builds_the_operands_python_integers_give(corpus):
+    """A batch that is no bucket size: blocks and nblocks are pad_md64's, and
+    r, s, Px, Py are the signature's four big-endian values as limbs, each
+    bucket-sized with zero rows behind the batch, dtype and shape those the
+    program was compiled for."""
+    from fisco_bcos_tpu.ops.bigint import ints_to_limbs
+    from fisco_bcos_tpu.ops.hash_common import bucket_batch, pad_md64
+
+    payloads, sigs = corpus
+    bb = bucket_batch(N_LANES)
+    assert bb > N_LANES
+    sigs = _broken(sigs)  # zero and order-sized values among the lanes
+    blocks, nblocks, *limbs = admission._marshal_sm(payloads, sigs, bb)
+    want_blocks, want_nblocks = pad_md64(payloads)
+    np.testing.assert_array_equal(blocks, want_blocks)
+    np.testing.assert_array_equal(nblocks, want_nblocks)
+    assert len(limbs) == 4
+    for got, lo in zip(limbs, (0, 32, 64, 96)):
+        want = np.zeros((bb, 16), dtype=np.uint32)
+        want[:N_LANES] = ints_to_limbs(
+            int.from_bytes(bytes(row), "big") for row in sigs[:, lo : lo + 32]
+        )
+        assert got.dtype == np.uint32 and got.shape == (bb, 16) and got.flags.c_contiguous
+        np.testing.assert_array_equal(got, want)
+
+
 def test_policy_sends_a_cpu_backend_to_the_native_loop(corpus, monkeypatch):
     """Without the pin the CPU backend rides the host loop, like the secp
     body: same answer, dispatch noted as native."""
