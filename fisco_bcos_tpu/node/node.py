@@ -314,6 +314,7 @@ class Node:
             self.front,
             consensus=self.engine,
             validator=self.block_validator,
+            group_id=config.group_id,
         )
         self.tx_sync = TransactionSync(self.txpool, self.front)
         # proposal straggler fetch (asyncVerifyBlock's fetch-missing hook)
@@ -479,13 +480,25 @@ class Node:
         loop (below the cutover, or any size on a CPU backend) there is no
         program to compile, and the log says so instead of claiming warm
         kernels. Payloads are padded into the two-block keccak bucket that
-        precompiled calls land in — the shape key is (bucket, blocks)."""
+        precompiled calls land in — the shape key is (bucket, blocks).
+
+        Besides the sizes asked for, the one block sync re-verifies in a
+        call when this node has to catch up: whole blocks of the chain's
+        ``tx_count_limit`` up to ``VERIFY_LANES_MAX`` lanes (ten 1,000-tx
+        blocks: the 10,240 bucket)."""
         from ..device.dispatch import use_native_batch
         from ..ops.hash_common import bucket_batch
         from ..protocol.transaction import Transaction
+        from ..sync.block_sync import VERIFY_LANES_MAX
         from ..txpool.validator import batch_admit
 
-        for b in batch_sizes:
+        limit = max(1, self.ledger.ledger_config().tx_count_limit)
+        gather = max(limit, VERIFY_LANES_MAX // limit * limit)
+        warmed: set[int] = set()
+        for b in (*batch_sizes, gather):
+            if bucket_batch(b) in warmed:
+                continue
+            warmed.add(bucket_batch(b))
             if use_native_batch(b, "admission"):
                 _log.info(
                     "warmup: a batch of %d rides the native host loop on "

@@ -3,8 +3,36 @@
 Reference: bcos-sync/bcos-sync/BlockSync.cpp (peer status registry
 state/SyncPeerStatus.cpp, download queue state/DownloadingQueue.cpp) with the
 commit path DownloadingQueue::applyBlock:260 → scheduler executeBlock(verify)
-:281 → BlockValidator QC check :407 → commitBlock:483. The QC check — every
-sealer signature on the header — is one device batch here (the #2 hot loop).
+:281 → BlockValidator QC check :407 → commitBlock:483.
+
+What a replica commits through sync it has checked itself, as upstream's
+decode with ``checkSig = true`` does one transaction after another
+(``Transaction::verify()``: recover, ``forceSender``), and here in batches
+across blocks. Decoded blocks wait in a download queue (upstream's
+DownloadingQueue) and leave it in **gathers**: the longest run of whole
+blocks that fits ``VERIFY_LANES_MAX`` lanes, ten 1,000-tx blocks. A gather
+is taken when the queue holds more than fits, or when nothing more is to be
+had from any peer (the tail); otherwise the next range is asked for first, so
+the calls stay full across responses. For a gather:
+
+- the sealer signatures of all its headers go to the dispatch seam as ONE
+  ``batch_verify`` (``BlockValidator.check_blocks``; at n = 4 that is 30-40
+  signatures, which the seam's policy sends down the native host loop);
+- all its transactions go through ONE ``batch_admit`` under
+  ``device_lane("sync")``: the suite's fused admission program (secp256k1 +
+  keccak256, or SM2 + SM3 on a national-crypto chain) through
+  ``device/dispatch``, breaker and host fallback included. The answer fills
+  every transaction's sender and hash, so execution sees the senders the
+  sealing replicas saw and neither ``tx_hashes`` nor the transaction root
+  hashes anything again;
+- the blocks are then executed and committed one after another. A block
+  with a refused lane (or a failed QC, parent or root) is not executed:
+  nothing of it reaches the ledger, the blocks before it stay applied, the
+  rest of the queue is dropped, the peer that served it takes a strike and
+  the range is asked for again from the best remaining peer.
+
+Verification of one gather and execution of the one before it run one after
+the other (ROADMAP Queue 1 has the overlap).
 
 Protocol (over ModuleID.BLOCK_SYNC): nodes broadcast their status on commit
 and on `maintain()`; a node behind a peer requests a block range; responses
@@ -15,21 +43,36 @@ carry full blocks (header + QC + txs). Timers live in the node runtime —
 from __future__ import annotations
 
 import threading
+import time
+from contextlib import contextmanager
 from dataclasses import dataclass
 from enum import IntEnum
+
+import numpy as np
 
 from ..codec.flat import FlatReader, FlatWriter
 from ..consensus.block_validator import BlockValidator
 from ..front.front import FrontService, ModuleID
 from ..ledger import Ledger
+from ..observability.tracer import TRACER
 from ..protocol.block import Block
 from ..resilience.crashpoints import InjectedCrash
 from ..scheduler.scheduler import Scheduler, SchedulerError
 from ..utils.log import get_logger
+from ..utils.metrics import REGISTRY
 
 _log = get_logger("block-sync")
 
 MAX_BLOCKS_PER_REQUEST = 32
+# the download queue's bound: blocks with few transactions are gathered by
+# count once this many wait (recalled from upstream's DownloadingQueue, 256)
+MAX_QUEUED_BLOCKS = 256
+
+# Lanes of one re-verification call: the bucket of the north star's block on
+# the batch ladder (ops/hash_common._bucket), which ten blocks at the Air
+# default tx_count_limit = 1000 fill to 10,000. A gather is whole blocks, so
+# its size follows from this and the blocks at hand.
+VERIFY_LANES_MAX = 10_240
 
 # a peer that times out this many requests in a row is demoted: the best-peer
 # choice skips it until it answers again (or every candidate is demoted, in
@@ -37,6 +80,24 @@ MAX_BLOCKS_PER_REQUEST = 32
 # Reference: bcos-sync's SyncPeerStatus drops idle peers from the download
 # queue choice rather than re-asking the same silent one forever.
 MAX_PEER_STRIKES = 3
+
+
+@contextmanager
+def _stage(name: str, **attrs):
+    """One stage of the catch-up: the span ``sync.<name>`` for a trace, and
+    its seconds in ``fisco_sync_stage_seconds_total{stage}`` for an operator
+    (where a replica that catches up spends its time)."""
+    t0 = time.perf_counter()
+    with TRACER.span(f"sync.{name}", **attrs) as sp:
+        try:
+            yield sp
+        finally:
+            REGISTRY.counter_add(
+                f'fisco_sync_stage_seconds_total{{stage="{name}"}}',
+                time.perf_counter() - t0,
+                help="seconds block sync spent, by stage: decode, qc, verify "
+                "on the replica that catches up, serve on the peer it asks",
+            )
 
 
 class SyncPacket(IntEnum):
@@ -87,6 +148,7 @@ class BlockSync:
         front: FrontService,
         consensus=None,  # PBFTEngine, notified on synced commits
         validator: BlockValidator | None = None,
+        group_id: str = "",  # the plane's tenant tag of this chain's batches
     ):
         self.ledger = ledger
         self.scheduler = scheduler
@@ -94,6 +156,7 @@ class BlockSync:
         self.consensus = consensus
         self.suite = ledger.suite
         self.validator = validator or BlockValidator(self.suite)
+        self.group_id = group_id
         self._peers: dict[bytes, SyncStatus] = {}
         self._requested_to: int = 0
         self._requested_at: float = 0.0
@@ -107,6 +170,14 @@ class BlockSync:
         self.request_timeout_initial: float = 2.0  # before any RTT sample
         self._rtt_ewma: dict[bytes, float] = {}
         self._strikes: dict[bytes, int] = {}
+        # the download queue: (block, the peer that served it), decoded and
+        # not yet applied, heights contiguous from the ledger's next
+        self._queue: list[tuple[Block, bytes]] = []
+        self._draining = False  # one thread at a time takes gathers
+        self._applying_to = 0  # the last height of the gather being applied
+        # a request was abandoned on its window: what is queued is applied
+        # as it stands, not held back for a full gather that may never come
+        self._tail_now = False
         # median peer clock tracking (bcos-tool NodeTimeMaintenance)
         from ..utils.time_sync import NodeTimeMaintenance
 
@@ -159,7 +230,7 @@ class BlockSync:
         if self._node_dead():
             return  # a crash point fired: this node is dead until reboot
         self.broadcast_status()
-        self._request_missing()
+        self._drain()
 
     def _timeout_for(self, nid: bytes | None) -> float:
         """The decay window for an outstanding request to this peer:
@@ -173,11 +244,10 @@ class BlockSync:
         )
 
     def _request_missing(self) -> None:
-        import time as _time
-
-        my_number = self.ledger.block_number()
         with self._lock:
-            now = _time.monotonic()
+            # the next height to download lies past what is queued
+            my_number = self._tip_locked()
+            now = time.monotonic()
             if self._requested_to >= my_number + 1:
                 # an unanswered request must not stall sync forever: decay
                 # it on the ADAPTIVE window and demote the silent peer
@@ -191,6 +261,7 @@ class BlockSync:
                 self._requested_to = 0
                 self._requested_at = 0.0
                 self._requested_peer = None
+                self._tail_now = bool(self._queue)
                 if lag is not None and lag in self._peers:
                     strikes = self._strikes.get(lag, 0) + 1
                     self._strikes[lag] = strikes
@@ -199,8 +270,6 @@ class BlockSync:
                         "strike %d/%d)", lag.hex()[:8],
                         window, strikes, MAX_PEER_STRIKES,
                     )
-                    from ..utils.metrics import REGISTRY
-
                     REGISTRY.counter_add(
                         "fisco_sync_request_timeouts_total", 1.0,
                         help="block requests abandoned on the adaptive window",
@@ -225,7 +294,11 @@ class BlockSync:
                 )
                 self._strikes.clear()
                 healthy = candidates
-            nid, st = max(healthy, key=lambda c: c[1].number)
+            # the highest peer; among equals the one with the fewest strikes,
+            # so a range a peer served badly goes to another next
+            nid, st = max(
+                healthy, key=lambda c: (c[1].number, -self._strikes.get(c[0], 0))
+            )
             start = my_number + 1
             count = min(st.number - my_number, MAX_BLOCKS_PER_REQUEST)
             self._requested_to = start + count - 1
@@ -255,17 +328,7 @@ class BlockSync:
                 r.done()
                 self._on_response(src, blocks)
         except InjectedCrash:
-            # a crash point fired on the sync-commit path (the same
-            # scheduler seams consensus hits): absorb at the transport
-            # boundary — one node's death must never unwind the gateway's
-            # delivery to its peers — and halt this node wholesale
-            self._crashed = True
-            if self.consensus is not None:
-                self.consensus._crashed = True
-            _log.error(
-                "injected crash while syncing — node halted (reboot to "
-                "recover)"
-            )
+            self._halt_injected()
         except Exception as e:
             _log.warning("bad sync message from %s: %s", src.hex()[:8], e)
 
@@ -288,73 +351,267 @@ class BlockSync:
         if self.time_maintenance is not None:
             self.time_maintenance.on_peer_time(src, st.utc_ms)
         if st.number > self.ledger.block_number():
-            self._request_missing()
+            self._drain()
 
     def _on_request(self, src: bytes, start: int, count: int) -> None:
         count = max(0, min(count, MAX_BLOCKS_PER_REQUEST))
         blocks: list[bytes] = []
-        for n in range(start, start + count):
-            blk = self.ledger.block_by_number(n, with_txs=True)
-            if blk is None:
-                break
-            blocks.append(blk.encode())
+        with _stage("serve_request", start=start, count=count) as sp:
+            for n in range(start, start + count):
+                blk = self.ledger.block_by_number(n, with_txs=True)
+                if blk is None:
+                    break
+                blocks.append(blk.encode())
+            response = _encode_response(blocks) if blocks else b""
+            sp.set(blocks=len(blocks), bytes=len(response))
         if blocks:
-            self.front.send_message(ModuleID.BLOCK_SYNC, src, _encode_response(blocks))
+            self.front.send_message(ModuleID.BLOCK_SYNC, src, response)
 
     def _on_response(self, src: bytes, raw_blocks: list[bytes]) -> None:
-        import time as _time
-
         with self._lock:
-            # an answer redeems the peer and feeds the adaptive window; the
-            # outstanding-request markers are consumed HERE so a duplicate
-            # or late second response cannot record a bogus RTT sample
+            # an answer feeds the adaptive window; the outstanding-request
+            # markers are consumed HERE so a duplicate or late second
+            # response cannot record a bogus RTT sample. What redeems a
+            # peer's strikes is a block of its that applies (_apply_gather)
             if src == self._requested_peer and self._requested_at:
-                rtt = max(1e-3, _time.monotonic() - self._requested_at)
+                rtt = max(1e-3, time.monotonic() - self._requested_at)
                 prev = self._rtt_ewma.get(src)
                 self._rtt_ewma[src] = (
                     rtt if prev is None else 0.7 * prev + 0.3 * rtt
                 )
                 self._requested_peer = None
                 self._requested_at = 0.0
-                self._strikes.pop(src, None)
-        applied = 0
-        for raw in raw_blocks:
-            try:
-                block = Block.decode(raw)
-            except Exception:
-                _log.warning("undecodable block from %s", src.hex()[:8])
-                break
-            if not self._apply_block(block):
-                break
-            applied += 1
+        self._enqueue(src, raw_blocks)
         with self._lock:
             self._requested_to = 0  # allow the next request round
-        if applied:
-            self.broadcast_status()
-            self._request_missing()
+        self._drain()
+
+    # -- the download queue (DownloadingQueue.cpp) ---------------------------
+
+    def _tip_locked(self) -> int:
+        """The height the queue reaches, past the ledger's head and the gather
+        being applied. Blocks the ledger has passed meanwhile (this node also
+        commits through consensus) are dropped, and a queue that no longer
+        continues from there is dropped whole."""
+        head = max(self.ledger.block_number(), self._applying_to)
+        while self._queue and self._queue[0][0].header.number <= head:
+            self._queue.pop(0)
+        if self._queue and self._queue[0][0].header.number != head + 1:
+            self._queue.clear()
+        return head + len(self._queue)
+
+    def _enqueue(self, src: bytes, raw_blocks: list[bytes]) -> None:
+        """Decode a response into the queue: the blocks that continue it, in
+        order. The first undecodable block ends the response, counts as a
+        refused block and strikes the peer, as a refused signature does."""
+        blocks: list[Block] = []
+        with _stage(
+            "decode", blocks=len(raw_blocks),
+            bytes=sum(len(raw) for raw in raw_blocks),
+        ):
+            for raw in raw_blocks:
+                try:
+                    blocks.append(Block.decode(raw))
+                except Exception:
+                    self._refuse(src, None, "decode")
+                    break
+        with self._lock:
+            tip = self._tip_locked()
+            for block in blocks:
+                if block.header.number <= tip:
+                    continue  # a duplicate of what is applied or queued
+                if block.header.number != tip + 1:
+                    break
+                self._queue.append((block, src))
+                tip += 1
+
+    def _take_gather_locked(self, tail: bool) -> list[tuple[Block, bytes]]:
+        """Pop the next gather: the longest run of whole queued blocks within
+        ``VERIFY_LANES_MAX`` lanes, one block at least. A gather the queue
+        ends inside could still grow; it is taken only as the ``tail``: when
+        no request is on its way, or one was just abandoned."""
+        self._tip_locked()
+        lanes = n = 0
+        for block, _src in self._queue:
+            if n and lanes + len(block.transactions) > VERIFY_LANES_MAX:
+                break
+            lanes += len(block.transactions)
+            n += 1
+        full = n < len(self._queue) or n >= MAX_QUEUED_BLOCKS
+        if not n or not (
+            full or (tail and (self._tail_now or not self._requested_to))
+        ):
+            return []
+        self._tail_now = False
+        gather, self._queue = self._queue[:n], self._queue[n:]
+        self._applying_to = gather[-1][0].header.number
+        return gather
+
+    def _drain(self) -> None:
+        """Apply what is queued, gather by gather, asking for the next range
+        whenever the queue ends inside a gather, until neither moves. One
+        thread at a time: a response that arrives meanwhile (under an
+        in-process gateway: inside the request's own send) only queues its
+        blocks, and this loop finds them."""
+        while True:
+            with self._lock:
+                if self._draining or self._node_dead():
+                    return
+                self._draining = True
+            try:
+                while not self._node_dead():
+                    with self._lock:
+                        gather = self._take_gather_locked(tail=False)
+                    if not gather:
+                        with self._lock:
+                            tip = self._tip_locked()
+                        self._request_missing()
+                        with self._lock:
+                            if self._tip_locked() > tip:
+                                continue  # the answer came inline
+                            gather = self._take_gather_locked(tail=True)
+                    if not gather:
+                        break
+                    try:
+                        applied = self._apply_gather(gather)
+                    finally:
+                        with self._lock:
+                            self._applying_to = 0
+                    if applied:
+                        self.broadcast_status()
+            except InjectedCrash:
+                self._halt_injected()
+            finally:
+                with self._lock:
+                    self._draining = False
+            with self._lock:
+                # a response that came between the loop's last look and the
+                # flag's release found the door shut: look once more
+                if not self._queue or self._requested_to:
+                    return
+
+    def _halt_injected(self) -> None:
+        """A crash point fired on the sync-commit path (the same scheduler
+        seams consensus hits): halt this node wholesale. Absorbed here and at
+        the transport boundary: one node's death must never unwind the
+        gateway's delivery to its peers."""
+        self._crashed = True
+        if self.consensus is not None:
+            self.consensus._crashed = True
+        _log.error(
+            "injected crash while syncing — node halted (reboot to recover)"
+        )
+
+    def _refuse(self, src: bytes, number: int | None, reason: str) -> None:
+        """A block that is not applied: counted by reason, the queue behind
+        it dropped, its peer struck, the range free to be asked for again
+        (from the best remaining peer: the strike board decides)."""
+        REGISTRY.counter_add(
+            f'fisco_sync_blocks_refused_total{{reason="{reason}"}}', 1.0,
+            help="downloaded blocks refused by block sync, by what failed",
+        )
+        with self._lock:
+            self._queue.clear()
+            self._requested_to = 0
+            strikes = 0
+            if src:
+                strikes = self._strikes[src] = self._strikes.get(src, 0) + 1
+        _log.warning(
+            "block %s from %s refused (%s), strike %d/%d",
+            "?" if number is None else number, src.hex()[:8] or "-", reason,
+            strikes, MAX_PEER_STRIKES,
+        )
 
     # -- the commit path (applyBlock:260) ------------------------------------
 
     def _apply_block(self, block: Block) -> bool:
+        """One block through the whole path, a gather of its own."""
+        if block.header.number != self.ledger.block_number() + 1:
+            return False
+        return self._apply_gather([(block, b"")]) == 1
+
+    def _apply_gather(self, gather: list[tuple[Block, bytes]]) -> int:
+        """QC of every header as one batch, every transaction through one
+        admission call, then execute and commit block by block up to the
+        first that fails anything. -> blocks applied."""
+        from ..device.plane import device_group, device_lane
+        from ..txpool.validator import batch_admit
+
+        blocks = [b for b, _src in gather]
+        committee = self.ledger.consensus_nodes()
+        with _stage(
+            "qc", headers=len(blocks),
+            signatures=sum(len(b.header.signature_list) for b in blocks),
+        ):
+            # QC first: a forged block must not reach admission or execution
+            qc_ok = self.validator.check_blocks(
+                [b.header for b in blocks], committee
+            )
+        sound = qc_ok.index(False) if False in qc_ok else len(blocks)
+        txs = [t for b in blocks[:sound] for t in b.transactions]
+        ok = np.zeros(0, dtype=bool)
+        if txs:
+            with _stage("verify", blocks=sound, lanes=len(txs)):
+                with device_group(self.group_id), device_lane("sync"):
+                    # fills every admitted transaction's sender and hash
+                    ok = batch_admit(txs, self.suite)
+            REGISTRY.counter_add(
+                "fisco_sync_verify_lanes_total", float(len(txs)),
+                help="transactions of downloaded blocks re-verified by block sync",
+            )
+            REGISTRY.counter_add(
+                "fisco_sync_verify_calls_total", 1.0,
+                help="admission calls block sync made, one a gather of blocks",
+            )
+        applied = lo = 0
+        for k, (block, src) in enumerate(gather):
+            hi = lo + len(block.transactions)
+            if k >= sound:
+                reason = "qc"
+            elif not ok[lo:hi].all():
+                reason = "signature"
+            else:
+                reason = self._execute_and_commit(block)
+            lo = hi
+            if reason is not None:
+                self._refuse(src, block.header.number, reason)
+                break
+            applied += 1
+            if src:
+                with self._lock:
+                    self._strikes.pop(src, None)  # a block that applies redeems
+            if k + 1 < len(gather) and self.ledger.consensus_nodes() != committee:
+                # this block changed the committee: what follows is held to
+                # the one that signed it, in a gather of its own
+                with self._lock:
+                    self._applying_to = 0
+                    self._queue[:0] = gather[k + 1:]
+                break
+        return applied
+
+    def _execute_and_commit(self, block: Block) -> str | None:
+        """-> None when the block is committed, else why it was not."""
         number = block.header.number
         if number != self.ledger.block_number() + 1:
-            return False
-        # QC first: a forged block must not reach execution
-        committee = self.ledger.consensus_nodes()
-        if not self.validator.check_block(block.header, committee):
-            _log.warning("block %d: QC validation failed", number)
-            return False
+            return "parent"
         parent = self.ledger.block_hash_by_number(number - 1)
         if block.header.parent_info and block.header.parent_info[0].hash != parent:
             _log.warning("block %d: parent hash mismatch", number)
-            return False
-        try:
-            header = self.scheduler.execute_block(block, verify=True)
-            self.scheduler.commit_block(header)
-        except SchedulerError as e:
-            _log.warning("block %d: apply failed: %s", number, e)
-            return False
+            return "parent"
+        with TRACER.span(
+            "sync.apply_block", block=number, txs=len(block.transactions)
+        ):
+            try:
+                header = self.scheduler.execute_block(block, verify=True)
+                self.scheduler.commit_block(header)
+            except SchedulerError as e:
+                _log.warning("block %d: apply failed: %s", number, e)
+                return "root"
+        REGISTRY.counter_add(
+            "fisco_sync_blocks_applied_total", 1.0,
+            help="downloaded blocks executed and committed by block sync",
+        )
         if self.consensus is not None:
             self.consensus.on_synced_block(number)
         _log.info("synced block %d (%d txs)", number, len(block.transactions))
-        return True
+        return None
