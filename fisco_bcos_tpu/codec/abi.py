@@ -17,7 +17,7 @@ precompiled/common/Utilities.cpp).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import Any
 
 _WORD = 32
@@ -287,36 +287,135 @@ def abi_decode(types: list[str], data: bytes) -> list[Any]:
 
 
 # ---------------------------------------------------------------------------
+# Compiled coders: a type list's shape worked out once, not once a call
+# ---------------------------------------------------------------------------
+
+_WORD_BASES = ("uint", "int", "bool", "address", "fbytes")
+
+
+class Coder:
+    """``encode(values) -> bytes`` and ``decode(data) -> list`` of one type
+    list, built once (``coder_for``). A list of one-word types and
+    ``string`` / ``bytes`` gets the direct form: every head is one word, so
+    the heads are packed and unpacked in one pass with no type algebra. Any
+    other list (arrays, tuples) keeps the generic coder above, its types
+    parsed once. Both forms give the generic coder's bytes, values and
+    errors: it is the reference the tests compare them against."""
+
+    __slots__ = ("types", "encode", "decode")
+
+    def __init__(self, types: tuple[str, ...]):
+        self.types = types
+        parsed = [parse_type(t) for t in types]
+        if all(t.base in _WORD_BASES or t.base in ("string", "bytes") for t in parsed):
+            self.encode, self.decode = _direct_coder(parsed)
+        else:
+            self.encode, self.decode = _generic_coder(parsed)
+
+
+def _generic_coder(parsed: list[AbiType]):
+    def encode(values) -> bytes:
+        if len(values) != len(parsed):
+            raise ValueError("types/values length mismatch")
+        return _encode_sequence(parsed, list(values))
+
+    def decode(data: bytes) -> list[Any]:
+        return _decode_sequence(parsed, data, 0)
+
+    return encode, decode
+
+
+def _direct_coder(parsed: list[AbiType]):
+    # per position (encode a word, decode a word, is text): the first two are
+    # None for a string / bytes, whose head is the offset of its tail
+    plan = [
+        (partial(_encode_static_word, t), partial(_decode_static_word, t), False)
+        if t.base in _WORD_BASES else (None, None, t.base == "string")
+        for t in parsed
+    ]
+    n = len(plan)
+
+    def encode(values) -> bytes:
+        if len(values) != n:
+            raise ValueError("types/values length mismatch")
+        heads: list[bytes] = []
+        tails: list[bytes] = []
+        offset = _WORD * n
+        for (enc, _dec, _text), v in zip(plan, values):
+            if enc is not None:
+                heads.append(enc(v))
+                continue
+            raw = v.encode() if isinstance(v, str) else bytes(v)
+            tail = len(raw).to_bytes(_WORD, "big") + _pad_right(raw)
+            heads.append(offset.to_bytes(_WORD, "big"))
+            tails.append(tail)
+            offset += len(tail)
+        heads.extend(tails)
+        return b"".join(heads)
+
+    def decode(data: bytes) -> list[Any]:
+        out: list[Any] = []
+        size = len(data)
+        pos = 0
+        for _enc, dec, text in plan:
+            end = pos + _WORD
+            if end > size:
+                raise ValueError("abi decode: word out of range")
+            if dec is not None:
+                out.append(dec(data[pos:end]))
+            else:
+                at = int.from_bytes(data[pos:end], "big")
+                start = at + _WORD
+                if start > size:
+                    raise ValueError("abi decode: word out of range")
+                length = int.from_bytes(data[at:start], "big")
+                raw = data[start : start + length]
+                if len(raw) != length:
+                    raise ValueError("abi decode: truncated dynamic data")
+                out.append(raw.decode() if text else raw)
+            pos = end
+        return out
+
+    return encode, decode
+
+
+@lru_cache(maxsize=4096)
+def coder_for(types: tuple[str, ...]) -> Coder:
+    return Coder(types)
+
+
+@lru_cache(maxsize=4096)
+def _signature_coder(signature: str) -> Coder:
+    inner = signature[signature.index("(") + 1 : signature.rindex(")")]
+    return coder_for(tuple(split_toplevel(inner)))
+
+
+# ---------------------------------------------------------------------------
 # Selector-aware codec
 # ---------------------------------------------------------------------------
 
 
 class ABICodec:
     """Selector-aware codec bound to a crypto suite's hash
-    (reference: ContractABICodec + getFuncSelector)."""
+    (reference: ContractABICodec + getFuncSelector). Every method goes
+    through the compiled coder of its signature or type list."""
 
     def __init__(self, hash_fn):
         self._hash = hash_fn
+        self.selector = lru_cache(maxsize=1024)(self._selector)
 
-    def selector(self, signature: str) -> bytes:
+    def _selector(self, signature: str) -> bytes:
         return self._hash(signature.encode())[:4]
 
-    @staticmethod
-    def _sig_types(signature: str) -> list[str]:
-        inner = signature[signature.index("(") + 1 : signature.rindex(")")]
-        return split_toplevel(inner)
-
     def encode_call(self, signature: str, *values: Any) -> bytes:
-        return self.selector(signature) + abi_encode(
-            self._sig_types(signature), list(values)
-        )
+        return self.selector(signature) + _signature_coder(signature).encode(values)
 
     def decode_input(self, signature: str, data: bytes) -> list[Any]:
         """Decode calldata that includes the 4-byte selector."""
-        return abi_decode(self._sig_types(signature), data[4:])
+        return _signature_coder(signature).decode(data[4:])
 
     def encode_output(self, types: list[str], *values: Any) -> bytes:
-        return abi_encode(types, list(values))
+        return coder_for(tuple(types)).encode(values)
 
     def decode_output(self, types: list[str], data: bytes) -> list[Any]:
-        return abi_decode(types, data)
+        return coder_for(tuple(types)).decode(data)

@@ -92,6 +92,13 @@ class HashImpl:
     def hash(self, data: bytes) -> bytes:
         raise NotImplementedError
 
+    def hash_each(self, msgs: list[bytes]) -> list[bytes]:
+        """Each message's digest, on the host: many short messages whose
+        digests the caller needs at once (a block's receipts). One native
+        call over all of them where the native core has a batch entry for
+        this hash; else ``hash`` a message."""
+        return [self.hash(m) for m in msgs]
+
     def _batch_async_direct(self, msgs):
         """Direct deferred-sync dispatch, one device program: () -> [B, 32]
         (an ops ``*_batch_async``, so the plane executor can defer the sync)."""
@@ -128,6 +135,12 @@ class Keccak256(HashImpl):
 
         return native_bind.keccak256(data) or ref_keccak256(data)
 
+    def hash_each(self, msgs):
+        from .. import native_bind
+
+        digests = native_bind.keccak256_batch(msgs)
+        return digests if digests is not None else super().hash_each(msgs)
+
     def _batch_async_direct(self, msgs):
         return keccak_ops.keccak256_batch_async(msgs)
 
@@ -139,6 +152,12 @@ class SM3(HashImpl):
         from .. import native_bind
 
         return native_bind.sm3(data) or ref_sm3(data)
+
+    def hash_each(self, msgs):
+        from .. import native_bind
+
+        digests = native_bind.sm3_batch(msgs)
+        return digests if digests is not None else super().hash_each(msgs)
 
     def _batch_async_direct(self, msgs):
         return sm3_ops.sm3_batch_async(msgs)
@@ -641,6 +660,9 @@ class CryptoSuite:
 
     def hash(self, data: bytes) -> bytes:
         return self.hash_impl.hash(data)
+
+    def hash_each(self, msgs: list[bytes]) -> list[bytes]:
+        return self.hash_impl.hash_each(msgs)
 
     def hash_batch(self, msgs) -> np.ndarray:
         return self.hash_impl.hash_batch(msgs)

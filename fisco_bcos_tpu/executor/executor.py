@@ -58,6 +58,7 @@ from .evm import (
 )
 from . import eth_builtins
 from .precompiled import default_registry
+from .precompiled.account import ABOLISH, FREEZE, account_status
 from .precompiled.base import (
     BASE_GAS,
     Precompiled,
@@ -108,6 +109,35 @@ class BlockContext:
     # killSuicides at getHash time. set.add is GIL-atomic, so DAG-level
     # worker threads can register concurrently.
     suicides: set = field(default_factory=set)
+    # sender -> its governance status at this height, read once a sender by
+    # the run frame: a status written at block N takes effect at N + 1
+    # (precompiled/account.py), so it is constant while the block executes
+    account_statuses: dict = field(default_factory=dict)
+
+
+_PRECOMPILED_ERROR = int(TransactionStatus.PRECOMPILED_ERROR)
+# account governance (TransactionExecutive.cpp:1292 checkAccountAvailable): a
+# frozen or abolished origin cannot transact; its receipt's status and output
+_ACCOUNT_REFUSALS = {
+    FREEZE: (int(TransactionStatus.ACCOUNT_FROZEN), b"account is frozen"),
+    ABOLISH: (int(TransactionStatus.ACCOUNT_ABOLISHED), b"account is abolished"),
+}
+
+
+def _call_precompile(
+    pre: Precompiled, ctx: PrecompiledCallContext, data: bytes, gas: int
+) -> tuple[int, bytes, int, list]:
+    """One call of a registry precompile under its revert rule ->
+    (status, output, gas left, logs). A PrecompiledError answers with its own
+    status and message, any other fault with PRECOMPILED_ERROR; either leaves
+    no gas, and the caller drops the call's writes."""
+    try:
+        result = pre.call(ctx, data)
+    except PrecompiledError as e:
+        return int(e.status), str(e).encode(), 0, []
+    except Exception as e:  # malformed input etc. — revert, never crash
+        return _PRECOMPILED_ERROR, f"precompile fault: {e}".encode(), 0, []
+    return 0, result.output, max(gas - result.gas_used, 0), result.logs
 
 
 class TransactionExecutor:
@@ -303,23 +333,8 @@ class TransactionExecutor:
             gas_limit=block.gas_limit,
             static_call=msg.static,
         )
-        try:
-            result = pre.call(ctx, msg.data)
-        except PrecompiledError as e:
-            return EVMResult(
-                status=int(e.status), output=str(e).encode(), gas_left=0
-            )
-        except Exception as e:  # malformed input etc. — revert, never crash
-            return EVMResult(
-                status=int(TransactionStatus.PRECOMPILED_ERROR),
-                output=f"precompile fault: {e}".encode(),
-                gas_left=0,
-            )
-        return EVMResult(
-            output=result.output,
-            gas_left=max(msg.gas - result.gas_used, 0),
-            logs=result.logs,
-        )
+        status, output, gas_left, logs = _call_precompile(pre, ctx, msg.data, msg.gas)
+        return EVMResult(status=status, output=output, gas_left=gas_left, logs=logs)
 
     def start_executive(
         self, msg: EVMCall, root_storage: StorageInterface, block: BlockContext,
@@ -355,20 +370,12 @@ class TransactionExecutor:
             rc.output = b"unknown contract address"
             rc.gas_used = BASE_GAS
             return rc
-        # account governance (TransactionExecutive.cpp:1292
-        # checkAccountAvailable): a frozen/abolished origin cannot transact
         if not static_call:
-            from .precompiled.account import ABOLISH, FREEZE, account_status
-
-            st = account_status(overlay, tx.sender, block.number)
-            if st == FREEZE:
-                rc.status = int(TransactionStatus.ACCOUNT_FROZEN)
-                rc.output = b"account is frozen"
-                rc.gas_used = BASE_GAS
-                return rc
-            if st == ABOLISH:
-                rc.status = int(TransactionStatus.ACCOUNT_ABOLISHED)
-                rc.output = b"account is abolished"
+            refusal = _ACCOUNT_REFUSALS.get(
+                account_status(overlay, tx.sender, block.number)
+            )
+            if refusal is not None:
+                rc.status, rc.output = refusal
                 rc.gas_used = BASE_GAS
                 return rc
         # auth governance (ContractAuthMgr enforcement): frozen contracts and
@@ -430,10 +437,19 @@ class TransactionExecutor:
 
 
     def execute_transactions(self, txs: list[Transaction]) -> list[TransactionReceipt]:
-        """Serial batch on the current block (executeTransactions:997)."""
+        """Serial batch on the current block (executeTransactions:997).
+
+        The list is cut into maximal runs of consecutive calls to one
+        registry precompile; a run of two or more executes inside one frame
+        (``_execute_run``), every other transaction as ``_execute_one``.
+        Order, receipts and state are those of ``_execute_one`` transaction
+        by transaction: the run only stops paying per transaction what the
+        input shows to be the same for all of them."""
         if self._block is None:
             raise RuntimeError("call next_block_header first")
+        block = self._block
         base = self.reserve_contexts(len(txs))
+        registry = self.registry
         # reentrant no-op under scheduler.execute_block's execute stage;
         # the REAL accounting seam for the Max executor-service processes,
         # where this is the block work's entry point
@@ -441,12 +457,77 @@ class TransactionExecutor:
             "executor.execute", mode="serial", txs=len(txs)
         ), PIPELINE.busy("execute"):
             t0 = time.perf_counter()
-            out = [
-                self._execute_one(tx, self._block, context_id=base + i)
-                for i, tx in enumerate(txs)
-            ]
+            out: list[TransactionReceipt] = []
+            i, n = 0, len(txs)
+            while i < n:
+                to = txs[i].to
+                j = i + 1
+                if to in registry:
+                    while j < n and txs[j].to == to:
+                        j += 1
+                if j - i > 1:
+                    out.extend(self._execute_run(registry[to], txs[i:j], block))
+                else:
+                    out.append(self._execute_one(txs[i], block, context_id=base + i))
+                i = j
         self._record_batch("serial", len(txs), time.perf_counter() - t0)
         return out
+
+    def _execute_run(
+        self, pre: Precompiled, txs: list[Transaction], block: BlockContext
+    ) -> list[TransactionReceipt]:
+        """Consecutive calls to one registry precompile, in order, in one
+        frame: one call context whose sender is set a transaction, one
+        overlay that a success merges into the block and a fault empties
+        (``_call_precompile``: the statuses and outputs of
+        ``_run_registry_precompile``), the sender's governance status looked
+        up once a sender a block. No Executive, EVMCall or EVMHost: the
+        callee is known to be a precompile, which uses none of them."""
+        t0 = time.perf_counter()
+        gas, number = block.gas_limit, block.number
+        overlay = StateStorage(block.storage)
+        ctx = PrecompiledCallContext(
+            storage=overlay,
+            suite=self.suite,
+            codec=self.codec,
+            to=txs[0].to,
+            block_number=number,
+            timestamp=block.timestamp,
+            gas_limit=gas,
+        )
+        statuses = block.account_statuses
+        receipts: list[TransactionReceipt] = []
+        with TRACER.span("executor.run", callee=txs[0].to.hex(), txs=len(txs)):
+            for tx in txs:
+                sender = tx.sender
+                rc = TransactionReceipt(version=tx.version, block_number=number)
+                receipts.append(rc)
+                st = statuses.get(sender)
+                if st is None:
+                    st = statuses[sender] = account_status(block.storage, sender, number)
+                refusal = _ACCOUNT_REFUSALS.get(st)
+                if refusal is not None:
+                    rc.status, rc.output = refusal
+                    rc.gas_used = BASE_GAS
+                    continue
+                ctx.sender = ctx.origin = sender
+                rc.status, rc.output, gas_left, rc.log_entries = _call_precompile(
+                    pre, ctx, tx.input, gas
+                )
+                rc.gas_used = max(gas - gas_left, BASE_GAS)
+                if rc.status == 0:
+                    overlay.merge_into_prev()
+                else:
+                    overlay.discard()
+        self._record_batch("run", len(txs), time.perf_counter() - t0)
+        REGISTRY.counter_add(
+            "fisco_executor_run_txs_total",
+            len(txs),
+            help="txs executed inside a run frame (consecutive calls to one "
+            "registry precompile); beside fisco_executor_batch_txs' serial "
+            "sum it gives the share of a block the frame took",
+        )
+        return receipts
 
     def _record_batch(self, mode: str, n: int, dur: float) -> None:
         REGISTRY.observe(

@@ -9,6 +9,9 @@ makes blocks of them DAG-parallel (and, here, vectorizable per DAG level).
 
 from __future__ import annotations
 
+from functools import lru_cache
+
+from ...codec.abi import abi_encode
 from ...storage.entry import Entry
 from .base import (
     Precompiled,
@@ -20,6 +23,13 @@ from .base import (
 _U256_MAX = (1 << 256) - 1
 
 DAG_TRANSFER_TABLE = "dag_transfer"
+
+
+@lru_cache(maxsize=16)
+def _code_word(code: int) -> bytes:
+    """A return code as its ``uint256`` output: a handful of codes, each
+    encoded once."""
+    return abi_encode(["uint256"], [code])
 
 
 class DagTransferPrecompiled(Precompiled):
@@ -79,7 +89,7 @@ class DagTransferPrecompiled(Precompiled):
 
     @staticmethod
     def _ret(ctx, code: int) -> PrecompiledResult:
-        return PrecompiledResult(output=ctx.codec.encode_output(["uint256"], code))
+        return PrecompiledResult(output=_code_word(code))
 
     # -- methods (return codes follow the reference: 0 = ok) ----------------
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import itertools
 import os
 import subprocess
 import threading
@@ -249,6 +250,13 @@ def _bind_symbols(lib: ctypes.CDLL, u8p) -> None:
         fn = getattr(lib, name)
         fn.argtypes = [u8p, ctypes.c_size_t, u8p]
         fn.restype = None
+    for name in ("fisco_keccak256_batch", "fisco_sm3_batch"):
+        fn = getattr(lib, name)
+        fn.argtypes = [
+            ctypes.c_size_t, ctypes.c_char_p, ctypes.POINTER(ctypes.c_uint64),
+            ctypes.c_char_p,
+        ]
+        fn.restype = None
     lib.fisco_sm4_cbc.argtypes = [
         u8p, u8p, u8p, ctypes.c_size_t, u8p, ctypes.c_int,
     ]
@@ -319,6 +327,29 @@ def sha256(data: bytes) -> bytes | None:
 
 def sm3(data: bytes) -> bytes | None:
     return _hash_via("fisco_sm3", data)
+
+
+def _hash_batch_via(name: str, msgs: list[bytes]) -> list[bytes] | None:
+    """Digests of ``msgs`` by one call of the library's batch entry."""
+    lib = load()
+    if lib is None:
+        return None
+    n = len(msgs)
+    if n == 0:
+        return []
+    offsets = (ctypes.c_uint64 * (n + 1))(0, *itertools.accumulate(map(len, msgs)))
+    out = ctypes.create_string_buffer(32 * n)
+    getattr(lib, name)(n, b"".join(msgs), offsets, out)
+    raw = out.raw
+    return [raw[i : i + 32] for i in range(0, 32 * n, 32)]
+
+
+def keccak256_batch(msgs: list[bytes]) -> list[bytes] | None:
+    return _hash_batch_via("fisco_keccak256_batch", msgs)
+
+
+def sm3_batch(msgs: list[bytes]) -> list[bytes] | None:
+    return _hash_batch_via("fisco_sm3_batch", msgs)
 
 
 def sm4_cbc(key: bytes, iv: bytes, data: bytes, decrypt: bool) -> bytes | None:

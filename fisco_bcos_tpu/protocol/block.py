@@ -16,7 +16,7 @@ import numpy as np
 from ..codec.flat import FlatReader, FlatWriter
 from ..crypto.suite import CryptoSuite
 from .block_header import BlockHeader
-from .receipt import TransactionReceipt
+from .receipt import TransactionReceipt, hash_receipts
 from .transaction import Transaction, hash_transactions_batch
 
 _EMPTY_ROOT = b"\x00" * 32
@@ -92,7 +92,7 @@ class Block:
     def calculate_receipts_root_async(self, suite: CryptoSuite):
         if not self.receipts:
             return lambda: _EMPTY_ROOT
-        hashes = [rc.hash(suite) for rc in self.receipts]
+        hashes = hash_receipts(self.receipts, suite)
         leaves = np.frombuffer(b"".join(hashes), dtype=np.uint8).reshape(-1, 32)
         return suite.merkle_root_async(leaves)
 

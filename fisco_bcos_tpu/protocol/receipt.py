@@ -7,6 +7,7 @@ bcos-protocol/TransactionStatus.h.
 
 from __future__ import annotations
 
+import struct
 from dataclasses import dataclass, field
 from enum import IntEnum
 
@@ -73,6 +74,12 @@ class LogEntry:
         )
 
 
+_U32_U64_U32 = struct.Struct("<IQI")
+_U32_U32 = struct.Struct("<II")
+_I64_U32 = struct.Struct("<qI")
+_NO_LOGS = struct.pack("<I", 0)
+
+
 @dataclass
 class TransactionReceipt:
     version: int = 0
@@ -89,19 +96,27 @@ class TransactionReceipt:
     def encode(self) -> bytes:
         """Cached after first call (same invariant as ``_hash``: the
         executor builds a receipt fully before anything encodes it; the
-        block path then encodes twice — receipts root and ledger prewrite)."""
+        block path then encodes twice — receipts root and ledger prewrite).
+        The flat codec's layout, packed directly: a block encodes a
+        thousand of these, nearly all without logs."""
         if self._enc is not None:
             return self._enc
-        w = FlatWriter()
-        w.u32(self.version)
-        w.u64(self.gas_used)
-        w.bytes_(self.contract_address)
-        w.u32(self.status)
-        w.bytes_(self.output)
-        w.seq(self.log_entries, lambda w2, e: e.encode_into(w2))
-        w.i64(self.block_number)
-        w.str_(self.effective_gas_price)
-        self._enc = w.out()
+        if self.log_entries:
+            w = FlatWriter()
+            w.seq(self.log_entries, lambda w2, e: e.encode_into(w2))
+            logs = w.out()
+        else:
+            logs = _NO_LOGS
+        price = self.effective_gas_price.encode("utf-8")
+        self._enc = b"".join((
+            _U32_U64_U32.pack(self.version, self.gas_used, len(self.contract_address)),
+            self.contract_address,
+            _U32_U32.pack(self.status, len(self.output)),
+            self.output,
+            logs,
+            _I64_U32.pack(self.block_number, len(price)),
+            price,
+        ))
         return self._enc
 
     @classmethod
@@ -133,3 +148,15 @@ class TransactionReceipt:
         if self._hash is None:
             self._hash = suite.hash(self.encode())
         return self._hash
+
+
+def hash_receipts(receipts: list[TransactionReceipt], suite: CryptoSuite) -> list[bytes]:
+    """Every receipt's digest, in order; those not hashed yet are finished as
+    one batch: their wire forms written, the digests computed by one
+    ``suite.hash_each`` call, and ``_hash`` filled, so the receipts root, the
+    ledger's prewrite and ``hash()`` find both and compute nothing again."""
+    todo = [rc for rc in receipts if rc._hash is None]
+    if todo:
+        for rc, digest in zip(todo, suite.hash_each([rc.encode() for rc in todo])):
+            rc._hash = digest
+    return [rc._hash for rc in receipts]

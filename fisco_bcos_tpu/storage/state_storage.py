@@ -95,6 +95,11 @@ class StateStorage(TraversableStorage):
         with self._lock:
             return len(self._data)
 
+    def discard(self) -> None:
+        """Drop the local writes (a reverted frame whose overlay lives on)."""
+        with self._lock:
+            self._data.clear()
+
     def merge_into_prev(self) -> None:
         """Push local writes down one layer (scheduler commit path).
 

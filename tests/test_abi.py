@@ -225,3 +225,130 @@ def test_selector_and_call_roundtrip():
         [5, "five"],
         [b"\xaa" * 20],
     ]
+
+
+# -- compiled coders against the generic coder --------------------------------
+#
+# ABICodec goes through a coder compiled once a signature or type list
+# (codec.abi.coder_for): a direct form for lists of one-word types and
+# string / bytes, the generic coder with its types parsed once for the rest.
+# abi_encode / abi_decode stay the generic coder: the reference here.
+
+import random  # noqa: E402
+
+from fisco_bcos_tpu.codec.abi import Coder, coder_for  # noqa: E402
+
+CODER_TYPE_LISTS = [
+    # direct form: one-word types, string, bytes
+    ["uint256"], ["uint8", "int16", "bool", "address", "bytes4"], ["string"], ["bytes"],
+    ["string", "uint256"], ["string", "string", "uint256"], ["uint256", "uint256"],
+    ["bytes32", "string", "int256", "bytes", "bool"], ["address", "uint8"], ["int32"], [],
+    # generic form: arrays, nested arrays, tuples
+    ["uint256[]"], ["uint8[3]"], ["string[]", "uint256"], ["uint256[][]", "string[]"],
+    ["(uint256,string)", "address[]"], ["(uint256[],(string,bool))[2]"], ["bytes[2][]"],
+    ["string", "(int8,bytes3)[]", "bool"],
+]
+
+
+def _random_value(rng: random.Random, t):
+    if t.base == "uint":
+        return rng.choice([0, 1, (1 << t.bits) - 1, rng.getrandbits(t.bits)])
+    if t.base == "int":
+        half = 1 << (t.bits - 1)
+        return rng.choice([0, -1, -half, half - 1, rng.getrandbits(t.bits) - half])
+    if t.base == "bool":
+        return rng.random() < 0.5
+    if t.base == "address":
+        return rng.randbytes(20)
+    if t.base == "fbytes":
+        return rng.randbytes(t.bits)
+    if t.base == "bytes":
+        return rng.randbytes(rng.choice([0, 1, 31, 32, 33, 100]))
+    if t.base == "string":
+        return "".join(rng.choice("abcxyz é中0 ") for _ in range(rng.choice([0, 1, 31, 32, 40])))
+    if t.base == "array":
+        n = t.length if t.length >= 0 else rng.randrange(4)
+        return [_random_value(rng, t.elem) for _ in range(n)]
+    assert t.base == "tuple"
+    return [_random_value(rng, c) for c in t.components]
+
+
+@pytest.mark.parametrize("types", CODER_TYPE_LISTS, ids=lambda ts: ",".join(ts) or "empty")
+def test_compiled_coder_matches_generic(types):
+    rng = random.Random(",".join(types))
+    coder = coder_for(tuple(types))
+    assert coder is coder_for(tuple(types)), "built once a type list"
+    codec = ABICodec(keccak256)
+    signature = "f(" + ",".join(types) + ")"
+    for _ in range(50):
+        vals = [_random_value(rng, parse_type(t)) for t in types]
+        want = abi_encode(types, vals)
+        assert coder.encode(vals) == want
+        assert coder.decode(want) == abi_decode(types, want)
+        # the four codec methods every caller uses
+        assert codec.encode_output(types, *vals) == want
+        assert codec.decode_output(types, want) == abi_decode(types, want)
+        call = codec.encode_call(signature, *vals)
+        assert call == codec.selector(signature) + want
+        assert codec.decode_input(signature, call) == abi_decode(types, want)
+        # damaged input: both refuse, or both read the same values
+        for damaged in (want[:-1], want[: len(want) // 2], want[:31],
+                        _with_word(want, rng.randrange(max(1, len(want) // 32)),
+                                   rng.choice([len(want), 1 << 255, len(want) - 1, 7]))):
+            try:
+                expect = abi_decode(types, damaged)
+            except ValueError as e:
+                with pytest.raises(ValueError) as caught:
+                    coder.decode(damaged)
+                assert str(caught.value) == str(e)
+            else:
+                assert coder.decode(damaged) == expect
+
+
+def _with_word(data: bytes, index: int, value: int) -> bytes:
+    """``data`` with its ``index``-th word replaced: a bad offset or length."""
+    if len(data) < 32 * (index + 1):
+        return data
+    return data[: 32 * index] + value.to_bytes(32, "big") + data[32 * (index + 1):]
+
+
+@pytest.mark.parametrize("types,vals", [
+    (["uint8"], [256]), (["uint256"], [-1]), (["int8"], [128]), (["address"], [b"\x01" * 19]),
+    (["bytes4"], [b"12345"]), (["uint256", "string"], [1]), (["string"], ["a", "b"]),
+    (["uint256[2]"], [[1]]), (["(uint256,bool)"], [[1]]),
+])
+def test_compiled_coder_refuses_what_generic_refuses(types, vals):
+    with pytest.raises(ValueError) as generic:
+        abi_encode(types, vals)
+    with pytest.raises(ValueError) as compiled:
+        coder_for(tuple(types)).encode(vals)
+    assert str(compiled.value) == str(generic.value)
+    with pytest.raises(ValueError):
+        ABICodec(keccak256).encode_output(types, *vals)
+
+
+def test_coder_forms_and_unknown_types():
+    direct, generic = Coder(("string", "uint256")), Coder(("uint256[]",))
+    assert direct.encode.__qualname__.startswith("_direct_coder")
+    assert generic.encode.__qualname__.startswith("_generic_coder")
+    with pytest.raises(ValueError):
+        coder_for(("uint7",))
+    with pytest.raises(ValueError):
+        ABICodec(keccak256).encode_call("f(fixed128x18)", 1)
+    # a selector needs no coder: only the signature's bytes
+    assert len(ABICodec(keccak256).selector("f(fixed128x18)")) == 4
+
+
+def test_generators_user_add_call_is_pinned():
+    """benchmark/generators/transfer_batches.py builds every chain cell's
+    corpus through this call; `correct` replays the bytes."""
+    codec = ABICodec(keccak256)
+    assert codec.encode_call("userAdd(string,uint256)", "user-0000042", 1_000_000).hex() == (
+        "3fe8e3f5"
+        + W(0x40) + W(1_000_000) + W(12)
+        + "757365722d303030303034320000000000000000000000000000000000000000"
+    )
+    assert codec.decode_input(
+        "userAdd(string,uint256)",
+        codec.encode_call("userAdd(string,uint256)", "user-0000042", 1_000_000),
+    ) == ["user-0000042", 1_000_000]

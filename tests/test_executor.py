@@ -413,3 +413,295 @@ class TestSelfdestructPipeline:
             return h3.state_root, h3.receipts_root
 
         assert run(True) == run(False)
+
+
+# -- the run frame against the per-transaction path ---------------------------
+#
+# execute_transactions runs consecutive calls to one registry precompile in
+# one frame (executor._execute_run). Every case below executes the same
+# blocks twice, through execute_transactions and through _execute_one
+# transaction by transaction, on both suites, and compares every receipt's
+# wire form (also against the flat codec written out by hand), digest and
+# gas, the state root and the receipts root.
+
+import numpy as np  # noqa: E402
+from receipt_ref import flat_receipt  # noqa: E402
+
+from fisco_bcos_tpu.crypto.suite import sm_suite  # noqa: E402
+from fisco_bcos_tpu.executor.precompiled import (  # noqa: E402
+    ACCOUNT_MGR_ADDRESS,
+    CPU_HEAVY_ADDRESS,
+)
+from fisco_bcos_tpu.protocol.receipt import TransactionStatus  # noqa: E402
+from fisco_bcos_tpu.protocol.transaction import Transaction  # noqa: E402
+from fisco_bcos_tpu.storage.entry import Entry  # noqa: E402
+from fisco_bcos_tpu.utils.metrics import REGISTRY  # noqa: E402
+
+SUITES = {"keccak256": SUITE, "sm3": sm_suite()}
+GOVERNOR, ALICE, BOB = b"\x0a" * 20, b"\x0b" * 20, b"\x0c" * 20
+U256_MAX = (1 << 256) - 1
+ADD, SAVE, DRAW = "userAdd(string,uint256)", "userSave(string,uint256)", "userDraw(string,uint256)"
+TRANSFER = "userTransfer(string,string,uint256)"
+
+
+class _Calls:
+    """Transactions for one suite's selectors, senders forced."""
+
+    def __init__(self, suite):
+        self.codec = ABICodec(suite.hash)
+
+    def __call__(self, to, sig, *args, sender=ALICE):
+        return Transaction(to=to, input=self.codec.encode_call(sig, *args), sender=sender)
+
+    def dag(self, sig, *args, sender=ALICE):
+        return self(DAG_TRANSFER_ADDRESS, sig, *args, sender=sender)
+
+    def raw(self, to, data, sender=ALICE):
+        return Transaction(to=to, input=data, sender=sender)
+
+    def status(self, account, status):
+        return self(ACCOUNT_MGR_ADDRESS, "setAccountStatus(address,uint8)", account, status,
+                    sender=GOVERNOR)
+
+
+def _full_user_add(c):
+    return [[c.dag(ADD, f"user-{i}", 1000 + i, sender=bytes([1 + i % 7]) * 20)
+             for i in range(64)]]
+
+
+def _name_added_twice(c):
+    return [[c.dag(ADD, "ann", 5), c.dag(ADD, "bea", 6), c.dag(ADD, "ann", 7),
+             c.dag(ADD, "cy", 8)]]
+
+
+def _empty_user(c):
+    return [[c.dag(ADD, "ann", 5), c.dag(ADD, "", 6), c.dag(SAVE, "", 1), c.dag(ADD, "bea", 7)]]
+
+
+def _save_draw_transfer_chain(c):
+    return [[c.dag(ADD, "a", 100), c.dag(ADD, "b", 0), c.dag(ADD, "c", 0),
+             c.dag(SAVE, "a", 50), c.dag(DRAW, "a", 30),
+             c.dag(TRANSFER, "a", "b", 120), c.dag(TRANSFER, "b", "c", 70),
+             c.dag("userBalance(string)", "c")]]
+
+
+def _insufficient_and_overflow(c):
+    return [[c.dag(ADD, "a", 10), c.dag(ADD, "b", U256_MAX),
+             c.dag(DRAW, "a", 11), c.dag(TRANSFER, "a", "b", 11),  # insufficient: 4
+             c.dag(SAVE, "b", 1), c.dag(TRANSFER, "a", "b", 1),  # overflow: 3, 5
+             c.dag(DRAW, "nobody", 1), c.dag(TRANSFER, "a", "nobody", 1),
+             c.dag(SAVE, "a", 0), c.dag(TRANSFER, "a", "a", 3)]]
+
+
+def _unknown_selector_mid_run(c):
+    return [[c.dag(ADD, "a", 1), c.dag("nonexistent(uint256)", 1), c.dag(ADD, "b", 2),
+             c.raw(DAG_TRANSFER_ADDRESS, b"\x01\x02"), c.dag(ADD, "c", 3)]]
+
+
+def _truncated_input_mid_run(c):
+    whole = c.dag(ADD, "dora", 9).input
+    return [[c.dag(ADD, "a", 1), c.raw(DAG_TRANSFER_ADDRESS, whole[:40]),
+             c.raw(DAG_TRANSFER_ADDRESS, whole[:-30]), c.dag(ADD, "dora", 9),
+             c.raw(DAG_TRANSFER_ADDRESS, whole[:4] + b"\xff" * 64), c.dag(ADD, "b", 2)]]
+
+
+def _fault_drops_its_writes(c):
+    # sendPayment debits a, then the credit of b overflows nothing but the
+    # draw below raises after a write: the run goes on from the state before
+    return [[c(SMALLBANK_ADDRESS, "updateBalance(string,uint256)", "a", 10),
+             c(SMALLBANK_ADDRESS, "sendPayment(string,string,uint256)", "a", "b", 11),
+             c(SMALLBANK_ADDRESS, "writeCheck(string,uint256)", "a", 11),
+             c(SMALLBANK_ADDRESS, "sendPayment(string,string,uint256)", "a", "b", 4),
+             c(SMALLBANK_ADDRESS, "getBalance(string)", "a"),
+             c(SMALLBANK_ADDRESS, "getBalance(string)", "b")]]
+
+
+def _frozen_sender_mid_run(c):
+    return [[c.status(ALICE, 1)],
+            [c.dag(ADD, "a", 1, sender=BOB), c.dag(ADD, "b", 2), c.dag(ADD, "c", 3, sender=BOB),
+             c.dag(ADD, "d", 4), c.dag(ADD, "e", 5, sender=BOB)]]
+
+
+def _abolished_sender_mid_run(c):
+    return [[c.status(ALICE, 2)],
+            [c.dag(ADD, "a", 1, sender=BOB), c.dag(ADD, "b", 2), c.dag(ADD, "c", 3, sender=BOB)]]
+
+
+def _freeze_takes_effect_next_block(c):
+    # the block that freezes ALICE still carries her calls: status 0 there,
+    # frozen from the next block on; a second write in the block changes nothing
+    return [[c.dag(ADD, "a", 1), c.status(ALICE, 1), c.dag(ADD, "b", 2), c.dag(ADD, "c", 3),
+             c.status(ALICE, 0), c.status(ALICE, 1), c.dag(ADD, "d", 4), c.dag(ADD, "e", 5)],
+            [c.dag(ADD, "f", 6), c.dag(ADD, "g", 7, sender=BOB), c.dag(ADD, "h", 8)]]
+
+
+def _governor_run(c):
+    # a run of AccountManager calls: an unauthorised caller's soft code, a
+    # governor's own status (a fault), an unknown status
+    return [[c.status(ALICE, 1), c.status(BOB, 2),
+             c(ACCOUNT_MGR_ADDRESS, "setAccountStatus(address,uint8)", BOB, 1, sender=BOB),
+             c.status(GOVERNOR, 1), c.status(ALICE, 7),
+             c(ACCOUNT_MGR_ADDRESS, "getAccountStatus(address)", ALICE)]]
+
+
+def _broken_by_evm_create_and_precompiled(c):
+    from evm_asm import _deployer, counter_runtime
+
+    init = _deployer(counter_runtime(c.codec))
+    inc = c.codec.selector("inc()")
+    # block 1 deploys; its address is the create address of (1, context 0, seq 0)
+    return [[c.raw(b"", init)],
+            lambda addr: [
+                c.dag(ADD, "a", 1), c.dag(ADD, "b", 2),
+                c.raw(addr, inc),  # an EVM call
+                c.dag(ADD, "c", 3), c.dag(ADD, "a", 9),
+                c.raw(b"", init),  # a create
+                c.dag(ADD, "d", 4),  # a run of one
+                c(SMALLBANK_ADDRESS, "updateBalance(string,uint256)", "a", 10),
+                c(SMALLBANK_ADDRESS, "updateSaving(string,uint256)", "a", 5),
+                c.raw(b"\x99" * 20, b"\x00" * 4),  # no such callee
+                c.dag(ADD, "e", 5), c.dag(ADD, "f", 6),
+                c.raw((4).to_bytes(20, "big"), b"identity"),  # an EVM builtin
+                c.dag(ADD, "g", 7), c.dag(TRANSFER, "a", "g", 1),
+            ]]
+
+
+def _run_of_one(c):
+    return [[c.dag(ADD, "solo", 1)], [c.dag(ADD, "solo", 1)]]
+
+
+def _smallbank_run(c):
+    return [[c(SMALLBANK_ADDRESS, "updateBalance(string,uint256)", "alice", 1000),
+             c(SMALLBANK_ADDRESS, "updateSaving(string,uint256)", "alice", 200),
+             c(SMALLBANK_ADDRESS, "sendPayment(string,string,uint256)", "alice", "bob", 400),
+             c(SMALLBANK_ADDRESS, "amalgamate(string,string)", "alice", "bob"),
+             c(SMALLBANK_ADDRESS, "writeCheck(string,uint256)", "bob", 1),
+             c(SMALLBANK_ADDRESS, "getBalance(string)", "bob")]]
+
+
+def _cpu_heavy_run(c):
+    return [[c(CPU_HEAVY_ADDRESS, "sort(uint256,uint256)", 50, 7),
+             c(CPU_HEAVY_ADDRESS, "sort(uint256,uint256)", 1_000_001, 7),  # too large: a fault
+             c(CPU_HEAVY_ADDRESS, "sort(uint256,uint256)", 0, 0),
+             c(CPU_HEAVY_ADDRESS, "sort(uint256,uint256)", 300, 1)]]
+
+
+RUN_CASES = [
+    _full_user_add, _name_added_twice, _empty_user, _save_draw_transfer_chain,
+    _insufficient_and_overflow, _unknown_selector_mid_run, _truncated_input_mid_run,
+    _fault_drops_its_writes, _frozen_sender_mid_run, _abolished_sender_mid_run,
+    _freeze_takes_effect_next_block, _governor_run, _broken_by_evm_create_and_precompiled,
+    _run_of_one, _smallbank_run, _cpu_heavy_run,
+]
+
+
+
+
+def _execute_blocks(suite, case, framed: bool):
+    """-> per block (receipts, state root, receipts root), through the run
+    frame or transaction by transaction."""
+    backend = MemoryStorage()
+    backend.set_row("s_config", b"auth_governors",
+                    Entry().set(("0x" + GOVERNOR.hex()).encode()))
+    ex = TransactionExecutor(backend, suite)
+    out, created = [], b""
+    for number, txs in enumerate(case(_Calls(suite)), start=1):
+        if callable(txs):
+            txs = txs(created)
+        ex.next_block_header(BlockHeader(number=number, timestamp=1_700_000_000 + number))
+        if framed:
+            receipts = ex.execute_transactions(txs)
+        else:
+            base = ex.reserve_contexts(len(txs))
+            receipts = [ex._execute_one(tx, ex._block, context_id=base + i)
+                        for i, tx in enumerate(txs)]
+        created = created or receipts[0].contract_address
+        state_root = ex.get_hash()
+        out.append((receipts, state_root, Block(receipts=receipts).calculate_receipts_root(suite)))
+        ex._block.storage.merge_into_prev()  # what the scheduler's 2PC does live
+    return out
+
+
+@pytest.mark.parametrize("suite_name", sorted(SUITES))
+@pytest.mark.parametrize("case", RUN_CASES, ids=lambda f: f.__name__.lstrip("_"))
+def test_run_frame_matches_per_transaction_path(case, suite_name):
+    suite = SUITES[suite_name]
+    framed = _execute_blocks(suite, case, framed=True)
+    plain = _execute_blocks(suite, case, framed=False)
+    assert len(framed) == len(plain)
+    for (got, got_state, got_root), (want, want_state, want_root) in zip(framed, plain):
+        assert [rc.status for rc in got] == [rc.status for rc in want]
+        assert [rc.output for rc in got] == [rc.output for rc in want]
+        assert [rc.gas_used for rc in got] == [rc.gas_used for rc in want]
+        assert [rc.encode() for rc in got] == [flat_receipt(rc) for rc in want]
+        assert [rc.hash(suite) for rc in got] == [suite.hash(flat_receipt(rc)) for rc in want]
+        assert [rc.encode() for rc in want] == [flat_receipt(rc) for rc in want]
+        assert got_state == want_state and got_root == want_root
+        leaves = np.frombuffer(b"".join(suite.hash(flat_receipt(rc)) for rc in want),
+                               dtype=np.uint8).reshape(-1, 32)
+        assert got_root == suite.merkle_root_async(leaves)()
+
+
+def test_run_frame_cases_show_what_they_claim():
+    """The cases above compare two paths; this pins the outcomes they are
+    named for, so an equal pair of wrong answers cannot pass."""
+    def statuses(case, block=-1):
+        return [rc.status for rc in _execute_blocks(SUITE, case, framed=True)[block][0]]
+
+    def codes(case, block=-1):
+        return [CODEC.decode_output(["uint256"], rc.output)[0]
+                if rc.status == 0 and len(rc.output) == 32 else None
+                for rc in _execute_blocks(SUITE, case, framed=True)[block][0]]
+
+    fault = int(TransactionStatus.PRECOMPILED_ERROR)
+    assert codes(_name_added_twice) == [0, 0, 2, 0]
+    assert codes(_empty_user) == [0, 1, 1, 0]
+    assert codes(_save_draw_transfer_chain)[:7] == [0] * 7
+    last = _execute_blocks(SUITE, _save_draw_transfer_chain, framed=True)[0][0][-1]
+    assert CODEC.decode_output(["uint256", "uint256"], last.output) == [0, 70]
+    assert codes(_insufficient_and_overflow) == [0, 0, 4, 4, 3, 5, 2, 3, 1, 0]
+    assert statuses(_unknown_selector_mid_run) == [0, fault, 0, fault, 0]
+    assert statuses(_truncated_input_mid_run) == [0, fault, fault, 0, fault, 0]
+    assert statuses(_fault_drops_its_writes) == [0, fault, fault, 0, 0, 0]
+    rcs = _execute_blocks(SUITE, _fault_drops_its_writes, framed=True)[0][0]
+    assert [CODEC.decode_output(["uint256"], rc.output)[0] for rc in rcs[-2:]] == [6, 4]
+    frozen = int(TransactionStatus.ACCOUNT_FROZEN)
+    assert statuses(_frozen_sender_mid_run) == [0, frozen, 0, frozen, 0]
+    assert statuses(_abolished_sender_mid_run) == [
+        0, int(TransactionStatus.ACCOUNT_ABOLISHED), 0]
+    assert statuses(_freeze_takes_effect_next_block, block=0) == [0] * 8
+    assert statuses(_freeze_takes_effect_next_block, block=1) == [frozen, 0, frozen]
+    assert statuses(_governor_run) == [0, 0, 0, fault, fault, 0]
+    mixed = statuses(_broken_by_evm_create_and_precompiled)
+    assert mixed[9] == int(TransactionStatus.CALL_ADDRESS_ERROR)
+    assert [s for i, s in enumerate(mixed) if i != 9] == [0] * 14
+    assert codes(_broken_by_evm_create_and_precompiled)[4] == 2  # "a" was added in the run before
+    assert codes(_run_of_one, block=1) == [2]
+    assert statuses(_cpu_heavy_run) == [0, fault, 0, 0]
+
+
+def test_run_frame_counts_its_transactions():
+    """Runs of two or more take the frame and say so: the run counter, the
+    mode "run" of the batch histograms and one executor.run span a run."""
+    from fisco_bcos_tpu.observability import TRACER
+
+    def counted():
+        return sum(REGISTRY.counters_matching("fisco_executor_run_txs_total").values())
+
+    c = _Calls(SUITE)
+    ex = TransactionExecutor(MemoryStorage(), SUITE)
+    ex.next_block_header(BlockHeader(number=1))
+    before = counted()
+    TRACER.clear()
+    with TRACER.span("test.block"):
+        rcs = ex.execute_transactions(
+            [c.dag(ADD, f"u{i}", i) for i in range(5)]
+            + [c(SMALLBANK_ADDRESS, "updateBalance(string,uint256)", "a", 1)]  # a run of one
+            + [c.dag(ADD, f"v{i}", i) for i in range(3)]
+        )
+    assert [rc.status for rc in rcs] == [0] * 9
+    assert counted() - before == 8
+    runs = [r for r in TRACER.spans() if r.name == "executor.run"]
+    assert [r.attrs["txs"] for r in runs] == [5, 3]
+    assert {r.attrs["callee"] for r in runs} == {DAG_TRANSFER_ADDRESS.hex()}
+    assert {r.parent for r in runs} == {"executor.execute"}

@@ -16,6 +16,7 @@ from fisco_bcos_tpu.protocol import (
     TransactionReceipt,
 )
 from fisco_bcos_tpu.protocol.transaction import hash_transactions_batch
+from receipt_ref import flat_receipt
 
 SUITES = [ecdsa_suite(), sm_suite()]
 
@@ -165,3 +166,69 @@ def test_block_roots_match_merkle():
     # metadata-only block (proposal form) yields the same root
     prop = Block(tx_metadata=hashes)
     assert prop.calculate_txs_root(suite) == tree.root
+
+
+# -- receipts: the wire form packed directly, a block's digests as one batch --
+
+RECEIPTS = {
+    "default": TransactionReceipt(),
+    "precompiled": TransactionReceipt(gas_used=16_000, output=b"\x00" * 32, block_number=41),
+    "fault": TransactionReceipt(version=2, gas_used=3_000_000_000, status=15,
+                                output=bytearray(b"precompile fault: x"), block_number=1 << 40),
+    "deploy_with_logs": TransactionReceipt(
+        version=1, gas_used=21_000, contract_address=b"\x22" * 20, output=b"\x01",
+        log_entries=[LogEntry(b"\x22" * 20, [b"\xaa" * 32, b"\xbb" * 32], b"payload"), LogEntry()],
+        block_number=7, effective_gas_price="0x12a05f200"),
+    "negative_height": TransactionReceipt(block_number=-1, effective_gas_price="é"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(RECEIPTS))
+def test_receipt_wire_form_is_the_flat_codecs(name):
+    rc = RECEIPTS[name]
+    rc.invalidate_caches()
+    assert rc.encode() == flat_receipt(rc)
+    again = TransactionReceipt.decode(rc.encode())
+    assert (again.version, again.gas_used, again.contract_address, again.status,
+            bytes(again.output), again.block_number, again.effective_gas_price) == (
+        rc.version, rc.gas_used, rc.contract_address, rc.status,
+        bytes(rc.output), rc.block_number, rc.effective_gas_price)
+    assert again.log_entries == rc.log_entries
+
+
+@pytest.mark.parametrize("suite", SUITES, ids=["ecdsa", "sm"])
+def test_receipts_are_hashed_as_one_batch(suite, monkeypatch):
+    from fisco_bcos_tpu.protocol.receipt import hash_receipts
+
+    receipts = [TransactionReceipt(gas_used=16_000 + i, output=bytes([i]) * (i % 40),
+                                   block_number=3) for i in range(70)]
+    receipts[5].log_entries = [LogEntry(b"\x01" * 20, [b"\x02" * 32], b"d")]
+    already = receipts[9].hash(suite)  # hashed before the batch: left alone
+    calls = []
+    real = type(suite.hash_impl).hash_each
+    monkeypatch.setattr(type(suite.hash_impl), "hash_each",
+                        lambda self, msgs: calls.append(len(msgs)) or real(self, msgs))
+    want = [suite.hash(flat_receipt(rc)) for rc in receipts]
+    assert hash_receipts(receipts, suite) == want
+    assert calls == [69] and receipts[9]._hash is already
+    assert all(rc._hash == w and rc._enc == flat_receipt(rc) for rc, w in zip(receipts, want))
+    # the root and a later hash() find them: nothing is hashed again
+    monkeypatch.setattr(type(suite.hash_impl), "hash", lambda self, data: 1 / 0)
+    leaves = np.frombuffer(b"".join(want), dtype=np.uint8).reshape(-1, 32)
+    assert Block(receipts=receipts).calculate_receipts_root(suite) == MerkleTree(
+        leaves, hasher=suite.hash_impl.name).root
+    assert [rc.hash(suite) for rc in receipts] == want and calls == [69]
+    assert hash_receipts([], suite) == []
+
+
+@pytest.mark.parametrize("suite", SUITES, ids=["ecdsa", "sm"])
+def test_hash_each_is_hash_of_each(suite, monkeypatch):
+    from fisco_bcos_tpu import native_bind
+
+    msgs = [b"", b"abc", b"\xff" * 135, b"\x00" * 136, b"x" * 137, b"\x80" * 55, b"\x80" * 56,
+            bytes(range(256)) * 5] + [bytes([i]) * 72 for i in range(40)]
+    want = [suite.hash(m) for m in msgs]
+    assert suite.hash_each(msgs) == want and suite.hash_each([]) == []
+    # no native core: the per-message loop
+    monkeypatch.setattr(native_bind, "load", lambda: None)
+    assert suite.hash_each(msgs) == want
