@@ -658,7 +658,17 @@ class TransactionExecutor:
         if workers <= 0:
             workers = min(8, _os.cpu_count() or 1)
         use_pool = workers > 1 and not _os.environ.get("FISCO_DAG_SERIAL")
-        levels = self.dag_levels(txs)
+        # seconds of this call by stage, and the transactions run as futures:
+        # the DAG runner's own split (fisco_executor_dag_stage_seconds_total)
+        stage = {"levelize": 0.0, "run": 0.0, "validate": 0.0}
+        pooled_txs = 0
+
+        def note(name: str, key: str, t0: float, **attrs) -> None:
+            """One finished interval of the driving thread: a span under
+            ``executor.execute`` and its seconds for the stage counter."""
+            dur = time.perf_counter() - t0
+            stage[key] += dur
+            TRACER.record(name, t0, dur, **attrs)
 
         def shadow_ctx() -> BlockContext:
             return BlockContext(
@@ -676,78 +686,113 @@ class TransactionExecutor:
             # tests/test_abi_conflict.py::test_reordering_levels_keep_receipt_identity)
             out: list = [None] * len(txs)
             for level in levels:
+                t_level = time.perf_counter()
                 for i in level:
                     out[i] = self._execute_one(txs[i], block, context_id=base + i)
+                note("executor.dag_level", "run", t_level,
+                     width=len(level), pooled=False)
             return out
 
-        receipts: list[TransactionReceipt | None] = [None] * len(txs)
-        shadow = shadow_ctx()
-        conflict = False
-        if use_pool:
-            from concurrent.futures import ThreadPoolExecutor
-
-            with ThreadPoolExecutor(workers) as pool:
-                for level in levels:
-                    accesses: dict[int, list] = {i: [] for i in level}
-                    if len(level) > 1:
-                        futs = {
-                            i: pool.submit(
-                                self._execute_one, txs[i], shadow,
-                                context_id=base + i,
-                                access_out=accesses[i],
-                            )
-                            for i in level
-                        }
-                        for i, fut in futs.items():
-                            receipts[i] = fut.result()
-                        # runtime validation: every key written by a level
-                        # member must be untouched (read OR written) by its
-                        # peers, else the declarations lied and schedule
-                        # order would decide the state
-                        touched: dict[tuple, int] = {}
-                        for i in level:
-                            ov = accesses[i][0]
-                            for k in ov.last_writes | ov.read_track:
-                                owner = touched.setdefault(k, i)
-                                if owner != i and (
-                                    k in ov.last_writes
-                                    or k in accesses[owner][0].last_writes
-                                ):
-                                    conflict = True
-                        if conflict:
-                            _log.warning(
-                                "DAG level of %d txs touched overlapping "
-                                "state its conflict declarations called "
-                                "disjoint; re-executing the block serially",
-                                len(level),
-                            )
-                            break
-                    else:
-                        for i in level:
-                            receipts[i] = self._execute_one(
-                                txs[i], shadow, context_id=base + i
-                            )
-        else:
-            receipts = run_serial(shadow)
-        if conflict:
-            # the discarded attempt's suicide registrations die with its
-            # shadow context; the serial rerun regenerates them — the same
-            # deterministic outcome on every node
+        with TRACER.span("executor.execute", mode="dag", txs=len(txs)) as span:
+            t_levelize = time.perf_counter()
+            levels = self.dag_levels(txs)
+            note("executor.dag_levelize", "levelize", t_levelize,
+                 txs=len(txs), levels=len(levels))
+            receipts: list[TransactionReceipt | None] = [None] * len(txs)
             shadow = shadow_ctx()
-            receipts = run_serial(shadow)
-        shadow.storage.merge_into_prev()
-        self._block.suicides |= shadow.suicides
-        dur = time.perf_counter() - t_dag0
-        self._record_batch("dag", len(txs), dur)
+            conflict = False
+            if use_pool:
+                from concurrent.futures import ThreadPoolExecutor
+
+                with ThreadPoolExecutor(workers) as pool:
+                    for level in levels:
+                        accesses: dict[int, list] = {i: [] for i in level}
+                        t_level = time.perf_counter()
+                        if len(level) > 1:
+                            futs = {
+                                i: pool.submit(
+                                    self._execute_one, txs[i], shadow,
+                                    context_id=base + i,
+                                    access_out=accesses[i],
+                                )
+                                for i in level
+                            }
+                            for i, fut in futs.items():
+                                receipts[i] = fut.result()
+                            pooled_txs += len(level)
+                            note("executor.dag_level", "run", t_level,
+                                 width=len(level), pooled=True)
+                            # runtime validation: every key written by a level
+                            # member must be untouched (read OR written) by its
+                            # peers, else the declarations lied and schedule
+                            # order would decide the state
+                            t_validate = time.perf_counter()
+                            touched: dict[tuple, int] = {}
+                            for i in level:
+                                ov = accesses[i][0]
+                                for k in ov.last_writes | ov.read_track:
+                                    owner = touched.setdefault(k, i)
+                                    if owner != i and (
+                                        k in ov.last_writes
+                                        or k in accesses[owner][0].last_writes
+                                    ):
+                                        conflict = True
+                            note("executor.dag_validate", "validate", t_validate,
+                                 width=len(level), conflict=conflict)
+                            if conflict:
+                                _log.warning(
+                                    "DAG level of %d txs touched overlapping "
+                                    "state its conflict declarations called "
+                                    "disjoint; re-executing the block serially",
+                                    len(level),
+                                )
+                                break
+                        else:
+                            for i in level:
+                                receipts[i] = self._execute_one(
+                                    txs[i], shadow, context_id=base + i
+                                )
+                            note("executor.dag_level", "run", t_level,
+                                 width=len(level), pooled=False)
+            else:
+                receipts = run_serial(shadow)
+            if conflict:
+                # the discarded attempt's suicide registrations die with its
+                # shadow context; the serial rerun regenerates them — the same
+                # deterministic outcome on every node
+                shadow = shadow_ctx()
+                receipts = run_serial(shadow)
+            shadow.storage.merge_into_prev()
+            self._block.suicides |= shadow.suicides
+            span.set(levels=len(levels), reruns=int(conflict))
+        self._record_batch("dag", len(txs), time.perf_counter() - t_dag0)
+        REGISTRY.counter_add(
+            "fisco_executor_dag_levels_total",
+            len(levels),
+            help="dependent levels the DAG runner cut its batches into "
+            "(a level's txs share no declared conflict key)",
+        )
+        for key, seconds in stage.items():
+            REGISTRY.counter_add(
+                f'fisco_executor_dag_stage_seconds_total{{stage="{key}"}}',
+                seconds,
+                help="seconds of the DAG runner by stage: levelize (conflict "
+                "keys + levels), run (the levels' execution, pooled and "
+                "inline, a serial rerun too), validate (the pairwise check "
+                "of a pooled level's access sets)",
+            )
+        REGISTRY.counter_add(
+            "fisco_executor_dag_pooled_txs_total",
+            pooled_txs,
+            help="txs the DAG runner executed as futures on its thread pool "
+            "(members of a level wider than one)",
+        )
         if conflict:
             REGISTRY.counter_add(
                 "fisco_executor_dag_conflict_reruns_total",
                 help="DAG levels whose conflict declarations lied "
                 "(block re-executed serially)",
             )
-        TRACER.record(
-            "executor.execute", t_dag0, dur, mode="dag", txs=len(txs)
-        )
         return receipts  # type: ignore[return-value]
 
     # -- read-only call (call:672) ------------------------------------------

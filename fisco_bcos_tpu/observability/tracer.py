@@ -50,6 +50,7 @@ A span that ran longer than :data:`SLOW_SPAN_S` hands the flight recorder one
 from __future__ import annotations
 
 import contextvars
+import itertools
 import json
 import os
 import random
@@ -124,6 +125,10 @@ def trace_hex(ctx: TraceContext | None) -> str | None:
 # which names at most this many (span name, thread) witnesses
 SLOW_SPAN_S = 1.0
 SLOW_SPAN_WITNESSES = 32
+# every this-many-th append notes the clock beside the count of appends, so a
+# slow span's witnesses are read from what was appended since it began and
+# not from the whole ring (0.1 s a span at 262,144 records: PERF.md §6, PR 32)
+MARK_EVERY = 1024
 # the ring holds a benchmark window with a margin of two (PERF.md §6, PR 24):
 # the busiest cell writes ~26k records in 51 s; ~0.35 KB a record
 DEFAULT_CAPACITY = 65536
@@ -310,6 +315,8 @@ class Tracer:
                 sample_rate = 1.0
         self.sample_rate = sample_rate
         self._buf: deque[SpanRecord] = deque()
+        self._appended = 0
+        self._marks: deque[tuple[float, int]] = deque(maxlen=1024)
         self._lock = threading.Lock()
         self._tls = threading.local()
         # drop accounting: plain ints (GIL-cheap on the hot path), mirrored
@@ -538,6 +545,9 @@ class Tracer:
                 self._buf.popleft()
                 self._dropped["ring_evict"] += 1
             self._buf.append(rec)
+            self._appended += 1
+            if not self._appended % MARK_EVERY:
+                self._marks.append((time.perf_counter(), self._appended))
         if rec.dur >= SLOW_SPAN_S and not rec.derived:
             self._note_slow(rec)
 
@@ -577,7 +587,7 @@ class Tracer:
                 return
             lo, hi = rec.ts, rec.ts + rec.dur
             by: dict[tuple[str, int], list] = {}
-            for r in self.spans():
+            for r in self._since(lo):
                 if r is rec or r.derived:
                     continue
                 overlap = min(hi, r.ts + r.dur) - max(lo, r.ts)
@@ -613,6 +623,24 @@ class Tracer:
             self._drain_gc()
         with self._lock:
             return list(self._buf)
+
+    def _since(self, t: float) -> list[SpanRecord]:
+        """Every record that can overlap an interval beginning at clock ``t``,
+        oldest first: those appended since the last mark at or before ``t``
+        (a record is appended no earlier than it ended, so one appended
+        before ``t`` ended before it). The whole ring where no mark is that
+        old."""
+        if _GC_PENDING and self._takes_gc:
+            self._drain_gc()
+        with self._lock:
+            n = self._appended
+            for at, count in reversed(self._marks):
+                if at <= t:
+                    n -= count
+                    break
+            recent = list(itertools.islice(reversed(self._buf), n))
+        recent.reverse()
+        return recent
 
     def clear(self) -> None:
         with self._lock:

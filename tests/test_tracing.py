@@ -629,6 +629,41 @@ def test_slow_span_keeps_its_witnesses():
     assert not any(e["name"] == "slowtest.quick" for e in FLIGHT.snapshot())
 
 
+def test_slow_span_reads_what_was_appended_since_it_began():
+    """The witnesses of a slow span come from the records appended since it
+    began (to the mark before: every ``MARK_EVERY``-th append notes the
+    clock), not from a walk of the whole ring: at the 262,144 records of the
+    parallel-transfer cell a walk cost 0.1 s, and every block over a second
+    paid one (PERF.md §6, PR 32). What they name is what the whole ring
+    would have named."""
+    import time
+
+    from fisco_bcos_tpu.observability.flight import FLIGHT
+    from fisco_bcos_tpu.observability.tracer import MARK_EVERY
+
+    tr = Tracer(capacity=1 << 16)
+    t = time.perf_counter()
+    for i in range(20 * MARK_EVERY):  # long over before the slow span began
+        tr.record("sincetest.old", t - 100.0 + i * 1e-3, 1e-3, width=i)
+    lo = time.perf_counter()
+    tr.record("sincetest.gap", lo, 0.5, derived=True)
+    for i in range(MARK_EVERY + 7):  # inside its interval, across a mark
+        tr.record("sincetest.level", lo + i * 1e-4, 1e-4)
+    recent = tr._since(lo)
+    assert MARK_EVERY + 8 <= len(recent) <= 2 * MARK_EVERY + 8
+    assert recent == tr.spans()[-len(recent):]  # oldest first, none skipped
+    tr.record("sincetest.stalled", lo, 1.5)
+    (ev,) = [e for e in FLIGHT.snapshot()
+             if e["category"] == "slow_span" and e["name"] == "sincetest.stalled"]
+    (level,) = ev["detail"]["overlaps"]
+    assert level["name"] == "sincetest.level" and level["n"] == MARK_EVERY + 7
+    # before any mark, or for an interval older than every mark: the ring
+    assert len(tr._since(t - 200.0)) == len(tr.spans())
+    small = Tracer(capacity=8)
+    small.record("a", t, 0.1)
+    assert [r.name for r in small._since(t + 5.0)] == ["a"]
+
+
 def test_ring_holds_a_window_and_a_record_stays_small():
     """The default ring holds a 51 s window of the busiest cell with a margin
     of two (PERF.md §6, PR 24); a record without attributes shares one empty
