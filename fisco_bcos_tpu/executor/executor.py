@@ -29,8 +29,10 @@ from __future__ import annotations
 
 import hashlib
 import itertools
+import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 from ..codec.abi import ABICodec
@@ -138,6 +140,88 @@ def _call_precompile(
     except Exception as e:  # malformed input etc. — revert, never crash
         return _PRECOMPILED_ERROR, f"precompile fault: {e}".encode(), 0, []
     return 0, result.output, max(gas - result.gas_used, 0), result.logs
+
+
+_NO_KEYS: frozenset = frozenset()
+
+
+class _PrecompileFrame:
+    """Top-level calls to registry precompiles on one block's state, one
+    after another, without a frame chain each: one call context whose
+    sender and callee are set a transaction, one overlay that a success
+    merges into the block and a fault empties (``_call_precompile``: the
+    statuses and outputs of ``_run_registry_precompile``), the sender's
+    governance status looked up once a sender a block. No Executive, EVMCall
+    or EVMHost: the callee is known to be a precompile, which uses none of
+    them. The serial batch's runs and the DAG runner's levels both execute
+    through ``execute``; they differ in how they group transactions and in
+    whether a call's access sets are kept."""
+
+    __slots__ = ("block", "overlay", "ctx")
+
+    def __init__(self, executor: "TransactionExecutor", block: BlockContext):
+        self.block = block
+        self.overlay = StateStorage(block.storage)
+        self.ctx = PrecompiledCallContext(
+            storage=self.overlay,
+            suite=executor.suite,
+            codec=executor.codec,
+            block_number=block.number,
+            timestamp=block.timestamp,
+            gas_limit=block.gas_limit,
+        )
+
+    def execute(
+        self, pre: Precompiled, tx: Transaction, access_out: list | None = None
+    ) -> TransactionReceipt:
+        """One call, its receipt as ``_execute_one`` gives it. With
+        `access_out`, what ``_execute_one(..., access_out=...)`` tracks is
+        appended to it as (keys read through to the state below, keys
+        written; none after a fault). The overlay is empty between calls,
+        so a read of an earlier call's write falls through and is seen."""
+        block, overlay, ctx = self.block, self.overlay, self.ctx
+        gas, number, sender = block.gas_limit, block.number, tx.sender
+        rc = TransactionReceipt(version=tx.version, block_number=number)
+        overlay.read_track = reads = None if access_out is None else set()
+        writes = _NO_KEYS
+        statuses = block.account_statuses
+        st = statuses.get(sender)
+        if st is None:
+            st = statuses[sender] = account_status(block.storage, sender, number)
+        refusal = _ACCOUNT_REFUSALS.get(st)
+        if refusal is not None:
+            rc.status, rc.output = refusal
+            rc.gas_used = BASE_GAS
+        else:
+            ctx.sender = ctx.origin = sender
+            ctx.to = tx.to
+            rc.status, rc.output, gas_left, rc.log_entries = _call_precompile(
+                pre, ctx, tx.input, gas
+            )
+            rc.gas_used = max(gas - gas_left, BASE_GAS)
+            if rc.status == 0:
+                if reads is not None:
+                    writes = set(overlay._data)
+                overlay.merge_into_prev()
+            else:
+                overlay.discard()
+        if reads is not None:
+            access_out.append((reads, writes))
+        return rc
+
+
+def _level_conflicts(accesses: list[tuple[set, set]]) -> bool:
+    """Whether a level's members touched overlapping state: every key a
+    member wrote must be untouched (read OR written) by its peers, else the
+    declarations lied and schedule order would decide the state.
+    `accesses`: a member's (keys read through, keys written), in any order."""
+    touched: dict[tuple, int] = {}
+    for i, (reads, writes) in enumerate(accesses):
+        for k in writes | reads:
+            owner = touched.setdefault(k, i)
+            if owner != i and (k in writes or k in accesses[owner][1]):
+                return True
+    return False
 
 
 class TransactionExecutor:
@@ -354,15 +438,14 @@ class TransactionExecutor:
         """One tx frame on its own overlay; merge on success, drop on revert
         (the reference's TransactionExecutive + revert semantics).
 
-        With `access_out`, the tx overlay is appended to it and tracks the
-        tx's external read-set (overlay.read_track) and, on success, its
-        write-set (overlay.last_writes) — the DAG runner's runtime conflict
-        validation inputs."""
+        With `access_out`, (the tx's external read-set, its write-set) is
+        appended to it, the second filled on success — the DAG runner's
+        runtime conflict validation inputs."""
         overlay = StateStorage(block.storage)
         if access_out is not None:
             overlay.read_track = set()
-            overlay.last_writes = set()
-            access_out.append(overlay)
+            writes: set = set()
+            access_out.append((overlay.read_track, writes))
         rc = TransactionReceipt(version=tx.version, block_number=block.number)
         is_create = not tx.to
         if not is_create and not self.known_callee(tx.to, overlay):
@@ -421,7 +504,7 @@ class TransactionExecutor:
 
                 bind_admin(overlay, res.create_address, tx.sender)
             if access_out is not None:
-                overlay.last_writes = set(overlay._data)
+                writes.update(overlay._data)
             overlay.merge_into_prev()
         return rc
 
@@ -477,48 +560,11 @@ class TransactionExecutor:
         self, pre: Precompiled, txs: list[Transaction], block: BlockContext
     ) -> list[TransactionReceipt]:
         """Consecutive calls to one registry precompile, in order, in one
-        frame: one call context whose sender is set a transaction, one
-        overlay that a success merges into the block and a fault empties
-        (``_call_precompile``: the statuses and outputs of
-        ``_run_registry_precompile``), the sender's governance status looked
-        up once a sender a block. No Executive, EVMCall or EVMHost: the
-        callee is known to be a precompile, which uses none of them."""
+        frame (``_PrecompileFrame``)."""
         t0 = time.perf_counter()
-        gas, number = block.gas_limit, block.number
-        overlay = StateStorage(block.storage)
-        ctx = PrecompiledCallContext(
-            storage=overlay,
-            suite=self.suite,
-            codec=self.codec,
-            to=txs[0].to,
-            block_number=number,
-            timestamp=block.timestamp,
-            gas_limit=gas,
-        )
-        statuses = block.account_statuses
-        receipts: list[TransactionReceipt] = []
+        frame = _PrecompileFrame(self, block)
         with TRACER.span("executor.run", callee=txs[0].to.hex(), txs=len(txs)):
-            for tx in txs:
-                sender = tx.sender
-                rc = TransactionReceipt(version=tx.version, block_number=number)
-                receipts.append(rc)
-                st = statuses.get(sender)
-                if st is None:
-                    st = statuses[sender] = account_status(block.storage, sender, number)
-                refusal = _ACCOUNT_REFUSALS.get(st)
-                if refusal is not None:
-                    rc.status, rc.output = refusal
-                    rc.gas_used = BASE_GAS
-                    continue
-                ctx.sender = ctx.origin = sender
-                rc.status, rc.output, gas_left, rc.log_entries = _call_precompile(
-                    pre, ctx, tx.input, gas
-                )
-                rc.gas_used = max(gas - gas_left, BASE_GAS)
-                if rc.status == 0:
-                    overlay.merge_into_prev()
-                else:
-                    overlay.discard()
+            receipts = [frame.execute(pre, tx) for tx in txs]
         self._record_batch("run", len(txs), time.perf_counter() - t0)
         REGISTRY.counter_add(
             "fisco_executor_run_txs_total",
@@ -629,39 +675,45 @@ class TransactionExecutor:
     def _dag_execute_transactions(
         self, txs: list[Transaction]
     ) -> list[TransactionReceipt]:
-        """Conflict-DAG execution: level-by-level; txs WITHIN a level run on
-        a thread pool (the reference's TxDAG2 + tbb::parallel_for axis,
-        SURVEY §2.8 row 5), VALIDATED at runtime. Real parallelism comes
-        from the native EVM engine and native crypto calls releasing the
-        GIL; pure-Python precompile frames interleave under the GIL.
+        """Conflict-DAG execution (the reference's TxDAG2 axis, SURVEY §2.8
+        row 5): level by level, a level's members each in one of two ways,
+        chosen a member from its callee, then VALIDATED at runtime.
 
-        Determinism contract: context ids are pre-reserved per tx index and
-        each tx runs on its own overlay, so for txs whose declared conflict
-        sets are HONEST (disjoint state), any schedule produces serial-
-        identical results. Because a lying conflictFields declaration must
-        not let host core count leak into the state root (one node pools,
-        another doesn't), every pooled level's actual read/write sets are
-        checked pairwise after it completes; ANY overlap discards the whole
-        attempt and re-executes the block serially — the same deterministic
-        outcome every node computes. The whole DAG run happens on a shadow
-        overlay so the discard is clean. FISCO_DAG_SERIAL=1 pins serial."""
+        A call to a registry precompile is a pure-Python frame, which a
+        thread could only run in turns with the interpreter: it executes on
+        this thread, in index order, inside the one ``_PrecompileFrame`` of
+        the call (one of the schedules a pool could have produced, and the
+        only one that is the same on every host). Any other callee (user
+        bytecode: the native EVM engine and native crypto release the GIL)
+        goes to a thread pool as ``_execute_one`` on its own overlay, where
+        its level is wider than one and FISCO_DAG_WORKERS allows a pool; the
+        pool is opened by the first such member.
+
+        Determinism contract: context ids are pre-reserved per tx index, so
+        for txs whose declared conflict sets are HONEST (disjoint state),
+        any schedule produces serial-identical results. Because a lying
+        declaration must not let the schedule leak into the state root,
+        every level wider than one has its members' actual read/write sets
+        checked pairwise after it completes, whatever ran them; ANY overlap
+        discards the whole attempt and re-executes the block serially
+        through ``_execute_one``, the same deterministic outcome every node
+        computes. The whole DAG run happens on a shadow overlay so the
+        discard is clean. FISCO_DAG_SERIAL=1 pins that serial loop."""
         if self._block is None:
             raise RuntimeError("call next_block_header first")
         t_dag0 = time.perf_counter()
         base = self.reserve_contexts(len(txs))
-        import os as _os
-
         try:
-            workers = int(_os.environ.get("FISCO_DAG_WORKERS", "0"))
+            workers = int(os.environ.get("FISCO_DAG_WORKERS", "0"))
         except ValueError:
             workers = 0
         if workers <= 0:
-            workers = min(8, _os.cpu_count() or 1)
-        use_pool = workers > 1 and not _os.environ.get("FISCO_DAG_SERIAL")
-        # seconds of this call by stage, and the transactions run as futures:
-        # the DAG runner's own split (fisco_executor_dag_stage_seconds_total)
+            workers = min(8, os.cpu_count() or 1)
+        registry = self.registry
+        # seconds of this call by stage, and the transactions by how they
+        # ran: the DAG runner's own split (fisco_executor_dag_*)
         stage = {"levelize": 0.0, "run": 0.0, "validate": 0.0}
-        pooled_txs = 0
+        pooled_txs = framed_txs = 0
 
         def note(name: str, key: str, t0: float, **attrs) -> None:
             """One finished interval of the driving thread: a span under
@@ -676,6 +728,7 @@ class TransactionExecutor:
                 timestamp=self._block.timestamp,
                 gas_limit=self._block.gas_limit,
                 storage=StateStorage(self._block.storage),
+                account_statuses=self._block.account_statuses,
             )
 
         def run_serial(block: BlockContext) -> list:
@@ -690,7 +743,63 @@ class TransactionExecutor:
                 for i in level:
                     out[i] = self._execute_one(txs[i], block, context_id=base + i)
                 note("executor.dag_level", "run", t_level,
-                     width=len(level), pooled=False)
+                     width=len(level), pooled=False, framed=0)
+            return out
+
+        def run_levels(block: BlockContext) -> list | None:
+            """The levels on `block`, or None where a level's check failed."""
+            nonlocal pooled_txs, framed_txs
+            out: list = [None] * len(txs)
+            frame = _PrecompileFrame(self, block)
+            pool = None
+            try:
+                for level in levels:
+                    t_level = time.perf_counter()
+                    wide = len(level) > 1
+                    # every member of a wide level appends its access sets
+                    tracked: list | None = [] if wide else None
+                    futs = {}
+                    framed = 0
+                    for i in level:
+                        tx = txs[i]
+                        pre = registry.get(tx.to)
+                        if pre is not None:
+                            out[i] = frame.execute(pre, tx, tracked)
+                            framed += 1
+                        elif wide and workers > 1:
+                            if pool is None:
+                                pool = ThreadPoolExecutor(workers)
+                            futs[i] = pool.submit(
+                                self._execute_one, tx, block,
+                                context_id=base + i, access_out=tracked,
+                            )
+                        else:
+                            out[i] = self._execute_one(
+                                tx, block, context_id=base + i, access_out=tracked
+                            )
+                    for i, fut in futs.items():
+                        out[i] = fut.result()
+                    framed_txs += framed
+                    pooled_txs += len(futs)
+                    note("executor.dag_level", "run", t_level,
+                         width=len(level), pooled=bool(futs), framed=framed)
+                    if not wide:
+                        continue
+                    t_validate = time.perf_counter()
+                    conflict = _level_conflicts(tracked)
+                    note("executor.dag_validate", "validate", t_validate,
+                         width=len(level), conflict=conflict)
+                    if conflict:
+                        _log.warning(
+                            "DAG level of %d txs touched overlapping "
+                            "state its conflict declarations called "
+                            "disjoint; re-executing the block serially",
+                            len(level),
+                        )
+                        return None
+            finally:
+                if pool is not None:
+                    pool.shutdown()
             return out
 
         with TRACER.span("executor.execute", mode="dag", txs=len(txs)) as span:
@@ -698,64 +807,10 @@ class TransactionExecutor:
             levels = self.dag_levels(txs)
             note("executor.dag_levelize", "levelize", t_levelize,
                  txs=len(txs), levels=len(levels))
-            receipts: list[TransactionReceipt | None] = [None] * len(txs)
             shadow = shadow_ctx()
-            conflict = False
-            if use_pool:
-                from concurrent.futures import ThreadPoolExecutor
-
-                with ThreadPoolExecutor(workers) as pool:
-                    for level in levels:
-                        accesses: dict[int, list] = {i: [] for i in level}
-                        t_level = time.perf_counter()
-                        if len(level) > 1:
-                            futs = {
-                                i: pool.submit(
-                                    self._execute_one, txs[i], shadow,
-                                    context_id=base + i,
-                                    access_out=accesses[i],
-                                )
-                                for i in level
-                            }
-                            for i, fut in futs.items():
-                                receipts[i] = fut.result()
-                            pooled_txs += len(level)
-                            note("executor.dag_level", "run", t_level,
-                                 width=len(level), pooled=True)
-                            # runtime validation: every key written by a level
-                            # member must be untouched (read OR written) by its
-                            # peers, else the declarations lied and schedule
-                            # order would decide the state
-                            t_validate = time.perf_counter()
-                            touched: dict[tuple, int] = {}
-                            for i in level:
-                                ov = accesses[i][0]
-                                for k in ov.last_writes | ov.read_track:
-                                    owner = touched.setdefault(k, i)
-                                    if owner != i and (
-                                        k in ov.last_writes
-                                        or k in accesses[owner][0].last_writes
-                                    ):
-                                        conflict = True
-                            note("executor.dag_validate", "validate", t_validate,
-                                 width=len(level), conflict=conflict)
-                            if conflict:
-                                _log.warning(
-                                    "DAG level of %d txs touched overlapping "
-                                    "state its conflict declarations called "
-                                    "disjoint; re-executing the block serially",
-                                    len(level),
-                                )
-                                break
-                        else:
-                            for i in level:
-                                receipts[i] = self._execute_one(
-                                    txs[i], shadow, context_id=base + i
-                                )
-                            note("executor.dag_level", "run", t_level,
-                                 width=len(level), pooled=False)
-            else:
-                receipts = run_serial(shadow)
+            run = run_serial if os.environ.get("FISCO_DAG_SERIAL") else run_levels
+            receipts = run(shadow)
+            conflict = receipts is None
             if conflict:
                 # the discarded attempt's suicide registrations die with its
                 # shadow context; the serial rerun regenerates them — the same
@@ -777,15 +832,23 @@ class TransactionExecutor:
                 f'fisco_executor_dag_stage_seconds_total{{stage="{key}"}}',
                 seconds,
                 help="seconds of the DAG runner by stage: levelize (conflict "
-                "keys + levels), run (the levels' execution, pooled and "
-                "inline, a serial rerun too), validate (the pairwise check "
-                "of a pooled level's access sets)",
+                "keys + levels), run (the levels' execution, framed, pooled "
+                "and inline, a serial rerun too), validate (the pairwise "
+                "check of the access sets of a level wider than one)",
             )
         REGISTRY.counter_add(
             "fisco_executor_dag_pooled_txs_total",
             pooled_txs,
             help="txs the DAG runner executed as futures on its thread pool "
-            "(members of a level wider than one)",
+            "(members of a level wider than one whose callee is no registry "
+            "precompile)",
+        )
+        REGISTRY.counter_add(
+            "fisco_executor_dag_framed_txs_total",
+            framed_txs,
+            help="txs the DAG runner executed inside its level frame, on the "
+            "thread that executes the block (calls to registry precompiles); "
+            "beside fisco_executor_batch_txs' dag sum, the frame's share",
         )
         if conflict:
             REGISTRY.counter_add(

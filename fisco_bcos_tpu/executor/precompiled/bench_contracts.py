@@ -11,7 +11,7 @@ from __future__ import annotations
 
 from functools import lru_cache
 
-from ...codec.abi import abi_encode
+from ...codec.abi import abi_encode, coder_for
 from ...storage.entry import Entry
 from .base import (
     Precompiled,
@@ -44,15 +44,16 @@ class DagTransferPrecompiled(Precompiled):
         self.register(codec, "userDraw(string,uint256)", self._draw)
         self.register(codec, "userBalance(string)", self._balance)
         self.register(codec, "userTransfer(string,string,uint256)", self._transfer)
+        # selector -> (its compiled decoder, how many leading user names)
         self._crit_sigs = {
-            codec.selector("userAdd(string,uint256)"): (["string", "uint256"], 1),
-            codec.selector("userSave(string,uint256)"): (["string", "uint256"], 1),
-            codec.selector("userDraw(string,uint256)"): (["string", "uint256"], 1),
-            codec.selector("userBalance(string)"): (["string"], 1),
-            codec.selector("userTransfer(string,string,uint256)"): (
-                ["string", "string", "uint256"],
-                2,
-            ),
+            codec.selector(sig): (coder_for(types).decode, n_users)
+            for sig, types, n_users in (
+                ("userAdd(string,uint256)", ("string", "uint256"), 1),
+                ("userSave(string,uint256)", ("string", "uint256"), 1),
+                ("userDraw(string,uint256)", ("string", "uint256"), 1),
+                ("userBalance(string)", ("string",), 1),
+                ("userTransfer(string,string,uint256)", ("string", "string", "uint256"), 2),
+            )
         }
 
     def criticals(self, codec, data: bytes):
@@ -61,13 +62,11 @@ class DagTransferPrecompiled(Precompiled):
         entry = self._crit_sigs.get(data[:4])
         if entry is None:
             return None
-        from ...codec.abi import abi_decode
-
-        types, n_users = entry
+        decode, n_users = entry
         # conflict keys = the user-name string args (reference: conflict
         # fields annotated on each parallel method)
         try:
-            vals = abi_decode(types, data[4:])
+            vals = decode(data[4:])
         except ValueError:
             return None
         return [v.encode() for v in vals[:n_users]]
@@ -181,10 +180,8 @@ class SmallBankPrecompiled(Precompiled):
         n = self._crit_counts.get(data[:4])
         if n is None:
             return None
-        from ...codec.abi import abi_decode
-
         try:
-            vals = abi_decode(["string"] * n, data[4:])
+            vals = coder_for(("string",) * n).decode(data[4:])
         except ValueError:
             return None
         return [v.encode() for v in vals]

@@ -4,10 +4,13 @@ existing accounts, payer and payee Zipf 0.99, every transaction DAG) through
 the conflict-DAG runner, at 64 transactions over 40 accounts: whatever the
 number of workers it gives the receipts, in index order, and the state root of
 ``execute_transactions`` on the same list; its counters and spans say what it
-did; a declaration that lies is caught and the block still ends on the serial
-root. And the generator: the same bytes for a seed, rank frequencies that
-follow theta."""
+did (calls to registry precompiles in the level frame, on no pool; a deployed
+contract's calls as futures); a declaration that lies, by a write or by a
+read, is caught and the block still ends on the serial root; a framed member
+that faults leaves what ``_execute_one`` leaves. And the generator: the same
+bytes for a seed, rank frequencies that follow theta."""
 
+import json
 import math
 import random
 
@@ -15,17 +18,29 @@ import pytest
 
 from benchmark import manifest
 from benchmark.generators import dag_transfer_batches as gen
+from fisco_bcos_tpu.codec.abi import ABICodec
 from fisco_bcos_tpu.crypto.suite import ecdsa_suite
 from fisco_bcos_tpu.executor import TransactionExecutor
-from fisco_bcos_tpu.executor.precompiled import DAG_TRANSFER_ADDRESS, default_registry
+from fisco_bcos_tpu.executor import executor as executor_module
+from fisco_bcos_tpu.executor.precompiled import (
+    ACCOUNT_MGR_ADDRESS,
+    DAG_TRANSFER_ADDRESS,
+    default_registry,
+)
+from fisco_bcos_tpu.executor.precompiled.base import PrecompiledError
 from fisco_bcos_tpu.executor.precompiled.bench_contracts import DagTransferPrecompiled
 from fisco_bcos_tpu.observability import TRACER
 from fisco_bcos_tpu.protocol import BlockHeader
-from fisco_bcos_tpu.protocol.transaction import TransactionAttribute
-from fisco_bcos_tpu.storage import MemoryStorage
+from fisco_bcos_tpu.protocol.receipt import TransactionStatus
+from fisco_bcos_tpu.protocol.transaction import Transaction, TransactionAttribute
+from fisco_bcos_tpu.storage import Entry, MemoryStorage
 from fisco_bcos_tpu.utils.metrics import REGISTRY
 
+from evm_asm import _deployer
+from test_abi_conflict import SETFOR_ABI, _setfor_runtime
+
 SUITE = ecdsa_suite()
+CODEC = ABICodec(SUITE.hash)
 SEED = 2**31 + 3232
 CONFIG = dict(manifest.config_of(manifest.load(), "air4-dagtransfer"), user_batches=5)
 TRAFFIC = dict(manifest.traffic_of("flood"), batch_txs=8, senders=4)  # 5 x 8 = 40 accounts
@@ -48,9 +63,9 @@ def block_of(c, n=0):
     return [tx for batch in c.batches[n * per:(n + 1) * per] for tx in batch]
 
 
-def opened(c, registry=None):
+def opened(c, registry=None, backend=None):
     """An executor inside block 1 with the corpus's accounts open."""
-    ex = TransactionExecutor(MemoryStorage(), SUITE, registry=registry)
+    ex = TransactionExecutor(backend or MemoryStorage(), SUITE, registry=registry)
     ex.next_block_header(BlockHeader(number=1))
     opening = ex.execute_transactions([tx for batch in c.opening for tx in batch])
     assert all(rc.status == 0 and int.from_bytes(rc.output, "big") == 0 for rc in opening)
@@ -58,7 +73,21 @@ def opened(c, registry=None):
 
 
 def plain(receipts):
-    return [(rc.status, rc.output, rc.gas_used, rc.block_number) for rc in receipts]
+    return [(rc.status, rc.output, rc.gas_used, rc.block_number, rc.contract_address)
+            for rc in receipts]
+
+
+def call(to, signature, *args, sender=b"\x0b" * 20):
+    return Transaction(to=to, input=CODEC.encode_call(signature, *args), sender=sender)
+
+
+def dag_counts():
+    return {k: counter(f"fisco_executor_dag_{k}_total")
+            for k in ("levels", "pooled_txs", "framed_txs", "conflict_reruns")}
+
+
+def moved(before):
+    return {k: v - before[k] for k, v in dag_counts().items()}
 
 
 def counter(name):
@@ -90,28 +119,19 @@ def test_dag_runner_is_serial_equivalent_whatever_the_workers(workers, serial, m
     assert 1 < len(levels) < len(txs), "a block with a wide level and a chain"
     assert sorted(i for level in levels for i in level) == list(range(len(txs)))
 
-    before = {
-        "levels": counter("fisco_executor_dag_levels_total"),
-        "pooled": counter("fisco_executor_dag_pooled_txs_total"),
-        "reruns": counter("fisco_executor_dag_conflict_reruns_total"),
-        "stage": stage_seconds(),
-    }
+    before, stage_before = dag_counts(), stage_seconds()
     TRACER.clear()
     receipts = ex.dag_execute_transactions(txs)
     assert (plain(receipts), ex.get_hash()) == serial
     codes = {int.from_bytes(rc.output, "big") for rc in receipts}
     assert codes <= {0, 4} and 0 in codes
 
-    # the counters say what dag_levels says
-    assert counter("fisco_executor_dag_levels_total") - before["levels"] == len(levels)
-    wide = sum(len(level) for level in levels if len(level) > 1)
-    assert counter("fisco_executor_dag_pooled_txs_total") - before["pooled"] == (
-        wide if workers > 1 else 0)
-    assert counter("fisco_executor_dag_conflict_reruns_total") == before["reruns"]
+    # the counters say what dag_levels says, and that every call ran in the
+    # frame whatever the workers: no future, the check on every wide level
+    assert moved(before) == {"levels": len(levels), "pooled_txs": 0,
+                             "framed_txs": len(txs), "conflict_reruns": 0}
     after = stage_seconds()
-    assert after["levelize"] > before["stage"]["levelize"]
-    assert after["run"] > before["stage"]["run"]
-    assert (after["validate"] > before["stage"]["validate"]) == (workers > 1)
+    assert all(after[k] > stage_before[k] for k in after)
 
     # and so do the spans: one a level, one a checked level, under one block record
     spans = TRACER.spans()
@@ -121,17 +141,19 @@ def test_dag_runner_is_serial_equivalent_whatever_the_workers(workers, serial, m
     assert block[0].attrs["levels"] == len(levels) and block[0].attrs["reruns"] == 0
     ran = [s for s in spans if s.name == "executor.dag_level"]
     assert [s.attrs["width"] for s in ran] == [len(level) for level in levels]
-    assert [s.attrs["pooled"] for s in ran] == [
-        workers > 1 and len(level) > 1 for level in levels]
+    assert [s.attrs["framed"] for s in ran] == [len(level) for level in levels]
+    assert not any(s.attrs["pooled"] for s in ran)
     checked = [s for s in spans if s.name == "executor.dag_validate"]
-    assert len(checked) == (sum(1 for level in levels if len(level) > 1) if workers > 1 else 0)
+    assert [s.attrs["width"] for s in checked] == [
+        len(level) for level in levels if len(level) > 1]
+    assert not any(s.attrs["conflict"] for s in checked)
     (levelize,) = [s for s in spans if s.name == "executor.dag_levelize"]
     assert levelize.attrs["levels"] == len(levels)
     inside = [s for s in spans if s.name.startswith("executor.dag_")]
     assert all(s.parent == "executor.execute" for s in inside)
     parts = sum(s.dur for s in inside)
     assert parts <= block[0].dur and parts == pytest.approx(
-        sum(after[k] - before["stage"][k] for k in after), rel=1e-6)
+        sum(after[k] - stage_before[k] for k in after), rel=1e-6)
 
 
 def test_several_blocks_in_a_row_keep_the_serial_state(monkeypatch):
@@ -170,6 +192,191 @@ def test_a_declaration_that_lies_is_caught_and_the_block_ends_on_the_serial_root
     (block,) = [s for s in TRACER.spans() if s.name == "executor.execute"]
     assert block.attrs["reruns"] == 1
     assert any(s.attrs["conflict"] for s in TRACER.spans() if s.name == "executor.dag_validate")
+
+
+class BlindBalance(DagTransferPrecompiled):
+    """Declares nothing for ``userBalance``: a lie by omission, since the
+    call reads the account's row, which a transfer of its level may write."""
+
+    def criticals(self, codec, data):
+        if data[:4] == codec.selector("userBalance(string)"):
+            return []
+        return super().criticals(codec, data)
+
+
+def test_a_read_of_a_peers_write_inside_a_framed_level_is_caught(monkeypatch):
+    """Balance reads that declare no key land in level 0 beside transfers that
+    write the rows they read, some before their writer in block order and some
+    after it. The block ends where the pinned serial loop ends
+    (``FISCO_DAG_SERIAL=1``: ``_execute_one`` in level order, the rerun's own
+    order, which is not block order once a declaration lies)."""
+    monkeypatch.setenv("FISCO_DAG_WORKERS", "8")
+    c = corpus()
+    transfers = block_of(c)
+    hot = [c.names[rank] for rank in range(4)]
+    txs = ([call(DAG_TRANSFER_ADDRESS, "userBalance(string)", name) for name in hot[:2]]
+           + transfers
+           + [call(DAG_TRANSFER_ADDRESS, "userBalance(string)", name) for name in hot[2:]])
+    registry = {**default_registry(), DAG_TRANSFER_ADDRESS: BlindBalance()}
+    ex, judge = opened(c, registry=registry), opened(c, registry=registry)
+    level0 = ex.dag_levels(txs)[0]
+    written = {name for i in level0 if 2 <= i < 2 + len(transfers)
+               for name in c.records[(i - 2) // c.batch_txs][(i - 2) % c.batch_txs][:2]}
+    assert {0, 1, len(txs) - 2, len(txs) - 1} <= set(level0) and written & set(hot)
+    before = dag_counts()
+    TRACER.clear()
+    receipts = ex.dag_execute_transactions(txs)
+    assert moved(before)["conflict_reruns"] == 1 and moved(before)["pooled_txs"] == 0
+    checked = [s for s in TRACER.spans() if s.name == "executor.dag_validate"]
+    ran = [s for s in TRACER.spans() if s.name == "executor.dag_level"]
+    monkeypatch.setenv("FISCO_DAG_SERIAL", "1")
+    assert plain(receipts) == plain(judge.dag_execute_transactions(txs))
+    assert ex.get_hash() == judge.get_hash()
+    assert moved(before)["conflict_reruns"] == 1, "the pinned loop checks nothing"
+    (first,) = checked
+    assert first.attrs["conflict"] and first.attrs["width"] == len(level0)
+    assert ran[0].attrs["framed"] == len(level0) and len(ran) == 1 + len(ex.dag_levels(txs))
+    assert all(s.attrs["framed"] == 0 and not s.attrs["pooled"] for s in ran[1:])
+
+
+def deployed_setfor(ex):
+    """``setFor(uint256,uint256)``, parallel by its first parameter
+    (``tests/test_abi_conflict.py``), deployed inside the executor's block."""
+    (rc,) = ex.execute_transactions([Transaction(
+        to=b"", input=_deployer(_setfor_runtime()), abi=json.dumps(SETFOR_ABI),
+        sender=b"\x0d" * 20)])
+    assert rc.status == 0 and rc.contract_address
+    return rc.contract_address
+
+
+def mixed_block(c, contract):
+    """24 transfers with six contract calls among them: five on keys of their
+    own, the sixth on a key already taken, so it waits a level."""
+    calls = [call(contract, "setFor(uint256,uint256)", key, 100 + n)
+             for n, key in enumerate((0, 1, 2, 3, 4, 1))]
+    txs = []
+    for n, tx in enumerate(block_of(c)[:24]):
+        txs.append(tx)
+        if n % 4 == 0:
+            txs.append(calls[n // 4])
+    return txs
+
+
+@pytest.mark.parametrize("workers", [1, 2, 8])
+def test_a_level_of_precompile_and_contract_calls_splits_by_callee(workers, monkeypatch):
+    monkeypatch.setenv("FISCO_DAG_WORKERS", str(workers))
+    pools = []
+    real = executor_module.ThreadPoolExecutor
+    monkeypatch.setattr(executor_module, "ThreadPoolExecutor",
+                        lambda n: pools.append(n) or real(n))
+    c = corpus()
+    ex, ser = opened(c), opened(c)
+    contract = deployed_setfor(ex)
+    assert deployed_setfor(ser) == contract
+    txs = mixed_block(c, contract)
+    levels = ex.dag_levels(txs)
+    of_contract = [[i for i in level if txs[i].to == contract] for level in levels]
+    assert len(of_contract[0]) == 5 and len(levels[0]) > 5, "one level, both kinds of callee"
+    assert sum(map(len, of_contract)) == 6
+
+    before = dag_counts()
+    TRACER.clear()
+    receipts = ex.dag_execute_transactions(txs)
+    assert plain(receipts) == plain(ser.execute_transactions(txs))
+    assert ex.get_hash() == ser.get_hash()
+    assert all(rc.status == 0 for rc in receipts)
+
+    # a contract's member of a wide level is a future where a pool is allowed,
+    # inline where it is not; a precompile's member is framed either way
+    to_pool = [len(mine) if workers > 1 and len(level) > 1 else 0
+               for mine, level in zip(of_contract, levels)]
+    assert moved(before) == {"levels": len(levels), "pooled_txs": sum(to_pool),
+                             "framed_txs": len(txs) - 6, "conflict_reruns": 0}
+    assert sum(to_pool) >= 5 * (workers > 1)
+    assert pools == ([workers] if workers > 1 else []), "one pool a call, opened when needed"
+    ran = [s for s in TRACER.spans() if s.name == "executor.dag_level"]
+    assert [s.attrs["pooled"] for s in ran] == [n > 0 for n in to_pool]
+    assert [s.attrs["framed"] for s in ran] == [
+        len(level) - len(mine) for mine, level in zip(of_contract, levels)]
+    checked = [s for s in TRACER.spans() if s.name == "executor.dag_validate"]
+    assert len(checked) == sum(1 for level in levels if len(level) > 1)
+
+
+def test_a_block_of_precompile_calls_opens_no_pool(serial, monkeypatch):
+    monkeypatch.setenv("FISCO_DAG_WORKERS", "8")
+    built = []
+    monkeypatch.setattr(executor_module, "ThreadPoolExecutor",
+                        lambda *a, **kw: built.append(a) or pytest.fail("a pool was opened"))
+    c = corpus()
+    ex = opened(c)
+    assert (plain(ex.dag_execute_transactions(block_of(c))), ex.get_hash()) == serial
+    assert built == []
+
+
+GOVERNOR, ALICE, BOB = b"\x0a" * 20, b"\x0b" * 20, b"\x0c" * 20
+
+
+class SaveFaults(DagTransferPrecompiled):
+    """``userSave`` writes the row, then faults."""
+
+    def _save(self, ctx, user, amount):
+        self._set_balance(ctx, user, 10**9)
+        raise PrecompiledError("save refused")
+
+
+@pytest.mark.parametrize("workers", [1, 8])
+def test_a_framed_member_that_faults_leaves_what_execute_one_leaves(workers, monkeypatch):
+    """A PrecompiledError after a write, a frozen sender and input no selector
+    matches, the first two inside a level wider than one: status, output and
+    gas are ``_execute_one``'s, and none of the faulted call's writes stay."""
+    monkeypatch.setenv("FISCO_DAG_WORKERS", str(workers))
+    c = corpus()
+    n = c.names
+
+    def in_block_two():
+        backend = MemoryStorage()
+        backend.set_row("s_config", b"auth_governors",
+                        Entry().set(("0x" + GOVERNOR.hex()).encode()))
+        ex = opened(c, registry={**default_registry(), DAG_TRANSFER_ADDRESS: SaveFaults()},
+                    backend=backend)
+        (rc,) = ex.execute_transactions([call(
+            ACCOUNT_MGR_ADDRESS, "setAccountStatus(address,uint8)", ALICE, 1, sender=GOVERNOR)])
+        assert rc.status == 0
+        ex._block.storage.merge_into_prev()  # what the scheduler's 2PC does live
+        ex.next_block_header(BlockHeader(number=2))
+        return ex
+
+    transfer = "userTransfer(string,string,uint256)"
+    txs = [
+        call(DAG_TRANSFER_ADDRESS, transfer, n[0], n[1], 1, sender=BOB),
+        call(DAG_TRANSFER_ADDRESS, "userSave(string,uint256)", n[2], 5, sender=BOB),
+        call(DAG_TRANSFER_ADDRESS, transfer, n[3], n[4], 1, sender=ALICE),
+        call(DAG_TRANSFER_ADDRESS, "userBalance(string)", n[2], sender=BOB),
+        Transaction(to=DAG_TRANSFER_ADDRESS, input=b"\x01\x02", sender=BOB),
+        call(DAG_TRANSFER_ADDRESS, transfer, n[5], n[6], 2, sender=BOB),
+    ]
+    ex, judge = in_block_two(), in_block_two()
+    assert ex.dag_levels(txs) == [[0, 1, 2], [3], [4], [5]]
+    before = dag_counts()
+    receipts = ex.dag_execute_transactions(txs)
+    base = judge.reserve_contexts(len(txs))
+    want = [judge._execute_one(tx, judge._block, context_id=base + i)
+            for i, tx in enumerate(txs)]
+    assert plain(receipts) == plain(want) and ex.get_hash() == judge.get_hash()
+    assert [rc.log_entries for rc in receipts] == [rc.log_entries for rc in want]
+    assert moved(before) == {"levels": 4, "pooled_txs": 0, "framed_txs": 6,
+                             "conflict_reruns": 0}
+
+    fault = int(TransactionStatus.PRECOMPILED_ERROR)
+    assert [rc.status for rc in receipts] == [
+        0, fault, int(TransactionStatus.ACCOUNT_FROZEN), 0, fault, 0]
+    assert receipts[1].output == b"save refused"
+    assert receipts[2].output == b"account is frozen"
+    opening = {user: balance for recs in c.opening_records for user, balance, _who in recs}
+    assert CODEC.decode_output(["uint256", "uint256"], receipts[3].output) == [0, opening[n[2]]]
+    rows = dict(((t, k), e) for t, k, e in ex._block.storage.traverse())
+    touched = {k.decode() for t, k in rows if t == "dag_transfer"}
+    assert touched == {n[0], n[1], n[5], n[6]}, "the frozen sender's transfer moved nothing"
 
 
 # -- the generator ----------------------------------------------------------------

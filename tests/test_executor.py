@@ -642,6 +642,40 @@ def test_run_frame_matches_per_transaction_path(case, suite_name):
         assert got_root == suite.merkle_root_async(leaves)()
 
 
+# sha256 over every receipt's encoding, then the state root and the receipts
+# root, block by block, of ``execute_transactions`` at ed1e450 (PR 32), where
+# the run frame's body stood inline in ``_execute_run``: the serial cells'
+# path stays byte for byte where it was now that the DAG runner shares it
+PINNED_AT_PR32 = {
+    ("_broken_by_evm_create_and_precompiled", "keccak256"):
+        "f0bc2a38f08a3e306b100651210c366bec93dc61d691fa8124a7af49d5fc5d07",
+    ("_broken_by_evm_create_and_precompiled", "sm3"):
+        "f03217187fcb9170a1736b659e4b13702a4da2595df24f0b263c2f88ae6dab85",
+    ("_fault_drops_its_writes", "keccak256"):
+        "a6f616e14cb22bb20440de332c63f78deb2e402297e621464202cac7ff0aa054",
+    ("_fault_drops_its_writes", "sm3"):
+        "a9958d183238af4f05292d728fd0f4cb50f3c63aafc8e1b2b8ee8017269f69de",
+    ("_frozen_sender_mid_run", "keccak256"):
+        "347e2ee42212592d67b650d5fdfbbe83c8c4f0084b4f5510befd126b2521ed0e",
+    ("_frozen_sender_mid_run", "sm3"):
+        "6644e969163f054376747ab8c09455fc39e95e11e89c9635069af086a06c769f",
+}
+
+
+@pytest.mark.parametrize("case_name,suite_name", sorted(PINNED_AT_PR32))
+def test_shared_frame_body_keeps_the_serial_path_where_it_was(case_name, suite_name):
+    import hashlib
+
+    digest = hashlib.sha256()
+    for receipts, state_root, receipts_root in _execute_blocks(
+            SUITES[suite_name], globals()[case_name], framed=True):
+        for rc in receipts:
+            digest.update(rc.encode())
+        digest.update(state_root)
+        digest.update(receipts_root)
+    assert digest.hexdigest() == PINNED_AT_PR32[case_name, suite_name]
+
+
 def test_run_frame_cases_show_what_they_claim():
     """The cases above compare two paths; this pins the outcomes they are
     named for, so an equal pair of wrong answers cannot pass."""
