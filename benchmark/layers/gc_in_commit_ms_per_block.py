@@ -1,0 +1,9 @@
+"""Node process: collector pauses (``gc.gen*``) that interrupted a
+``scheduler.commit_block`` on its thread, over the window, per block.
+Rule: ``benchmark/stage_parts.py``."""
+
+from benchmark import stage_parts
+
+
+def read(ctx):
+    return stage_parts.read(ctx, "gc_in_commit_ms_per_block")

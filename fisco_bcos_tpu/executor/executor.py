@@ -710,17 +710,12 @@ class TransactionExecutor:
         if workers <= 0:
             workers = min(8, os.cpu_count() or 1)
         registry = self.registry
-        # seconds of this call by stage, and the transactions by how they
-        # ran: the DAG runner's own split (fisco_executor_dag_*)
-        stage = {"levelize": 0.0, "run": 0.0, "validate": 0.0}
-        pooled_txs = framed_txs = 0
-
-        def note(name: str, key: str, t0: float, **attrs) -> None:
-            """One finished interval of the driving thread: a span under
-            ``executor.execute`` and its seconds for the stage counter."""
-            dur = time.perf_counter() - t0
-            stage[key] += dur
-            TRACER.record(name, t0, dur, **attrs)
+        # (width, framed, pooled) of every level that ran, in order (a serial
+        # rerun's after the failed attempt's), and every check's verdict: the
+        # facts of the block's one record; the seconds by stage are the span's
+        # marks (levelize, run, validate)
+        ran: list[tuple[int, int, int]] = []
+        conflicts: list[bool] = []
 
         def shadow_ctx() -> BlockContext:
             return BlockContext(
@@ -739,22 +734,19 @@ class TransactionExecutor:
             # tests/test_abi_conflict.py::test_reordering_levels_keep_receipt_identity)
             out: list = [None] * len(txs)
             for level in levels:
-                t_level = time.perf_counter()
                 for i in level:
                     out[i] = self._execute_one(txs[i], block, context_id=base + i)
-                note("executor.dag_level", "run", t_level,
-                     width=len(level), pooled=False, framed=0)
+                ran.append((len(level), 0, 0))
+                span.stage("run")
             return out
 
         def run_levels(block: BlockContext) -> list | None:
             """The levels on `block`, or None where a level's check failed."""
-            nonlocal pooled_txs, framed_txs
             out: list = [None] * len(txs)
             frame = _PrecompileFrame(self, block)
             pool = None
             try:
                 for level in levels:
-                    t_level = time.perf_counter()
                     wide = len(level) > 1
                     # every member of a wide level appends its access sets
                     tracked: list | None = [] if wide else None
@@ -779,16 +771,13 @@ class TransactionExecutor:
                             )
                     for i, fut in futs.items():
                         out[i] = fut.result()
-                    framed_txs += framed
-                    pooled_txs += len(futs)
-                    note("executor.dag_level", "run", t_level,
-                         width=len(level), pooled=bool(futs), framed=framed)
+                    ran.append((len(level), framed, len(futs)))
+                    span.stage("run")
                     if not wide:
                         continue
-                    t_validate = time.perf_counter()
                     conflict = _level_conflicts(tracked)
-                    note("executor.dag_validate", "validate", t_validate,
-                         width=len(level), conflict=conflict)
+                    conflicts.append(conflict)
+                    span.stage("validate")
                     if conflict:
                         _log.warning(
                             "DAG level of %d txs touched overlapping "
@@ -803,10 +792,8 @@ class TransactionExecutor:
             return out
 
         with TRACER.span("executor.execute", mode="dag", txs=len(txs)) as span:
-            t_levelize = time.perf_counter()
             levels = self.dag_levels(txs)
-            note("executor.dag_levelize", "levelize", t_levelize,
-                 txs=len(txs), levels=len(levels))
+            span.stage("levelize")
             shadow = shadow_ctx()
             run = run_serial if os.environ.get("FISCO_DAG_SERIAL") else run_levels
             receipts = run(shadow)
@@ -819,7 +806,12 @@ class TransactionExecutor:
                 receipts = run_serial(shadow)
             shadow.storage.merge_into_prev()
             self._block.suicides |= shadow.suicides
-            span.set(levels=len(levels), reruns=int(conflict))
+            widths, framed_by_level, pooled_by_level = zip(*ran) if ran else ((), (), ())
+            span.set(
+                levels=len(levels), reruns=int(conflict), widths=widths,
+                framed=framed_by_level, pooled=pooled_by_level,
+                conflicts=tuple(conflicts),
+            )
         self._record_batch("dag", len(txs), time.perf_counter() - t_dag0)
         REGISTRY.counter_add(
             "fisco_executor_dag_levels_total",
@@ -827,7 +819,7 @@ class TransactionExecutor:
             help="dependent levels the DAG runner cut its batches into "
             "(a level's txs share no declared conflict key)",
         )
-        for key, seconds in stage.items():
+        for key, seconds in span.stages.items():
             REGISTRY.counter_add(
                 f'fisco_executor_dag_stage_seconds_total{{stage="{key}"}}',
                 seconds,
@@ -838,14 +830,14 @@ class TransactionExecutor:
             )
         REGISTRY.counter_add(
             "fisco_executor_dag_pooled_txs_total",
-            pooled_txs,
+            sum(pooled_by_level),
             help="txs the DAG runner executed as futures on its thread pool "
             "(members of a level wider than one whose callee is no registry "
             "precompile)",
         )
         REGISTRY.counter_add(
             "fisco_executor_dag_framed_txs_total",
-            framed_txs,
+            sum(framed_by_level),
             help="txs the DAG runner executed inside its level frame, on the "
             "thread that executes the block (calls to registry precompiles); "
             "beside fisco_executor_batch_txs' dag sum, the frame's share",

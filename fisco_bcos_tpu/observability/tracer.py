@@ -45,6 +45,16 @@ programs. A process that never imported JAX skips it.
 
 A span that ran longer than :data:`SLOW_SPAN_S` hands the flight recorder one
 ``slow_span`` event naming what else the ring saw during its interval.
+
+The stage clock: ``span.stage(name)`` gives the seconds since the span's
+previous mark (or its start) to ``name``. A mark is one clock reading and one
+dict update and writes no record; the span's one record closes with the sums
+(``stages``) and, while there are at most :data:`MAX_MARKS` of them, the
+marks' ordered end offsets (``marks``), and each sum is added to
+``fisco_span_stage_seconds_total{span,stage}``. A span opened with
+``stage_log=(logger, badge)`` also writes the reference's BlockTrace line
+(``[badge.k]|stage|stageMs=..|totalMs=..|k=v``, DMCExecute.0..6 in
+bcos-scheduler BlockExecutive.cpp:849-1010) at every mark, tracer on or off.
 """
 
 from __future__ import annotations
@@ -52,6 +62,7 @@ from __future__ import annotations
 import contextvars
 import itertools
 import json
+import logging
 import os
 import random
 import sys
@@ -129,6 +140,9 @@ SLOW_SPAN_WITNESSES = 32
 # slow span's witnesses are read from what was appended since it began and
 # not from the whole ring (0.1 s a span at 262,144 records: PERF.md §6, PR 32)
 MARK_EVERY = 1024
+# a span keeps the ordered offsets of its stage marks up to this many; a span
+# that marks more often (a DAG block: a mark a level and a check) keeps sums
+MAX_MARKS = 16
 # the ring holds a benchmark window with a margin of two (PERF.md §6, PR 24):
 # the busiest cell writes ~26k records in 51 s; ~0.35 KB a record
 DEFAULT_CAPACITY = 65536
@@ -195,6 +209,13 @@ class _NoopSpan:
     def set(self, **kv) -> "_NoopSpan":
         return self
 
+    def stage(self, name: str, **kv) -> None:
+        pass
+
+    @property
+    def stages(self) -> types.MappingProxyType:
+        return _NO_ATTRS
+
     def link(self, ctxs) -> None:
         pass
 
@@ -211,10 +232,60 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+class _StageLine:
+    """The BlockTrace line of a span's marks: ``[badge.k]|stage|stageMs=..|
+    totalMs=..`` and the mark's own pairs last (the benchmark's DAG driver
+    parses the ``execute`` line's ``dag=``/``serial=`` tail)."""
+
+    __slots__ = ("_logger", "_badge", "_n")
+
+    def __init__(self, logger, badge: str):
+        self._logger = logger
+        self._badge = badge
+        self._n = 0
+
+    def emit(self, name: str, stage_s: float, total_s: float, kv: dict) -> None:
+        if not self._logger.isEnabledFor(logging.INFO):
+            return
+        from ..utils.log import kv_line
+
+        self._logger.info(
+            kv_line(
+                f"{self._badge}.{self._n}",
+                name,
+                stageMs=round(stage_s * 1e3, 3),
+                totalMs=round(total_s * 1e3, 3),
+                **kv,
+            )
+        )
+        self._n += 1
+
+
+class _LineSpan(_NoopSpan):
+    """What a disabled or unsampled tracer hands out for a span opened with a
+    stage log: no record, no sums, and the line all the same (an operator's
+    log does not go quiet with the telemetry)."""
+
+    __slots__ = ("_line", "_t0", "_mark")
+
+    def __init__(self, line: _StageLine):
+        self._line = line
+
+    def __enter__(self):
+        self._t0 = self._mark = time.perf_counter()
+        return self
+
+    def stage(self, name: str, **kv) -> None:
+        now = time.perf_counter()
+        self._line.emit(name, now - self._mark, now - self._t0, kv)
+        self._mark = now
+
+
 class _Span:
     __slots__ = (
         "_tracer", "name", "attrs", "_t0", "depth", "parent",
         "ctx", "_parent_ctx", "links", "_token", "_annotation", "_discard",
+        "_mark", "_stages", "_marks", "_line",
     )
 
     def __init__(
@@ -224,6 +295,7 @@ class _Span:
         attrs: dict,
         parent_ctx: TraceContext | None,
         links: tuple = (),
+        line: _StageLine | None = None,
     ):
         self._tracer = tracer
         self.name = name
@@ -231,6 +303,9 @@ class _Span:
         self._parent_ctx = parent_ctx
         self.links = tuple(links)
         self._discard = False
+        self._stages: dict[str, float] | None = None
+        self._marks: list | None = None
+        self._line = line
 
     def link(self, ctxs) -> None:
         """Add links found while the span was open (the seal span learns
@@ -247,6 +322,31 @@ class _Span:
         assignment on ``attrs`` silently vanishes on a disabled tracer)."""
         self.attrs.update(kv)
         return self
+
+    def stage(self, name: str, **kv) -> None:
+        """The seconds since the previous mark (or the span's start) go to
+        ``name``. ``kv`` are for the stage line of a span that has one."""
+        now = time.perf_counter()
+        dur = now - self._mark
+        self._mark = now
+        sums = self._stages
+        if sums is None:
+            sums = self._stages = {}
+            self._marks = []
+        sums[name] = sums.get(name, 0.0) + dur
+        marks = self._marks
+        if marks is not None:
+            if len(marks) < MAX_MARKS:
+                marks.append((name, now - self._t0))
+            else:
+                self._marks = None
+        if self._line is not None:
+            self._line.emit(name, dur, now - self._t0, kv)
+
+    @property
+    def stages(self) -> "dict | types.MappingProxyType":
+        """Seconds by stage name so far (the whole span's once it closed)."""
+        return self._stages if self._stages is not None else _NO_ATTRS
 
     def __enter__(self):
         tr = self._tracer
@@ -273,7 +373,7 @@ class _Span:
             self._annotation.__enter__()
         else:
             self._annotation = None
-        self._t0 = time.perf_counter()
+        self._t0 = self._mark = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
@@ -283,6 +383,11 @@ class _Span:
         _CURRENT.reset(self._token)
         if self._discard:
             return False
+        if self._stages is not None:
+            self.attrs["stages"] = self._stages
+            if self._marks is not None:
+                self.attrs["marks"] = tuple(self._marks)
+            _count_stages(self.name, self._stages)
         self._tracer.record(
             self.name,
             t0=self._t0,
@@ -295,6 +400,22 @@ class _Span:
             **self.attrs,
         )
         return False
+
+
+def _count_stages(span: str, stages: dict) -> None:
+    from ..utils.metrics import REGISTRY
+
+    for stage, seconds in stages.items():
+        REGISTRY.counter_add(
+            f'fisco_span_stage_seconds_total{{span="{span}",stage="{stage}"}}',
+            seconds,
+            help="seconds of recorded spans by stage mark (span.stage): the "
+            "same sums each span's record carries as `stages`",
+        )
+
+
+def _unrecorded(stage_log) -> _NoopSpan:
+    return _NOOP if stage_log is None else _LineSpan(_StageLine(*stage_log))
 
 
 class Tracer:
@@ -439,29 +560,33 @@ class Tracer:
         name: str,
         parent: TraceContext | None = None,
         links: tuple = (),
+        stage_log: tuple | None = None,
         **attrs,
     ):
         """Context manager timing a region; yields the span so callers can
-        add attrs (``sp.set(txs=n)``) before it closes. ``parent`` overrides
-        the ambient context (cross-thread/remote parents); ``links`` are
-        (trace_id, span_id) pairs or TraceContexts from OTHER traces."""
+        add attrs (``sp.set(txs=n)``) and stage marks (``sp.stage(name)``)
+        before it closes. ``parent`` overrides the ambient context
+        (cross-thread/remote parents); ``links`` are (trace_id, span_id)
+        pairs or TraceContexts from OTHER traces; ``stage_log`` is a
+        ``(logger, badge)`` whose BlockTrace line every mark writes."""
         if not self.enabled:
-            return _NOOP
+            return _unrecorded(stage_log)
         pctx = parent if parent is not None else _CURRENT.get()
         if pctx is not None and not pctx.sampled:
             # unsampled trace: skip the span but keep the ambient decision
             self._drop("sampled")
-            return _NOOP
+            return _unrecorded(stage_log)
         if pctx is None and self.sample_rate <= 0.0:
             # fast path: nothing upstream and sampling is off — no root
             self._drop("sampled")
-            return _NOOP
+            return _unrecorded(stage_log)
         if links:
             links = tuple(
                 (l.trace_id, l.span_id) if isinstance(l, TraceContext) else tuple(l)
                 for l in links
             )
-        return _Span(self, name, attrs, parent, links)
+        line = _StageLine(*stage_log) if stage_log is not None else None
+        return _Span(self, name, attrs, parent, links, line)
 
     def record(
         self,

@@ -38,7 +38,7 @@ from ..resilience.crashpoints import (
 from ..storage.interfaces import TransactionalStorage, TwoPCParams
 from ..storage.state_storage import StateStorage
 from ..utils.error import ErrorCode
-from ..utils.log import StageTimer, get_logger
+from ..utils.log import get_logger
 from ..utils.metrics import REGISTRY
 from ..utils.worker import Worker
 
@@ -277,8 +277,12 @@ class Scheduler:
         # the lock covers the whole execution: the executor's block context is
         # shared state, and two interleaved same-height executions would
         # corrupt each other's state layer
+        # the stage marks (sp.stage) are the block's BlockTrace lines, its
+        # record's `stages` and fisco_span_stage_seconds_total, all at once
         with TRACER.span(
-            "scheduler.execute_block", block=number
+            "scheduler.execute_block",
+            block=number,
+            stage_log=(_log, f"ExecuteBlock.{number}"),
         ) as sp, PIPELINE.busy("execute"):
             with self._lock:
                 cached = self._executed.get(number)
@@ -296,10 +300,11 @@ class Scheduler:
                     )
                     if lazy_roots:
                         return cached.header
-                    return self._resolve_roots_locked(cached)
+                    sp.stage("cached")
+                    return self._resolve_roots_locked(cached, sp)
                 t0 = time.perf_counter()
                 header = self._execute_block_locked(
-                    block, verify, number, proposal_ident, lazy_roots
+                    block, verify, number, proposal_ident, lazy_roots, sp
                 )
                 from ..observability.tracer import trace_hex
 
@@ -315,10 +320,8 @@ class Scheduler:
 
     def _execute_block_locked(
         self, block: Block, verify: bool, number: int, proposal_ident,
-        lazy_roots: bool = False,
+        lazy_roots: bool, sp,
     ) -> BlockHeader:
-        timer = StageTimer(_log, f"ExecuteBlock.{number}")
-
         # An in-flight lock-free 2PC (commit_block) used to mutate the
         # committing block's post-state overlay (ledger prewrite merge) —
         # a torn read for anything executing through it, so executions
@@ -380,7 +383,6 @@ class Scheduler:
                 )
             txs = fetched
             block.transactions = txs
-        timer.stage("fillBlock", txs=len(txs))
 
         dag_idx = [
             i for i, t in enumerate(txs) if t.attribute & TransactionAttribute.DAG
@@ -388,6 +390,8 @@ class Scheduler:
         serial_idx = [
             i for i, t in enumerate(txs) if not (t.attribute & TransactionAttribute.DAG)
         ]
+        # the gates, the fill and the DAG / serial split; `execute` is run_block
+        sp.stage("fillBlock", txs=len(txs))
 
         def run_block():
             if base is not None:
@@ -427,7 +431,7 @@ class Scheduler:
             )
             receipts = run_block()
         block.receipts = receipts  # type: ignore[assignment]
-        timer.stage("execute", dag=len(dag_idx), serial=len(serial_idx))
+        sp.stage("execute", dag=len(dag_idx), serial=len(serial_idx))
 
         header = block.header
         header.gas_used = sum(rc.gas_used for rc in block.receipts)
@@ -438,8 +442,11 @@ class Scheduler:
         state_f = (
             get_hash_async() if get_hash_async else (lambda: self.executor.get_hash())
         )
+        sp.stage("stateRoot")
         txs_f = block.calculate_txs_root_async(self.suite)
+        sp.stage("txsRoot")
         receipts_f = block.calculate_receipts_root_async(self.suite)
+        sp.stage("receiptsRoot")
         # pipeline mode, speculative pre-execution: all three programs are
         # dispatched (above), the sync is deferred to quorum time — the
         # device computes the roots while the prepare/commit votes
@@ -464,14 +471,14 @@ class Scheduler:
             header.txs_root = txs_root
             header.receipts_root = receipts_root
             header.clear_hash_cache()
-            timer.stage("roots", state_root=state_root.hex()[:16])
+            sp.stage("roots", state_root=state_root.hex()[:16])
         else:
             REGISTRY.counter_add(
                 "fisco_scheduler_lazy_roots_total",
                 help="speculative executions returning pending (dispatched, "
                 "un-synced) root futures",
             )
-            timer.stage("roots", dispatched="lazy")
+            sp.stage("roots", dispatched="lazy")
 
         if self.state_plane is not None:
             # incremental commitment update from THIS block's write set
@@ -500,7 +507,7 @@ class Scheduler:
                 else:
                     header.state_commitment = commitment
                     header.clear_hash_cache()
-                timer.stage("stateCommit")
+                sp.stage("stateCommit")
 
         with self._lock:
             # anything executed ABOVE this height was chained on the state
@@ -519,12 +526,15 @@ class Scheduler:
                 ),
                 pending_roots=pending,
             )
+        sp.stage("store")
         return header
 
-    def _resolve_roots_locked(self, eb: ExecutedBlock) -> BlockHeader:
+    def _resolve_roots_locked(self, eb: ExecutedBlock, sp=None) -> BlockHeader:
         """Sync a lazily-executed block's pending root futures into its
         header (runs under self._lock — single resolver). The wait is a
-        device sync, attributed as such for the observatory."""
+        device sync, attributed as such for the observatory, and the
+        `roots` stage of the span `sp` it happens under (the caller marks
+        what came before it)."""
         pend = eb.pending_roots
         if pend is not None:
             state_f, txs_f, receipts_f = pend
@@ -535,6 +545,8 @@ class Scheduler:
                 header.receipts_root = receipts_f()
             header.clear_hash_cache()
             eb.pending_roots = None
+            if sp is not None:
+                sp.stage("roots")
         return eb.header
 
     # -- commitBlock:390 -----------------------------------------------------
@@ -542,7 +554,9 @@ class Scheduler:
     def commit_block(self, header: BlockHeader) -> None:
         number = header.number
         with TRACER.span(
-            "scheduler.commit_block", block=number
+            "scheduler.commit_block",
+            block=number,
+            stage_log=(_log, f"CommitBlock.{number}"),
         ) as sp, PIPELINE.busy("commit"):
             t0 = time.perf_counter()
             with self._lock:
@@ -555,14 +569,14 @@ class Scheduler:
                     with PIPELINE.blocked("prior_commit"):
                         while self._committing:
                             self._commit_done.wait()
-                cached = self._gate_commit_locked(header)
+                cached = self._gate_commit_locked(header, sp)
+            sp.stage("gate")
             # The prewrite reads and the 2PC legs run OUTSIDE the scheduler
             # lock: on the Pro/Max splits they round-trip to remote
             # executor/storage services, and holding self._lock across that
             # IO would serialize execute_block callers behind remote
             # latency (the runtime lock-order recorder flags it). The
             # in-flight marker keeps commits strictly serialized anyway.
-            timer = StageTimer(_log, f"CommitBlock.{number}")
             # storage observatory: the per-block commit ledger window —
             # every codec/copy seam touched until finish_commit folds into
             # block `number`'s record, and encodes on this thread carry
@@ -572,6 +586,7 @@ class Scheduler:
                 with codec_ctx(CTX_COMMIT):
                     ledger_writes = StateStorage()
                     self.ledger.prewrite_block(cached.block, ledger_writes)
+                    sp.stage("prewrite")
                     params = TwoPCParams(number=number)
                     # the 2PC legs as spans: on a remote executor/storage
                     # split these parent the service-side svc.*.prepare/
@@ -586,7 +601,7 @@ class Scheduler:
                         self.executor.prepare(
                             params, extra_writes=ledger_writes
                         )
-                    timer.stage("prepare")
+                    sp.stage("prepare")
                     STORAGE.end_prepare(number)
                     # crash window: the 2PC slot is durably staged, the
                     # commit has not run — a reboot finds the prepared-but-
@@ -601,7 +616,7 @@ class Scheduler:
                         "scheduler.2pc_commit", block=number
                     ), PIPELINE.blocked("2pc_commit"):
                         self.executor.commit(params)
-                    timer.stage("commit")
+                    sp.stage("commit")
                 FLIGHT.record(
                     "2pc", "booked", scope=self.crash_scope, height=number
                 )
@@ -644,6 +659,7 @@ class Scheduler:
                     self._notify.post(
                         lambda cb=cb: _run_notify(cb, number, block)
                     )
+            sp.stage("booked")
             from ..observability.tracer import trace_hex
 
             REGISTRY.observe(
@@ -653,9 +669,11 @@ class Scheduler:
                 exemplar=trace_hex(sp.ctx),
             )
 
-    def _gate_commit_locked(self, header: BlockHeader) -> "ExecutedBlock":
+    def _gate_commit_locked(self, header: BlockHeader, sp) -> "ExecutedBlock":
         """Height-order gate + in-flight marker (runs under self._lock);
-        returns the cached execution whose 2PC the caller drives lock-free."""
+        returns the cached execution whose 2PC the caller drives lock-free.
+        Roots still pending are synced here, as the `roots` stage of the
+        commit span `sp` between two parts of its `gate`."""
         number = header.number
         # commits must land in height order: with the block pipeline, a
         # SPECULATIVE block N+1 is executed (and preparable) while N is
@@ -676,7 +694,9 @@ class Scheduler:
             raise SchedulerError(
                 ErrorCode.SCHEDULER_INVALID_BLOCK, f"commit of unexecuted block {number}"
             )
-        self._resolve_roots_locked(cached)
+        if cached.pending_roots is not None:
+            sp.stage("gate")
+            self._resolve_roots_locked(cached, sp)
         if cached.header.hash(self.suite) != header.hash(self.suite):
             raise SchedulerError(
                 ErrorCode.SCHEDULER_INVALID_BLOCK,

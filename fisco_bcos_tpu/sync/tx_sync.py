@@ -126,22 +126,24 @@ class TransactionSync:
             _log.warning("bad tx-sync message from %s: %s", src.hex()[:8], e)
 
     def _on_push(self, raw: list[bytes], src: bytes = b"") -> None:
-        txs = []
-        for b in raw:
-            try:
-                txs.append(Transaction.decode(b))
-            except Exception as e:
-                # a peer pushing undecodable txs is worth counting
-                note_swallowed("tx_sync.push_decode", e)
-                continue
-        if txs:
-            # device batch verify + admission (importDownloadedTxs:521);
-            # gossip rides the plane's lowest-priority lane, and the peer id
-            # is the strike source — a peer spamming invalid signatures gets
-            # demoted at this pool's door
-            self.txpool.submit_batch(
-                txs, lane="sync", source=f"peer:{src.hex()[:16]}"
-            )
+        with TRACER.span("txsync.push", txs=len(raw)) as sp:
+            txs = []
+            for b in raw:
+                try:
+                    txs.append(Transaction.decode(b))
+                except Exception as e:
+                    # a peer pushing undecodable txs is worth counting
+                    note_swallowed("tx_sync.push_decode", e)
+                    continue
+            sp.stage("decode")
+            if txs:
+                # device batch verify + admission (importDownloadedTxs:521);
+                # gossip rides the plane's lowest-priority lane, and the peer
+                # id is the strike source — a peer spamming invalid signatures
+                # gets demoted at this pool's door
+                self.txpool.submit_batch(
+                    txs, lane="sync", source=f"peer:{src.hex()[:16]}"
+                )
 
     def _on_request(self, src: bytes, hashes: list[bytes]) -> None:
         found = [t.encode() for t in self.txpool.fetch_txs(hashes) if t is not None]

@@ -156,10 +156,18 @@ class TxPool:
         and only rejected lanes pay a host hash for their result row."""
         from ..observability.pipeline import PIPELINE
 
-        with TRACER.span(
+        # the span's stage marks split a batch's admission where it happens:
+        # static (the per-transaction checks and the quota), verify (the fused
+        # program, its waits included), insert (the pool, the results, the
+        # index, the persist, the batch's telemetry and the frame's release).
+        # The span sits inside the stage's busy mark and takes its last stage
+        # mark here, so the marks add up to it
+        with PIPELINE.busy("admission"), TRACER.span(
             "txpool.submit_batch", batch=len(txs), lane=lane
-        ) as sp, PIPELINE.busy("admission"):
-            return self._submit_batch_spanned(txs, lane, source, policed, sp)
+        ) as sp:
+            results = self._submit_batch_spanned(txs, lane, source, policed, sp)
+            sp.stage("insert")
+            return results
 
     def _submit_batch_spanned(
         self,
@@ -218,6 +226,7 @@ class TxPool:
                     txs[i].hash(self.suite), ErrorCode.OVER_GROUP_QUOTA
                 )
             to_verify = to_verify[:granted]
+        sp.stage("static")
         if to_verify:
             from ..device.plane import device_group, device_lane
 
@@ -227,6 +236,7 @@ class TxPool:
             # tenant's traffic.
             with device_group(self.group_id), device_lane(lane):
                 ok = batch_admit([txs[i] for i in to_verify], self.suite)
+            sp.stage("verify")
             invalid = 0
             persisted: list[tuple[bytes, "Entry"]] = []
             for j, i in enumerate(to_verify):

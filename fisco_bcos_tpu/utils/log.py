@@ -9,7 +9,6 @@ from __future__ import annotations
 
 import logging
 import threading
-import time
 from typing import Any
 
 _FORMAT = "%(asctime)s %(levelname)s [%(name)s] %(message)s"
@@ -69,29 +68,3 @@ def note_swallowed(site: str, exc: BaseException | None = None) -> None:
         logging.getLogger("fisco.swallowed").debug(
             "swallowed at %s: %r", site, exc
         )
-
-
-class StageTimer:
-    """Stage-timing helper mirroring the reference's BlockTrace logs
-    (e.g. DMCExecute.0..6 in bcos-scheduler BlockExecutive.cpp:849-1010)."""
-
-    def __init__(self, logger: logging.Logger, badge: str):
-        self._logger = logger
-        self._badge = badge
-        self._t0 = time.monotonic()
-        self._last = self._t0
-        self._stage = 0
-
-    def stage(self, desc: str, **kvs: Any) -> None:
-        now = time.monotonic()
-        self._logger.info(
-            kv_line(
-                f"{self._badge}.{self._stage}",
-                desc,
-                stageMs=round((now - self._last) * 1e3, 3),
-                totalMs=round((now - self._t0) * 1e3, 3),
-                **kvs,
-            )
-        )
-        self._last = now
-        self._stage += 1

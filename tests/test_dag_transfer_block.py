@@ -133,26 +133,21 @@ def test_dag_runner_is_serial_equivalent_whatever_the_workers(workers, serial, m
     after = stage_seconds()
     assert all(after[k] > stage_before[k] for k in after)
 
-    # and so do the spans: one a level, one a checked level, under one block record
+    # and so does the block's one record: a width, a framed and a pooled count
+    # a level, a verdict a checked level, the seconds of the three stages
     spans = TRACER.spans()
-    block = [s for s in spans if s.name == "executor.execute"]
-    assert len(block) == 1
-    assert block[0].attrs["mode"] == "dag" and block[0].attrs["txs"] == len(txs)
-    assert block[0].attrs["levels"] == len(levels) and block[0].attrs["reruns"] == 0
-    ran = [s for s in spans if s.name == "executor.dag_level"]
-    assert [s.attrs["width"] for s in ran] == [len(level) for level in levels]
-    assert [s.attrs["framed"] for s in ran] == [len(level) for level in levels]
-    assert not any(s.attrs["pooled"] for s in ran)
-    checked = [s for s in spans if s.name == "executor.dag_validate"]
-    assert [s.attrs["width"] for s in checked] == [
-        len(level) for level in levels if len(level) > 1]
-    assert not any(s.attrs["conflict"] for s in checked)
-    (levelize,) = [s for s in spans if s.name == "executor.dag_levelize"]
-    assert levelize.attrs["levels"] == len(levels)
-    inside = [s for s in spans if s.name.startswith("executor.dag_")]
-    assert all(s.parent == "executor.execute" for s in inside)
-    parts = sum(s.dur for s in inside)
-    assert parts <= block[0].dur and parts == pytest.approx(
+    (block,) = [s for s in spans if s.name == "executor.execute"]
+    assert not [s for s in spans if s.name.startswith("executor.dag_")]
+    at = block.attrs
+    assert at["mode"] == "dag" and at["txs"] == len(txs)
+    assert at["levels"] == len(levels) and at["reruns"] == 0
+    assert at["widths"] == at["framed"] == tuple(len(level) for level in levels)
+    assert at["pooled"] == (0,) * len(levels)
+    assert at["conflicts"] == (False,) * sum(1 for level in levels if len(level) > 1)
+    assert set(at["stages"]) == {"levelize", "run", "validate"}
+    assert "marks" not in at, "a mark a level and a check: sums only"
+    parts = sum(at["stages"].values())
+    assert parts <= block.dur and parts == pytest.approx(
         sum(after[k] - stage_before[k] for k in after), rel=1e-6)
 
 
@@ -190,8 +185,7 @@ def test_a_declaration_that_lies_is_caught_and_the_block_ends_on_the_serial_root
     assert counter("fisco_executor_dag_conflict_reruns_total") == reruns + 1
     assert (plain(receipts), ex.get_hash()) == serial
     (block,) = [s for s in TRACER.spans() if s.name == "executor.execute"]
-    assert block.attrs["reruns"] == 1
-    assert any(s.attrs["conflict"] for s in TRACER.spans() if s.name == "executor.dag_validate")
+    assert block.attrs["reruns"] == 1 and block.attrs["conflicts"][-1] is True
 
 
 class BlindBalance(DagTransferPrecompiled):
@@ -227,16 +221,17 @@ def test_a_read_of_a_peers_write_inside_a_framed_level_is_caught(monkeypatch):
     TRACER.clear()
     receipts = ex.dag_execute_transactions(txs)
     assert moved(before)["conflict_reruns"] == 1 and moved(before)["pooled_txs"] == 0
-    checked = [s for s in TRACER.spans() if s.name == "executor.dag_validate"]
-    ran = [s for s in TRACER.spans() if s.name == "executor.dag_level"]
+    (block,) = [s for s in TRACER.spans() if s.name == "executor.execute"]
     monkeypatch.setenv("FISCO_DAG_SERIAL", "1")
     assert plain(receipts) == plain(judge.dag_execute_transactions(txs))
     assert ex.get_hash() == judge.get_hash()
     assert moved(before)["conflict_reruns"] == 1, "the pinned loop checks nothing"
-    (first,) = checked
-    assert first.attrs["conflict"] and first.attrs["width"] == len(level0)
-    assert ran[0].attrs["framed"] == len(level0) and len(ran) == 1 + len(ex.dag_levels(txs))
-    assert all(s.attrs["framed"] == 0 and not s.attrs["pooled"] for s in ran[1:])
+    # the first level ran in the frame and failed its check; then every level again
+    at = block.attrs
+    assert at["conflicts"] == (True,) and at["reruns"] == 1
+    assert at["widths"] == (len(level0),) + tuple(len(level) for level in ex.dag_levels(txs))
+    assert at["framed"] == (len(level0),) + (0,) * (len(at["widths"]) - 1)
+    assert at["pooled"] == (0,) * len(at["widths"])
 
 
 def deployed_setfor(ex):
@@ -294,12 +289,12 @@ def test_a_level_of_precompile_and_contract_calls_splits_by_callee(workers, monk
                              "framed_txs": len(txs) - 6, "conflict_reruns": 0}
     assert sum(to_pool) >= 5 * (workers > 1)
     assert pools == ([workers] if workers > 1 else []), "one pool a call, opened when needed"
-    ran = [s for s in TRACER.spans() if s.name == "executor.dag_level"]
-    assert [s.attrs["pooled"] for s in ran] == [n > 0 for n in to_pool]
-    assert [s.attrs["framed"] for s in ran] == [
-        len(level) - len(mine) for mine, level in zip(of_contract, levels)]
-    checked = [s for s in TRACER.spans() if s.name == "executor.dag_validate"]
-    assert len(checked) == sum(1 for level in levels if len(level) > 1)
+    (block,) = [s for s in TRACER.spans()
+                if s.name == "executor.execute" and s.attrs["mode"] == "dag"]
+    assert block.attrs["pooled"] == tuple(to_pool)
+    assert block.attrs["framed"] == tuple(
+        len(level) - len(mine) for mine, level in zip(of_contract, levels))
+    assert len(block.attrs["conflicts"]) == sum(1 for level in levels if len(level) > 1)
 
 
 def test_a_block_of_precompile_calls_opens_no_pool(serial, monkeypatch):

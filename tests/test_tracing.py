@@ -697,3 +697,158 @@ def test_slow_span_witness_outlives_the_last_events_ring():
     assert len(snap) == FLIGHT_CAP + 1
     assert snap[0]["category"] == "slow_span" and snap[0]["detail"]["dur_ms"] == 4236.0
     assert [e["t"] for e in snap] == sorted(e["t"] for e in snap)
+
+
+# ---------------------------------------------------------------------------
+# the stage clock
+# ---------------------------------------------------------------------------
+
+
+def _stage_counters(span):
+    from fisco_bcos_tpu.utils.metrics import REGISTRY
+
+    base = f'fisco_span_stage_seconds_total{{span="{span}",stage="'
+    return {name[len(base):-2]: v for name, v in REGISTRY.counters_matching(base).items()}
+
+
+def test_stage_marks_sum_by_name_and_keep_their_offsets():
+    """A mark gives the seconds since the previous one (or the start) to its
+    name; the record carries the sums and the ordered end offsets, and the
+    counter family moves by the same sums when the span closes."""
+    import time
+
+    tr = Tracer()
+    before = _stage_counters("stage.probe")
+    with tr.span("stage.probe") as sp:
+        time.sleep(0.002)
+        sp.stage("a")
+        sp.stage("b", ignored="for the line only")
+        time.sleep(0.001)
+        sp.stage("a")
+        assert set(sp.stages) == {"a", "b"} and not tr.spans(), "a mark writes no record"
+        assert _stage_counters("stage.probe") == before, "fed at close, not at a mark"
+    (rec,) = tr.spans()
+    stages, marks = rec.attrs["stages"], rec.attrs["marks"]
+    assert [name for name, _ in marks] == ["a", "b", "a"]
+    offsets = [off for _, off in marks]
+    assert offsets == sorted(offsets) and 0.003 <= offsets[-1] <= rec.dur
+    assert stages["a"] == pytest.approx(offsets[0] + offsets[2] - offsets[1])
+    assert stages["b"] == pytest.approx(offsets[1] - offsets[0])
+    assert stages["a"] >= 0.003 and sum(stages.values()) == pytest.approx(offsets[-1])
+    assert "ignored" not in rec.attrs
+    after = _stage_counters("stage.probe")
+    assert {k: after[k] - before.get(k, 0.0) for k in after} == pytest.approx(stages)
+    # a span that marks nothing closes as it always did
+    with tr.span("stage.unmarked"):
+        pass
+    assert "stages" not in tr.spans()[-1].attrs and "marks" not in tr.spans()[-1].attrs
+
+
+@pytest.mark.parametrize("n", [16, 17, 400])
+def test_a_span_keeps_offsets_up_to_sixteen_marks_and_sums_beyond(n):
+    from fisco_bcos_tpu.observability.tracer import MAX_MARKS
+
+    tr = Tracer()
+    with tr.span("stage.many") as sp:
+        for i in range(n):
+            sp.stage(("run", "validate")[i % 2])
+    (rec,) = tr.spans()
+    assert set(rec.attrs["stages"]) == {"run", "validate"}
+    assert sum(rec.attrs["stages"].values()) <= rec.dur
+    if n <= MAX_MARKS:
+        assert len(rec.attrs["marks"]) == n
+    else:
+        assert "marks" not in rec.attrs
+
+
+def test_a_disabled_tracer_marks_nothing_and_keeps_nothing():
+    import logging
+    import sys
+
+    from fisco_bcos_tpu.observability.tracer import _NOOP
+
+    tr = Tracer(enabled=False)
+    before = _stage_counters("stage.off")
+    with tr.span("stage.off") as sp:
+        assert sp is _NOOP
+        sp.stage("warm", txs=1)
+        blocks = sys.getallocatedblocks()
+        for _ in range(1000):
+            sp.stage("a", txs=1)
+        assert sys.getallocatedblocks() - blocks <= 2
+        assert not sp.stages
+    assert not tr.spans() and _stage_counters("stage.off") == before
+    # an unsampled trace is the same no-op
+    assert Tracer(sample_rate=0.0).span("stage.off") is _NOOP
+
+    # with a stage log attached the line is written all the same: an
+    # operator's log does not go quiet with the telemetry
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda record: lines.append(record.getMessage())
+    log = logging.getLogger("test.stage_off")
+    log.setLevel(logging.INFO)
+    log.addHandler(handler)
+    try:
+        with tr.span("stage.off", stage_log=(log, "Probe.7")) as sp:
+            sp.stage("fill", txs=3)
+            sp.stage("execute", dag=2, serial=1)
+            assert not sp.stages
+    finally:
+        log.removeHandler(handler)
+    assert not tr.spans()
+    assert lines[0].startswith("[Probe.7.0]|fill|stageMs=") and lines[0].endswith("|txs=3")
+    assert lines[1].startswith("[Probe.7.1]|execute|stageMs=")
+    assert lines[1].endswith("|dag=2|serial=1")
+
+
+@pytest.mark.parametrize("telemetry", [True, False])
+def test_scheduler_stage_lines_still_match_the_dag_drivers_pattern(telemetry, monkeypatch):
+    """``benchmark/drivers/air4_dag.py`` reads ``dag=``/``serial=`` off the
+    ``execute`` line of every block: one of ``correct``'s numbers. The marks
+    around it are new; the line keeps its shape, with the tracer on or off."""
+    import logging
+
+    from benchmark.drivers.air4_dag import _STAGE_RE
+    from fisco_bcos_tpu.executor.precompiled import DAG_TRANSFER_ADDRESS
+    from fisco_bcos_tpu.protocol.transaction import TransactionAttribute
+    from test_executor import Env
+
+    monkeypatch.setattr(TRACER, "enabled", telemetry)
+    lines = []
+    handler = logging.Handler(level=logging.INFO)
+    handler.emit = lambda record: lines.append(record.getMessage())
+    logging.getLogger("scheduler").addHandler(handler)
+    TRACER.clear()
+    try:
+        env = Env()
+        env.run_block([
+            env.tx(DAG_TRANSFER_ADDRESS, "userAdd(string,uint256)", "alice", 100),
+            env.tx(DAG_TRANSFER_ADDRESS, "userAdd(string,uint256)", "bob", 50),
+        ])
+        env.run_block([
+            env.tx(DAG_TRANSFER_ADDRESS, "userTransfer(string,string,uint256)",
+                   "alice", "bob", 30, attribute=TransactionAttribute.DAG),
+            env.tx(DAG_TRANSFER_ADDRESS, "userAdd(string,uint256)", "carol", 1),
+        ])
+    finally:
+        logging.getLogger("scheduler").removeHandler(handler)
+    matched = [m.groups() for m in map(_STAGE_RE.match, lines) if m]
+    assert matched == [("1", "0", "2"), ("2", "1", "1")]
+    stages = [ln.split("|")[1] for ln in lines if ln.startswith("[ExecuteBlock.2.")]
+    assert stages == ["fillBlock", "execute", "stateRoot", "txsRoot", "receiptsRoot",
+                      "roots", "store"]
+    assert [ln.split("|")[1] for ln in lines if ln.startswith("[CommitBlock.2.")] == [
+        "gate", "prewrite", "prepare", "commit", "booked"]
+    recs = {r.name: r for r in TRACER.spans() if r.attrs.get("block") == 2}
+    if not telemetry:
+        assert not recs
+        return
+    ex, co = recs["scheduler.execute_block"], recs["scheduler.commit_block"]
+    assert [name for name, _ in ex.attrs["marks"]] == stages
+    assert list(co.attrs["stages"]) == ["gate", "prewrite", "prepare", "commit", "booked"]
+    for rec in (ex, co):
+        assert 0.0 < sum(rec.attrs["stages"].values()) <= rec.dur
+    # the 2PC's legs keep their child spans: a Pro/Max split parents remote spans on them
+    assert recs["scheduler.2pc_prepare"].parent == recs["scheduler.2pc_commit"].parent \
+        == "scheduler.commit_block"
