@@ -42,7 +42,12 @@ from ..observability.pipeline import PIPELINE
 from ..protocol.block_header import BlockHeader
 from ..protocol.receipt import TransactionReceipt, TransactionStatus
 from ..protocol.transaction import Transaction
-from ..storage.interfaces import StorageInterface, TransactionalStorage, TwoPCParams
+from ..storage.interfaces import (
+    StorageInterface,
+    TransactionalStorage,
+    TwoPCParams,
+    staged_rows,
+)
 from ..storage.state_storage import StateStorage
 from ..utils.log import get_logger
 from ..utils.metrics import REGISTRY
@@ -858,7 +863,9 @@ class TransactionExecutor:
 
     # -- 2PC (prepare:1681 / commit:1745 / rollback:1813) -------------------
 
-    def prepare(self, params: TwoPCParams, extra_writes: StorageInterface | None = None) -> None:
+    def prepare(
+        self, params: TwoPCParams, extra_writes: StorageInterface | None = None
+    ) -> dict[str, int] | None:
         """Stage the block's state (plus the scheduler's ledger writes)
         into the durable backend. The ledger rows are CHAINED as a
         traverse view, never merged into the block overlay: block N+1's
@@ -866,7 +873,9 @@ class TransactionExecutor:
         is in flight (the pipelined commit), and a mutating merge here
         would be a torn read under it. Every backend's prepare is a
         per-key last-wins merge, so the chained order (block rows, then
-        ledger rows) stages identically to the old in-place merge."""
+        ledger rows) stages identically to the old in-place merge.
+        Returns the backend's tally of the rows it staged
+        (storage.interfaces.count_prepared), None where it gives none."""
         ctx = self._blocks.get(params.number)
         if ctx is None:
             raise RuntimeError(f"no executed block {params.number} to prepare")
@@ -888,12 +897,13 @@ class TransactionExecutor:
                 rows += extra_count()
             STORAGE.note_commit_rows(params.number, rows)
         t0 = time.perf_counter()
-        self.backend.prepare(params, writes)
+        staged = self.backend.prepare(params, writes)
         REGISTRY.observe(
             "fisco_storage_prepare_latency_ms",
             (time.perf_counter() - t0) * 1e3,
             help="2PC prepare (durable staging) wall latency",
         )
+        return staged
 
     def commit(self, params: TwoPCParams) -> None:
         t0 = time.perf_counter()
@@ -934,6 +944,14 @@ class _StagedWrites:
     def traverse(self):
         for layer in self._layers:
             yield from layer.traverse()
+
+    def borrow_rows(self):
+        """The layers' own objects where they lend them
+        (TraversableStorage.borrow_rows), copies from a layer that cannot."""
+        rows = {}
+        for layer in self._layers:
+            rows.update(staged_rows(layer)[0])
+        return rows
 
 
 class _ExecFrame:

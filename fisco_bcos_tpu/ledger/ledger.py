@@ -227,12 +227,15 @@ class Ledger:
 
     def prewrite_block(self, block: Block, out: StorageInterface) -> None:
         """Stage all chain-data writes for `block` into the overlay `out`
-        (Ledger.cpp asyncPrewriteBlock)."""
+        (Ledger.cpp asyncPrewriteBlock). Each row's Entry is built once,
+        here, for this row: an overlay that can adopt it
+        (StateStorage.adopt_row) keeps that object; any other
+        StorageInterface copies it in through set_row."""
         header = block.header
         suite = self.suite
         num_key = str(header.number).encode()
         h = header.hash(suite)
-        put = out.set_row
+        put = getattr(out, "adopt_row", None) or out.set_row
         put(SYS_NUMBER_2_HEADER, num_key, Entry().set(header.encode()))
         put(SYS_NUMBER_2_HASH, num_key, Entry().set(h))
         put(SYS_HASH_2_NUMBER, h, Entry().set(num_key))

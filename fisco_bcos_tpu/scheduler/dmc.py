@@ -207,6 +207,10 @@ class TrackingStorage(StateStorage):
         self.touched.add((table, bytes(key)))
         super().set_row(table, key, entry)
 
+    def adopt_row(self, table: str, key: bytes, entry: Entry) -> None:
+        self.touched.add((table, bytes(key)))
+        super().adopt_row(table, key, entry)
+
 
 @dataclass
 class _Parked:

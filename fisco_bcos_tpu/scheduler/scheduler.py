@@ -598,10 +598,12 @@ class Scheduler:
                     with TRACER.span(
                         "scheduler.2pc_prepare", block=number
                     ), PIPELINE.blocked("2pc_prepare"):
-                        self.executor.prepare(
+                        staged = self.executor.prepare(
                             params, extra_writes=ledger_writes
                         )
-                    sp.stage("prepare")
+                    # rows the backend kept as they were / copied on the way
+                    # in (count_prepared; nothing from a remote executor)
+                    sp.stage("prepare", **(staged or {}))
                     STORAGE.end_prepare(number)
                     # crash window: the 2PC slot is durably staged, the
                     # commit has not run — a reboot finds the prepared-but-

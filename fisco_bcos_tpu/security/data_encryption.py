@@ -94,8 +94,8 @@ class EncryptedStorage(TransactionalStorage):
 
     # -- 2PC: encrypt the staged write-set on its way down -------------------
 
-    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> None:
-        self.inner.prepare(params, _EncryptingView(writes, self.enc))
+    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> dict[str, int] | None:
+        return self.inner.prepare(params, _EncryptingView(writes, self.enc))
 
     def commit(self, params: TwoPCParams) -> None:
         self.inner.commit(params)

@@ -10,13 +10,12 @@ sqlite.
 
 from __future__ import annotations
 
-from typing import Iterator
-
 from ..codec.flat import FlatReader, FlatWriter
 from ..resilience import RetryPolicy
 from ..storage.entry import Entry
 from ..utils.log import note_swallowed
 from ..storage.interfaces import (
+    RowsView,
     TransactionalStorage,
     TraversableStorage,
     TwoPCParams,
@@ -93,12 +92,7 @@ class StorageService:
             lambda r2: (r2.str_(), r2.bytes_(), Entry.decode(r2.bytes_()))
         )
         r.done()
-
-        class _View(TraversableStorage):
-            def traverse(self) -> Iterator:
-                yield from rows
-
-        self.backend.prepare(TwoPCParams(number=number), _View())
+        self.backend.prepare(TwoPCParams(number=number), RowsView(rows))
         return b""
 
     def _commit(self, payload: bytes) -> bytes:

@@ -19,6 +19,7 @@ from .interfaces import (
     TransactionalStorage,
     TraversableStorage,
     TwoPCParams,
+    staged_rows,
 )
 
 
@@ -96,11 +97,13 @@ class CacheStorage(TransactionalStorage):
 
     # -- 2PC -------------------------------------------------------------------
 
-    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> None:
-        staged = [(t, bytes(k)) for t, k, _ in writes.traverse()]
+    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> dict[str, int] | None:
+        staged = list(staged_rows(writes)[0])
         with self._lock:
-            self._staged_keys[params.number] = staged
-        self.inner.prepare(params, writes)
+            # per number, every participant's keys (a Max block is prepared
+            # by several executors): commit must drop them all
+            self._staged_keys.setdefault(params.number, []).extend(staged)
+        return self.inner.prepare(params, writes)
 
     def commit(self, params: TwoPCParams) -> None:
         self.inner.commit(params)

@@ -30,7 +30,6 @@ layer, so shard layout never leaks into them).
 from __future__ import annotations
 
 import hashlib
-from typing import Iterator
 
 from ..observability.storagelog import STORAGE as _OBS
 from ..resilience import HEALTH
@@ -38,21 +37,15 @@ from ..service.rpc import ServiceConnectionError, ServiceRemoteError
 from ..service.storage_service import RemoteStorage
 from ..storage.entry import Entry
 from ..storage.interfaces import (
+    RowsView,
     TransactionalStorage,
     TraversableStorage,
     TwoPCParams,
+    staged_rows,
 )
 from ..utils.log import get_logger
 
 _log = get_logger("dist-storage")
-
-
-class _RowsView(TraversableStorage):
-    def __init__(self, rows):
-        self._rows = rows
-
-    def traverse(self) -> Iterator:
-        yield from self._rows
 
 
 class DistributedStorage(TransactionalStorage):
@@ -159,7 +152,7 @@ class DistributedStorage(TransactionalStorage):
         # so a leftover re-drive task must not kill them later
         self._rolled_back.pop(params.number, None)
         parts: dict[int, list] = {i: [] for i in range(len(self.shards))}
-        for t, k, e in writes.traverse():
+        for (t, k), e in staged_rows(writes)[0].items():  # read and re-sent: no copy
             parts[self.shard_of(t, k)].append((t, k, e))
         # primary (shard 0) first — its prepared slot carries the commit
         # witness, so the witness becomes durable exactly when the primary
@@ -173,7 +166,7 @@ class DistributedStorage(TransactionalStorage):
         )
         for idx in range(len(self.shards)):
             if not _OBS.enabled:
-                self.shards[idx].prepare(params, _RowsView(parts[idx]))
+                self.shards[idx].prepare(params, RowsView(parts[idx]))
                 continue
             # staged-byte attribution by encode-delta across the leg: the
             # RemoteStorage client encodes every row for the wire inside
@@ -181,7 +174,7 @@ class DistributedStorage(TransactionalStorage):
             # staged payload — no second encode pass
             t0 = _OBS.clock()
             b0 = _OBS.encode_bytes_now()
-            self.shards[idx].prepare(params, _RowsView(parts[idx]))
+            self.shards[idx].prepare(params, RowsView(parts[idx]))
             _OBS.shard_note(
                 "prepare",
                 idx,

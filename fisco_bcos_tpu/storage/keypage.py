@@ -31,9 +31,11 @@ from ..observability.storagelog import (
 )
 from .entry import Entry, EntryStatus
 from .interfaces import (
+    RowsView,
     TransactionalStorage,
     TraversableStorage,
     TwoPCParams,
+    staged_rows,
 )
 
 META_TABLE = "__kp_meta__"
@@ -263,19 +265,11 @@ class KeyPageStorage(TransactionalStorage):
 
     # -- 2PC: repack the row write-set into page writes ------------------------
 
-    class _PageView(TraversableStorage):
-        def __init__(self, rows: list[tuple[str, bytes, Entry]]):
-            self._rows = rows
-
-        def traverse(self):
-            yield from self._rows
-
-    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> None:
+    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> dict[str, int] | None:
         with self._lock:
             staged: dict[tuple[str, bytes], dict[bytes, Entry]] = {}
             metas: dict[str, list[bytes]] = {}
-            for table, key, entry in writes.traverse():
-                key = bytes(key)
+            for (table, key), entry in staged_rows(writes)[0].items():
                 if table not in metas:  # setdefault would re-copy per row
                     metas[table] = self._meta_locked(table)
                 starts = metas[table]
@@ -286,10 +280,10 @@ class KeyPageStorage(TransactionalStorage):
                 start = starts[idx]
                 # pending writes as a dict (last wins), merged into the
                 # decoded page once — per-item list surgery is quadratic
-                # on a 2000-row block write-set
-                if _OBS.enabled:
-                    _OBS.note_copy("keypage.prepare", table)
-                staged.setdefault((table, start), {})[key] = entry.copy()
+                # on a 2000-row block write-set. The row is only read: the
+                # merged page is encoded below and nothing of it is kept
+                # (commit drops the decoded caches), so no copy
+                staged.setdefault((table, start), {})[key] = entry
             rows: list[tuple[str, bytes, Entry]] = []
             for (table, start), pending in staged.items():
                 starts = metas[table]
@@ -327,7 +321,7 @@ class KeyPageStorage(TransactionalStorage):
                         Entry({"value": _encode_meta(starts)}),
                     )
                 )
-            self.inner.prepare(params, self._PageView(rows))
+            return self.inner.prepare(params, RowsView(rows))
 
     def commit(self, params: TwoPCParams) -> None:
         # the 2PC write-set lands through inner.prepare/commit, bypassing

@@ -6,7 +6,13 @@ import threading
 from typing import Iterator
 
 from .entry import Entry
-from .interfaces import TransactionalStorage, TraversableStorage, TwoPCParams
+from .interfaces import (
+    TransactionalStorage,
+    TraversableStorage,
+    TwoPCParams,
+    count_prepared,
+    staged_rows,
+)
 
 
 class MemoryStorage(TransactionalStorage):
@@ -38,21 +44,24 @@ class MemoryStorage(TransactionalStorage):
 
     # -- 2PC ------------------------------------------------------------
 
-    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> None:
+    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> dict[str, int]:
         """Stage writes for `number`. PER-KEY MERGE, not slot replacement:
         a Max-form block is prepared by several executor participants, each
         staging its own (disjoint) dirty set into the same number — TiKV's
         multi-participant prewrite semantics. Re-preparing the same key
-        (block re-execution after a term switch) overwrites per key."""
+        (block re-execution after a term switch) overwrites per key.
+
+        The slot KEEPS the Entry objects it is given (borrow_rows' contract:
+        nobody mutates a stored Entry, and this store copies in and out
+        like the overlays do), so a row changes hands and is not copied."""
+        rows, mode = staged_rows(writes)
         with self._lock:
-            slot = self._pending.setdefault(params.number, {})
-            for t, k, e in writes.traverse():
-                slot[(t, bytes(k))] = e.copy()
+            self._pending.setdefault(params.number, {}).update(rows)
+        return count_prepared(mode, len(rows))
 
     def commit(self, params: TwoPCParams) -> None:
         with self._lock:
-            for (t, k), e in self._pending.pop(params.number, {}).items():
-                self._data[(t, k)] = e
+            self._data.update(self._pending.pop(params.number, {}))
 
     def rollback(self, params: TwoPCParams) -> None:
         with self._lock:

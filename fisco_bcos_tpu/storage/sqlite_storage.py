@@ -16,7 +16,13 @@ from typing import Iterator
 
 from ..observability.storagelog import CTX_INGRESS, codec_ctx
 from .entry import Entry
-from .interfaces import TransactionalStorage, TraversableStorage, TwoPCParams
+from .interfaces import (
+    TransactionalStorage,
+    TraversableStorage,
+    TwoPCParams,
+    count_prepared,
+    staged_rows,
+)
 
 
 class SQLiteStorage(TransactionalStorage):
@@ -90,15 +96,14 @@ class SQLiteStorage(TransactionalStorage):
 
     # -- 2PC ------------------------------------------------------------
 
-    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> None:
+    def prepare(self, params: TwoPCParams, writes: TraversableStorage) -> dict[str, int]:
         """Durably stage writes for `number`. Per-key merge, not slot
         replacement (multi-participant 2PC: several Max executors prepare
-        the same block; see MemoryStorage.prepare)."""
+        the same block; see MemoryStorage.prepare). The rows are only
+        encoded here, so they are read where they lie (staged_rows)."""
+        lent, mode = staged_rows(writes)
         with self._lock:
-            rows = [
-                (params.number, t, bytes(k), e.encode())
-                for t, k, e in writes.traverse()
-            ]
+            rows = [(params.number, t, k, e.encode()) for (t, k), e in lent.items()]
             self.bytes_staged += sum(len(r[3]) for r in rows)
             self._conn.executemany(
                 "INSERT OR REPLACE INTO pending_2pc (num, tbl, k, v)"
@@ -106,6 +111,7 @@ class SQLiteStorage(TransactionalStorage):
                 rows,
             )
             self._conn.commit()
+        return count_prepared(mode, len(rows))
 
     def commit(self, params: TwoPCParams) -> None:
         with self._lock:

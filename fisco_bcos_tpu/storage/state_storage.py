@@ -77,6 +77,12 @@ class StateStorage(TraversableStorage):
         with self._lock:
             self._data[(table, bytes(key))] = entry.copy()
 
+    def adopt_row(self, table: str, key: bytes, entry: Entry) -> None:
+        """``set_row`` for a caller that built ``entry`` for this row and
+        gives it up: the object is stored, not a copy of it."""
+        with self._lock:
+            self._data[(table, bytes(key))] = entry
+
     def remove_row(self, table: str, key: bytes) -> None:
         self.set_row(table, key, Entry(status=EntryStatus.DELETED))
 
@@ -90,6 +96,10 @@ class StateStorage(TraversableStorage):
             if track:
                 _OBS.note_copy("state.traverse", t)
             yield t, k, e.copy()
+
+    def borrow_rows(self) -> dict[tuple[str, bytes], Entry]:
+        with self._lock:
+            return self._data.copy()
 
     def dirty_count(self) -> int:
         with self._lock:
