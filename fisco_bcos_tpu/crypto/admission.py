@@ -279,7 +279,17 @@ def _body_of(suite) -> _Body | None:
 
 # -- multi-device fan-out -----------------------------------------------------
 
-_SHARD_CACHE: dict[tuple[str, int], object] = {}
+
+@dataclass(frozen=True)
+class _Fanout:
+    """A body's program over the local mesh, and where its operands go."""
+
+    step: Callable  # jit(shard_map(body.packed)): operands -> [B, 117] on every device
+    sharding: object  # NamedSharding(mesh, P(DATA_AXIS)): every operand is batch-leading
+    devices: int
+
+
+_SHARD_CACHE: dict[tuple[str, int], _Fanout] = {}
 
 
 def _shard_min() -> int:
@@ -293,25 +303,42 @@ def _shard_min() -> int:
         return 4096
 
 
-def _maybe_sharded_step(body: _Body, bb: int):
-    """The cached sharded admission program when the bucketed batch `bb`
-    clears the fan-out threshold on a multi-device mesh; None otherwise
-    (single-chip jit). Nothing is caught here: a mesh or program that fails
+def mesh_devices(bb: int) -> int:
+    """The rule of the fan-out: how many devices a bucketed batch of `bb`
+    lanes goes out over. Every local device where there is more than one,
+    `bb` clears :func:`_shard_min` and the mesh divides it; otherwise 1, the
+    single-chip jit, without a word (a caller that must know which it got
+    reads ``fisco_device_mesh_calls_total``)."""
+    ndev = len(jax.devices())
+    if ndev <= 1 or bb < max(_shard_min(), ndev) or bb % ndev:
+        return 1
+    return ndev
+
+
+def _maybe_sharded_step(body: _Body, bb: int) -> _Fanout | None:
+    """The cached sharded admission program when :func:`mesh_devices` sends
+    the bucketed batch `bb` out over the mesh; None otherwise (single-chip
+    jit). Nothing is caught here: a mesh or program that fails
     raises into the caller's device leg, where the dispatch seam counts the
     failure, answers from the host loop and feeds the breaker —
     fan-out stays an optimization, never a liveness dependency, without
     hiding that it broke."""
-    ndev = len(jax.devices())
-    if ndev <= 1 or bb < max(_shard_min(), ndev) or bb % ndev:
+    ndev = mesh_devices(bb)
+    if ndev == 1:
         return None
-    step = _SHARD_CACHE.get((body.op, ndev))
-    if step is None:
-        from ..parallel.sharding import make_mesh, sharded_admission_packed
+    fanout = _SHARD_CACHE.get((body.op, ndev))
+    if fanout is None:
+        from jax.sharding import NamedSharding, PartitionSpec
 
-        step = _SHARD_CACHE[body.op, ndev] = sharded_admission_packed(
-            make_mesh(ndev), body=body.packed
+        from ..parallel.sharding import DATA_AXIS, make_mesh, sharded_admission_packed
+
+        mesh = make_mesh(ndev)
+        fanout = _SHARD_CACHE[body.op, ndev] = _Fanout(
+            sharded_admission_packed(mesh, body=body.packed),
+            NamedSharding(mesh, PartitionSpec(DATA_AXIS)),
+            ndev,
         )
-    return step
+    return fanout
 
 
 def _admit_batch_device(
@@ -320,8 +347,10 @@ def _admit_batch_device(
     """The fused device program of `body` (hash → recover or verify →
     address), one result transfer. `allow_shard=True` (the seam's device leg)
     fans the bucketed batch out over the local device mesh when it clears
-    _shard_min."""
-    from ..observability.device import device_span
+    _shard_min: the operands are then placed on the mesh as a phase of their
+    own (``place``), the span says how wide the mesh was, and the call counts
+    as a mesh call once its answer is on the host."""
+    from ..observability.device import LEDGER, device_span
     from ..ops.hash_common import bucket_batch
 
     bsz = len(payloads)
@@ -332,17 +361,26 @@ def _admit_batch_device(
     # bucket
     bb = bucket_batch(max(bsz, 1))
     mblocks = bucket_batch(body.mblocks(max(map(len, payloads), default=0)))
-    step = _maybe_sharded_step(body, bb) if allow_shard else None
-    op = body.op if step is None else body.op + "_sharded"
-    if step is None:
-        step = body.step
+    fanout = _maybe_sharded_step(body, bb) if allow_shard else None
+    op, step = (
+        (body.op, body.step) if fanout is None
+        else (body.op + "_sharded", fanout.step)
+    )
     with device_span(op, bsz, shape_key=(bb, mblocks)) as sp:
         with sp.phase("marshal"):
             operands = body.marshal(payloads, np.asarray(sigs, dtype=np.uint8), bb)
+        if fanout is not None:
+            sp.set(devices=fanout.devices, lanes_per_device=bb // fanout.devices)
+            with sp.phase("place"):  # one shard of every operand to its device
+                operands = jax.block_until_ready(
+                    jax.device_put(operands, fanout.sharding)
+                )
         with sp.phase("enqueue"):  # a shape's first call traces + compiles here
             dev = step(*operands)
         with sp.phase("sync"):  # waits for the device, brings the result over
             packed = np.asarray(dev)
+        if fanout is not None:
+            LEDGER.note_mesh_call(body.op, fanout.devices, bb // fanout.devices)
         with sp.phase("unpack"):
             packed = packed[:bsz]
             return (

@@ -14,6 +14,11 @@ crypto/admission):
 - ``fisco_device_compile_total{op=...}`` / ``fisco_device_cached_call_total``
   first-call-per-bucketed-shape vs repeat-shape calls (the PR 3 heuristic,
   kept for continuity and as the ledger's cross-check).
+- ``fisco_device_mesh_calls_total{op,devices}`` calls that went out over a
+  device mesh and whose answer reached the host (``op`` is the body's own,
+  ``admission`` / ``admission_sm``; their lanes are
+  ``fisco_device_items_total{op="<op>_sharded"}``). A call that fell to one
+  chip or that the host loop answered for adds nothing here.
 
 The ISSUE 13 instruments on top (all behind ``FISCO_DEVICE_OBS``, default
 on; ``=0`` turns every one into a shared noop):
@@ -36,7 +41,10 @@ on; ``=0`` turns every one into a shared noop):
   split), ``enqueue`` (the jitted call returning; holds the jaxpr trace and
   the compile on a shape's first call), ``sync`` (the ``np.asarray`` that
   waits for the device and brings the result over), ``unpack``, and
-  ``transfer`` in the stand-alone verify wrappers. Every phase is measured
+  ``transfer`` in the stand-alone verify wrappers; a call fanned out over
+  the local mesh (``<op>_sharded``) has ``place`` between ``marshal`` and
+  ``enqueue``: the operands put on the mesh, one shard a device, and waited
+  for. Every phase is measured
   start to end on the clock: a live tracer span ``device.<op>.<phase>`` (so
   it shows on a profiler capture too), ``fisco_device_phase_ms{op,phase}`` on
   :data:`DEVICE_PHASE_BUCKETS_MS` and ``phase_ms`` in ``/device``. Beside
@@ -202,6 +210,7 @@ class CompileLedger:
         # op -> {"count", "last_error"}: device programs that raised and
         # were answered by the host loop (the breaker in device/dispatch.py)
         self._failures: dict[str, dict] = {}
+        self._mesh: dict[str, dict] = {}
 
     # -- attribution frames (device_span drives these) -----------------------
 
@@ -455,6 +464,27 @@ class CompileLedger:
         with self._lock:
             return {op: dict(rec) for op, rec in self._failures.items()}
 
+    def note_mesh_call(self, op: str, devices: int, lanes_per_device: int) -> None:
+        """One call of ``op`` went out over a mesh of ``devices`` and its
+        answer is on the host: ``GET /device`` → ``mesh`` and
+        ``fisco_device_mesh_calls_total{op,devices}``. One device program
+        serves either leg and ``fisco_device_dispatch_path_total`` says
+        ``device`` for both; this is what tells a block the mesh verified
+        from one that fell to a single chip or to the host loop."""
+        with self._lock:
+            rec = self._mesh.setdefault(op, {"calls": 0})
+            rec.update(devices=devices, lanes_per_device=lanes_per_device)
+            rec["calls"] += 1
+        _metrics.REGISTRY.counter_add(
+            f'fisco_device_mesh_calls_total{{op="{op}",devices="{devices}"}}',
+            1.0,
+            help="device calls fanned out over a mesh whose answer reached the host",
+        )
+
+    def mesh(self) -> dict[str, dict]:
+        with self._lock:
+            return {op: dict(rec) for op, rec in self._mesh.items()}
+
     def add_overhead(self, secs: float) -> None:
         with self._lock:
             self._overhead_s += secs
@@ -518,6 +548,7 @@ class CompileLedger:
             self._last_adj_op = None
             self._overhead_s = 0.0
             self._failures.clear()
+            self._mesh.clear()
 
 
 # process-wide ledger (ops wrappers and the jax listeners feed it directly,
@@ -634,8 +665,9 @@ def install_observatory() -> bool:
 def device_doc(tail: int = 64) -> dict:
     """Everything the device observatory knows, one JSON: which device
     the process is on (platform / device_kind / count, as JAX reports it),
-    device programs that failed over to the host loop, the compile
-    ledger (cold vs persistent-cache attribution), per-op phase totals,
+    device programs that failed over to the host loop, the mesh each
+    fanned-out op last went out over (devices, lanes a device, calls), the
+    compile ledger (cold vs persistent-cache attribution), per-op phase totals,
     the first-shape heuristic counters for cross-checking, storm state,
     live-buffer bytes + their watermark rings, and the plane's scheduler
     stats. Served at ``GET /device`` on Air and through the facade on the
@@ -647,6 +679,7 @@ def device_doc(tail: int = 64) -> dict:
         "epoch": TRACER.epoch,
         "device": device_identity(),
         "failures": LEDGER.failures(),
+        "mesh": LEDGER.mesh(),
         "max_batch": LEDGER.max_batches() if enabled else {},
         "ledger": LEDGER.snapshot() if enabled else [],
         "phase_ms": LEDGER.phase_totals() if enabled else {},
@@ -796,11 +829,16 @@ class device_span:
         self._obs_s = 0.0  # this span's own observatory bookkeeping wall
 
     def phase(self, name: str):
-        """Mark a real segment (``marshal``/``enqueue``/``sync``/``unpack``/
-        ``transfer``) of this span's wall."""
+        """Mark a real segment (``marshal``/``place``/``enqueue``/``sync``/
+        ``unpack``/``transfer``) of this span's wall."""
         if self._frame is None:
             return _NOOP_PHASE
         return _Phase(self.op, name, self)
+
+    def set(self, **kv) -> None:
+        """Attributes on the ``device.<op>`` trace record (inside the
+        ``with`` only)."""
+        self._span.set(**kv)
 
     def __enter__(self):
         reg = _metrics.REGISTRY

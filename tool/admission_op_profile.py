@@ -5,11 +5,15 @@ the chip-free tally of their exact carry chains.
 Runs ``crypto.admission._admit_batch_device`` (the program every benchmark
 cell waits for) at every benchmark shape — 500 lanes (bucket 512), 1,000
 (1,024) and 10,000 (10,240) of secp256k1 + keccak256, 10,000 of SM2 + SM3
-(``sm:10000``) — under ``jax.profiler`` with the device's ops line on, and
-reduces the capture to: device ops a call, the op kinds by count and by time,
-the program's device time. The mechanism counter of PERF.md §6 (PR 25).
+(``sm:10000``), and ``10000/4``: the 2,560 lanes one chip of a four-chip host
+is given of the 10,000-lane block (``verify10k-quad.stream``: the first
+quarter of the bucket's operands through the same body; the mesh program adds
+the all_gather of the packed result) — under ``jax.profiler`` with the
+device's ops line on, and reduces the capture to: device ops a call, the op
+kinds by count and by time, the program's device time. The mechanism counter
+of PERF.md §6 (PR 25).
 
-    python tool/admission_op_profile.py --label parent [--lanes 1000,sm:10000]
+    python tool/admission_op_profile.py --label parent [--lanes 1000,sm:10000,10000/4]
 
 Refuses to run off the chip (exit 4): an op count of the CPU backend says
 nothing about the TPU's fusions. Writes ``chiprun_out/op_profile/<label>.json``
@@ -192,10 +196,26 @@ def chains_main() -> int:
 # -- the device-op profile (chip only) ----------------------------------------
 
 
+def shard_call(body, block, shards: int):
+    """What one chip of a mesh of `shards` runs for `block`: the first of the
+    equal parts of the bucket's operands through the body's own jit (no
+    bucket of the one-chip ladder has that size, so `_admit_batch_device`
+    cannot be asked for it) -> admit_batch's tuple for those lanes."""
+    import numpy as np
+
+    from fisco_bcos_tpu.ops.hash_common import bucket_batch
+
+    payloads = list(block["payloads"])
+    bb = bucket_batch(len(payloads))
+    operands = body.marshal(payloads, np.asarray(block["sigs"], dtype=np.uint8), bb)
+    packed = np.asarray(body.step(*(o[: bb // shards] for o in operands)))
+    return packed[:, :20], packed[:, 20] != 0, packed[:, 21:85], packed[:, 85:117]
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--label")
-    ap.add_argument("--lanes", default="500,1000,10000,sm:10000")
+    ap.add_argument("--lanes", default="500,1000,10000,sm:10000,10000/4")
     ap.add_argument("--seed", type=int, default=2500000001)
     ap.add_argument("--chains", action="store_true",
                     help="no chip: trace the two programs and count their exact carry chains")
@@ -222,16 +242,20 @@ def main() -> int:
         "secp": (signed_payloads.Corpus, admission._SECP),
         "sm": (sm_signed_payloads.Corpus, admission._BODIES["sm2", "sm3"]),
     }
-    shapes = args.lanes.split(",")  # "1000" or "sm:10000"
-    blocks, bodies = {}, {}
+    shapes = args.lanes.split(",")  # "1000", "sm:10000" or "10000/4"
+    blocks, bodies, shards = {}, {}, {}
     for shape in shapes:
         suite, _, n = shape.rpartition(":")
+        n, _, shard = n.partition("/")
         corpus, bodies[shape] = suites[suite or "secp"]
+        shards[shape] = int(shard or 1)
         blocks[shape] = corpus({"lanes": int(n), "signers": 64, "rotations": 1}, args.seed).blocks[0]
 
     def call(shape):
         b = blocks[shape]
-        return admission._admit_batch_device(b["payloads"], b["sigs"], body=bodies[shape])
+        if shards[shape] == 1:
+            return admission._admit_batch_device(b["payloads"], b["sigs"], body=bodies[shape])
+        return shard_call(bodies[shape], b, shards[shape])
 
     # first call of a shape traces and compiles (or loads): the shapes side by side
     setup = {}
@@ -261,7 +285,7 @@ def main() -> int:
         out = call(shape)
         wall_ms = (time.perf_counter() - t) * 1e3
         # hundreds of MB: not under chiprun_out/
-        trace_dir = tempfile.mkdtemp(prefix=f"op_profile_{shape.replace(':', '_')}_")
+        trace_dir = tempfile.mkdtemp(prefix=f"op_profile_{re.sub(r'[:/]', '_', shape)}_")
         jax.profiler.start_trace(trace_dir)
         try:
             call(shape)
