@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..device.dispatch import BatchOp, dispatch
-from ..ops import keccak, secp256k1, sm2, sm3
+from ..ops import keccak, limb, secp256k1, sm2, sm3
 from ..ops.address import sender_address_device, sm_sender_address_device
 from ..ops.bigint import digest_words_be_to_limbs, digest_words_le_to_limbs
 from ..ops.hash_common import pad_keccak, pad_md64
@@ -86,8 +86,46 @@ def pack_admission_device(addr, ok, qx, qy, z):
     )
 
 
-def _admission_packed(blocks, nblocks, r, s, v):
+def _in_tiles(whole, tile: int, *operands):
+    """`whole` (a packed admission program: batch-leading operands ->
+    [B, 117]) over `operands`, `tile` lanes at a time inside ONE program: the
+    operands are padded to a whole number of tiles, `whole` runs over the
+    tiles under ``lax.map`` (one body in the program and a loop around it;
+    nothing is unrolled and nothing is dispatched twice), and the pad lanes
+    are dropped before the result leaves. A pad lane repeats the batch's last
+    row: every operand is batch-leading, so it is a lane of a kind the batch
+    already holds (a signed payload, or one of the marshaller's own empty pad
+    rows), it never raises, and no lane's answer depends on another's. One
+    tile that holds the whole batch is `whole` itself, with no loop around
+    it. The tile is the plan's (``_Body.plan``); only a test or the op
+    profile hands in another."""
+    lanes = operands[0].shape[0]
+    tiles = -(-lanes // tile)
+    if tiles == 1:
+        return whole(*operands)
+    pad = tiles * tile - lanes
+
+    def stacked(o):  # [lanes, ...] -> [tiles, tile, ...]
+        if pad:
+            o = jnp.concatenate([o, jnp.broadcast_to(o[-1:], (pad,) + o.shape[1:])])
+        return o.reshape((tiles, tile) + o.shape[1:])
+
+    out = jax.lax.map(lambda tile_operands: whole(*tile_operands),
+                      tuple(stacked(o) for o in operands))
+    return out.reshape((tiles * tile,) + out.shape[2:])[:lanes]
+
+
+def _admission_whole(blocks, nblocks, r, s, v):
     return pack_admission_device(*admission_core(blocks, nblocks, r, s, v))
+
+
+def _admission_packed(blocks, nblocks, r, s, v):
+    """The packed program as the plan of its lane count runs it
+    (``limb.lane_plan``: a pure function of ``blocks.shape[0]``, known when
+    the program is traced; the form of a tile is ``limb.lane_dense``'s, from
+    the same table)."""
+    tile = limb.lane_plan(blocks.shape[0]).tile
+    return _in_tiles(_admission_whole, tile, blocks, nblocks, r, s, v)
 
 
 admission_step_packed = jax.jit(_admission_packed)
@@ -241,6 +279,7 @@ class _Body(BatchOp):
     packed: Callable  # the unjitted program -> [B, 117]; the sharded wrapper's body
     step: Callable  # jax.jit(packed), through the module's name for it
     mblocks: Callable[[int], int]  # longest payload's bytes -> message blocks
+    plan: Callable[[int], limb.LanePlan]  # a device's lanes -> how `packed` runs them
     marshal: Callable  # (payloads, sigs, bucket) -> the program's operands
 
 
@@ -254,6 +293,7 @@ _BODIES = {
         op="admission", sig_len=65, packed=_admission_packed,
         step=lambda *operands: admission_step_packed(*operands),
         mblocks=lambda n: n // 136 + 1, marshal=_marshal_secp,
+        plan=lambda lanes: limb.lane_plan(lanes),
     ),
     ("sm2", "sm3"): _Body(
         "admission", "admission.sm",
@@ -263,6 +303,8 @@ _BODIES = {
         op="admission_sm", sig_len=128, packed=_sm_admission_packed,
         step=lambda *operands: sm_admission_step_packed(*operands),
         mblocks=lambda n: (n + 8) // 64 + 1, marshal=_marshal_sm,
+        # no size is cheap enough to tile to (ops/limb.lane_plan's table)
+        plan=limb.whole_plan,
     ),
 }
 _SECP = _BODIES["secp256k1", "keccak256"]
@@ -369,8 +411,13 @@ def _admit_batch_device(
     with device_span(op, bsz, shape_key=(bb, mblocks)) as sp:
         with sp.phase("marshal"):
             operands = body.marshal(payloads, np.asarray(sigs, dtype=np.uint8), bb)
+        # what the program does with a device's lanes is a function of their
+        # number alone: known here without asking the program
+        lanes = bb // (fanout.devices if fanout is not None else 1)
+        plan = body.plan(lanes)
+        sp.plan(plan.tiles(lanes), plan.tile)
         if fanout is not None:
-            sp.set(devices=fanout.devices, lanes_per_device=bb // fanout.devices)
+            sp.set(devices=fanout.devices, lanes_per_device=lanes)
             with sp.phase("place"):  # one shard of every operand to its device
                 operands = jax.block_until_ready(
                     jax.device_put(operands, fanout.sharding)
@@ -380,7 +427,7 @@ def _admit_batch_device(
         with sp.phase("sync"):  # waits for the device, brings the result over
             packed = np.asarray(dev)
         if fanout is not None:
-            LEDGER.note_mesh_call(body.op, fanout.devices, bb // fanout.devices)
+            LEDGER.note_mesh_call(body.op, fanout.devices, lanes)
         with sp.phase("unpack"):
             packed = packed[:bsz]
             return (

@@ -14,6 +14,11 @@ crypto/admission):
 - ``fisco_device_compile_total{op=...}`` / ``fisco_device_cached_call_total``
   first-call-per-bucketed-shape vs repeat-shape calls (the PR 3 heuristic,
   kept for continuity and as the ledger's cross-check).
+- ``fisco_device_tiled_items_total{op=...}`` the items of calls whose program
+  ran the lanes a device was given in more than one tile (the admission
+  body's lane plan, ``ops/limb.lane_plan``; the ``device.<op>`` record says
+  ``tiles`` and ``tile_lanes``). A one-tile call adds nothing to it (the
+  series stands at what it was, 0 from the op's first call on).
 - ``fisco_device_mesh_calls_total{op,devices}`` calls that went out over a
   device mesh and whose answer reached the host (``op`` is the body's own,
   ``admission`` / ``admission_sm``; their lanes are
@@ -815,7 +820,9 @@ class device_span:
     dispatch, under its own op label.
     """
 
-    __slots__ = ("op", "batch", "key", "_t0", "_span", "_phases", "_frame", "_obs_s")
+    __slots__ = (
+        "op", "batch", "key", "_t0", "_span", "_phases", "_frame", "_obs_s", "_tiled",
+    )
 
     def __init__(self, op: str, batch: int, shape_key=None):
         self.op = op
@@ -827,6 +834,7 @@ class device_span:
         self._phases: list[tuple[str, float, float]] = []
         self._frame: dict | None = None
         self._obs_s = 0.0  # this span's own observatory bookkeeping wall
+        self._tiled: bool | None = None  # plan(): more than one tile; None = no plan
 
     def phase(self, name: str):
         """Mark a real segment (``marshal``/``place``/``enqueue``/``sync``/
@@ -839,6 +847,15 @@ class device_span:
         """Attributes on the ``device.<op>`` trace record (inside the
         ``with`` only)."""
         self._span.set(**kv)
+
+    def plan(self, tiles: int, tile_lanes: int) -> None:
+        """How the call's program runs the lanes a device is given (the
+        admission body's lane plan, ``ops/limb.lane_plan``): ``tiles`` and
+        ``tile_lanes`` on the trace record, and where there is more than one
+        tile the call's items count into
+        ``fisco_device_tiled_items_total{op}`` as well."""
+        self._span.set(tiles=tiles, tile_lanes=tile_lanes)
+        self._tiled = tiles > 1
 
     def __enter__(self):
         reg = _metrics.REGISTRY
@@ -883,6 +900,13 @@ class device_span:
                 float(self.batch),
                 help="items processed by device-crypto ops",
             )
+            if self._tiled is not None:  # a one-tile call adds 0: the series is there
+                reg.counter_add(
+                    f'fisco_device_tiled_items_total{{op="{self.op}"}}',
+                    float(self.batch) if self._tiled else 0.0,
+                    help="items of device admission programs that ran their "
+                    "lanes in more than one tile",
+                )
             reg.counter_add(
                 f'fisco_device_op_seconds_total{{op="{self.op}"}}',
                 dt,

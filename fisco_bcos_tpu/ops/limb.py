@@ -7,12 +7,14 @@ bcos-crypto's secp256k1/SM2 paths) with a formulation shaped for the TPU VPU:
 - A 256-bit number is 16 little-endian 16-bit limbs in a uint32 array whose
   **leading axis is the limb index** and whose trailing dimensions are the
   batch. The secp256k1 and SM2 entry points make the batch **lane-dense**
-  (:func:`lane_dense`, which pads it to a multiple of 128 lanes): a batch in
-  which a limb is more than one vreg (over 1,024 lanes) becomes
-  ``[L, S, 128]`` with ``S = lanes / 128``, so the batch fills both tiled
-  dimensions (sublanes × lanes) and the limb index is on neither; up to
-  1,024 lanes it stays ``[L, T]``, limbs sharing vregs along the sublanes
-  (which form wins at which size was measured: :func:`lane_dense`). Every
+  (:func:`lane_dense`, which pads it to a multiple of 128 lanes): over 1,024
+  lanes it becomes ``[L, S, 128]`` with ``S = lanes / 128``, so the batch
+  fills both tiled dimensions (sublanes × lanes) and the limb index is on
+  neither; up to 1,024 lanes it stays ``[L, T]``, limbs sharing vregs along
+  the sublanes. Which form, and how many lanes at a time, is one measured
+  rule, :func:`lane_plan` (its table is the chip's, PR 39: ``[L, S, 128]``
+  is cheap an op up to 2,048 lanes and three times dearer a call at 2,560,
+  so the 2,560 lanes of a four-chip host's shard run as two tiles). Every
   function here is written against ``[L, ...]`` and never looks at the
   trailing shape; programs that have not moved yet (ed25519, BLS12-381,
   Poseidon, bn128) still pass ``[L, T]``.
@@ -113,29 +115,113 @@ def const_rows(limbs_np: np.ndarray, t: int | jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 LANES = 128  # the minor tiled dimension: one vreg row
-_VREG = 8 * LANES  # 32-bit elements in one vreg (8 sublanes x 128 lanes)
+
+
+_ROWS_MAX = 8 * LANES  # a limb is one vreg (8 sublanes x 128 lanes): the most lanes of [L, T]
+_WHOLE_MAX = 2048  # the most lanes [L, S, 128] runs whole at the low price an op
+_TILE_MAX = 1280  # the most lanes of a tile that runs at that price inside a loop
+
+
+@dataclass(frozen=True)
+class LanePlan:
+    """How a body runs a batch of lanes: `tile` lanes at a time, each tile in
+    the form `dense` says (``[L, S, 128]``, else ``[L, T]``)."""
+
+    tile: int
+    dense: bool
+
+    def tiles(self, lanes: int) -> int:
+        return -(-lanes // self.tile)
+
+
+def _padded(lanes: int) -> int:
+    return lanes + (-lanes % LANES)
+
+
+def _whole_dense(lanes: int) -> bool:
+    """The form of `lanes` (padded) lanes run as one block, one half of
+    :func:`lane_plan`'s rule: ``[L, S, 128]`` over 1,024 lanes, else
+    ``[L, T]``."""
+    return lanes > _ROWS_MAX
+
+
+def whole_plan(lanes: int) -> LanePlan:
+    """The plan of a body that runs every batch as one block: one tile, in the
+    form :func:`lane_plan`'s table gives that many lanes."""
+    return LanePlan(lanes, _whole_dense(_padded(lanes)))
+
+
+def lane_plan(lanes: int) -> LanePlan:
+    """The one rule of the shape the limb arithmetic runs at, a pure function
+    of a batch's lane count: how many lanes at a time (the tile; the fused
+    secp256k1 admission body loops over the tiles inside its one program,
+    ``crypto/admission._in_tiles``) and in which form (:func:`lane_dense`
+    asks for the form of the lanes it is handed, a tile's or a whole
+    batch's).
+
+    - Up to 1,024 lanes: whole, ``[L, T]``, limbs sharing vregs along the
+      sublanes. Over 1,024 lanes the form is ``[L, S, 128]``: the batch fills
+      both tiled dimensions and the limb index is the leading, untiled axis.
+    - Over 2,048 and up to 2,560 lanes (the 2,560 lanes a chip of a four-chip
+      host is given of a 10,240-lane bucket): two equal tiles of at most
+      1,280 lanes, a multiple of 128, each in ``[L, S, 128]``. Every other
+      batch runs whole.
+
+    The rule is the secp256k1 admission program's device time a call on one
+    TPU v5e chip ("TPU v5 lite"), 2026-10-01, ``tool/admission_op_profile.py``
+    with the device's ops line on (PERF.md §6, PR 39; 512 and 1,024 lanes
+    are PR 27's profile, 10,240 the ledger's), ms:
+
+    ====== ========== =============== ====================================
+    lanes  ``[L, T]`` ``[L, S, 128]`` in tiles, one program
+    ====== ========== =============== ====================================
+    512    16.79      —               —
+    1,024  19.61      —               —
+    1,280  46.05      22.65           —
+    1,536  59.61      22.69           —
+    2,048  58.73      25.56           —
+    2,560  76.69      74.37           **2 x 1,280 [L, S, 128] 45.37**;
+                                      3 x 1,024 [L, T] 60.92;
+                                      2 x 1,280 [L, T] 94.77
+    4,096  86.35      73.38           2 x 2,048 [L, S, 128] 105.62
+    6,144  —          93.67           3 x 2,048 [L, S, 128] 158.67
+    8,192  —          99.54           —
+    10,240 —          107.15          5 x 2,048 [L, S, 128] 264.56
+    ====== ========== =============== ====================================
+
+    A lane count takes the fastest measured row of the bucket it is: tiles
+    only where a measured row won (2,560 lanes), whole wherever the table has
+    no faster row, the sizes between the measured ones included (3,072 and
+    5,120 lanes, a mesh of two's shares, were not measured and stay whole). A
+    tile of 2,048 lanes costs 25.6 ms as a program of its own and 52.9 inside
+    the loop, so no bucket over 2,560 lanes has a plan that beats it whole;
+    three tiles of 1,024 lanes in ``[L, T]`` cost what three 1,024-lane calls
+    cost. The SM2 body has no cheap size to tile to (1,280 lanes alone 140.3
+    ms, 2,560 whole 213.5, two tiles of 1,280 261.8, 10,240 whole 339.6): it
+    runs :func:`whole_plan` at every size.
+
+    Not understood: the edge itself. Much the same device ops run at every
+    size (114,000 to 125,000 a call), and past 1,024 lanes in ``[L, T]``, or
+    2,048 in ``[L, S, 128]`` (1,280 inside a loop), the same op kinds cost up
+    to thirty times more an op (``pad_shift-right-logical_fusion`` 0.46 ms a
+    call at 1,280 lanes, 14.97 at 2,560), while the compiled programs assign
+    their buffers to memory spaces in the same proportions on either side."""
+    padded = _padded(lanes)
+    if not _WHOLE_MAX < padded <= 2 * _TILE_MAX:
+        return whole_plan(lanes)
+    return whole_plan(-(-padded // (2 * LANES)) * LANES)
 
 
 def lane_dense(x: jax.Array) -> jax.Array:
     """Batch-major [B, L] limbs -> limb-leading, the batch padded to a
-    multiple of 128 lanes. The one relayout on the way in.
-
-    A batch in which a limb is more than one vreg (over 1,024 lanes) becomes
-    ``[L, S, 128]``: the batch fills both tiled dimensions and the limb index
-    is the leading, untiled axis. Up to 1,024 lanes it stays ``[L, T]``,
-    limbs sharing vregs along the sublanes. The rule is the admission
-    program's device time in both forms on a TPU v5e (PERF.md §6, PR 25),
-    ``[L, T]`` / ``[L, S, 128]``: 512 lanes 43.9 / 58 ms (four sublanes pad
-    to eight: the call pays for 1,024 lanes), 1,024 lanes 53.1 / 55.8 ms,
-    2,560 lanes 99.6 / 84.7 ms, 10,240 lanes 166.7 / 148.7 ms: while a limb
-    fits one vreg a device op costs its fixed ≈ 300 ns whatever it moves, and
-    past that the sublane shifts of ``[L, T]`` show. Padding lanes hold zero:
-    an invalid scalar, so they lower their validity bit like any other bad
-    lane and never raise."""
+    multiple of 128 lanes. The one relayout on the way in; the form is
+    :func:`lane_plan`'s for this many lanes run as one block. Padding lanes
+    hold zero: an invalid scalar, so they lower their validity bit like any
+    other bad lane and never raise."""
     b, width = x.shape
-    t = b + (-b % LANES)
+    t = _padded(b)
     xt = jnp.pad(x.T, ((0, 0), (0, t - b)))
-    return xt.reshape(width, -1, LANES) if t > _VREG else xt
+    return xt.reshape(width, -1, LANES) if _whole_dense(t) else xt
 
 
 def lane_mask(v: jax.Array, like: jax.Array) -> jax.Array:

@@ -33,6 +33,8 @@ from __future__ import annotations
 
 import argparse
 import collections
+import concurrent.futures
+import functools
 import glob
 import json
 import os
@@ -195,12 +197,38 @@ def chains_main() -> int:
 
 # -- the device-op profile (chip only) ----------------------------------------
 
+# a shape of --lanes: [sm:]N[/shards][@[tile][rows|dense]]
+_SHAPE = re.compile(
+    r"(?:(?P<suite>sm):)?(?P<n>\d+)(?:/(?P<shards>\d+))?"
+    r"(?P<plan>@(?P<tile>\d+)?(?P<form>rows|dense)?)?$"
+)
 
-def shard_call(body, block, shards: int):
-    """What one chip of a mesh of `shards` runs for `block`: the first of the
-    equal parts of the bucket's operands through the body's own jit (no
-    bucket of the one-chip ladder has that size, so `_admit_batch_device`
-    cannot be asked for it) -> admit_batch's tuple for those lanes."""
+
+def parse_shape(shape: str) -> dict:
+    """``1000`` / ``sm:10000`` / ``10000/4``: `n` signatures (their bucket's
+    lanes, or the first of `shards` equal parts of it) through the body as the
+    program's own plan runs them. ``@`` names a plan instead
+    (``limb.lane_plan``'s two halves): ``10000/4@1280`` the quarter bucket in
+    tiles of 1,280 lanes, ``10000/4@rows`` whole in ``[16, 2560]``,
+    ``10000/4@dense`` whole in ``[16, 20, 128]``, ``10000/4@1024rows`` both;
+    a half left out is the whole batch, or the rule's form for the tile."""
+    m = _SHAPE.match(shape)
+    if m is None or (m["plan"] and not (m["tile"] or m["form"])):
+        raise ValueError(f"--lanes: cannot read {shape!r}")
+    return {
+        "suite": m["suite"] or "secp",
+        "n": int(m["n"]),
+        "shards": int(m["shards"] or 1),
+        "planned": bool(m["plan"]),
+        "tile": int(m["tile"]) if m["tile"] else None,
+        "dense": {"rows": False, "dense": True, None: None}[m["form"]],
+    }
+
+
+def shard_operands(body, block, shards: int):
+    """What one chip of a mesh of `shards` is given of `block`: the first of
+    the equal parts of the bucket's operands (no bucket of the one-chip
+    ladder has that size, so `_admit_batch_device` cannot be asked for it)."""
     import numpy as np
 
     from fisco_bcos_tpu.ops.hash_common import bucket_batch
@@ -208,15 +236,54 @@ def shard_call(body, block, shards: int):
     payloads = list(block["payloads"])
     bb = bucket_batch(len(payloads))
     operands = body.marshal(payloads, np.asarray(block["sigs"], dtype=np.uint8), bb)
-    packed = np.asarray(body.step(*(o[: bb // shards] for o in operands)))
-    return packed[:, :20], packed[:, 20] != 0, packed[:, 21:85], packed[:, 85:117]
+    return tuple(o[: bb // shards] for o in operands)
+
+
+def lower_plan(whole, operands, tile, dense):
+    """`whole` over `operands` in tiles of `tile` lanes (None: one tile), a
+    tile in the form `dense` says (None: the rule's), as one jitted program,
+    traced and lowered here -> (the lowering, the plan it was traced under).
+    The form is forced where the rule is asked (``limb._whole_dense``), for
+    the time of the trace; jit's trace caches are dropped first, because a
+    nested jit of the same shape remembers the form it was traced in."""
+    import jax
+
+    from fisco_bcos_tpu.crypto import admission
+    from fisco_bcos_tpu.ops import limb
+
+    lanes = operands[0].shape[0]
+    tile = tile or lanes
+    rule = limb._whole_dense
+    if dense is None:
+        dense = rule(limb._padded(tile))
+
+    def program(*ops):
+        return admission._in_tiles(whole, tile, *ops)
+
+    program.__name__ = f"admission_plan_{tile}_{'dense' if dense else 'rows'}"
+    asked = []
+    jax.clear_caches()
+    limb._whole_dense = lambda lanes: asked.append(lanes) or dense
+    try:
+        lowered = jax.jit(program).lower(*operands)
+    finally:
+        limb._whole_dense = rule
+        jax.clear_caches()
+    if not asked:
+        raise RuntimeError(f"{program.__name__}: the trace never asked for its form")
+    plan = {"lanes": lanes, "tile": tile, "tiles": -(-lanes // tile),
+            "form": "dense" if dense else "rows"}
+    return lowered, plan
 
 
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--label")
-    ap.add_argument("--lanes", default="500,1000,10000,sm:10000,10000/4")
+    ap.add_argument("--lanes", default="500,1000,10000,sm:10000,10000/4",
+                    help="shapes, comma-separated: [sm:]N[/shards][@[tile][rows|dense]]")
     ap.add_argument("--seed", type=int, default=2500000001)
+    ap.add_argument("--compilers", type=int, default=5,
+                    help="shapes compiled side by side")
     ap.add_argument("--chains", action="store_true",
                     help="no chip: trace the two programs and count their exact carry chains")
     args = ap.parse_args()
@@ -224,6 +291,8 @@ def main() -> int:
         return chains_main()
     if not args.label:
         ap.error("--label is required for the device-op profile")
+    shapes = args.lanes.split(",")
+    specs = {shape: parse_shape(shape) for shape in shapes}
 
     from fisco_bcos_tpu.utils import jaxenv
 
@@ -235,41 +304,64 @@ def main() -> int:
         print(f"refused: op profile needs the chip, got {dev.platform}", file=sys.stderr)
         return 4
 
+    import numpy as np
+
     from benchmark.generators import signed_payloads, sm_signed_payloads
     from fisco_bcos_tpu.crypto import admission
 
     suites = {
-        "secp": (signed_payloads.Corpus, admission._SECP),
-        "sm": (sm_signed_payloads.Corpus, admission._BODIES["sm2", "sm3"]),
+        "secp": (signed_payloads.Corpus, admission._SECP, admission._admission_whole),
+        "sm": (sm_signed_payloads.Corpus, admission._SM, admission._sm_admission_packed),
     }
-    shapes = args.lanes.split(",")  # "1000", "sm:10000" or "10000/4"
-    blocks, bodies, shards = {}, {}, {}
-    for shape in shapes:
-        suite, _, n = shape.rpartition(":")
-        n, _, shard = n.partition("/")
-        corpus, bodies[shape] = suites[suite or "secp"]
-        shards[shape] = int(shard or 1)
-        blocks[shape] = corpus({"lanes": int(n), "signers": 64, "rotations": 1}, args.seed).blocks[0]
+    # a shape's first call traces and compiles (or loads). Tracing holds the
+    # interpreter, and a named plan is traced under a forced rule, so the
+    # shapes are traced one after another here; each compiles on the pool
+    # while the next is traced, `--compilers` of them at a time (a compile
+    # peaks at 3.5 GiB of the host's memory and most of it stays with the
+    # process: a dozen shapes a process is what 40 GiB hold)
+    pool = concurrent.futures.ThreadPoolExecutor(args.compilers)
+    slots = threading.Semaphore(args.compilers)
+    corpora, calls, plans, setup, warming = {}, {}, {}, {}, []
 
-    def call(shape):
-        b = blocks[shape]
-        if shards[shape] == 1:
-            return admission._admit_batch_device(b["payloads"], b["sigs"], body=bodies[shape])
-        return shard_call(bodies[shape], b, shards[shape])
+    def warm(shape, lowered, t0, operands=None):
+        try:
+            if lowered is not None:
+                calls[shape] = functools.partial(_packed_call, lowered.compile(), operands)
+                del lowered
+                _give_back()
+            calls[shape]()
+        finally:
+            slots.release()
+        setup[shape] = time.monotonic() - t0
+        print(f"[op profile] {shape}: first call after {setup[shape]:.1f} s, "
+              f"process at {_rss_gib():.1f} GiB", file=sys.stderr, flush=True)
 
-    # first call of a shape traces and compiles (or loads): the shapes side by side
-    setup = {}
-
-    def warm(shape):
-        t = time.monotonic()
-        call(shape)
-        setup[shape] = time.monotonic() - t
-
-    threads = [threading.Thread(target=warm, args=(shape,)) for shape in shapes]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
+    # the named plans first: no shape is traced under the rule while it is forced
+    for shape, spec in sorted(specs.items(), key=lambda kv: not kv[1]["planned"]):
+        slots.acquire()
+        t0 = time.monotonic()
+        corpus, body, whole = suites[spec["suite"]]
+        key = spec["suite"], spec["n"]
+        if key not in corpora:
+            corpora[key] = corpus(
+                {"lanes": spec["n"], "signers": 64, "rotations": 1}, args.seed).blocks[0]
+        block = corpora[key]
+        if not spec["planned"] and spec["shards"] == 1:
+            # the program every cell waits for, through the device leg itself
+            calls[shape] = functools.partial(
+                admission._admit_batch_device, block["payloads"], block["sigs"], body=body)
+            warming.append(pool.submit(warm, shape, None, t0))
+            continue
+        operands = shard_operands(body, block, spec["shards"])
+        if spec["planned"]:
+            lowered, plans[shape] = lower_plan(whole, operands, spec["tile"], spec["dense"])
+        else:  # a mesh's share as the program's own plan runs it
+            lowered = jax.jit(body.packed).lower(*operands)
+        warming.append(pool.submit(warm, shape, lowered, t0, jax.device_put(operands)))
+        del lowered
+    for w in warming:
+        w.result()
+    pool.shutdown()
 
     out_dir = os.path.join(ROOT, "chiprun_out", "op_profile")
     os.makedirs(out_dir, exist_ok=True)
@@ -280,28 +372,57 @@ def main() -> int:
         "shapes": {},
     }
     for shape in shapes:
-        call(shape)  # warm, untimed
+        call = calls[shape]
+        call()  # warm, untimed
         t = time.perf_counter()
-        out = call(shape)
+        out = call()
         wall_ms = (time.perf_counter() - t) * 1e3
         # hundreds of MB: not under chiprun_out/
-        trace_dir = tempfile.mkdtemp(prefix=f"op_profile_{re.sub(r'[:/]', '_', shape)}_")
+        trace_dir = tempfile.mkdtemp(prefix=f"op_profile_{re.sub(r'[:/@]', '_', shape)}_")
         jax.profiler.start_trace(trace_dir)
         try:
-            call(shape)
+            call()
         finally:
             jax.profiler.stop_trace()
         (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
         red = reduce_ops(path)
         red["wall_ms_untraced"] = wall_ms
-        red["lanes_ok"] = int(out[1].sum())
+        red["lanes_ok"] = int(np.asarray(out[1]).sum())
+        if shape in plans:
+            red["plan"] = plans[shape]
         result["shapes"][shape] = red
         shutil.rmtree(trace_dir, ignore_errors=True)
-    with open(os.path.join(out_dir, f"{args.label}.json"), "w") as f:
-        json.dump(result, f, indent=1)
+        # the table is long and a call may be cut: what is measured is kept
+        with open(os.path.join(out_dir, f"{args.label}.json"), "w") as f:
+            json.dump(result, f, indent=1)
     print(json.dumps(result))
     sys.stdout.flush()
     os._exit(0)  # the program's daemon threads have no shutdown (PERF.md §7)
+
+
+def _give_back() -> None:
+    """The compiler's freed memory back to the host (3.5 -> 1.9 GiB a
+    compiled shape): glibc keeps it otherwise."""
+    import ctypes
+
+    try:
+        ctypes.CDLL("libc.so.6").malloc_trim(0)
+    except (OSError, AttributeError):
+        pass
+
+
+def _rss_gib() -> float:
+    with open("/proc/self/statm") as f:
+        return int(f.read().split()[1]) * os.sysconf("SC_PAGE_SIZE") / 2**30
+
+
+def _packed_call(compiled, operands):
+    """One call of a shape's compiled program on operands that are on the
+    device already -> admit_batch's tuple for those lanes."""
+    import numpy as np
+
+    packed = np.asarray(compiled(*operands))
+    return packed[:, :20], packed[:, 20] != 0, packed[:, 21:85], packed[:, 85:117]
 
 
 if __name__ == "__main__":
