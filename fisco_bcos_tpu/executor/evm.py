@@ -83,6 +83,11 @@ class EVMResult:
     gas_left: int = 0
     logs: list[LogEntry] = field(default_factory=list)
     create_address: bytes = b""
+    # which engine finished the frame, where `interpret` ran it: "native"
+    # (the whole frame on native/fisco_native.cpp fisco_evm_run) or
+    # "interpreter" (the Python loop ran it, or resumed it where the native
+    # engine escaped); empty for a result no EVM frame produced
+    engine: str = ""
 
     @property
     def ok(self) -> bool:
@@ -318,10 +323,13 @@ def _native_prefix(host: EVMHost, msg: EVMCall, code: bytes, f: "_Frame"):
         _, status, gas_left, output = out
         if status in (0, int(TransactionStatus.REVERT_INSTRUCTION)):
             return EVMResult(
-                status=status, output=output, gas_left=gas_left, logs=f.logs
+                status=status, output=output, gas_left=gas_left, logs=f.logs,
+                engine="native",
             )
         # error statuses drop logs and zero gas, like the _VMError path
-        return EVMResult(status=status, output=b"", gas_left=0, logs=[])
+        return EVMResult(
+            status=status, output=b"", gas_left=0, logs=[], engine="native"
+        )
     _, pc, gas_left, stack, memory = out
     f.pc = pc
     f.gas = gas_left
@@ -352,7 +360,8 @@ def interpret(host: EVMHost, msg: EVMCall, code: bytes):
 
     def ret(status: int, output: bytes = b"") -> EVMResult:
         return EVMResult(
-            status=int(status), output=output, gas_left=max(f.gas, 0), logs=f.logs
+            status=int(status), output=output, gas_left=max(f.gas, 0), logs=f.logs,
+            engine="interpreter",
         )
 
     try:
@@ -718,4 +727,7 @@ def interpret(host: EVMHost, msg: EVMCall, code: bytes):
                 raise _VMError(TransactionStatus.BAD_INSTRUCTION)
         return ret(0)
     except _VMError as e:
-        return EVMResult(status=int(e.status), output=b"", gas_left=0, logs=[])
+        return EVMResult(
+            status=int(e.status), output=b"", gas_left=0, logs=[],
+            engine="interpreter",
+        )

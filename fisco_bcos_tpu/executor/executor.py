@@ -439,13 +439,36 @@ class TransactionExecutor:
     def _execute_one(
         self, tx: Transaction, block: BlockContext, static_call: bool = False,
         context_id: int = 0, access_out: list | None = None,
+        tally: list | None = None,
     ) -> TransactionReceipt:
         """One tx frame on its own overlay; merge on success, drop on revert
         (the reference's TransactionExecutive + revert semantics).
 
         With `access_out`, (the tx's external read-set, its write-set) is
         appended to it, the second filled on success — the DAG runner's
-        runtime conflict validation inputs."""
+        runtime conflict validation inputs.
+
+        With `tally` (a block's batch gives one for a callee that is no
+        registry precompile), what the call cost on the thread that ran it is
+        appended to it: (seconds in here, seconds inside the VM, the engine
+        that finished the top-level frame). Two clock readings and one
+        GIL-atomic append a transaction (and two more readings around the
+        VM's run, ``Executive.step``), no lock and no record: the batch sums
+        the list once (``_record_contract_txs``)."""
+        if tally is None:
+            return self._execute_frames(tx, block, static_call, context_id, access_out)[0]
+        t0 = time.perf_counter()
+        rc, ex = self._execute_frames(tx, block, static_call, context_id, access_out)
+        vm_s, engine = (ex.vm_s, ex.engine) if ex is not None else (0.0, "")  # None: refused
+        tally.append((time.perf_counter() - t0, vm_s, engine))
+        return rc
+
+    def _execute_frames(
+        self, tx: Transaction, block: BlockContext, static_call: bool,
+        context_id: int, access_out: list | None,
+    ) -> tuple[TransactionReceipt, "Executive | None"]:
+        """``_execute_one``'s body -> (the receipt, the Executive that ran the
+        frame chain, or None where the call was refused before one started)."""
         overlay = StateStorage(block.storage)
         if access_out is not None:
             overlay.read_track = set()
@@ -457,7 +480,7 @@ class TransactionExecutor:
             rc.status = int(TransactionStatus.CALL_ADDRESS_ERROR)
             rc.output = b"unknown contract address"
             rc.gas_used = BASE_GAS
-            return rc
+            return rc, None
         if not static_call:
             refusal = _ACCOUNT_REFUSALS.get(
                 account_status(overlay, tx.sender, block.number)
@@ -465,7 +488,7 @@ class TransactionExecutor:
             if refusal is not None:
                 rc.status, rc.output = refusal
                 rc.gas_used = BASE_GAS
-                return rc
+                return rc, None
         # auth governance (ContractAuthMgr enforcement): frozen contracts and
         # method ACLs gate deployed-contract calls before a frame starts
         if not is_create and tx.to not in self.registry:
@@ -475,12 +498,12 @@ class TransactionExecutor:
                 rc.status = int(TransactionStatus.CONTRACT_FROZEN)
                 rc.output = b"contract is frozen"
                 rc.gas_used = BASE_GAS
-                return rc
+                return rc, None
             if not acl_allows(overlay, tx.to, tx.input[:4], tx.sender):
                 rc.status = int(TransactionStatus.PERMISSION_DENIED)
                 rc.output = b"method ACL denies sender"
                 rc.gas_used = BASE_GAS
-                return rc
+                return rc, None
         msg = EVMCall(
             kind="create" if is_create else "call",
             sender=tx.sender,
@@ -511,7 +534,7 @@ class TransactionExecutor:
             if access_out is not None:
                 writes.update(overlay._data)
             overlay.merge_into_prev()
-        return rc
+        return rc, ex
 
     # -- code/abi access (getCode:1881 / getABI:1999) -----------------------
 
@@ -546,6 +569,7 @@ class TransactionExecutor:
         ), PIPELINE.busy("execute"):
             t0 = time.perf_counter()
             out: list[TransactionReceipt] = []
+            tally: list = []  # what each contract call cost: _execute_one
             i, n = 0, len(txs)
             while i < n:
                 to = txs[i].to
@@ -556,9 +580,13 @@ class TransactionExecutor:
                 if j - i > 1:
                     out.extend(self._execute_run(registry[to], txs[i:j], block))
                 else:
-                    out.append(self._execute_one(txs[i], block, context_id=base + i))
+                    out.append(self._execute_one(
+                        txs[i], block, context_id=base + i,
+                        tally=None if to in registry else tally,
+                    ))
                 i = j
         self._record_batch("serial", len(txs), time.perf_counter() - t0)
+        self._record_contract_txs(tally)
         return out
 
     def _execute_run(
@@ -594,6 +622,48 @@ class TransactionExecutor:
             help="txs per execution batch by mode",
             mode=mode,
         )
+
+    def _record_contract_txs(self, tally: list) -> tuple[int, int, float]:
+        """A batch's contract calls (``_execute_one``'s `tally`) summed and
+        added to the counters once -> (calls, top-level frames the native
+        engine finished, seconds inside the VM)."""
+        if not tally:
+            return 0, 0, 0.0
+        seconds = sum(t[0] for t in tally)
+        vm_s = sum(t[1] for t in tally)
+        native = sum(1 for t in tally if t[2] == "native")
+        interpreted = sum(1 for t in tally if t[2] == "interpreter")
+        REGISTRY.counter_add(
+            "fisco_executor_contract_txs_total",
+            len(tally),
+            help="block txs whose callee is no registry precompile (a deployed "
+            "contract's call, a deploy): each one frame chain on its own "
+            "overlay through _execute_one, in the serial batch and the DAG runner",
+        )
+        REGISTRY.counter_add(
+            "fisco_executor_contract_tx_seconds_total",
+            seconds,
+            help="seconds inside _execute_one for those txs, on the thread that "
+            "ran each (a DAG pool worker's include its waits for the "
+            "interpreter's lock)",
+        )
+        for engine, calls in (("native", native), ("interpreter", interpreted)):
+            REGISTRY.counter_add(
+                f'fisco_executor_evm_calls_total{{engine="{engine}"}}',
+                calls,
+                help="those txs' top-level EVM frames by the engine that "
+                "finished them: native (the whole frame on fisco_evm_run) or "
+                "interpreter (the Python loop ran it, or resumed it where the "
+                "native engine escaped)",
+            )
+        REGISTRY.counter_add(
+            "fisco_executor_evm_seconds_total",
+            vm_s,
+            help="those txs' seconds inside the VM (the frames' interpreter "
+            "generators: the native engine's run with its storage callbacks, "
+            "and the Python loop)",
+        )
+        return len(tally), native, vm_s
 
     # -- DAG parallel (dagExecuteTransactions:1063) -------------------------
 
@@ -721,6 +791,8 @@ class TransactionExecutor:
         # marks (levelize, run, validate)
         ran: list[tuple[int, int, int]] = []
         conflicts: list[bool] = []
+        tally: list = []  # what each contract call cost: _execute_one
+        pool_wait_s = 0.0  # this thread inside the levels' fut.result()
 
         def shadow_ctx() -> BlockContext:
             return BlockContext(
@@ -740,13 +812,18 @@ class TransactionExecutor:
             out: list = [None] * len(txs)
             for level in levels:
                 for i in level:
-                    out[i] = self._execute_one(txs[i], block, context_id=base + i)
+                    tx = txs[i]
+                    out[i] = self._execute_one(
+                        tx, block, context_id=base + i,
+                        tally=None if tx.to in registry else tally,
+                    )
                 ran.append((len(level), 0, 0))
                 span.stage("run")
             return out
 
         def run_levels(block: BlockContext) -> list | None:
             """The levels on `block`, or None where a level's check failed."""
+            nonlocal pool_wait_s
             out: list = [None] * len(txs)
             frame = _PrecompileFrame(self, block)
             pool = None
@@ -765,17 +842,23 @@ class TransactionExecutor:
                             framed += 1
                         elif wide and workers > 1:
                             if pool is None:
-                                pool = ThreadPoolExecutor(workers)
+                                pool = ThreadPoolExecutor(
+                                    workers, thread_name_prefix="dag-exec"
+                                )
                             futs[i] = pool.submit(
-                                self._execute_one, tx, block,
-                                context_id=base + i, access_out=tracked,
+                                self._execute_one, tx, block, context_id=base + i,
+                                access_out=tracked, tally=tally,
                             )
                         else:
                             out[i] = self._execute_one(
-                                tx, block, context_id=base + i, access_out=tracked
+                                tx, block, context_id=base + i,
+                                access_out=tracked, tally=tally,
                             )
-                    for i, fut in futs.items():
-                        out[i] = fut.result()
+                    if futs:
+                        t_wait = time.perf_counter()
+                        for i, fut in futs.items():
+                            out[i] = fut.result()
+                        pool_wait_s += time.perf_counter() - t_wait
                     ran.append((len(level), framed, len(futs)))
                     span.stage("run")
                     if not wide:
@@ -812,10 +895,12 @@ class TransactionExecutor:
             shadow.storage.merge_into_prev()
             self._block.suicides |= shadow.suicides
             widths, framed_by_level, pooled_by_level = zip(*ran) if ran else ((), (), ())
+            contract_txs, evm_native, evm_s = self._record_contract_txs(tally)
             span.set(
                 levels=len(levels), reruns=int(conflict), widths=widths,
                 framed=framed_by_level, pooled=pooled_by_level,
-                conflicts=tuple(conflicts),
+                conflicts=tuple(conflicts), contract_txs=contract_txs,
+                evm_native=evm_native, evm_s=evm_s, pool_wait_s=pool_wait_s,
             )
         self._record_batch("dag", len(txs), time.perf_counter() - t_dag0)
         REGISTRY.counter_add(
@@ -846,6 +931,13 @@ class TransactionExecutor:
             help="txs the DAG runner executed inside its level frame, on the "
             "thread that executes the block (calls to registry precompiles); "
             "beside fisco_executor_batch_txs' dag sum, the frame's share",
+        )
+        REGISTRY.counter_add(
+            "fisco_executor_dag_pool_wait_seconds_total",
+            pool_wait_s,
+            help="seconds the thread that executes the block spent waiting for "
+            "a level's futures (fut.result()): the pooled members' run as that "
+            "thread sees it",
         )
         if conflict:
             REGISTRY.counter_add(
@@ -996,6 +1088,11 @@ class Executive:
         self._start_msg = msg
         self._start_abi = abi
         self.is_local = is_local if is_local is not None else (lambda addr: True)
+        # seconds inside the frames' interpreter generators (`interpret`: the
+        # native engine's run and the Python loop; not the frames' set-up),
+        # and which engine finished the top-level frame (EVMResult.engine)
+        self.vm_s = 0.0
+        self.engine = ""
 
     def _host(self, overlay: StorageInterface) -> EVMHost:
         return EVMHost(
@@ -1086,11 +1183,15 @@ class Executive:
             response = None
         while self.frames:
             fr = self.frames[-1]
+            t_vm = time.perf_counter()
             try:
                 req = fr.gen.send(response)
             except StopIteration as si:
+                self.vm_s += time.perf_counter() - t_vm
                 res: EVMResult = si.value
                 self.frames.pop()
+                if not self.frames:
+                    self.engine = res.engine
                 if fr.create_addr:
                     if res.ok:
                         if len(res.output) > MAX_CODE_SIZE:
@@ -1113,6 +1214,7 @@ class Executive:
                     fr.overlay.merge_into_prev()
                 response = res
                 continue
+            self.vm_s += time.perf_counter() - t_vm
             # external request from the top frame
             if req.kind in ("create", "create2") or self.is_local(req.code_address):
                 immediate = self._open(req, fr.overlay)

@@ -260,10 +260,11 @@ def mixed_block(c, contract):
 @pytest.mark.parametrize("workers", [1, 2, 8])
 def test_a_level_of_precompile_and_contract_calls_splits_by_callee(workers, monkeypatch):
     monkeypatch.setenv("FISCO_DAG_WORKERS", str(workers))
-    pools = []
+    pools, names = [], []
     real = executor_module.ThreadPoolExecutor
-    monkeypatch.setattr(executor_module, "ThreadPoolExecutor",
-                        lambda n: pools.append(n) or real(n))
+    monkeypatch.setattr(
+        executor_module, "ThreadPoolExecutor",
+        lambda n, **kw: pools.append(n) or names.append(kw) or real(n, **kw))
     c = corpus()
     ex, ser = opened(c), opened(c)
     contract = deployed_setfor(ex)
@@ -289,6 +290,7 @@ def test_a_level_of_precompile_and_contract_calls_splits_by_callee(workers, monk
                              "framed_txs": len(txs) - 6, "conflict_reruns": 0}
     assert sum(to_pool) >= 5 * (workers > 1)
     assert pools == ([workers] if workers > 1 else []), "one pool a call, opened when needed"
+    assert names == [{"thread_name_prefix": "dag-exec"}] * len(pools), "the pool's threads say whose they are"
     (block,) = [s for s in TRACER.spans()
                 if s.name == "executor.execute" and s.attrs["mode"] == "dag"]
     assert block.attrs["pooled"] == tuple(to_pool)
