@@ -32,7 +32,6 @@ import itertools
 import os
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 
 from ..codec.abi import ABICodec
@@ -113,8 +112,7 @@ class BlockContext:
     next_ctx: int = 0
     # addresses registered by SELFDESTRUCT this block
     # (BlockContext::m_suicides, BlockContext.h:147); applied by
-    # killSuicides at getHash time. set.add is GIL-atomic, so DAG-level
-    # worker threads can register concurrently.
+    # killSuicides at getHash time.
     suicides: set = field(default_factory=set)
     # sender -> its governance status at this height, read once a sender by
     # the run frame: a status written at block N takes effect at N + 1
@@ -452,7 +450,7 @@ class TransactionExecutor:
         registry precompile), what the call cost on the thread that ran it is
         appended to it: (seconds in here, seconds inside the VM, the engine
         that finished the top-level frame). Two clock readings and one
-        GIL-atomic append a transaction (and two more readings around the
+        append a transaction (and two more readings around the
         VM's run, ``Executive.step``), no lock and no record: the batch sums
         the list once (``_record_contract_txs``)."""
         if tally is None:
@@ -644,8 +642,7 @@ class TransactionExecutor:
             "fisco_executor_contract_tx_seconds_total",
             seconds,
             help="seconds inside _execute_one for those txs, on the thread that "
-            "ran each (a DAG pool worker's include its waits for the "
-            "interpreter's lock)",
+            "executes the block",
         )
         for engine, calls in (("native", native), ("interpreter", interpreted)):
             REGISTRY.counter_add(
@@ -751,48 +748,41 @@ class TransactionExecutor:
         self, txs: list[Transaction]
     ) -> list[TransactionReceipt]:
         """Conflict-DAG execution (the reference's TxDAG2 axis, SURVEY §2.8
-        row 5): level by level, a level's members each in one of two ways,
-        chosen a member from its callee, then VALIDATED at runtime.
+        row 5): level by level, every member of a level on this thread, in
+        index order, then VALIDATED at runtime.
 
-        A call to a registry precompile is a pure-Python frame, which a
-        thread could only run in turns with the interpreter: it executes on
-        this thread, in index order, inside the one ``_PrecompileFrame`` of
-        the call (one of the schedules a pool could have produced, and the
-        only one that is the same on every host). Any other callee (user
-        bytecode: the native EVM engine and native crypto release the GIL)
-        goes to a thread pool as ``_execute_one`` on its own overlay, where
-        its level is wider than one and FISCO_DAG_WORKERS allows a pool; the
-        pool is opened by the first such member.
+        A call to a registry precompile executes inside the one
+        ``_PrecompileFrame`` of the call; any other callee (user bytecode)
+        through ``_execute_one`` on its own overlay. That is one of the
+        schedules a pool could have produced, and the only one that is the
+        same on every host. A pool of threads for bytecode members never
+        measured faster than this thread (the native EVM engine needs the
+        interpreter back for every storage read and write): one can come back
+        with an engine that takes a frame's storage without the interpreter,
+        and with a reading that it wins.
 
         Determinism contract: context ids are pre-reserved per tx index, so
         for txs whose declared conflict sets are HONEST (disjoint state),
         any schedule produces serial-identical results. Because a lying
-        declaration must not let the schedule leak into the state root,
+        declaration must not let the levels' order leak into the state root,
         every level wider than one has its members' actual read/write sets
-        checked pairwise after it completes, whatever ran them; ANY overlap
-        discards the whole attempt and re-executes the block serially
-        through ``_execute_one``, the same deterministic outcome every node
+        checked pairwise after it completes; ANY overlap discards the whole
+        attempt and re-executes the block serially through
+        ``_execute_one``, the same deterministic outcome every node
         computes. The whole DAG run happens on a shadow overlay so the
         discard is clean. FISCO_DAG_SERIAL=1 pins that serial loop."""
         if self._block is None:
             raise RuntimeError("call next_block_header first")
         t_dag0 = time.perf_counter()
         base = self.reserve_contexts(len(txs))
-        try:
-            workers = int(os.environ.get("FISCO_DAG_WORKERS", "0"))
-        except ValueError:
-            workers = 0
-        if workers <= 0:
-            workers = min(8, os.cpu_count() or 1)
         registry = self.registry
-        # (width, framed, pooled) of every level that ran, in order (a serial
+        # (width, framed) of every level that ran, in order (a serial
         # rerun's after the failed attempt's), and every check's verdict: the
         # facts of the block's one record; the seconds by stage are the span's
         # marks (levelize, run, validate)
-        ran: list[tuple[int, int, int]] = []
+        ran: list[tuple[int, int]] = []
         conflicts: list[bool] = []
         tally: list = []  # what each contract call cost: _execute_one
-        pool_wait_s = 0.0  # this thread inside the levels' fut.result()
 
         def shadow_ctx() -> BlockContext:
             return BlockContext(
@@ -807,7 +797,7 @@ class TransactionExecutor:
             # receipts land at their TX INDEX (execution walks level order) —
             # a flattened comprehension here once misassigned receipts
             # whenever levelization reordered txs (review r5: consensus fork
-            # between pooled and serial nodes; see
+            # between nodes; see
             # tests/test_abi_conflict.py::test_reordering_levels_keep_receipt_identity)
             out: list = [None] * len(txs)
             for level in levels:
@@ -817,66 +807,45 @@ class TransactionExecutor:
                         tx, block, context_id=base + i,
                         tally=None if tx.to in registry else tally,
                     )
-                ran.append((len(level), 0, 0))
+                ran.append((len(level), 0))
                 span.stage("run")
             return out
 
         def run_levels(block: BlockContext) -> list | None:
             """The levels on `block`, or None where a level's check failed."""
-            nonlocal pool_wait_s
             out: list = [None] * len(txs)
             frame = _PrecompileFrame(self, block)
-            pool = None
-            try:
-                for level in levels:
-                    wide = len(level) > 1
-                    # every member of a wide level appends its access sets
-                    tracked: list | None = [] if wide else None
-                    futs = {}
-                    framed = 0
-                    for i in level:
-                        tx = txs[i]
-                        pre = registry.get(tx.to)
-                        if pre is not None:
-                            out[i] = frame.execute(pre, tx, tracked)
-                            framed += 1
-                        elif wide and workers > 1:
-                            if pool is None:
-                                pool = ThreadPoolExecutor(
-                                    workers, thread_name_prefix="dag-exec"
-                                )
-                            futs[i] = pool.submit(
-                                self._execute_one, tx, block, context_id=base + i,
-                                access_out=tracked, tally=tally,
-                            )
-                        else:
-                            out[i] = self._execute_one(
-                                tx, block, context_id=base + i,
-                                access_out=tracked, tally=tally,
-                            )
-                    if futs:
-                        t_wait = time.perf_counter()
-                        for i, fut in futs.items():
-                            out[i] = fut.result()
-                        pool_wait_s += time.perf_counter() - t_wait
-                    ran.append((len(level), framed, len(futs)))
-                    span.stage("run")
-                    if not wide:
-                        continue
-                    conflict = _level_conflicts(tracked)
-                    conflicts.append(conflict)
-                    span.stage("validate")
-                    if conflict:
-                        _log.warning(
-                            "DAG level of %d txs touched overlapping "
-                            "state its conflict declarations called "
-                            "disjoint; re-executing the block serially",
-                            len(level),
+            for level in levels:
+                wide = len(level) > 1
+                # every member of a wide level appends its access sets
+                tracked: list | None = [] if wide else None
+                framed = 0
+                for i in level:
+                    tx = txs[i]
+                    pre = registry.get(tx.to)
+                    if pre is not None:
+                        out[i] = frame.execute(pre, tx, tracked)
+                        framed += 1
+                    else:
+                        out[i] = self._execute_one(
+                            tx, block, context_id=base + i,
+                            access_out=tracked, tally=tally,
                         )
-                        return None
-            finally:
-                if pool is not None:
-                    pool.shutdown()
+                ran.append((len(level), framed))
+                span.stage("run")
+                if not wide:
+                    continue
+                conflict = _level_conflicts(tracked)
+                conflicts.append(conflict)
+                span.stage("validate")
+                if conflict:
+                    _log.warning(
+                        "DAG level of %d txs touched overlapping "
+                        "state its conflict declarations called "
+                        "disjoint; re-executing the block serially",
+                        len(level),
+                    )
+                    return None
             return out
 
         with TRACER.span("executor.execute", mode="dag", txs=len(txs)) as span:
@@ -894,13 +863,12 @@ class TransactionExecutor:
                 receipts = run_serial(shadow)
             shadow.storage.merge_into_prev()
             self._block.suicides |= shadow.suicides
-            widths, framed_by_level, pooled_by_level = zip(*ran) if ran else ((), (), ())
+            widths, framed_by_level = zip(*ran) if ran else ((), ())
             contract_txs, evm_native, evm_s = self._record_contract_txs(tally)
             span.set(
                 levels=len(levels), reruns=int(conflict), widths=widths,
-                framed=framed_by_level, pooled=pooled_by_level,
-                conflicts=tuple(conflicts), contract_txs=contract_txs,
-                evm_native=evm_native, evm_s=evm_s, pool_wait_s=pool_wait_s,
+                framed=framed_by_level, conflicts=tuple(conflicts),
+                contract_txs=contract_txs, evm_native=evm_native, evm_s=evm_s,
             )
         self._record_batch("dag", len(txs), time.perf_counter() - t_dag0)
         REGISTRY.counter_add(
@@ -914,16 +882,26 @@ class TransactionExecutor:
                 f'fisco_executor_dag_stage_seconds_total{{stage="{key}"}}',
                 seconds,
                 help="seconds of the DAG runner by stage: levelize (conflict "
-                "keys + levels), run (the levels' execution, framed, pooled "
-                "and inline, a serial rerun too), validate (the pairwise "
+                "keys + levels), run (the levels' execution, framed and "
+                "through _execute_one, a serial rerun too), validate (the pairwise "
                 "check of the access sets of a level wider than one)",
             )
+        # the runner has no pool since PR 41. These two stay registered and
+        # read 0 because the benchmark still reads them (dag_pooled_tx_share is
+        # an entry of BENCHMARK.json, and tests/benchmark_checks holds
+        # dag_pool_wait_ms_per_block to a number): PERF.md, Open questions
         REGISTRY.counter_add(
             "fisco_executor_dag_pooled_txs_total",
-            sum(pooled_by_level),
-            help="txs the DAG runner executed as futures on its thread pool "
-            "(members of a level wider than one whose callee is no registry "
-            "precompile)",
+            0.0,
+            help="always 0: the DAG runner has no thread pool since PR 41 "
+            "(every member of a level executes on the thread that executes "
+            "the block); kept for the benchmark's dag_pooled_tx_share",
+        )
+        REGISTRY.counter_add(
+            "fisco_executor_dag_pool_wait_seconds_total",
+            0.0,
+            help="always 0: the DAG runner has no thread pool to wait for "
+            "since PR 41; kept for the benchmark's dag_pool_wait_ms_per_block",
         )
         REGISTRY.counter_add(
             "fisco_executor_dag_framed_txs_total",
@@ -931,13 +909,6 @@ class TransactionExecutor:
             help="txs the DAG runner executed inside its level frame, on the "
             "thread that executes the block (calls to registry precompiles); "
             "beside fisco_executor_batch_txs' dag sum, the frame's share",
-        )
-        REGISTRY.counter_add(
-            "fisco_executor_dag_pool_wait_seconds_total",
-            pool_wait_s,
-            help="seconds the thread that executes the block spent waiting for "
-            "a level's futures (fut.result()): the pooled members' run as that "
-            "thread sees it",
         )
         if conflict:
             REGISTRY.counter_add(

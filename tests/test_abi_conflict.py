@@ -189,13 +189,12 @@ def test_liquid_path_key_accepted():
     assert ka == kb and ka is not None
 
 
-def test_dag_pool_matches_serial(monkeypatch):
-    """The threaded level runner must be bit-identical to the serial loop
-    (pre-reserved context ids + per-tx overlays + disjoint criticals make
-    the schedule irrelevant) — forced on even on a 1-core host."""
-    def run(pooled: bool):
-        if pooled:
-            monkeypatch.setenv("FISCO_DAG_WORKERS", "4")
+def test_dag_levels_match_serial(monkeypatch):
+    """The level runner (tracked overlays, the check after the wide level)
+    must be bit-identical to the pinned serial loop (pre-reserved context
+    ids + per-tx overlays + disjoint criticals)."""
+    def run(levels: bool):
+        if levels:
             monkeypatch.delenv("FISCO_DAG_SERIAL", raising=False)
         else:
             monkeypatch.setenv("FISCO_DAG_SERIAL", "1")
@@ -214,13 +213,12 @@ def test_dag_pool_matches_serial(monkeypatch):
 
 def test_lying_declaration_detected_and_serialized(monkeypatch, caplog):
     """Two txs whose conflictFields claim disjoint state but whose code
-    writes the SAME storage slot: the pooled runner must detect the overlap
+    writes the SAME storage slot: the level runner must detect the overlap
     at runtime and re-execute serially, producing the serial result — a
-    lying annotation must never let host core count decide the state root
-    (review finding r5)."""
+    lying annotation must never let anything but the block decide the state
+    root (review finding r5)."""
     import json as _json
 
-    monkeypatch.setenv("FISCO_DAG_WORKERS", "4")
     monkeypatch.delenv("FISCO_DAG_SERIAL", raising=False)
 
     # setFixed(uint256,uint256) IGNORES param 0 and always writes slot 7 —
@@ -240,9 +238,8 @@ def test_lying_declaration_detected_and_serialized(monkeypatch, caplog):
         "conflictFields": [{"kind": 3, "value": [0], "slot": 0}],
     }]
 
-    def run(pooled: bool):
-        if pooled:
-            monkeypatch.setenv("FISCO_DAG_WORKERS", "4")
+    def run(levels: bool):
+        if levels:
             monkeypatch.delenv("FISCO_DAG_SERIAL", raising=False)
         else:
             monkeypatch.setenv("FISCO_DAG_SERIAL", "1")
@@ -262,24 +259,23 @@ def test_lying_declaration_detected_and_serialized(monkeypatch, caplog):
                 env.ledger.header_by_number(2).state_root)
 
     # levelization puts all 4 in one level (disjoint declared keys)...
-    pooled = run(True)
+    checked = run(True)
     serial = run(False)
     # ...but the runtime validation must force the serial outcome anyway
-    assert pooled == serial
+    assert checked == serial
 
 
 def test_reordering_levels_keep_receipt_identity(monkeypatch):
     """Levelization that REORDERS txs (conflicting tx sinks to level 1 while
     a later tx stays in level 0) must still put every receipt at its tx
-    index — on the serial path, the pooled path, and the conflict-fallback
-    path (review r5: a flattened serial loop swapped receipts and forked
-    the receipts root between 1-core and multicore nodes)."""
+    index — on the pinned serial path, the level runner's path, and the
+    conflict-fallback path (review r5: a flattened serial loop swapped
+    receipts and forked the receipts root between nodes)."""
     def run(mode: str):
         if mode == "serial":
             monkeypatch.setenv("FISCO_DAG_SERIAL", "1")
         else:
             monkeypatch.delenv("FISCO_DAG_SERIAL", raising=False)
-            monkeypatch.setenv("FISCO_DAG_WORKERS", "4")
         env = Env()
         addr = env.deploy_setfor()
         dag = TransactionAttribute.DAG
@@ -292,14 +288,14 @@ def test_reordering_levels_keep_receipt_identity(monkeypatch):
         assert all(rc.status == 0 for rc in blk.receipts)
         return blk.receipts, env.ledger.header_by_number(2).state_root
 
-    for mode in ("serial", "pooled"):
+    for mode in ("serial", "levels"):
         receipts, root = run(mode)
         # tx1 re-writes slot 0 (SSTORE reset, 5k); tx0/tx2 first-write their
         # slots (SSTORE set, 20k) — a receipt swap inverts this relation
         assert receipts[1].gas_used < receipts[0].gas_used, mode
         assert receipts[1].gas_used < receipts[2].gas_used, mode
         assert receipts[0].gas_used == receipts[2].gas_used, mode
-    assert run("serial") == run("pooled")
+    assert run("serial") == run("levels")
 
 
 def test_malformed_conflictfields_serialize_not_crash():

@@ -2,13 +2,14 @@
 (``benchmark/generators/parallelok_batches.py``: ``ParallelOk.transfer``
 between existing accounts, payer and payee Zipf 0.99, every transaction DAG,
 the conflict keys read from the ABI the contract was deployed with) through the
-conflict-DAG runner, at 256 transactions over 400 accounts: whatever the number
-of workers it gives the receipts, in index order, the storage rows and the
-state root of the serial loop on the same list, and those are the plain
-reference's; its counters and its one record say what the contract leg did
-(members pooled or inline, the engine that finished each call, the seconds in
-the VM, the wait for the pool); an ABI that lies about ``transfer`` is caught
-at block size and the block is run again in level order."""
+conflict-DAG runner, at 256 transactions over 400 accounts: it gives the
+receipts, in index order, the storage rows and the state root of the serial
+loop on the same list, and those are the plain reference's; its counters and
+its one record say what the contract leg did (every member through
+``_execute_one`` on the thread that executes the block, the engine that
+finished each call, the seconds in the VM); an ABI that lies about
+``transfer`` is caught at block size and the block is run again in level
+order."""
 
 import json
 
@@ -114,9 +115,7 @@ def serial():
     return plain(receipts), rows(ex, c), ex.get_hash()
 
 
-@pytest.mark.parametrize("workers", [1, 2, 8])
-def test_the_dag_run_is_the_serial_loop_is_the_reference(workers, serial, monkeypatch):
-    monkeypatch.setenv("FISCO_DAG_WORKERS", str(workers))
+def test_the_dag_run_is_the_serial_loop_is_the_reference(serial):
     c = corpus()
     txs = block_of(c)
     assert len(txs) == BLOCK_TXS and all(tx.attribute & TransactionAttribute.DAG for tx in txs)
@@ -130,20 +129,17 @@ def test_the_dag_run_is_the_serial_loop_is_the_reference(workers, serial, monkey
     receipts = ex.dag_execute_transactions(txs)
     assert (plain(receipts), rows(ex, c), ex.get_hash()) == serial
 
-    # a member of a wide level is a future where a pool is allowed, inline
-    # where it is not; each is one contract call the native engine finished
+    # every member, of a wide level or of a level of one, is one contract
+    # call the native engine finished, none of them a future
     got = moved(before)
-    pooled = wide if workers > 1 else 0
     assert (got["contract_txs"], got["evm_native"], got["evm_interpreter"]) == (BLOCK_TXS, BLOCK_TXS, 0)
-    assert (got["pooled_txs"], got["reruns"]) == (pooled, 0)
+    assert (got["pooled_txs"], got["pool_wait_s"], got["reruns"]) == (0, 0, 0)
     assert 0 < got["evm_s"] < got["contract_tx_s"]
-    assert (got["pool_wait_s"] > 0) == (workers > 1)
     (block,) = [s for s in TRACER.spans()
                 if s.name == "executor.execute" and s.attrs["mode"] == "dag"]
-    assert sum(block.attrs["pooled"]) == pooled and sum(block.attrs["framed"]) == 0
+    assert sum(block.attrs["widths"]) == BLOCK_TXS and sum(block.attrs["framed"]) == 0
     assert (block.attrs["contract_txs"], block.attrs["evm_native"]) == (BLOCK_TXS, BLOCK_TXS)
     assert block.attrs["evm_s"] == pytest.approx(got["evm_s"])
-    assert block.attrs["pool_wait_s"] == pytest.approx(got["pool_wait_s"])
     assert not [s for s in TRACER.spans() if s.name == "executor.tx"], "no record a transaction"
 
 
@@ -157,13 +153,11 @@ def test_the_serial_batch_counts_its_contract_calls_once_a_batch(serial):
     assert 0 < got["evm_s"] < got["contract_tx_s"]
 
 
-@pytest.mark.parametrize("workers", [1, 8])
-def test_an_abi_that_declares_only_from_is_caught_and_the_block_rerun(workers, serial, monkeypatch):
+def test_an_abi_that_declares_only_from_is_caught_and_the_block_rerun(serial):
     """``transfer`` writes both names; an ABI that declares the first alone puts
     transfers to one payee into one level. The check after that level sees the
     two writes of one row, and the block goes through the serial loop in level
-    order: the same answer at any number of workers."""
-    monkeypatch.setenv("FISCO_DAG_WORKERS", str(workers))
+    order."""
     abi = json.loads(gen.contract_files(CONFIG["contract"])[1])
     transfer = next(e for e in abi if e["name"] == "transfer")
     transfer["conflictFields"] = transfer["conflictFields"][:1]

@@ -1,11 +1,11 @@
 """Blocks of the parallel-transfer cell's own generator
 (``benchmark/generators/dag_transfer_batches.py``: ``userTransfer`` between
 existing accounts, payer and payee Zipf 0.99, every transaction DAG) through
-the conflict-DAG runner, at 64 transactions over 40 accounts: whatever the
-number of workers it gives the receipts, in index order, and the state root of
-``execute_transactions`` on the same list; its counters and spans say what it
-did (calls to registry precompiles in the level frame, on no pool; a deployed
-contract's calls as futures); a declaration that lies, by a write or by a
+the conflict-DAG runner, at 64 transactions over 40 accounts: it gives the
+receipts, in index order, and the state root of ``execute_transactions`` on
+the same list; its counters and spans say what it did (calls to registry
+precompiles in the level frame, a deployed contract's calls through
+``_execute_one``, all on the calling thread); a declaration that lies, by a write or by a
 read, is caught and the block still ends on the serial root; a framed member
 that faults leaves what ``_execute_one`` leaves. And the generator: the same
 bytes for a seed, rank frequencies that follow theta."""
@@ -108,9 +108,7 @@ def serial():
     return plain(receipts), ex.get_hash()
 
 
-@pytest.mark.parametrize("workers", [1, 2, 8])
-def test_dag_runner_is_serial_equivalent_whatever_the_workers(workers, serial, monkeypatch):
-    monkeypatch.setenv("FISCO_DAG_WORKERS", str(workers))
+def test_dag_runner_is_serial_equivalent(serial):
     c = corpus()
     txs = block_of(c)
     assert all(tx.attribute & TransactionAttribute.DAG for tx in txs)
@@ -127,14 +125,14 @@ def test_dag_runner_is_serial_equivalent_whatever_the_workers(workers, serial, m
     assert codes <= {0, 4} and 0 in codes
 
     # the counters say what dag_levels says, and that every call ran in the
-    # frame whatever the workers: no future, the check on every wide level
+    # frame: no future, the check on every wide level
     assert moved(before) == {"levels": len(levels), "pooled_txs": 0,
                              "framed_txs": len(txs), "conflict_reruns": 0}
     after = stage_seconds()
     assert all(after[k] > stage_before[k] for k in after)
 
-    # and so does the block's one record: a width, a framed and a pooled count
-    # a level, a verdict a checked level, the seconds of the three stages
+    # and so does the block's one record: a width and a framed count a level,
+    # a verdict a checked level, the seconds of the three stages
     spans = TRACER.spans()
     (block,) = [s for s in spans if s.name == "executor.execute"]
     assert not [s for s in spans if s.name.startswith("executor.dag_")]
@@ -142,7 +140,7 @@ def test_dag_runner_is_serial_equivalent_whatever_the_workers(workers, serial, m
     assert at["mode"] == "dag" and at["txs"] == len(txs)
     assert at["levels"] == len(levels) and at["reruns"] == 0
     assert at["widths"] == at["framed"] == tuple(len(level) for level in levels)
-    assert at["pooled"] == (0,) * len(levels)
+    assert "pooled" not in at and "pool_wait_s" not in at
     assert at["conflicts"] == (False,) * sum(1 for level in levels if len(level) > 1)
     assert set(at["stages"]) == {"levelize", "run", "validate"}
     assert "marks" not in at, "a mark a level and a check: sums only"
@@ -151,9 +149,8 @@ def test_dag_runner_is_serial_equivalent_whatever_the_workers(workers, serial, m
         sum(after[k] - stage_before[k] for k in after), rel=1e-6)
 
 
-def test_several_blocks_in_a_row_keep_the_serial_state(monkeypatch):
+def test_several_blocks_in_a_row_keep_the_serial_state():
     """Hot accounts carry their balance from block to block."""
-    monkeypatch.setenv("FISCO_DAG_WORKERS", "4")
     c = corpus(blocks=3)
     dag, ser = opened(c), opened(c)
     for n in range(3):
@@ -171,9 +168,7 @@ class PayerOnly(DagTransferPrecompiled):
         return keys[:1] if keys else keys
 
 
-def test_a_declaration_that_lies_is_caught_and_the_block_ends_on_the_serial_root(
-        serial, monkeypatch):
-    monkeypatch.setenv("FISCO_DAG_WORKERS", "8")
+def test_a_declaration_that_lies_is_caught_and_the_block_ends_on_the_serial_root(serial):
     c = corpus()
     txs = block_of(c)
     ex = opened(c, registry={**default_registry(), DAG_TRANSFER_ADDRESS: PayerOnly()})
@@ -204,7 +199,6 @@ def test_a_read_of_a_peers_write_inside_a_framed_level_is_caught(monkeypatch):
     after it. The block ends where the pinned serial loop ends
     (``FISCO_DAG_SERIAL=1``: ``_execute_one`` in level order, the rerun's own
     order, which is not block order once a declaration lies)."""
-    monkeypatch.setenv("FISCO_DAG_WORKERS", "8")
     c = corpus()
     transfers = block_of(c)
     hot = [c.names[rank] for rank in range(4)]
@@ -231,7 +225,6 @@ def test_a_read_of_a_peers_write_inside_a_framed_level_is_caught(monkeypatch):
     assert at["conflicts"] == (True,) and at["reruns"] == 1
     assert at["widths"] == (len(level0),) + tuple(len(level) for level in ex.dag_levels(txs))
     assert at["framed"] == (len(level0),) + (0,) * (len(at["widths"]) - 1)
-    assert at["pooled"] == (0,) * len(at["widths"])
 
 
 def deployed_setfor(ex):
@@ -257,14 +250,7 @@ def mixed_block(c, contract):
     return txs
 
 
-@pytest.mark.parametrize("workers", [1, 2, 8])
-def test_a_level_of_precompile_and_contract_calls_splits_by_callee(workers, monkeypatch):
-    monkeypatch.setenv("FISCO_DAG_WORKERS", str(workers))
-    pools, names = [], []
-    real = executor_module.ThreadPoolExecutor
-    monkeypatch.setattr(
-        executor_module, "ThreadPoolExecutor",
-        lambda n, **kw: pools.append(n) or names.append(kw) or real(n, **kw))
+def test_a_level_of_precompile_and_contract_calls_splits_by_callee():
     c = corpus()
     ex, ser = opened(c), opened(c)
     contract = deployed_setfor(ex)
@@ -282,32 +268,45 @@ def test_a_level_of_precompile_and_contract_calls_splits_by_callee(workers, monk
     assert ex.get_hash() == ser.get_hash()
     assert all(rc.status == 0 for rc in receipts)
 
-    # a contract's member of a wide level is a future where a pool is allowed,
-    # inline where it is not; a precompile's member is framed either way
-    to_pool = [len(mine) if workers > 1 and len(level) > 1 else 0
-               for mine, level in zip(of_contract, levels)]
-    assert moved(before) == {"levels": len(levels), "pooled_txs": sum(to_pool),
+    # a precompile's member is framed, a contract's goes through _execute_one:
+    # a level's widths less its framed are those members
+    assert moved(before) == {"levels": len(levels), "pooled_txs": 0,
                              "framed_txs": len(txs) - 6, "conflict_reruns": 0}
-    assert sum(to_pool) >= 5 * (workers > 1)
-    assert pools == ([workers] if workers > 1 else []), "one pool a call, opened when needed"
-    assert names == [{"thread_name_prefix": "dag-exec"}] * len(pools), "the pool's threads say whose they are"
     (block,) = [s for s in TRACER.spans()
                 if s.name == "executor.execute" and s.attrs["mode"] == "dag"]
-    assert block.attrs["pooled"] == tuple(to_pool)
     assert block.attrs["framed"] == tuple(
         len(level) - len(mine) for mine, level in zip(of_contract, levels))
+    assert tuple(w - f for w, f in zip(block.attrs["widths"], block.attrs["framed"])) == tuple(
+        map(len, of_contract))
+    assert block.attrs["contract_txs"] == 6
     assert len(block.attrs["conflicts"]) == sum(1 for level in levels if len(level) > 1)
 
 
-def test_a_block_of_precompile_calls_opens_no_pool(serial, monkeypatch):
-    monkeypatch.setenv("FISCO_DAG_WORKERS", "8")
-    built = []
-    monkeypatch.setattr(executor_module, "ThreadPoolExecutor",
-                        lambda *a, **kw: built.append(a) or pytest.fail("a pool was opened"))
+@pytest.mark.parametrize("block", ["precompile", "mixed"])
+def test_no_dag_call_opens_a_pool(block, serial, monkeypatch):
+    """The runner's module has no executor of futures to build, none is built
+    anywhere while a DAG call runs, and no thread is started."""
+    import concurrent.futures
+    import threading
+
+    assert not hasattr(executor_module, "ThreadPoolExecutor")
     c = corpus()
     ex = opened(c)
-    assert (plain(ex.dag_execute_transactions(block_of(c))), ex.get_hash()) == serial
-    assert built == []
+    txs = block_of(c) if block == "precompile" else mixed_block(c, deployed_setfor(ex))
+    started = []
+    real_start = threading.Thread.start
+    for kind in ("ThreadPoolExecutor", "ProcessPoolExecutor"):
+        monkeypatch.setattr(concurrent.futures, kind,
+                            lambda *a, **kw: pytest.fail("a pool was opened"))
+    monkeypatch.setattr(threading.Thread, "start",
+                        lambda self: started.append(self.name) or real_start(self))
+    receipts = ex.dag_execute_transactions(txs)
+    monkeypatch.undo()
+    assert started == []
+    if block == "precompile":
+        assert (plain(receipts), ex.get_hash()) == serial
+    else:
+        assert all(rc.status == 0 for rc in receipts)
 
 
 GOVERNOR, ALICE, BOB = b"\x0a" * 20, b"\x0b" * 20, b"\x0c" * 20
@@ -321,12 +320,10 @@ class SaveFaults(DagTransferPrecompiled):
         raise PrecompiledError("save refused")
 
 
-@pytest.mark.parametrize("workers", [1, 8])
-def test_a_framed_member_that_faults_leaves_what_execute_one_leaves(workers, monkeypatch):
+def test_a_framed_member_that_faults_leaves_what_execute_one_leaves():
     """A PrecompiledError after a write, a frozen sender and input no selector
     matches, the first two inside a level wider than one: status, output and
     gas are ``_execute_one``'s, and none of the faulted call's writes stay."""
-    monkeypatch.setenv("FISCO_DAG_WORKERS", str(workers))
     c = corpus()
     n = c.names
 
