@@ -270,12 +270,6 @@ def _signed(v: int) -> int:
     return v - MOD if v >= SIGN_BIT else v
 
 
-def _native_evm_enabled() -> bool:
-    import os
-
-    return not os.environ.get("FISCO_NO_NATIVE_EVM")
-
-
 # keccak256(b"") — the native engine hardcodes keccak for SHA3, so it may
 # only run for suites whose hash IS keccak (an SM chain computes sm3 storage
 # slots; running the native engine there would fork state roots between
@@ -285,17 +279,25 @@ _KECCAK_EMPTY = bytes.fromhex(
 )
 
 
+def native_engine_serves(hash_fn) -> bool:
+    """Whether a frame of a chain with this hash may run on the native engine:
+    it is not switched off (FISCO_NO_NATIVE_EVM) and the hash is keccak."""
+    import os
+
+    return not os.environ.get("FISCO_NO_NATIVE_EVM") and hash_fn(b"") == _KECCAK_EMPTY
+
+
 def _native_prefix(host: EVMHost, msg: EVMCall, code: bytes, f: "_Frame"):
     """Run the frame's straight-line prefix on the native engine
     (native/fisco_native.cpp fisco_evm_run — the evmone analog). Returns an
     EVMResult when the whole frame finished natively; None when the frame
-    should (continue to) run in Python — either the library is unavailable
-    or the engine escaped at an unmodeled opcode, in which case `f` has
+    should (continue to) run in Python — the engine may not serve this chain
+    (``native_engine_serves``), the library is unavailable, or the engine escaped at an unmodeled opcode, in which case `f` has
     been seeded with the escaped pc/gas/stack/memory and Python resumes
     bit-identically (gas schedule and edge semantics are kept in lockstep;
     differential-tested by tests/test_native_evm.py)."""
-    if host.hash_fn(b"") != _KECCAK_EMPTY:
-        return None  # non-keccak suite (sm3): Python interpreter only
+    if not native_engine_serves(host.hash_fn):
+        return None  # switched off, or a non-keccak suite (sm3): Python only
 
     from .. import native_bind
 
@@ -344,10 +346,9 @@ def interpret(host: EVMHost, msg: EVMCall, code: bytes):
     f = _Frame(msg.gas)
     code_len = len(code)
 
-    if _native_evm_enabled():
-        nat = _native_prefix(host, msg, code, f)
-        if nat is not None:
-            return nat
+    nat = _native_prefix(host, msg, code, f)
+    if nat is not None:
+        return nat
 
     # JUMPDEST analysis (skip PUSH immediates)
     jumpdests = set()

@@ -34,12 +34,13 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from .. import native_bind
 from ..codec.abi import ABICodec
 from ..crypto.suite import CryptoSuite
 from ..observability import BATCH_BUCKETS, TRACER
 from ..observability.pipeline import PIPELINE
 from ..protocol.block_header import BlockHeader
-from ..protocol.receipt import TransactionReceipt, TransactionStatus
+from ..protocol.receipt import LogEntry, TransactionReceipt, TransactionStatus
 from ..protocol.transaction import Transaction
 from ..storage.interfaces import (
     StorageInterface,
@@ -47,6 +48,7 @@ from ..storage.interfaces import (
     TwoPCParams,
     staged_rows,
 )
+from ..storage.entry import Entry
 from ..storage.state_storage import StateStorage
 from ..utils.log import get_logger
 from ..utils.metrics import REGISTRY
@@ -61,10 +63,12 @@ from .evm import (
     EVMResult,
     contract_table,
     interpret,
+    native_engine_serves,
 )
 from . import eth_builtins
 from .precompiled import default_registry
-from .precompiled.account import ABOLISH, FREEZE, account_status
+from .precompiled.account import ABOLISH, ACCOUNT_TABLE, FREEZE, account_status
+from .precompiled.auth import acl_allows, acl_row, bind_admin, is_frozen, meta_row
 from .precompiled.base import (
     BASE_GAS,
     Precompiled,
@@ -127,6 +131,11 @@ _ACCOUNT_REFUSALS = {
     FREEZE: (int(TransactionStatus.ACCOUNT_FROZEN), b"account is frozen"),
     ABOLISH: (int(TransactionStatus.ACCOUNT_ABOLISHED), b"account is abolished"),
 }
+# auth governance (ContractAuthMgr enforcement) of a deployed contract's call
+_CONTRACT_FROZEN = (int(TransactionStatus.CONTRACT_FROZEN), b"contract is frozen")
+_ACL_DENIES = (int(TransactionStatus.PERMISSION_DENIED), b"method ACL denies sender")
+_REVERT = int(TransactionStatus.REVERT_INSTRUCTION)
+_ZERO32 = b"\x00" * 32
 
 
 def _call_precompile(
@@ -146,6 +155,16 @@ def _call_precompile(
 
 
 _NO_KEYS: frozenset = frozenset()
+
+
+def _sender_refusal(block: BlockContext, sender: bytes) -> tuple[int, bytes] | None:
+    """A frozen or abolished sender's (status, output), the governance status
+    looked up once a sender a block (``BlockContext.account_statuses``)."""
+    statuses = block.account_statuses
+    st = statuses.get(sender)
+    if st is None:
+        st = statuses[sender] = account_status(block.storage, sender, block.number)
+    return _ACCOUNT_REFUSALS.get(st)
 
 
 class _PrecompileFrame:
@@ -187,11 +206,7 @@ class _PrecompileFrame:
         rc = TransactionReceipt(version=tx.version, block_number=number)
         overlay.read_track = reads = None if access_out is None else set()
         writes = _NO_KEYS
-        statuses = block.account_statuses
-        st = statuses.get(sender)
-        if st is None:
-            st = statuses[sender] = account_status(block.storage, sender, number)
-        refusal = _ACCOUNT_REFUSALS.get(st)
+        refusal = _sender_refusal(block, sender)
         if refusal is not None:
             rc.status, rc.output = refusal
             rc.gas_used = BASE_GAS
@@ -210,6 +225,245 @@ class _PrecompileFrame:
                 overlay.discard()
         if reads is not None:
             access_out.append((reads, writes))
+        return rc
+
+
+class _Member:
+    """What the engine's callbacks read of the member a contract frame is
+    executing: the callee's table and address, the member's tracked read set
+    (None where the level is not tracked), its logs, and the words it has
+    read from below the frame's overlay."""
+
+    __slots__ = ("table", "to", "reads", "logs", "seen")
+
+
+def _storage_callbacks(member: _Member, overlay: StateStorage):
+    """``EvmBinding``'s three callbacks over a contract frame's overlay. A
+    slot the member wrote is read from the overlay's own dict (the ``Entry``
+    its ``sstore`` put there, ``adopt_row``'s rule); any other from the state
+    below, once a member (``SSTORE`` asks again for the word ``SLOAD`` just
+    fetched), and noted in the tracked read set as the overlay's ``get_row``
+    would. Words go to the engine as stored."""
+    data = overlay._data
+    below = overlay.prev.get_row
+
+    def sload(slot: bytes) -> bytes:
+        table = member.table
+        row = data.get((table, slot))
+        if row is not None:
+            return row.fields["value"]
+        value = member.seen.get(slot)
+        if value is None:
+            if member.reads is not None:
+                member.reads.add((table, slot))
+            row = below(table, slot)
+            value = _ZERO32 if row is None else row.fields.get("value", b"")
+            if len(value) != 32:  # a row no SSTORE wrote: EVMHost.get_storage's reading
+                value = int.from_bytes(value, "big").to_bytes(32, "big")
+            member.seen[slot] = value
+        return value
+
+    def sstore(slot: bytes, value: bytes) -> None:
+        row = Entry()
+        row.fields["value"] = value
+        data[(member.table, slot)] = row
+
+    def log(topics: list, payload: bytes) -> None:
+        member.logs.append(LogEntry(address=member.to, topics=topics, data=payload))
+
+    return sload, sstore, log
+
+
+class _Callee:
+    """What a contract frame keeps of one callee, beside the rows it read it
+    from as the block's own dirty set held them at that moment (None where
+    the block had not written the row): a later write to one of them puts
+    another object there, and the callee is looked up again."""
+
+    __slots__ = (
+        "direct", "table", "code", "addr20", "frozen", "acls",
+        "account_key", "account_row", "meta_key", "meta_row",
+    )
+
+
+class _ContractFrame:
+    """Top-level, non-static calls to deployed EVM contracts on one block's
+    state, one after another, without a frame chain each:
+    ``_PrecompileFrame``'s sibling for bytecode. One overlay that a success
+    merges into the block and a revert or an error empties; the native
+    engine bound once, its storage callbacks on that overlay; what is the
+    same for every member of a callee looked up once a callee: the code, the
+    table, the freeze flag, the method ACL a (selector, sender). The
+    receipts, the rows and the tracked access sets are ``_execute_one``'s.
+
+    The frame stands aside, and the member goes through ``_execute_one``,
+    where it observes that there is nothing of the kind to take out of the
+    loop: a create, a registry or builtin callee, no code at the address, a
+    wasm chain, a hash that is not keccak, no native engine, and a callee
+    whose run the engine escaped from (that member again from the start,
+    the callee's later members straight there). Every loop of a block's
+    batch (the DAG levels, the serial rerun, the serial batch) executes its
+    contract members through ``execute``."""
+
+    __slots__ = (
+        "executor", "block", "tally", "framed", "overlay", "_on", "_dirty",
+        "_callees", "_engine", "_member",
+    )
+
+    def __init__(self, executor: "TransactionExecutor", block: BlockContext, tally: list):
+        self.executor = executor
+        self.block = block
+        self.tally = tally  # the batch's: _execute_one's `tally`
+        self.framed = 0  # members executed in here, the refused among them
+        self._on: bool | None = None  # asked at the first member
+        self._callees: dict[bytes, _Callee] = {}
+
+    def _engage(self) -> bool:
+        """Whether this chain's contract calls can run in the frame at all,
+        found out once: then the overlay is made and the engine bound."""
+        executor, storage = self.executor, self.block.storage
+        self._on = False
+        if (
+            executor.is_wasm
+            or type(storage) is not StateStorage
+            or not native_engine_serves(executor.suite.hash)
+        ):
+            return False
+        self.overlay = StateStorage(storage)
+        self._dirty = storage._data
+        self._member = _Member()
+        self._engine = native_bind.bind_evm(
+            *_storage_callbacks(self._member, self.overlay)
+        )
+        self._on = self._engine is not None
+        return self._on
+
+    def _lookup(self, to: bytes) -> _Callee:
+        executor, storage, dirty = self.executor, self.block.storage, self._dirty
+        callee = self._callees[to] = _Callee()
+        callee.table = table = contract_table(to)
+        callee.account_key = (table, b"#account")
+        callee.account_row = dirty.get(callee.account_key)
+        callee.meta_key = meta_row(to)
+        callee.meta_row = dirty.get(callee.meta_key)
+        code = b""
+        if to and to not in executor.registry and to not in _BUILTINS:
+            row = storage.get_row(table, b"#account")
+            if row is not None:
+                code = row.fields.get(F_CODE, b"")
+        callee.direct = not code
+        if code:
+            callee.code = code
+            callee.addr20 = native_bind.addr20(to)
+            callee.frozen = is_frozen(storage, to)
+            callee.acls = {}
+        return callee
+
+    def execute(
+        self, tx: Transaction, context_id: int, access_out: list | None = None
+    ) -> TransactionReceipt:
+        """One block transaction whose callee is no registry precompile:
+        its receipt, its access sets appended to `access_out` and its cost
+        to the batch's tally, all as ``_execute_one`` gives them."""
+        on = self._on
+        if on is None:
+            on = self._engage()
+        if on:
+            callee = self._callees.get(tx.to)
+            if (
+                callee is None
+                or self._dirty.get(callee.account_key) is not callee.account_row
+                or self._dirty.get(callee.meta_key) is not callee.meta_row
+            ):
+                callee = self._lookup(tx.to)
+            if not callee.direct:
+                rc = self._call(callee, tx, access_out)
+                if rc is not None:
+                    return rc
+                callee.direct = True  # the engine escaped: not this frame's kind
+        return self.executor._execute_one(
+            tx, self.block, context_id=context_id, access_out=access_out,
+            tally=self.tally,
+        )
+
+    def _refusal(self, callee: _Callee, tx: Transaction, reads: set | None):
+        """``_execute_frames``' gates in its order -> (status, output) or
+        None; `reads` gains the rows each gate passed stands on."""
+        block, sender = self.block, tx.sender
+        if reads is not None:
+            reads.add(callee.account_key)
+            reads.add((ACCOUNT_TABLE, sender))
+        refusal = _sender_refusal(block, sender)
+        if refusal is not None:
+            return refusal
+        if reads is not None:
+            reads.add(callee.meta_key)
+        if callee.frozen:
+            return _CONTRACT_FROZEN
+        selector = tx.input[:4]
+        acl = callee.acls.get(selector)
+        if acl is None or self._dirty.get(acl[0]) is not acl[1]:
+            key = acl_row(tx.to, selector)
+            acl = callee.acls[selector] = (key, self._dirty.get(key), {})
+        if reads is not None:
+            reads.add(acl[0])
+        allowed = acl[2].get(sender)
+        if allowed is None:
+            allowed = acl[2][sender] = acl_allows(block.storage, tx.to, selector, sender)
+        return None if allowed else _ACL_DENIES
+
+    def _call(
+        self, callee: _Callee, tx: Transaction, access_out: list | None
+    ) -> TransactionReceipt | None:
+        """The member in the frame, or None where the engine escaped (the
+        overlay emptied, nothing appended anywhere)."""
+        t0 = time.perf_counter()
+        block, data = self.block, self.overlay._data
+        gas = block.gas_limit
+        rc = TransactionReceipt(version=tx.version, block_number=block.number)
+        reads = None if access_out is None else set()
+        writes = _NO_KEYS
+        vm_s, engine = 0.0, ""
+        refusal = self._refusal(callee, tx, reads)
+        if refusal is not None:
+            rc.status, rc.output = refusal
+            rc.gas_used = BASE_GAS
+        else:
+            member = self._member
+            member.table, member.to, member.reads = callee.table, tx.to, reads
+            member.logs = logs = []
+            member.seen = {}
+            caller = native_bind.addr20(tx.sender)
+            t_vm = time.perf_counter()
+            try:
+                out = self._engine.run(
+                    callee.code, tx.input, callee.addr20, caller, caller, _ZERO32,
+                    gas, block.number, block.timestamp, gas, 0,
+                )
+            except BaseException:
+                data.clear()
+                raise
+            vm_s, engine = time.perf_counter() - t_vm, "native"
+            if out is None or out[0] != "done":
+                data.clear()
+                return None
+            _, status, gas_left, output = out
+            rc.status = status
+            if status == 0 or status == _REVERT:
+                rc.output, rc.log_entries = output, logs
+            else:  # an error status drops output and logs and leaves no gas
+                gas_left = 0
+            rc.gas_used = max(gas - gas_left, BASE_GAS)
+            if status == 0:
+                if reads is not None:
+                    writes = set(data)
+                self.overlay.merge_into_prev()
+            else:
+                data.clear()
+        if reads is not None:
+            access_out.append((reads, writes))
+        self.framed += 1
+        self.tally.append((time.perf_counter() - t0, vm_s, engine))
         return rc
 
 
@@ -446,8 +700,9 @@ class TransactionExecutor:
         appended to it, the second filled on success — the DAG runner's
         runtime conflict validation inputs.
 
-        With `tally` (a block's batch gives one for a callee that is no
-        registry precompile), what the call cost on the thread that ran it is
+        With `tally` (a block's batch gives one, through its contract frame,
+        for a callee that is no registry precompile), what the call cost on
+        the thread that ran it is
         appended to it: (seconds in here, seconds inside the VM, the engine
         that finished the top-level frame). Two clock readings and one
         append a transaction (and two more readings around the
@@ -490,16 +745,12 @@ class TransactionExecutor:
         # auth governance (ContractAuthMgr enforcement): frozen contracts and
         # method ACLs gate deployed-contract calls before a frame starts
         if not is_create and tx.to not in self.registry:
-            from .precompiled.auth import acl_allows, is_frozen
-
             if is_frozen(overlay, tx.to):
-                rc.status = int(TransactionStatus.CONTRACT_FROZEN)
-                rc.output = b"contract is frozen"
+                rc.status, rc.output = _CONTRACT_FROZEN
                 rc.gas_used = BASE_GAS
                 return rc, None
             if not acl_allows(overlay, tx.to, tx.input[:4], tx.sender):
-                rc.status = int(TransactionStatus.PERMISSION_DENIED)
-                rc.output = b"method ACL denies sender"
+                rc.status, rc.output = _ACL_DENIES
                 rc.gas_used = BASE_GAS
                 return rc, None
         msg = EVMCall(
@@ -526,8 +777,6 @@ class TransactionExecutor:
             if is_create and res.create_address:
                 # deploy-time admin binding (AuthManager: the deployer
                 # governs its contract's ACLs/freeze until handover)
-                from .precompiled.auth import bind_admin
-
                 bind_admin(overlay, res.create_address, tx.sender)
             if access_out is not None:
                 writes.update(overlay._data)
@@ -564,10 +813,11 @@ class TransactionExecutor:
         # where this is the block work's entry point
         with TRACER.span(
             "executor.execute", mode="serial", txs=len(txs)
-        ), PIPELINE.busy("execute"):
+        ) as span, PIPELINE.busy("execute"):
             t0 = time.perf_counter()
             out: list[TransactionReceipt] = []
             tally: list = []  # what each contract call cost: _execute_one
+            contracts = _ContractFrame(self, block, tally)
             i, n = 0, len(txs)
             while i < n:
                 to = txs[i].to
@@ -575,16 +825,16 @@ class TransactionExecutor:
                 if to in registry:
                     while j < n and txs[j].to == to:
                         j += 1
-                if j - i > 1:
-                    out.extend(self._execute_run(registry[to], txs[i:j], block))
+                    if j - i > 1:
+                        out.extend(self._execute_run(registry[to], txs[i:j], block))
+                    else:
+                        out.append(self._execute_one(txs[i], block, context_id=base + i))
                 else:
-                    out.append(self._execute_one(
-                        txs[i], block, context_id=base + i,
-                        tally=None if to in registry else tally,
-                    ))
+                    out.append(contracts.execute(txs[i], base + i))
                 i = j
+            if self._record_contract_txs(tally, contracts.framed)[0]:
+                span.set(contract_txs=len(tally), contract_framed=contracts.framed)
         self._record_batch("serial", len(txs), time.perf_counter() - t0)
-        self._record_contract_txs(tally)
         return out
 
     def _execute_run(
@@ -621,10 +871,13 @@ class TransactionExecutor:
             mode=mode,
         )
 
-    def _record_contract_txs(self, tally: list) -> tuple[int, int, float]:
-        """A batch's contract calls (``_execute_one``'s `tally`) summed and
-        added to the counters once -> (calls, top-level frames the native
-        engine finished, seconds inside the VM)."""
+    def _record_contract_txs(
+        self, tally: list, framed: int
+    ) -> tuple[int, int, float]:
+        """A batch's contract calls (``_execute_one``'s `tally`, which the
+        contract frame appends to as well) summed and added to the counters
+        once, `framed` of them executed in the contract frame -> (calls,
+        top-level frames the native engine finished, seconds inside the VM)."""
         if not tally:
             return 0, 0, 0.0
         seconds = sum(t[0] for t in tally)
@@ -635,14 +888,25 @@ class TransactionExecutor:
             "fisco_executor_contract_txs_total",
             len(tally),
             help="block txs whose callee is no registry precompile (a deployed "
-            "contract's call, a deploy): each one frame chain on its own "
-            "overlay through _execute_one, in the serial batch and the DAG runner",
+            "contract's call, a deploy), in the serial batch and the DAG "
+            "runner: executed in the batch's contract frame, or each one frame "
+            "chain on its own overlay through _execute_one",
+        )
+        REGISTRY.counter_add(
+            "fisco_executor_contract_framed_txs_total",
+            float(framed),
+            help="those of them executed in the batch's contract frame (a "
+            "deployed EVM contract's call: one overlay, the native engine bound "
+            "once, the gates looked up once a callee); the others went through "
+            "_execute_one (a create, no code, no native engine, a chain that is "
+            "not keccak, a callee whose run the engine escaped from)",
         )
         REGISTRY.counter_add(
             "fisco_executor_contract_tx_seconds_total",
             seconds,
-            help="seconds inside _execute_one for those txs, on the thread that "
-            "executes the block",
+            help="seconds those txs took, each from the contract frame's entry "
+            "to its receipt or inside _execute_one, on the thread that executes "
+            "the block",
         )
         for engine, calls in (("native", native), ("interpreter", interpreted)):
             REGISTRY.counter_add(
@@ -656,9 +920,10 @@ class TransactionExecutor:
         REGISTRY.counter_add(
             "fisco_executor_evm_seconds_total",
             vm_s,
-            help="those txs' seconds inside the VM (the frames' interpreter "
-            "generators: the native engine's run with its storage callbacks, "
-            "and the Python loop)",
+            help="those txs' seconds inside the VM: around the native engine's "
+            "run with its storage callbacks for a framed tx, inside the frames' "
+            "interpreter generators (the native run, the Python loop) for one "
+            "through _execute_one",
         )
         return len(tally), native, vm_s
 
@@ -752,8 +1017,10 @@ class TransactionExecutor:
         index order, then VALIDATED at runtime.
 
         A call to a registry precompile executes inside the one
-        ``_PrecompileFrame`` of the call; any other callee (user bytecode)
-        through ``_execute_one`` on its own overlay. That is one of the
+        ``_PrecompileFrame`` of the call; any other callee through the
+        call's ``_ContractFrame`` (a deployed EVM contract's call in the
+        frame, whatever the frame stands aside for through
+        ``_execute_one`` on its own overlay). That is one of the
         schedules a pool could have produced, and the only one that is the
         same on every host. A pool of threads for bytecode members never
         measured faster than this thread (the native EVM engine needs the
@@ -767,8 +1034,8 @@ class TransactionExecutor:
         declaration must not let the levels' order leak into the state root,
         every level wider than one has its members' actual read/write sets
         checked pairwise after it completes; ANY overlap discards the whole
-        attempt and re-executes the block serially through
-        ``_execute_one``, the same deterministic outcome every node
+        attempt and re-executes the block serially, member by member through
+        the same two ways, the same deterministic outcome every node
         computes. The whole DAG run happens on a shadow overlay so the
         discard is clean. FISCO_DAG_SERIAL=1 pins that serial loop."""
         if self._block is None:
@@ -783,6 +1050,7 @@ class TransactionExecutor:
         ran: list[tuple[int, int]] = []
         conflicts: list[bool] = []
         tally: list = []  # what each contract call cost: _execute_one
+        frames: list[_ContractFrame] = []  # one a run (a rerun makes a second)
 
         def shadow_ctx() -> BlockContext:
             return BlockContext(
@@ -800,13 +1068,15 @@ class TransactionExecutor:
             # between nodes; see
             # tests/test_abi_conflict.py::test_reordering_levels_keep_receipt_identity)
             out: list = [None] * len(txs)
+            contracts = _ContractFrame(self, block, tally)
+            frames.append(contracts)
             for level in levels:
                 for i in level:
                     tx = txs[i]
-                    out[i] = self._execute_one(
-                        tx, block, context_id=base + i,
-                        tally=None if tx.to in registry else tally,
-                    )
+                    if tx.to in registry:
+                        out[i] = self._execute_one(tx, block, context_id=base + i)
+                    else:
+                        out[i] = contracts.execute(tx, base + i)
                 ran.append((len(level), 0))
                 span.stage("run")
             return out
@@ -815,6 +1085,8 @@ class TransactionExecutor:
             """The levels on `block`, or None where a level's check failed."""
             out: list = [None] * len(txs)
             frame = _PrecompileFrame(self, block)
+            contracts = _ContractFrame(self, block, tally)
+            frames.append(contracts)
             for level in levels:
                 wide = len(level) > 1
                 # every member of a wide level appends its access sets
@@ -827,10 +1099,7 @@ class TransactionExecutor:
                         out[i] = frame.execute(pre, tx, tracked)
                         framed += 1
                     else:
-                        out[i] = self._execute_one(
-                            tx, block, context_id=base + i,
-                            access_out=tracked, tally=tally,
-                        )
+                        out[i] = contracts.execute(tx, base + i, tracked)
                 ran.append((len(level), framed))
                 span.stage("run")
                 if not wide:
@@ -864,11 +1133,15 @@ class TransactionExecutor:
             shadow.storage.merge_into_prev()
             self._block.suicides |= shadow.suicides
             widths, framed_by_level = zip(*ran) if ran else ((), ())
-            contract_txs, evm_native, evm_s = self._record_contract_txs(tally)
+            contract_framed = sum(f.framed for f in frames)
+            contract_txs, evm_native, evm_s = self._record_contract_txs(
+                tally, contract_framed
+            )
             span.set(
                 levels=len(levels), reruns=int(conflict), widths=widths,
                 framed=framed_by_level, conflicts=tuple(conflicts),
-                contract_txs=contract_txs, evm_native=evm_native, evm_s=evm_s,
+                contract_txs=contract_txs, contract_framed=contract_framed,
+                evm_native=evm_native, evm_s=evm_s,
             )
         self._record_batch("dag", len(txs), time.perf_counter() - t_dag0)
         REGISTRY.counter_add(
@@ -882,8 +1155,8 @@ class TransactionExecutor:
                 f'fisco_executor_dag_stage_seconds_total{{stage="{key}"}}',
                 seconds,
                 help="seconds of the DAG runner by stage: levelize (conflict "
-                "keys + levels), run (the levels' execution, framed and "
-                "through _execute_one, a serial rerun too), validate (the pairwise "
+                "keys + levels), run (the levels' execution, in the two frames "
+                "and through _execute_one, a serial rerun too), validate (the pairwise "
                 "check of the access sets of a level wider than one)",
             )
         # the runner has no pool since PR 41. These two stay registered and

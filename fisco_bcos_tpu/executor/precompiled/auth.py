@@ -36,6 +36,10 @@ def _key(contract: bytes, selector: bytes) -> bytes:
     return contract + b":" + selector
 
 
+def _meta_key(contract: bytes) -> bytes:
+    return contract + b":#meta"
+
+
 def _load(ctx, key: bytes) -> dict:
     e = ctx.storage.get_row(AUTH_TABLE, key)
     if e is None or not e.get():
@@ -70,10 +74,20 @@ def _load_raw(storage, key: bytes) -> dict:
     return json.loads(e.get().decode())
 
 
+def meta_row(contract: bytes) -> tuple[str, bytes]:
+    """The row ``is_frozen`` reads, as (table, key)."""
+    return AUTH_TABLE, _meta_key(contract)
+
+
+def acl_row(contract: bytes, selector: bytes) -> tuple[str, bytes]:
+    """The row ``acl_allows`` reads, as (table, key)."""
+    return AUTH_TABLE, _key(contract, selector[:4])
+
+
 def bind_admin(storage, contract: bytes, admin: bytes) -> None:
     """Deploy-time admin binding (AuthManager binds the deployer): first
     writer wins; an existing admin is never overwritten."""
-    key = contract + b":#meta"
+    key = _meta_key(contract)
     meta = _load_raw(storage, key)
     if meta.get("admin"):
         return
@@ -82,7 +96,7 @@ def bind_admin(storage, contract: bytes, admin: bytes) -> None:
 
 
 def is_frozen(storage, contract: bytes) -> bool:
-    return bool(_load_raw(storage, contract + b":#meta").get("frozen", False))
+    return bool(_load_raw(storage, _meta_key(contract)).get("frozen", False))
 
 
 def acl_allows(storage, contract: bytes, selector: bytes, account: bytes) -> bool:
@@ -112,7 +126,7 @@ class ContractAuthPrecompiled(Precompiled):
     # -- admin ----------------------------------------------------------------
 
     def _admin_of(self, ctx, contract: bytes) -> bytes:
-        meta = _load(ctx, contract + b":#meta")
+        meta = _load(ctx, _meta_key(contract))
         return _addr(meta["admin"]) if meta.get("admin") else b""
 
     def _require_admin(self, ctx, contract: bytes) -> None:
@@ -124,11 +138,11 @@ class ContractAuthPrecompiled(Precompiled):
         """First-touch admin binding (the reference binds the deployer via
         AuthManager at deploy time)."""
         c = _addr(contract)
-        meta = _load(ctx, c + b":#meta")
+        meta = _load(ctx, _meta_key(c))
         if meta.get("admin"):
             raise PrecompiledError("admin already set")
         meta["admin"] = "0x" + _addr(admin).hex()
-        _store(ctx, c + b":#meta", meta)
+        _store(ctx, _meta_key(c), meta)
         return PrecompiledResult(output=ctx.codec.encode_output(["int256"], 0))
 
     def _get_admin(self, ctx: PrecompiledCallContext, contract: str):
@@ -140,9 +154,9 @@ class ContractAuthPrecompiled(Precompiled):
     def _reset_admin(self, ctx: PrecompiledCallContext, contract: str, admin: str):
         c = _addr(contract)
         self._require_admin(ctx, c)
-        meta = _load(ctx, c + b":#meta")
+        meta = _load(ctx, _meta_key(c))
         meta["admin"] = "0x" + _addr(admin).hex()
-        _store(ctx, c + b":#meta", meta)
+        _store(ctx, _meta_key(c), meta)
         return PrecompiledResult(output=ctx.codec.encode_output(["int256"], 0))
 
     # -- method ACLs -----------------------------------------------------------
@@ -194,9 +208,9 @@ class ContractAuthPrecompiled(Precompiled):
     def _set_status(self, ctx: PrecompiledCallContext, contract: str, is_frozen: bool):
         c = _addr(contract)
         self._require_admin(ctx, c)
-        meta = _load(ctx, c + b":#meta")
+        meta = _load(ctx, _meta_key(c))
         meta["frozen"] = bool(is_frozen)
-        _store(ctx, c + b":#meta", meta)
+        _store(ctx, _meta_key(c), meta)
         return PrecompiledResult(output=ctx.codec.encode_output(["int256"], 0))
 
     def _available(self, ctx: PrecompiledCallContext, contract: str):

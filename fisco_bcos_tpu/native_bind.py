@@ -170,79 +170,123 @@ EVM_RESULT_FN = ctypes.CFUNCTYPE(
 )
 
 
+_ZERO32 = b"\x00" * 32
+
+
+class EvmBinding:
+    """``fisco_evm_run`` bound to one host: the four ``CFUNCTYPE`` objects are
+    made once, over the host's plain-Python callbacks, and every ``run``
+    passes them again, so a caller with many frames on one host (a block's
+    contract frame) pays the binding once and a caller with one (``evm_run``)
+    goes the same way.
+
+    sload(slot32) -> bytes32, sstore(slot32, val32), log(topics list, data).
+    ctypes swallows what a callback raises: the first such exception is kept
+    and raised after the run."""
+
+    __slots__ = ("_fn", "_callbacks", "_slot")
+
+    def __init__(self, lib: ctypes.CDLL, sload, sstore, log):
+        # [the run's result, the first exception of a callback]: a list, so
+        # that the closures below hold no reference to this object
+        self._slot = slot = [None, None]
+        string_at, memmove = ctypes.string_at, ctypes.memmove
+
+        def _sload(_ctx, slot_p, out_p):
+            try:
+                memmove(out_p, sload(string_at(slot_p, 32)), 32)
+            except Exception as e:
+                if slot[1] is None:
+                    slot[1] = e
+                memmove(out_p, _ZERO32, 32)
+
+        def _sstore(_ctx, slot_p, val_p):
+            try:
+                sstore(string_at(slot_p, 32), string_at(val_p, 32))
+            except Exception as e:
+                if slot[1] is None:
+                    slot[1] = e
+
+        def _log(_ctx, topics_p, ntopics, data_p, dlen):
+            try:
+                raw = string_at(topics_p, 32 * ntopics) if ntopics else b""
+                topics = [raw[32 * t : 32 * t + 32] for t in range(ntopics)]
+                log(topics, string_at(data_p, dlen) if dlen else b"")
+            except Exception as e:
+                if slot[1] is None:
+                    slot[1] = e
+
+        def _result(_ctx, kind, status, pc, gas_left, stack_p, n_stack, mem_p,
+                    mem_len, out_p, out_len):
+            try:
+                if kind == 0:
+                    slot[0] = (
+                        "done", status, gas_left,
+                        string_at(out_p, out_len) if out_len else b"",
+                    )
+                else:
+                    raw = string_at(stack_p, n_stack * 32) if n_stack else b""
+                    stack = [
+                        int.from_bytes(raw[i * 32 : i * 32 + 32], "big")
+                        for i in range(n_stack)
+                    ]
+                    memory = string_at(mem_p, mem_len) if mem_len else b""
+                    slot[0] = ("escape", pc, gas_left, stack, memory)
+            except Exception as e:
+                if slot[1] is None:
+                    slot[1] = e
+
+        self._fn = lib.fisco_evm_run
+        self._callbacks = (
+            EVM_SLOAD_FN(_sload), EVM_SSTORE_FN(_sstore), EVM_LOG_FN(_log),
+            EVM_RESULT_FN(_result),
+        )
+
+    def run(self, code: bytes, calldata: bytes, self_addr20: bytes,
+            caller20: bytes, origin20: bytes, value32: bytes, gas: int,
+            block_number: int, timestamp: int, gas_limit: int, static_flag: int):
+        """One frame -> ("done", status, gas_left, output) or
+        ("escape", pc, gas_left, [stack ints bottom-first], memory bytes).
+        The byte strings go to the engine as they are (no copy): addresses
+        are 20 bytes, the value 32."""
+        slot = self._slot
+        slot[0] = slot[1] = None
+        self._fn(
+            code, len(code), calldata, len(calldata), self_addr20, caller20,
+            origin20, value32, gas, block_number, timestamp, gas_limit,
+            static_flag, None, *self._callbacks,
+        )
+        if slot[1] is not None:
+            raise slot[1]
+        return slot[0]
+
+
+def addr20(addr: bytes) -> bytes:
+    """An address as the engine takes it: 20 bytes, left-padded."""
+    return addr if len(addr) == 20 else addr.rjust(20, b"\x00")[:20]
+
+
+def bind_evm(sload, sstore, log) -> EvmBinding | None:
+    """The native EVM fast-prefix engine bound to these callbacks; None when
+    the native library is unavailable."""
+    lib = load()
+    return None if lib is None else EvmBinding(lib, sload, sstore, log)
+
+
 def evm_run(code: bytes, calldata: bytes, self_addr: bytes, caller: bytes,
             origin: bytes, value: int, gas: int, block_number: int,
             timestamp: int, gas_limit: int, static_flag: bool,
             sload, sstore, log):
-    """Run the native EVM fast-prefix engine. Returns
-    ("done", status, gas_left, output) or
-    ("escape", pc, gas_left, [stack ints bottom-first], memory bytes),
-    or None when the native library is unavailable.
-
-    sload(slot32)->bytes32, sstore(slot32, val32), log(topics list, data)
-    are plain-Python callbacks (closures over the host overlay)."""
-    lib = load()
-    if lib is None:
+    """One frame on the native engine, the one-call form of ``EvmBinding``:
+    its result, or None when the native library is unavailable."""
+    engine = bind_evm(sload, sstore, log)
+    if engine is None:
         return None
-    result: list = []
-    cb_err: list = []
-
-    def _sload(_ctx, slot_p, out_p):
-        try:
-            v = sload(ctypes.string_at(slot_p, 32))
-            ctypes.memmove(out_p, v, 32)
-        except Exception as e:  # ctypes swallows callback exceptions
-            cb_err.append(e)
-            ctypes.memmove(out_p, b"\x00" * 32, 32)
-
-    def _sstore(_ctx, slot_p, val_p):
-        try:
-            sstore(ctypes.string_at(slot_p, 32), ctypes.string_at(val_p, 32))
-        except Exception as e:
-            cb_err.append(e)
-
-    def _log(_ctx, topics_p, ntopics, data_p, dlen):
-        try:
-            raw = ctypes.string_at(topics_p, 32 * ntopics) if ntopics else b""
-            topics = [raw[32 * t : 32 * t + 32] for t in range(ntopics)]
-            log(topics, ctypes.string_at(data_p, dlen) if dlen else b"")
-        except Exception as e:
-            cb_err.append(e)
-
-    def _result(_ctx, kind, status, pc, gas_left, stack_p, n_stack, mem_p,
-                mem_len, out_p, out_len):
-        try:
-            if kind == 0:
-                result.append(
-                    ("done", status, gas_left,
-                     ctypes.string_at(out_p, out_len) if out_len else b"")
-                )
-            else:
-                raw = ctypes.string_at(stack_p, n_stack * 32) if n_stack else b""
-                stack = [
-                    int.from_bytes(raw[i * 32 : i * 32 + 32], "big")
-                    for i in range(n_stack)
-                ]
-                memory = ctypes.string_at(mem_p, mem_len) if mem_len else b""
-                result.append(("escape", pc, gas_left, stack, memory))
-        except Exception as e:
-            cb_err.append(e)
-
-    lib.fisco_evm_run(
-        _buf(code or b"\x00"), len(code),
-        _buf(calldata or b"\x00"), len(calldata),
-        _buf(self_addr.rjust(20, b"\x00")[:20]),
-        _buf(caller.rjust(20, b"\x00")[:20]),
-        _buf(origin.rjust(20, b"\x00")[:20]),
-        _buf(value.to_bytes(32, "big")),
-        gas, block_number, timestamp, gas_limit,
-        1 if static_flag else 0, None,
-        EVM_SLOAD_FN(_sload), EVM_SSTORE_FN(_sstore), EVM_LOG_FN(_log),
-        EVM_RESULT_FN(_result),
+    return engine.run(
+        code, calldata, addr20(self_addr), addr20(caller), addr20(origin),
+        value.to_bytes(32, "big"), gas, block_number, timestamp, gas_limit,
+        1 if static_flag else 0,
     )
-    if cb_err:
-        raise cb_err[0]
-    return result[0] if result else None
 
 
 def _bind_symbols(lib: ctypes.CDLL, u8p) -> None:
@@ -293,11 +337,14 @@ def _bind_symbols(lib: ctypes.CDLL, u8p) -> None:
     lib.fisco_ed25519_pubkey.restype = ctypes.c_int
     lib.fisco_ed25519_sign.argtypes = [u8p, u8p, ctypes.c_size_t, u8p]
     lib.fisco_ed25519_sign.restype = ctypes.c_int
+    # the inputs are read-only byte strings: c_char_p hands the engine the
+    # bytes object's own buffer, where u8p would need a copy an argument
+    cbytes = ctypes.c_char_p
     lib.fisco_evm_run.argtypes = [
-        u8p, ctypes.c_size_t,  # code
-        u8p, ctypes.c_size_t,  # calldata
-        u8p, u8p, u8p,         # self, caller, origin
-        u8p,                   # value (32B be)
+        cbytes, ctypes.c_size_t,  # code
+        cbytes, ctypes.c_size_t,  # calldata
+        cbytes, cbytes, cbytes,   # self, caller, origin
+        cbytes,                   # value (32B be)
         ctypes.c_int64,        # gas
         ctypes.c_uint64, ctypes.c_uint64, ctypes.c_uint64,  # number/ts/limit
         ctypes.c_int,          # static flag

@@ -3,15 +3,19 @@ TransactionExecutor.cpp:1220-1395 extractConflictFields)."""
 
 import json
 
+import pytest
+
 from fisco_bcos_tpu.codec.abi import ABICodec
 from fisco_bcos_tpu.crypto.suite import ecdsa_suite
 from fisco_bcos_tpu.executor import TransactionExecutor, abi_conflict
 from fisco_bcos_tpu.ledger import ConsensusNode, GenesisConfig, Ledger
+from fisco_bcos_tpu.observability import TRACER
 from fisco_bcos_tpu.protocol import Block, BlockHeader, ParentInfo
 from fisco_bcos_tpu.protocol.transaction import TransactionAttribute, TransactionFactory
 from fisco_bcos_tpu.scheduler import Scheduler
 from fisco_bcos_tpu.storage import MemoryStorage
 from fisco_bcos_tpu.txpool import TxPool
+from fisco_bcos_tpu.utils.metrics import REGISTRY
 
 from evm_asm import _deployer, asm
 
@@ -211,18 +215,9 @@ def test_dag_levels_match_serial(monkeypatch):
     assert run(True) == run(False)
 
 
-def test_lying_declaration_detected_and_serialized(monkeypatch, caplog):
-    """Two txs whose conflictFields claim disjoint state but whose code
-    writes the SAME storage slot: the level runner must detect the overlap
-    at runtime and re-execute serially, producing the serial result — a
-    lying annotation must never let anything but the block decide the state
-    root (review finding r5)."""
-    import json as _json
-
-    monkeypatch.delenv("FISCO_DAG_SERIAL", raising=False)
-
-    # setFixed(uint256,uint256) IGNORES param 0 and always writes slot 7 —
-    # but its ABI (dishonestly) declares parallelism by param 0
+def _lying_setfixed():
+    """setFixed(uint256,uint256) IGNORES param 0 and always writes slot 7,
+    but its ABI (dishonestly) declares parallelism by param 0."""
     sel = int.from_bytes(CODEC.selector("setFixed(uint256,uint256)"), "big")
     runtime = asm(
         ("PUSH", 0), "CALLDATALOAD", ("PUSH", 224), "SHR",
@@ -237,6 +232,17 @@ def test_lying_declaration_detected_and_serialized(monkeypatch, caplog):
         "inputs": [{"type": "uint256"}, {"type": "uint256"}],
         "conflictFields": [{"kind": 3, "value": [0], "slot": 0}],
     }]
+    return runtime, lying_abi
+
+
+def test_lying_declaration_detected_and_serialized(monkeypatch, caplog):
+    """Two txs whose conflictFields claim disjoint state but whose code
+    writes the SAME storage slot: the level runner must detect the overlap
+    at runtime and re-execute serially, producing the serial result — a
+    lying annotation must never let anything but the block decide the state
+    root (review finding r5)."""
+    monkeypatch.delenv("FISCO_DAG_SERIAL", raising=False)
+    runtime, lying_abi = _lying_setfixed()
 
     def run(levels: bool):
         if levels:
@@ -245,7 +251,7 @@ def test_lying_declaration_detected_and_serialized(monkeypatch, caplog):
             monkeypatch.setenv("FISCO_DAG_SERIAL", "1")
         env = Env()
         rc = env.run_block(
-            [env.tx(b"", _deployer(runtime), abi=_json.dumps(lying_abi))]
+            [env.tx(b"", _deployer(runtime), abi=json.dumps(lying_abi))]
         ).receipts[0]
         assert rc.status == 0
         addr = rc.contract_address
@@ -263,6 +269,68 @@ def test_lying_declaration_detected_and_serialized(monkeypatch, caplog):
     serial = run(False)
     # ...but the runtime validation must force the serial outcome anyway
     assert checked == serial
+
+
+def _dag_record_of(run_block):
+    """`run_block()` with the tracer cleared before it -> (its result, the
+    attributes of the one DAG ``executor.execute`` record it left, what it
+    added to ``fisco_executor_contract_framed_txs_total``)."""
+    name = "fisco_executor_contract_framed_txs_total"
+    before = sum(REGISTRY.counters_matching(name).values())
+    TRACER.clear()
+    out = run_block()
+    (record,) = [s for s in TRACER.spans()
+                 if s.name == "executor.execute" and s.attrs.get("mode") == "dag"]
+    return out, record.attrs, sum(REGISTRY.counters_matching(name).values()) - before
+
+
+def _member_by_member(env_and_txs):
+    """The same block on a second chain, every transaction through
+    ``_execute_one`` in block order: what the contract frame is held to."""
+    env, txs = env_and_txs()
+    number = env.ledger.block_number() + 1
+    env.executor.next_block_header(BlockHeader(number=number, timestamp=1000))
+    for t in txs:
+        t.force_sender(SUITE.calculate_address(env.kp.pub))
+    base = env.executor.reserve_contexts(len(txs))
+    receipts = [env.executor._execute_one(t, env.executor._block, context_id=base + i)
+                for i, t in enumerate(txs)]
+    return [rc.encode() for rc in receipts], env.executor.get_hash()
+
+
+def _honest_block():
+    env = Env()
+    addr = env.deploy_setfor()
+    return env, [env.tx(addr, _call(i % 5, 700 + i), attribute=TransactionAttribute.DAG)
+                 for i in range(8)]
+
+
+def _lying_block():
+    runtime, lying_abi = _lying_setfixed()
+    env = Env()
+    rc = env.run_block([env.tx(b"", _deployer(runtime), abi=json.dumps(lying_abi))]).receipts[0]
+    return env, [env.tx(rc.contract_address,
+                        CODEC.encode_call("setFixed(uint256,uint256)", i, 10 + i),
+                        attribute=TransactionAttribute.DAG) for i in range(4)]
+
+
+@pytest.mark.parametrize("make, reruns", [(_honest_block, 0), (_lying_block, 1)],
+                         ids=["honest", "lying"])
+def test_a_served_dag_blocks_contract_calls_run_in_the_contract_frame(
+        make, reruns, monkeypatch):
+    """Every contract member of a served DAG block executes in the call's
+    contract frame, the members of a discarded attempt and of its rerun
+    alike, and the block is the one ``_execute_one`` gives member by member."""
+    monkeypatch.delenv("FISCO_DAG_SERIAL", raising=False)
+    env, txs = make()
+    blk, at, framed = _dag_record_of(lambda: env.run_block(txs))
+    assert all(rc.status == 0 for rc in blk.receipts)
+    assert at["reruns"] == reruns
+    assert at["contract_txs"] == at["contract_framed"] == framed == len(txs) * (1 + reruns)
+    assert at["evm_native"] == at["contract_txs"]
+    want_receipts, want_root = _member_by_member(make)
+    assert [rc.encode() for rc in blk.receipts] == want_receipts
+    assert env.ledger.header_by_number(env.ledger.block_number()).state_root == want_root
 
 
 def test_reordering_levels_keep_receipt_identity(monkeypatch):

@@ -3,13 +3,13 @@
 between existing accounts, payer and payee Zipf 0.99, every transaction DAG,
 the conflict keys read from the ABI the contract was deployed with) through the
 conflict-DAG runner, at 256 transactions over 400 accounts: it gives the
-receipts, in index order, the storage rows and the state root of the serial
-loop on the same list, and those are the plain reference's; its counters and
-its one record say what the contract leg did (every member through
-``_execute_one`` on the thread that executes the block, the engine that
-finished each call, the seconds in the VM); an ABI that lies about
-``transfer`` is caught at block size and the block is run again in level
-order."""
+receipts, in index order, the storage rows and the state root of
+``_execute_one`` on the same list, member by member, and those are the plain
+reference's; its counters and its one record say what the contract leg did
+(every member in the call's contract frame on the thread that executes the
+block, the engine that finished each call, the seconds in the VM); an ABI that
+lies about ``transfer`` is caught at block size and the block is run again in
+level order, to the receipts and the root of ``FISCO_DAG_SERIAL=1``."""
 
 import json
 
@@ -91,6 +91,8 @@ def rows(ex, c):
 
 def counted():
     got = contract_counters.snapshot()
+    got["framed"] = sum(
+        REGISTRY.counters_matching("fisco_executor_contract_framed_txs_total").values())
     got["pooled_txs"] = sum(REGISTRY.counters_matching("fisco_executor_dag_pooled_txs_total").values())
     got["reruns"] = sum(
         REGISTRY.counters_matching("fisco_executor_dag_conflict_reruns_total").values())
@@ -101,12 +103,22 @@ def moved(before):
     return {k: v - before[k] for k, v in counted().items()}
 
 
+def member_by_member(ex, txs):
+    """`txs` through ``_execute_one`` one by one, in block order: what the
+    batches' frames are held to (the DAG runner and the serial batch both
+    execute a contract member in their contract frame, so neither is the
+    other's judge)."""
+    base = ex.reserve_contexts(len(txs))
+    return [ex._execute_one(tx, ex._block, context_id=base + i) for i, tx in enumerate(txs)]
+
+
 @pytest.fixture(scope="module")
 def serial():
-    """The same list through the serial loop, and through the plain reference."""
+    """The same list through ``_execute_one`` member by member, and through
+    the plain reference."""
     c = corpus()
     ex = opened(c)
-    receipts = ex.execute_transactions(block_of(c))
+    receipts = member_by_member(ex, block_of(c))
     wires = [[c.deploy.encode()], [tx.encode() for b in c.opening for tx in b],
              [tx.encode() for tx in block_of(c)]]
     balances, expected = refcontract.replay(wires, c._to)
@@ -130,15 +142,18 @@ def test_the_dag_run_is_the_serial_loop_is_the_reference(serial):
     assert (plain(receipts), rows(ex, c), ex.get_hash()) == serial
 
     # every member, of a wide level or of a level of one, is one contract
-    # call the native engine finished, none of them a future
+    # call the native engine finished in the call's contract frame, none of
+    # them a future
     got = moved(before)
     assert (got["contract_txs"], got["evm_native"], got["evm_interpreter"]) == (BLOCK_TXS, BLOCK_TXS, 0)
+    assert got["framed"] == got["contract_txs"]
     assert (got["pooled_txs"], got["pool_wait_s"], got["reruns"]) == (0, 0, 0)
     assert 0 < got["evm_s"] < got["contract_tx_s"]
     (block,) = [s for s in TRACER.spans()
                 if s.name == "executor.execute" and s.attrs["mode"] == "dag"]
     assert sum(block.attrs["widths"]) == BLOCK_TXS and sum(block.attrs["framed"]) == 0
     assert (block.attrs["contract_txs"], block.attrs["evm_native"]) == (BLOCK_TXS, BLOCK_TXS)
+    assert block.attrs["contract_framed"] == block.attrs["contract_txs"]
     assert block.attrs["evm_s"] == pytest.approx(got["evm_s"])
     assert not [s for s in TRACER.spans() if s.name == "executor.tx"], "no record a transaction"
 
@@ -147,17 +162,25 @@ def test_the_serial_batch_counts_its_contract_calls_once_a_batch(serial):
     c = corpus()
     ex = opened(c)
     before = counted()
-    ex.execute_transactions(block_of(c))
+    TRACER.clear()
+    receipts = ex.execute_transactions(block_of(c))
+    assert (plain(receipts), rows(ex, c), ex.get_hash()) == serial
     got = moved(before)
     assert (got["contract_txs"], got["evm_native"], got["pool_wait_s"]) == (BLOCK_TXS, BLOCK_TXS, 0)
+    assert got["framed"] == BLOCK_TXS
     assert 0 < got["evm_s"] < got["contract_tx_s"]
+    (batch,) = [s for s in TRACER.spans()
+                if s.name == "executor.execute" and s.attrs["mode"] == "serial"]
+    assert (batch.attrs["contract_txs"], batch.attrs["contract_framed"]) == (BLOCK_TXS, BLOCK_TXS)
 
 
-def test_an_abi_that_declares_only_from_is_caught_and_the_block_rerun(serial):
+def test_an_abi_that_declares_only_from_is_caught_and_the_block_rerun(serial, monkeypatch):
     """``transfer`` writes both names; an ABI that declares the first alone puts
     transfers to one payee into one level. The check after that level sees the
     two writes of one row, and the block goes through the serial loop in level
-    order."""
+    order: the receipts and the root of ``FISCO_DAG_SERIAL=1`` on the same
+    list, and of ``_execute_one`` member by member in that order."""
+    monkeypatch.delenv("FISCO_DAG_SERIAL", raising=False)
     abi = json.loads(gen.contract_files(CONFIG["contract"])[1])
     transfer = next(e for e in abi if e["name"] == "transfer")
     transfer["conflictFields"] = transfer["conflictFields"][:1]
@@ -172,6 +195,19 @@ def test_an_abi_that_declares_only_from_is_caught_and_the_block_rerun(serial):
     got = moved(before)
     assert got["reruns"] == 1
     assert got["contract_txs"] > BLOCK_TXS, "the discarded attempt's calls and the rerun's"
+    assert got["framed"] == got["contract_txs"] == got["evm_native"], "both in the frame"
+    monkeypatch.setenv("FISCO_DAG_SERIAL", "1")
+    pinned = opened(c)
+    pinned_receipts = pinned.dag_execute_transactions(txs)
+    monkeypatch.delenv("FISCO_DAG_SERIAL")
+    assert (plain(receipts), ex.get_hash()) == (plain(pinned_receipts), pinned.get_hash())
+    judge = opened(c)
+    in_level_order = [i for level in judge.dag_levels(txs) for i in level]
+    base = judge.reserve_contexts(len(txs))
+    for i in in_level_order:
+        want = judge._execute_one(txs[i], judge._block, context_id=base + i)
+        assert plain([receipts[i]]) == plain([want]), i
+    assert ex.get_hash() == judge.get_hash()
     # the rerun walks the lying levels, so its receipts are the serial loop's
     # only where the lie reordered nothing that conflicts: hold it to a plain
     # replay of the same order instead

@@ -2,8 +2,8 @@
 (PR 41): a DAG call starts none and runs every member where it was called,
 whatever the callee; a level's members execute in index order and the levels
 in level order; a contract block executed while another thread fights for
-the interpreter ends on the serial loop's root; and the block's one record
-and the counters say so (no ``pooled``, no ``pool_wait_s``; the two pool
+the interpreter ends on the root of ``_execute_one`` member by member; and
+the block's one record and the counters say so (no ``pooled``, no ``pool_wait_s``; the two pool
 counters the benchmark still reads stay registered and never move)."""
 
 import os
@@ -27,7 +27,8 @@ TRANSFER = "userTransfer(string,string,uint256)"
 
 class Watch:
     """Every member's execution as (tx index, thread), in the order it
-    happened, through both of the runner's two ways to execute a member."""
+    happened, through the runner's three ways to execute a member (a member
+    the contract frame hands on to ``_execute_one`` is seen once)."""
 
     def __init__(self, monkeypatch, txs):
         self.seen: list[tuple[int, int]] = []
@@ -36,8 +37,9 @@ class Watch:
 
         def watched(real, tx_at):  # `real` with its transaction at args[tx_at]
             def method(*args, **kw):
-                if id(args[tx_at]) in index:
-                    seen.append((index[id(args[tx_at])], threading.get_ident()))
+                at = (index.get(id(args[tx_at])), threading.get_ident())
+                if at[0] is not None and seen[-1:] != [at]:
+                    seen.append(at)
                 return real(*args, **kw)
             return method
 
@@ -45,6 +47,8 @@ class Watch:
                             watched(TransactionExecutor._execute_one, 1))
         monkeypatch.setattr(executor_module._PrecompileFrame, "execute",
                             watched(executor_module._PrecompileFrame.execute, 2))
+        monkeypatch.setattr(executor_module._ContractFrame, "execute",
+                            watched(executor_module._ContractFrame.execute, 1))
 
     @property
     def order(self):
@@ -139,7 +143,7 @@ def test_members_execute_in_index_order_and_levels_in_level_order(make, monkeypa
 def test_a_contract_block_beside_a_thread_that_takes_the_interpreter_ends_on_the_serial_root():
     ex, txs = contract_block()
     ser, _ = contract_block()
-    want = con.plain(ser.execute_transactions(txs)), ser.get_hash()
+    want = con.plain(con.member_by_member(ser, txs)), ser.get_hash()
     stop = threading.Event()
     turns = [0]
 
@@ -180,6 +184,7 @@ def test_a_contract_blocks_record_has_no_pool_in_it_and_the_pool_counters_never_
     assert at["widths"] == tuple(map(len, levels)) and at["framed"] == (0,) * len(levels)
     assert "pooled" not in at and "pool_wait_s" not in at
     assert at["contract_txs"] == at["evm_native"] == len(txs) and at["reruns"] == 0
+    assert at["contract_framed"] == at["contract_txs"], "every member in the contract frame"
     # dag_pooled_tx_share (BENCHMARK.json) and dag_pool_wait_ms_per_block
     # (tests/benchmark_checks) read these two: present, one series each, at 0
     assert pool_counters() == before
@@ -205,7 +210,10 @@ def test_the_runner_asks_neither_the_environment_nor_the_host_for_a_width(monkey
     receipts = ex.dag_execute_transactions(txs)
     monkeypatch.undo()
     assert [key for key in asked if "DAG" in key] == ["FISCO_DAG_SERIAL"]
-    assert con.plain(receipts) == con.plain(ser.execute_transactions(txs))
+    # the contract frame asks once a call whether the native engine is switched off
+    assert sorted(set(asked)) == ["FISCO_DAG_SERIAL", "FISCO_NO_NATIVE_EVM"]
+    assert asked.count("FISCO_NO_NATIVE_EVM") == 1
+    assert con.plain(receipts) == con.plain(con.member_by_member(ser, txs))
 
 
 # -- the scheduler's blocks take the same one thread ------------------------------
