@@ -421,7 +421,7 @@ def child_air4(args) -> int:
         "cutover": device_min_batch(),
     }
 
-    # -- the chain: exactly bench_flood / ScenarioRunner._build_chain's shape
+    # -- the chain: exactly ScenarioRunner._build_chain's shape
     keypairs = [sign.generate_keypair(secret=0xC41B + i) for i in range(4)]
     committee = [ConsensusNode(kp.pub, weight=1) for kp in keypairs]
     gw = InprocGateway(auto=True)
@@ -474,8 +474,7 @@ def child_air4(args) -> int:
             out["expected_invalid"] = sorted(expected_invalid)
         return out
 
-    # One full-width block's payloads and signatures, as bench.py's
-    # bench_admission builds them. Its program (bucket 10,240) is compiled on
+    # One full-width block's payloads and signatures. Its program (bucket 10,240) is compiled on
     # a second thread while the 1,024 one compiles on the plane worker — two
     # minutes-class compiles overlap instead of queueing. That is set-up only:
     # the checked call further down goes through the public admit_batch and

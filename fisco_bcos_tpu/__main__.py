@@ -107,7 +107,6 @@ def build_node(opts: ChainOptions):
     from .observability.critical_path import trace_tx
     from .observability.device import device_doc
     from .observability.pipeline import pipeline_doc
-    from .observability.storagelog import storage_doc
     from .resilience import HEALTH
     from .rpc.group_manager import GroupManager, MultiGroupRpc
     from .utils.metrics import bind_node_metrics
@@ -132,7 +131,6 @@ def build_node(opts: ChainOptions):
         fleet=fleet.fleet_doc if fleet is not None else None,
         round_doc=fleet.round_forensics if fleet is not None else None,
         rounds=fleet.rounds_forensics if fleet is not None else None,
-        storage=storage_doc,
     )
     ws = None
     if opts.ws_listen_port:

@@ -72,8 +72,7 @@ def check_repo(
     root: str | None = None, baseline_path: str | None = None
 ) -> tuple[list[Finding], list[str]]:
     """(new findings vs baseline, stale baseline keys) — the enforcement
-    entry point shared by the CLI, the tier-1 test and bench.py's
-    --telemetry gate."""
+    entry point shared by the CLI and the tier-1 test."""
     findings = run_all(root)
     baseline = load_baseline(baseline_path)
     return diff_findings(findings, baseline)

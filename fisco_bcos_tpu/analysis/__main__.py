@@ -2,8 +2,7 @@
 
 Exit codes: 0 = clean (no non-baselined findings, no stale baseline
 entries), 1 = new findings or stale baseline entries — the same contract
-the tier-1 test and the ``bench.py --telemetry`` gate enforce — 2 = usage
-error. ``--update-baseline`` rewrites the baseline to the current finding
+the tier-1 test enforces — 2 = usage error. ``--update-baseline`` rewrites the baseline to the current finding
 set (review the diff before committing it — the baseline is accepted
 debt, not a mute button).
 """
@@ -86,8 +85,8 @@ def main(argv: list[str] | None = None) -> int:
     )
     p.add_argument(
         "--adjacency", default=None, metavar="JSON",
-        help="device artifact (GET /device or bench_telemetry.*.device."
-        "json) whose 'adjacency' map weights the fusion report",
+        help="device artifact (a saved GET /device document) whose "
+        "'adjacency' map weights the fusion report",
     )
     args = p.parse_args(argv)
 

@@ -1066,117 +1066,11 @@ class TornQuorumHarness:
         assert EVIDENCE.count() == 0, EVIDENCE.counts()
 
 
-# -- Storage observatory recorder ---------------------------------------------
-
-
-class StorageObsHarness:
-    """A commit-window writer (the scheduler's serialized 2PC ledger path)
-    races codec/copy note traffic from a worker thread and a snapshot
-    reader — the global codec/copy counters must not lose updates, the
-    per-block window must fold only commit-context bytes, and no snapshot
-    may tear (ISSUE 19: the recorder is always-on shared state touched by
-    every storage seam plus the /storage renderer)."""
-
-    name = "storage-obs"
-
-    def __init__(self):
-        from ..observability.storagelog import StorageRecorder
-
-        self.watch = [
-            (StorageRecorder, (
-                "_codec", "_copies", "_pages", "_blocks", "_cur",
-                "_shard_ms", "_shard_totals",
-            )),
-        ]
-
-    def setup(self):
-        from ..observability.storagelog import StorageRecorder
-
-        # deterministic injected clock (the explorer forbids wall clocks)
-        ticks = {"t": 0.0}
-        lock = threading.Lock()
-
-        def clock():
-            with lock:
-                ticks["t"] += 1.0
-                return ticks["t"]
-
-        rec = StorageRecorder(clock=clock, emit_metrics=False, enabled=True)
-        return {"rec": rec, "snaps": []}
-
-    def threads(self, ctx):
-        from ..observability.storagelog import CTX_COMMIT, codec_ctx
-
-        rec = ctx["rec"]
-        snaps = ctx["snaps"]
-
-        def committer():
-            # two serialized commit windows, as the scheduler runs them
-            for h in (1, 2):
-                rec.begin_commit(h)
-                rec.note_commit_rows(h, 2)
-                with codec_ctx(CTX_COMMIT, "t"):
-                    rec.note_encode(10)
-                    rec.note_encode(10)
-                rec.note_copy("keypage.prepare", "t")
-                rec.note_pages("t", 1)
-                rec.end_prepare(h)
-                rec.finish_commit(h)
-
-        def worker():
-            # read-path traffic landing DURING the windows: untagged
-            # codec bytes, cache copy-outs, a remote shard's 2PC legs
-            for _ in range(3):
-                rec.note_encode(5)
-            rec.note_copy("cache.get_row", "t")
-            rec.note_copy("cache.get_row", "t")
-            rec.shard_note("prepare", 0, 1.0, rows=1, n_bytes=5)
-            rec.shard_note("prepare", 0, 2.0, rows=1, n_bytes=5)
-
-        def reader():
-            snaps.append(rec.snapshot(last_blocks=8))
-
-        return [("commit", committer), ("worker", worker), ("snap", reader)]
-
-    def check(self, ctx):
-        rec = ctx["rec"]
-        snap = rec.snapshot(last_blocks=8)
-        # lost-update canaries: exact global counters whatever the schedule
-        assert snap["codec"]["encode:commit:t"] == {"calls": 4, "bytes": 40}, snap
-        assert snap["codec"]["encode:-:-"] == {"calls": 3, "bytes": 15}, snap
-        assert snap["copies"]["keypage.prepare:t"] == 2, snap
-        assert snap["copies"]["cache.get_row:t"] == 2, snap
-        assert snap["pages_rewritten"] == {"t": 2}, snap
-        assert rec.encode_bytes_now() == 55
-        assert rec.commit_bytes_total() == 40
-        # both windows closed; only commit-context bytes folded into them
-        blocks = {b["height"]: b for b in snap["blocks"]}
-        assert set(blocks) == {1, 2}, snap
-        for b in blocks.values():
-            assert not b["aborted"] and b["rows_written"] == 2, b
-            assert b["bytes_encoded"] == 20 and b["encode_calls"] == 2, b
-            assert b["pages_rewritten"] == 1, b
-            # the committer's own copy always lands in its window; the
-            # worker's cache copy-outs land in whichever window (or none)
-            # was open — bounded either way
-            assert 1 <= b["entries_copied"] <= 3, b
-        shard = snap["shards"]["0"]["prepare"]
-        assert shard["n"] == 2 and shard["rows"] == 2 and shard["bytes"] == 10
-        # a mid-run snapshot must parse as a consistent document, never
-        # exceed the final counters, and never show a torn codec cell
-        for s in ctx["snaps"]:
-            for cell in s["codec"].values():
-                assert cell["calls"] >= 0 and cell["bytes"] >= 0, s
-            assert len(s["blocks"]) <= 2, s
-            assert sum(s["copies"].values()) <= 4, s
-
-
 HARNESSES = {
     h.name: h
     for h in (DevicePlaneHarness, ProofPlaneHarness, AdmissionQuotasHarness,
               SchedulerHarness, PipelinedCommitHarness, PipelineObsHarness,
-              QuorumCollectorHarness, FleetObsHarness, TornQuorumHarness,
-              StorageObsHarness)
+              QuorumCollectorHarness, FleetObsHarness, TornQuorumHarness)
 }
 
 FIXTURE_HARNESSES = {RacyCounterHarness.name: RacyCounterHarness}

@@ -11,7 +11,7 @@ Joins two sources:
 - **measured** dispatch adjacency from the device observatory's ledger
   (``CompileLedger.adjacency()``: back-to-back op pairs counted at
   ``device_span`` exit and DevicePlane dispatch), when a live ledger or a
-  saved ``bench_telemetry.*.device.json`` provides one — measured counts
+  saved ``GET /device`` document provides one — measured counts
   weight the static edges by how often they actually ran in the flood.
 
 Per pair, the predicted saving is ``min(producer bytes_out, consumer

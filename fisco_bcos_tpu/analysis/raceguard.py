@@ -283,15 +283,6 @@ DEFAULT_WATCHLIST: tuple[tuple[str, str, tuple[str, ...]], ...] = (
     # "?": lock-free ring reads are the design (GIL-atomic deque.append);
     # only a post-publication reassignment of the ring itself may flag
     ("fisco_bcos_tpu.observability.flight", "FlightRecorder", ("?_ring",)),
-    # the storage observatory's shared state (ISSUE 19): codec/copy cells
-    # are bumped from every executor/cache/commit thread and the open
-    # commit window is handed between the scheduler's 2PC phases, all
-    # under the recorder lock; "?" on the dict-valued cells because the
-    # registry's pull-gauges read them lock-free at scrape by design
-    # (GIL-atomic dict.get of a published dict) — reassignment still flags
-    ("fisco_bcos_tpu.observability.storagelog", "StorageRecorder",
-     ("?_codec", "?_copies", "_pages", "_blocks", "_cur", "_shard_ms",
-      "_shard_totals")),
 )
 
 _installed = False

@@ -1221,17 +1221,6 @@ class TransactionExecutor:
             if extra_writes is None
             else _StagedWrites(ctx.storage, extra_writes)
         )
-        from ..observability.storagelog import STORAGE
-
-        if STORAGE.enabled:
-            # the block's LOGICAL write-set size (overlay dirty rows + the
-            # scheduler's ledger rows) — the denominator of the per-block
-            # copy-amplification number
-            rows = ctx.storage.dirty_count()
-            extra_count = getattr(extra_writes, "dirty_count", None)
-            if extra_count is not None:
-                rows += extra_count()
-            STORAGE.note_commit_rows(params.number, rows)
         t0 = time.perf_counter()
         staged = self.backend.prepare(params, writes)
         REGISTRY.observe(

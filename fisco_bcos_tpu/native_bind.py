@@ -505,7 +505,7 @@ def ec_pubkey(curve: str, d: int) -> bytes | None:
 
 
 def secp256k1_verify_batch(zs: bytes, rs: bytes, ss: bytes, pubs: bytes, n: int):
-    """n-item loop in one native call — the honest CPU baseline for bench.py.
+    """n-item loop in one native call (the suite's CPU verify leg).
     Returns a list[bool] or None when unavailable."""
     lib = load()
     if lib is None:

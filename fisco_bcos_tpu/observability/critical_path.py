@@ -19,8 +19,8 @@ boundaries that break naive per-trace grouping:
 
 ``stitch`` = tx-trace spans ∪ block-trace spans ∪ spans link-referencing
 either (the device-plane merged batch), ordered by wall time.
-``analyze`` names the dominant stage — the artifact ``bench.py
---telemetry`` and ``GET /trace/tx/<hash>`` serve.
+``analyze`` names the dominant stage — the artifact
+``GET /trace/tx/<hash>`` serves.
 """
 
 from __future__ import annotations
@@ -64,10 +64,7 @@ def reset() -> None:
 
 
 def clear_indexes() -> None:
-    """Drop the tx/block/miss indexes but keep registered SPAN_SOURCES —
-    the measured-window boundary (`bench.py --telemetry` clears here when
-    its profiler starts so the round artifact's per-stage aggregation
-    covers the measured flood only, not the warm/compile round)."""
+    """Drop the tx/block/miss indexes but keep registered SPAN_SOURCES."""
     with _lock:
         _tx_index.clear()
         _block_index.clear()
@@ -364,8 +361,7 @@ def trace_tx(tx_hash_hex: str) -> dict:
 
 def aggregate_stage_self_ms(committed_only: bool = True) -> dict:
     """Per-stage self-time totals across ALL sampled txs in the index —
-    the flood-window stage vector ``bench.py --telemetry`` writes into the
-    round artifact and ``tool/check_perf.py`` diffs round-over-round.
+    a window's stage vector.
 
     The per-exemplar ``trace_tx`` answers "where did THIS tx's time go";
     this aggregates: take the union of every indexed (committed) tx's
@@ -418,13 +414,3 @@ def aggregate_stage_self_ms(committed_only: bool = True) -> dict:
         "spans": len(spans),
         "stages": totals,
     }
-
-
-def latest_committed_tx() -> str | None:
-    """The most recently committed indexed tx hash (hex) — what
-    ``bench.py --telemetry`` stitches as its per-run exemplar artifact."""
-    with _lock:
-        for key in reversed(_tx_index):
-            if _tx_index[key]["committed"] is not None:
-                return key
-    return None

@@ -131,9 +131,8 @@ def _count_shape(op: str, key) -> None:
 
 def compile_counts() -> dict[str, int]:
     """Distinct compiled (bucketed) shapes seen per op — the in-process
-    view of ``fisco_device_compile_total``. tool/check_device_plane.py and
-    bench.py read it to assert/report that a ragged flood stays within the
-    bucket ladder instead of recompiling per batch size. With every
+    view of ``fisco_device_compile_total``. tool/check_device_plane.py reads
+    it to assert that a ragged flood stays within the bucket ladder instead of recompiling per batch size. With every
     wrapper passing its true bucketed shape key, this agrees with the
     ledger's measured program count (tests/test_device_obs.py pins it)."""
     with _seen_lock:

@@ -36,14 +36,12 @@ PROFILE_SECONDS_MAX = 30.0
 PROFILER_SAMPLE_BUCKETS_MS = (0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 25.0)
 
 _PKG_MARKER = f"fisco_bcos_tpu{os.sep}"
-# repo entrypoints whose frames count as "ours" under the package filter
-_EXTRA_KEEP = ("bench.py", "bench_storage.py", os.sep + "tool" + os.sep)
+# the repo's tools count as "ours" under the package filter
+_TOOL_DIR = os.sep + "tool" + os.sep
 
 
 def _keep_frame(filename: str) -> bool:
-    return _PKG_MARKER in filename or any(
-        filename.endswith(k) or k in filename for k in _EXTRA_KEEP
-    )
+    return _PKG_MARKER in filename or _TOOL_DIR in filename
 
 
 def _frame_label(frame) -> str:
@@ -245,20 +243,12 @@ class SamplingProfiler:
 _PROFILE_LOCK = threading.Lock()
 
 
-def profile(
-    seconds: float = 2.0, hz: float = DEFAULT_HZ, alloc: bool | None = None
-) -> dict:
+def profile(seconds: float = 2.0, hz: float = DEFAULT_HZ) -> dict:
     """The ``GET /profile?seconds=N`` implementation: sample this process
     for ``seconds`` (clamped to :data:`PROFILE_SECONDS_MAX`) on the calling
     thread and return the report. Single-flight: a second concurrent
     request gets ``{"error": "profiler busy"}`` instead of doubling the
-    overhead.
-
-    When the storage observatory is on (``alloc=None`` defers to its
-    switch), a tracemalloc window rides the same sampling cadence and the
-    report gains ``alloc_top`` — the top allocation sites over the window,
-    each attributed to a pipeline stage, so "codec churn on the commit
-    path" is a named list instead of a flamegraph guess."""
+    overhead."""
     try:
         seconds = float(seconds)
     except (TypeError, ValueError):
@@ -267,20 +257,8 @@ def profile(
     if not _PROFILE_LOCK.acquire(blocking=False):
         return {"error": "profiler busy", "seconds": seconds}
     try:
-        if alloc is None:
-            from .storagelog import storage_obs_enabled
-
-            alloc = storage_obs_enabled()
-        window = None
-        if alloc:
-            from .storagelog import AllocationWindow
-
-            window = AllocationWindow().start()
         p = SamplingProfiler(hz=hz)
         p.run_for(seconds)
-        report = p.report()
-        if window is not None:
-            report["alloc_top"] = window.top()
-        return report
+        return p.report()
     finally:
         _PROFILE_LOCK.release()

@@ -33,9 +33,9 @@ synced headers. ``FISCO_PROOF_PLANE=0`` disables the plane entirely —
 every caller takes the exact pre-plane direct rebuild path (the cache-off
 fallback kept in ledger.py).
 
-Bench: ``bench.py --scenario proof-storm`` (scenario/proof_storm.py)
+Bench: ``scenario.run_proof_storm_bench`` (scenario/proof_storm.py)
 hammers batched proofs from ~10^5 simulated light clients while the chain
-floods; ``tool/check_proofs.py`` is the CI smoke. See docs/proofs.md.
+floods; ``tool/check_proofs.py`` runs it as the CI smoke. See docs/proofs.md.
 """
 
 from __future__ import annotations

@@ -178,9 +178,6 @@ IDEMPOTENT_METHODS: set[str] = {
     # succinct state plane (ISSUE 18): pure reads off frozen per-height
     # snapshots — a re-served batch rebuilds at most a cached page tree
     "getStateProof", "state_proof", "state_proof_batch",
-    # storage observatory (ISSUE 19): a pure snapshot of the in-memory
-    # codec/copy ledger — re-pulling after a lost reply re-reads counters
-    "storage",
 }
 
 NON_IDEMPOTENT_METHODS: set[str] = {

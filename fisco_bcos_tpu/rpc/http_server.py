@@ -57,7 +57,6 @@ class RpcHttpServer:
         fleet=None,
         round_doc=None,
         rounds=None,
-        storage=None,
     ):
         self.impl = impl
         # `metrics` needs .render() -> str; `tracer` needs .export_json() ->
@@ -86,9 +85,6 @@ class RpcHttpServer:
         self.fleet = fleet or getattr(tracer, "fleet", None)
         self.round_doc = round_doc or getattr(tracer, "round_doc", None)
         self.rounds = rounds or getattr(tracer, "rounds", None)
-        # storage observatory (ISSUE 19): `storage` (() -> dict) serves the
-        # commit-path codec/copy ledger + 2PC shard attribution document
-        self.storage = storage or getattr(tracer, "storage", None)
         outer = self
 
         class Handler(BaseHTTPRequestHandler):
@@ -179,15 +175,6 @@ class RpcHttpServer:
                     # cold-vs-persistent-cache attribution, per-op phase
                     # totals, memory watermarks, recompile-storm state
                     data = json.dumps(outer.device(), default=str).encode()
-                    ctype = "application/json"
-                elif (
-                    self.path.split("?", 1)[0] == "/storage"
-                    and outer.storage is not None
-                ):
-                    # storage observatory (ISSUE 19): per-table codec
-                    # traffic, copy-amplification ledger per block,
-                    # per-shard 2PC attribution
-                    data = json.dumps(outer.storage(), default=str).encode()
                     ctype = "application/json"
                 elif (
                     self.path.split("?", 1)[0] == "/profile"

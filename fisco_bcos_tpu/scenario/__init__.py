@@ -7,8 +7,8 @@ millions-of-users deployment actually sees. This package generates that
 traffic as composable, *bit-deterministic* workload primitives
 (:mod:`workloads`), names canned compositions (:data:`SCENARIOS`) and
 drives them against a live multi-group chain (:mod:`runner`) emitting a
-per-group TPS/latency artifact through the same telemetry machinery the
-bench uses (``bench.py --scenario <name>``).
+per-group TPS/latency artifact (``tool/check_scenarios.py``,
+``ScenarioRunner(...).run()``).
 
 Seed contract: ``scenario.events(seed)`` is a pure function of
 ``(scenario, seed)`` — same seed, same byte-identical transaction/event
@@ -26,19 +26,16 @@ from .base import (
     get_scenario,
     list_scenarios,
 )
-from .big_committee import run_big_committee_bench
 from .byzantine import (
     ATTACK_NAMES,
     ByzantineHarness,
     ByzantineReplica,
-    run_byzantine_bench,
     run_byzantine_scenario,
 )
 from .proof_storm import run_proof_storm_bench
-from .runner import ScenarioRunner, run_isolation_bench
+from .runner import ScenarioRunner
 from .wire import (
     WireHarness,
-    run_wire_bench,
     run_wire_catalog,
     run_wire_colluders,
     run_wire_partition,
@@ -56,12 +53,8 @@ __all__ = [
     "WorkloadContext",
     "get_scenario",
     "list_scenarios",
-    "run_big_committee_bench",
-    "run_byzantine_bench",
     "run_byzantine_scenario",
-    "run_isolation_bench",
     "run_proof_storm_bench",
-    "run_wire_bench",
     "run_wire_catalog",
     "run_wire_colluders",
     "run_wire_partition",

@@ -1,93 +1,13 @@
-"""big-committee: QC bytes + verify time flat in committee size (ISSUE 12
-acceptance bench).
-
-Three legs, one artifact:
-
-1. **Quorum-layer sweep** — for n = 4 and n = 64: a deterministic
-   committee signs the same header hash, the votes are sealed into a
-   quorum certificate per round, and the certificate is aggregate-
-   verified. Measured per committee size: encoded QC bytes, aggregate
-   verify wall-ms (through the BLS scheme's DevicePlane-routed pairing
-   check — the host reference on CPU backends), certificate build ms,
-   and the n-sequential per-vote verification baseline the aggregate
-   replaces. Acceptance: n=64 QC bytes within 1.1x of n=4, and the
-   aggregate verify beating n sequential verifies at n=64.
-2. **Ed25519 comparison row** — the cheap rung's concatenated
-   certificate at both sizes, showing exactly the O(n) byte growth the
-   BLS rung removes.
-3. **Integration leg** — a real 4-node PBFT chain with a QC-capable BLS
-   committee commits blocks end-to-end; the committed headers must carry
-   the constant-size QC record (and no signature_list), replicas must
-   agree, and the committed QC bytes land in the artifact.
-
-Committees are derived from the seed (deterministic secrets -> derived
-QC keypairs), so the sweep is reproducible; wall-clock timings are the
-measurement, not part of the determinism contract.
+"""big-committee: a 4-node PBFT chain whose committee registered BLS
+``qc_pub`` keys commits blocks end to end; the committed headers must carry
+the constant-size QC record (and no signature_list) and the replicas must
+agree. ``tool/check_qc.py`` drives it; the committee is derived from the
+seed, so a run is reproducible.
 """
 
 from __future__ import annotations
 
-import hashlib
-import os
 import time
-
-from ..utils.log import get_logger
-from .runner import _pctl
-
-_log = get_logger("scenario")
-
-
-def _sweep_committee(scheme, n: int, seed: int, rounds: int, deadline=None) -> dict:
-    """One committee size: sign -> seal -> aggregate-verify per round,
-    plus the sequential per-vote baseline (one round's worth)."""
-    kps = [scheme.derive_keypair(0x51C0_0000 + seed * 100_000 + i) for i in range(n)]
-    pubs = [kp.pub for kp in kps]
-    build_ms, verify_ms, sign_ms, qc_bytes = [], [], [], 0
-    ok_all = True
-    done_rounds = 0
-    for r in range(rounds):
-        if deadline is not None and time.perf_counter() > deadline:
-            break
-        msg = hashlib.sha256(b"big-committee:%d:%d:%d" % (seed, n, r)).digest()
-        t0 = time.perf_counter()
-        sigs = {i: scheme.sign_vote(kp, msg) for i, kp in enumerate(kps)}
-        sign_ms.append((time.perf_counter() - t0) * 1e3 / n)
-        t0 = time.perf_counter()
-        cert = scheme.build_cert(sigs, n)
-        build_ms.append((time.perf_counter() - t0) * 1e3)
-        qc_bytes = len(cert.encode())
-        t0 = time.perf_counter()
-        ok_all &= scheme.verify_cert(cert, pubs, msg)
-        verify_ms.append((time.perf_counter() - t0) * 1e3)
-        done_rounds += 1
-    # the baseline the aggregate replaces: n sequential per-vote verifies
-    # (the single most expensive leg at n=64 — it honors the deadline too,
-    # or a tight bench budget would kill the child and lose the artifact)
-    sequential_ms = 0.0
-    if deadline is None or time.perf_counter() < deadline:
-        msg = hashlib.sha256(b"big-committee:%d:%d:base" % (seed, n)).digest()
-        sigs = {i: scheme.sign_vote(kp, msg) for i, kp in enumerate(kps)}
-        t0 = time.perf_counter()
-        seq_ok = all(
-            scheme.verify_one(pubs[i], msg, sigs[i]) for i in range(n)
-        )
-        sequential_ms = (time.perf_counter() - t0) * 1e3
-        ok_all &= seq_ok
-    agg = _pctl(verify_ms, 0.5)
-    return {
-        "n": n,
-        "rounds": done_rounds,
-        "qc_bytes": qc_bytes,
-        "verify_ms_p50": round(agg, 3),
-        "verify_ms": [round(v, 3) for v in verify_ms],
-        "build_ms_p50": round(_pctl(build_ms, 0.5), 3),
-        "sign_ms_per_vote": round(_pctl(sign_ms, 0.5), 3),
-        "sequential_verify_ms": round(sequential_ms, 3),
-        "aggregate_speedup_vs_sequential": round(sequential_ms / agg, 2)
-        if agg > 0
-        else 0.0,
-        "all_verified": ok_all,
-    }
 
 
 def _chain_leg(seed: int, blocks: int = 2) -> dict:
@@ -160,65 +80,3 @@ def _chain_leg(seed: int, blocks: int = 2) -> dict:
         "committed_qc_bytes": committed_qc_bytes,
         "headers_carry_qc": len(committed_qc_bytes) == nodes[0].block_number(),
     }
-
-
-def run_big_committee_bench(
-    seed: int = 0, scale: float = 1.0, deadline_s: float | None = None
-) -> dict:
-    """The ISSUE 12 acceptance bench; returns the artifact dict."""
-    from ..consensus.qc import get_scheme
-
-    t_wall = time.perf_counter()
-    deadline = t_wall + deadline_s if deadline_s is not None else None
-    rounds = max(2, int(3 * scale))
-    bls = get_scheme("bls")
-    ed = get_scheme("ed25519")
-    doc: dict = {"scenario": "big-committee", "seed": seed, "scheme": "bls"}
-    sweep = {}
-    for n in (4, 64):
-        sweep[str(n)] = _sweep_committee(bls, n, seed, rounds, deadline)
-    doc["committees"] = sweep
-    b4, b64 = sweep["4"]["qc_bytes"], sweep["64"]["qc_bytes"]
-    v4, v64 = sweep["4"]["verify_ms_p50"], sweep["64"]["verify_ms_p50"]
-    doc["qc_bytes_ratio_64_vs_4"] = round(b64 / b4, 3) if b4 else 0.0
-    doc["verify_ms_ratio_64_vs_4"] = round(v64 / v4, 3) if v4 else 0.0
-    doc["aggregate_speedup_vs_sequential_n64"] = sweep["64"][
-        "aggregate_speedup_vs_sequential"
-    ]
-    # the cheap rung's byte growth, for contrast
-    doc["ed25519"] = {
-        str(n): _sweep_committee(ed, n, seed, rounds=1, deadline=deadline)[
-            "qc_bytes"
-        ]
-        for n in (4, 64)
-    }
-    errors = []
-    for n, d in sweep.items():
-        if not d["all_verified"]:
-            errors.append(f"n={n}: a quorum failed verification")
-    if deadline is None or time.perf_counter() < deadline:
-        prev_qc = os.environ.get("FISCO_QC")
-        prev_scheme = os.environ.get("FISCO_QC_SCHEME")
-        os.environ["FISCO_QC"] = "1"
-        os.environ["FISCO_QC_SCHEME"] = "bls"
-        try:
-            doc["chain"] = _chain_leg(seed)
-            if not doc["chain"]["headers_carry_qc"]:
-                errors.append("chain leg committed headers without QC records")
-            if not doc["chain"]["heights_equal"]:
-                errors.append("chain leg replicas diverged")
-        finally:
-            for key, prev in (
-                ("FISCO_QC", prev_qc),
-                ("FISCO_QC_SCHEME", prev_scheme),
-            ):
-                if prev is None:
-                    os.environ.pop(key, None)
-                else:
-                    os.environ[key] = prev
-    else:
-        errors.append("deadline hit before the chain leg")
-    doc["wall_s"] = round(time.perf_counter() - t_wall, 3)
-    if errors:
-        doc["error"] = "; ".join(errors)
-    return doc

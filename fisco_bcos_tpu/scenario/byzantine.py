@@ -24,9 +24,7 @@ and the :data:`~fisco_bcos_tpu.consensus.audit.EVIDENCE` board), the
 attacker demoted through the existing strike/quota board, and the honest
 committee must keep committing — the
 :func:`~fisco_bcos_tpu.consensus.audit.audit_chain` safety auditor is the
-final gate of every run. ``run_byzantine_bench`` measures the liveness
-cost: honest commit throughput under attack vs. a clean flood of the same
-shape (the bench gate accepts ≥0.5x).
+final gate of every run.
 """
 
 from __future__ import annotations
@@ -38,10 +36,7 @@ from ..consensus.messages import PacketType, PBFTMessage, ViewChangePayload
 from ..front.front import ModuleID
 from ..protocol.block import Block
 from ..protocol.block_header import BlockHeader, ParentInfo
-from ..utils.log import get_logger
 from .base import WorkloadContext
-
-_log = get_logger("byzantine")
 
 ATTACK_NAMES = (
     "equivocation",
@@ -515,8 +510,8 @@ def run_byzantine_scenario(
         "scale": scale,
         "adversary_index": h.adv_index,
         "attacks": results,
-        # same vacuous-truth guard as run_byzantine_bench: a deadline-
-        # truncated run must not claim the whole requested catalog passed
+        # a deadline-truncated run must not claim the whole requested
+        # catalog passed
         "all_detected": (
             len(results) == len(attacks)
             and all(r["detected"] for r in results)
@@ -531,138 +526,3 @@ def run_byzantine_scenario(
         "audit": audit,
     }
     return doc
-
-
-def _flood_leg(
-    h: ByzantineHarness,
-    n_blocks: int,
-    txs_per_block: int,
-    deadline: float | None = None,
-) -> float:
-    """Commit up to `n_blocks` honest blocks (stopping at `deadline`, a
-    perf_counter stamp); returns committed tx/s (measured as the honest
-    ledger's total-tx delta — blocks committed while rotating leadership
-    inside an attack count too)."""
-    ledger = h.honest[0].ledger
-    t0 = time.perf_counter()
-    before = ledger.total_transaction_count()
-    for _ in range(n_blocks):
-        h.commit_block(txs_per_block)
-        if deadline is not None and time.perf_counter() > deadline:
-            break
-    dt = time.perf_counter() - t0
-    committed = ledger.total_transaction_count() - before
-    return committed / dt if dt > 0 else 0.0
-
-
-def run_byzantine_bench(
-    seed: int = 0,
-    scale: float = 1.0,
-    deadline_s: float | None = None,
-    hosts: int = 4,
-) -> dict:
-    """The acceptance bench: a clean flood leg, then the same flood with
-    the whole attack catalog interleaved — the honest commit rate under
-    attack must hold ≥0.5x clean, every attack must be detected, the
-    adversary demoted, and the safety auditor green on both legs.
-
-    Never raises: like the sibling scenario benches, a harness failure
-    comes back as ``doc["error"]`` (full metric shape, failing values) so
-    the bench round still emits error-annotated metric lines instead of
-    silently dropping the scenario."""
-    try:
-        return _run_byzantine_bench(seed, scale, deadline_s, hosts)
-    except Exception as e:  # noqa: BLE001 — reported through the artifact
-        _log.exception("byzantine bench failed")
-        bad_audit = {"ok": False, "violations": [f"bench error: {e}"]}
-        return {
-            "scenario": "byzantine-bench",
-            "seed": seed,
-            "scale": scale,
-            "error": str(e),
-            "clean_tps": 0.0,
-            "byzantine_tps": 0.0,
-            "liveness_ratio": 0.0,
-            "attacks": [],
-            "all_detected": False,
-            "adversary_demoted": False,
-            "evidence_counts": EVIDENCE.counts(),
-            "audit_clean": bad_audit,
-            "audit_byzantine": bad_audit,
-        }
-
-
-def _run_byzantine_bench(
-    seed: int, scale: float, deadline_s: float | None, hosts: int
-) -> dict:
-    from ..resilience import HEALTH
-    from ..txpool.quota import get_quotas
-
-    n_blocks = max(2, int(6 * scale))
-    txs = max(2, int(16 * scale))
-    # both legs' budgets anchored at ENTRY: the clean leg gets half the
-    # child budget and the attacked leg the rest — a slow host truncates
-    # block counts rather than eating the bench round's emit reserve
-    t_entry = time.perf_counter()
-    clean_deadline = (
-        t_entry + deadline_s / 2 if deadline_s is not None else None
-    )
-
-    get_quotas().reset()
-    HEALTH.reset()
-    EVIDENCE.reset()
-    clean = ByzantineHarness(seed=seed, hosts=hosts)
-    clean_tps = _flood_leg(clean, n_blocks, txs, deadline=clean_deadline)
-    clean_audit = clean.audit()
-    assert EVIDENCE.count() == 0, "clean flood raised evidence"
-
-    get_quotas().reset()
-    HEALTH.reset()
-    byz = ByzantineHarness(seed=seed, hosts=hosts)
-    deadline = (
-        t_entry + deadline_s if deadline_s is not None else None
-    )
-    attack_results = []
-    ledger = byz.honest[0].ledger
-    t0 = time.perf_counter()
-    before = ledger.total_transaction_count()
-    blocks_done = 0
-    for i in range(n_blocks):
-        if i < len(ATTACK_NAMES):
-            attack_results.append(byz.run_attack(ATTACK_NAMES[i]))
-        byz.commit_block(txs)
-        blocks_done += 1
-        if deadline is not None and time.perf_counter() > deadline:
-            break
-    # any cataloged attack the block budget didn't reach yet runs now —
-    # still under the deadline: the child must not eat the bench round's
-    # emit reserve (partial catalogs report honestly as fewer attacks)
-    for name in ATTACK_NAMES[blocks_done:]:
-        if deadline is not None and time.perf_counter() > deadline:
-            break
-        attack_results.append(byz.run_attack(name))
-        byz.commit_block(txs)
-    dt = time.perf_counter() - t0
-    byz_tps = (ledger.total_transaction_count() - before) / dt if dt > 0 else 0.0
-    byz.catch_up()
-    byz_audit = byz.audit()
-    ratio = byz_tps / clean_tps if clean_tps > 0 else 0.0
-    return {
-        "scenario": "byzantine-bench",
-        "seed": seed,
-        "scale": scale,
-        "clean_tps": round(clean_tps, 2),
-        "byzantine_tps": round(byz_tps, 2),
-        "liveness_ratio": round(ratio, 3),
-        "attacks": attack_results,
-        # a deadline-truncated catalog must NOT pass vacuously: all means
-        # every cataloged attack ran AND was detected
-        "all_detected": (
-            len(attack_results) == len(ATTACK_NAMES)
-            and all(r["detected"] for r in attack_results)
-        ),
-        "adversary_demoted": byz.adversary_demoted(),
-        "evidence_counts": EVIDENCE.counts(),
-        "audit_clean": clean_audit,
-        "audit_byzantine": byz_audit,
-    }

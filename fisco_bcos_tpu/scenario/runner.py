@@ -282,71 +282,3 @@ class ScenarioRunner:
                 st.rejected[reason] = st.rejected.get(reason, 0) + 1
         # gossip payloads so replicas can execute whatever gets sealed
         node.tx_sync.maintain()
-
-
-def run_isolation_bench(
-    seed: int = 0,
-    hosts: int = 4,
-    scale: float = 1.0,
-    deadline_s: float | None = None,
-) -> dict:
-    """The ISSUE 6 acceptance bench: victim group B solo, then B again
-    while group A floods invalid-signature spam on the same node. Emits
-    both artifacts plus the ratio the criterion pins (combined/solo
-    committed TPS must stay >= 0.7) and the admission-shed counters that
-    prove the abuse died at the door, not in the pipeline.
-    """
-    from ..utils.metrics import REGISTRY
-    from .base import Scenario
-    from . import workloads
-
-    iso = get_scenario("isolation")
-    victim, abuser = "groupB", "groupA"
-    solo = Scenario(
-        name="isolation-solo",
-        description="the isolation victim's workload with no abuser present",
-        groups=(victim,),
-        quota_rate=iso.quota_rate,  # same knobs, only the abuser is absent
-        build=lambda ctx, rng, s: [
-            workloads.valid_flood(
-                ctx, workloads._sub_rng(rng, 1), victim,
-                int(workloads._N * s) or 1,
-            ),
-        ],
-    )
-    split = (0.45, 0.55)  # solo is smaller: no spam to shed
-    solo_deadline = deadline_s * split[0] if deadline_s is not None else None
-    comb_deadline = deadline_s * split[1] if deadline_s is not None else None
-
-    ScenarioRunner._reset_shared_state()
-    solo_doc = ScenarioRunner(
-        solo, seed=seed, hosts=hosts, scale=scale, deadline_s=solo_deadline
-    ).run()
-    ScenarioRunner._reset_shared_state()
-    comb_doc = ScenarioRunner(
-        iso, seed=seed, hosts=hosts, scale=scale, deadline_s=comb_deadline
-    ).run()
-
-    solo_tps = solo_doc["groups"][victim]["tps"]
-    comb_tps = comb_doc["groups"][victim]["tps"]
-    ratio = comb_tps / solo_tps if solo_tps > 0 else 0.0
-    shed = REGISTRY.counters_matching("fisco_ratelimit_dropped_total")
-    doc = {
-        "scenario": "isolation-bench",
-        "seed": seed,
-        "victim_group": victim,
-        "abuser_group": abuser,
-        "victim_tps_solo": solo_tps,
-        "victim_tps_combined": comb_tps,
-        "victim_ratio": round(ratio, 3),
-        "abuse_shed_counters": shed,
-        "solo": solo_doc,
-        "combined": comb_doc,
-    }
-    abuser_doc = comb_doc["groups"][abuser]
-    shed_total = sum(
-        v for k, v in shed.items() if f'group="{abuser}"' in k
-    )
-    if shed_total <= 0 and abuser_doc["rejected"].get("sig", 0) == 0:
-        doc["error"] = "no abuse was shed or rejected — isolation unproven"
-    return doc

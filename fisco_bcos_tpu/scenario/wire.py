@@ -47,11 +47,8 @@ from ..resilience.faults import (
     install_fault_plan,
 )
 from ..txpool.quota import get_quotas
-from ..utils.log import get_logger
 from .base import WorkloadContext
 from .byzantine import ATTACK_NAMES, ByzantineHarness, ByzantineReplica
-
-_log = get_logger("wire")
 
 # attacks whose evidence family gossips (stale_view_replay is
 # indistinguishable from lag and never gossips; forged_qc_vote's FORGED
@@ -760,121 +757,3 @@ def run_wire_colluders(seed: int = 0, hosts: int = 7) -> dict:
     finally:
         h.stop()
         clear_fault_plan()
-
-
-def run_wire_bench(
-    seed: int = 0,
-    scale: float = 1.0,
-    deadline_s: float | None = None,
-    hosts: int = 4,
-) -> dict:
-    """``bench.py --scenario byzantine-wire``: a clean TCP flood leg,
-    then the catalog-under-attack leg on a fresh mesh — emits the
-    liveness ratio and the measured evidence-convergence rounds. Never
-    raises; failures come back as ``doc['error']`` with zeroed metrics."""
-    try:
-        return _run_wire_bench(seed, scale, deadline_s, hosts)
-    except Exception as e:  # noqa: BLE001 — reported through the artifact
-        _log.exception("byzantine-wire bench failed")
-        return {
-            "scenario": "byzantine-wire",
-            "seed": seed,
-            "scale": scale,
-            "error": str(e),
-            "clean_tps": 0.0,
-            "byzantine_tps": 0.0,
-            "liveness_ratio": 0.0,
-            "all_detected": False,
-            "gossip_converged": False,
-            "convergence_rounds_max": -1,
-            "adversary_demoted": False,
-            "audit": {"ok": False, "violations": [f"bench error: {e}"]},
-        }
-
-
-def _run_wire_bench(
-    seed: int, scale: float, deadline_s: float | None, hosts: int
-) -> dict:
-    n_blocks = max(2, int(4 * scale))
-    txs = max(2, int(8 * scale))
-    t_entry = time.perf_counter()
-
-    _reset_boards()
-    clean = WireHarness(seed=seed, hosts=hosts)
-    try:
-        ledger = clean.honest[0].ledger
-        t0 = time.perf_counter()
-        before = ledger.total_transaction_count()
-        clean_deadline = (
-            t_entry + deadline_s / 3 if deadline_s is not None else None
-        )
-        for _ in range(n_blocks):
-            clean.commit_block(txs)
-            if (
-                clean_deadline is not None
-                and time.perf_counter() > clean_deadline
-            ):
-                break
-        dt = time.perf_counter() - t0
-        clean_tps = (
-            (ledger.total_transaction_count() - before) / dt if dt > 0 else 0.0
-        )
-        clean_audit = clean.audit()
-    finally:
-        clean.stop()
-    assert EVIDENCE.count() == 0, "clean wire flood raised evidence"
-
-    catalog_deadline = (
-        deadline_s - (time.perf_counter() - t_entry)
-        if deadline_s is not None
-        else None
-    )
-    _reset_boards()
-    byz = WireHarness(seed=seed, hosts=hosts)
-    try:
-        ledger = byz.honest[0].ledger
-        offender = byz.adversary.node.node_id
-        t0 = time.perf_counter()
-        before = ledger.total_transaction_count()
-        results, rounds = [], []
-        for name in ATTACK_NAMES:
-            results.append(byz.run_attack(name))
-            if name in GOSSIPED_ATTACKS:
-                rounds.append(byz.await_convergence(offender))
-            byz.commit_block(txs)
-            if (
-                catalog_deadline is not None
-                and time.perf_counter() - t0 > catalog_deadline
-            ):
-                break
-        dt = time.perf_counter() - t0
-        byz_tps = (
-            (ledger.total_transaction_count() - before) / dt if dt > 0 else 0.0
-        )
-        byz.catch_up()
-        byz_audit = byz.audit()
-        demoted = byz.adversary_demoted()
-    finally:
-        byz.stop()
-        clear_fault_plan()
-    ratio = byz_tps / clean_tps if clean_tps > 0 else 0.0
-    return {
-        "scenario": "byzantine-wire",
-        "seed": seed,
-        "scale": scale,
-        "hosts": hosts,
-        "clean_tps": round(clean_tps, 2),
-        "byzantine_tps": round(byz_tps, 2),
-        "liveness_ratio": round(ratio, 3),
-        "attacks": results,
-        "all_detected": (
-            len(results) == len(ATTACK_NAMES)
-            and all(r["detected"] for r in results)
-        ),
-        "gossip_converged": bool(rounds) and all(r >= 0 for r in rounds),
-        "convergence_rounds_max": max(rounds, default=-1),
-        "adversary_demoted": demoted,
-        "evidence_counts": EVIDENCE.counts(),
-        "audit_clean": clean_audit,
-        "audit_byzantine": byz_audit,
-    }

@@ -26,7 +26,6 @@ from ..ledger import Ledger
 from ..observability import TRACER
 from ..observability.flight import FLIGHT
 from ..observability.pipeline import PIPELINE
-from ..observability.storagelog import CTX_COMMIT, STORAGE, codec_ctx
 from ..protocol.block import Block
 from ..protocol.block_header import BlockHeader
 from ..protocol.transaction import TransactionAttribute
@@ -577,55 +576,46 @@ class Scheduler:
             # IO would serialize execute_block callers behind remote
             # latency (the runtime lock-order recorder flags it). The
             # in-flight marker keeps commits strictly serialized anyway.
-            # storage observatory: the per-block commit ledger window —
-            # every codec/copy seam touched until finish_commit folds into
-            # block `number`'s record, and encodes on this thread carry
-            # the `commit` context tag (the 2PC re-encode attribution)
-            STORAGE.begin_commit(number)
             try:
-                with codec_ctx(CTX_COMMIT):
-                    ledger_writes = StateStorage()
-                    self.ledger.prewrite_block(cached.block, ledger_writes)
-                    sp.stage("prewrite")
-                    params = TwoPCParams(number=number)
-                    # the 2PC legs as spans: on a remote executor/storage
-                    # split these parent the service-side svc.*.prepare/
-                    # commit spans
-                    FLIGHT.record(
-                        "2pc", "prepare", scope=self.crash_scope,
-                        height=number,
+                ledger_writes = StateStorage()
+                self.ledger.prewrite_block(cached.block, ledger_writes)
+                sp.stage("prewrite")
+                params = TwoPCParams(number=number)
+                # the 2PC legs as spans: on a remote executor/storage
+                # split these parent the service-side svc.*.prepare/
+                # commit spans
+                FLIGHT.record(
+                    "2pc", "prepare", scope=self.crash_scope,
+                    height=number,
+                )
+                with TRACER.span(
+                    "scheduler.2pc_prepare", block=number
+                ), PIPELINE.blocked("2pc_prepare"):
+                    staged = self.executor.prepare(
+                        params, extra_writes=ledger_writes
                     )
-                    with TRACER.span(
-                        "scheduler.2pc_prepare", block=number
-                    ), PIPELINE.blocked("2pc_prepare"):
-                        staged = self.executor.prepare(
-                            params, extra_writes=ledger_writes
-                        )
-                    # rows the backend kept as they were / copied on the way
-                    # in (count_prepared; nothing from a remote executor)
-                    sp.stage("prepare", **(staged or {}))
-                    STORAGE.end_prepare(number)
-                    # crash window: the 2PC slot is durably staged, the
-                    # commit has not run — a reboot finds the prepared-but-
-                    # unresolved slot and must re-drive or roll it back
-                    # (Node's boot scan)
-                    crashpoint("scheduler.mid_2pc", self.crash_scope)
-                    FLIGHT.record(
-                        "2pc", "commit", scope=self.crash_scope,
-                        height=number,
-                    )
-                    with TRACER.span(
-                        "scheduler.2pc_commit", block=number
-                    ), PIPELINE.blocked("2pc_commit"):
-                        self.executor.commit(params)
-                    sp.stage("commit")
+                # rows the backend kept as they were / copied on the way
+                # in (count_prepared; nothing from a remote executor)
+                sp.stage("prepare", **(staged or {}))
+                # crash window: the 2PC slot is durably staged, the
+                # commit has not run — a reboot finds the prepared-but-
+                # unresolved slot and must re-drive or roll it back
+                # (Node's boot scan)
+                crashpoint("scheduler.mid_2pc", self.crash_scope)
+                FLIGHT.record(
+                    "2pc", "commit", scope=self.crash_scope,
+                    height=number,
+                )
+                with TRACER.span(
+                    "scheduler.2pc_commit", block=number
+                ), PIPELINE.blocked("2pc_commit"):
+                    self.executor.commit(params)
+                sp.stage("commit")
                 FLIGHT.record(
                     "2pc", "booked", scope=self.crash_scope, height=number
                 )
-                STORAGE.finish_commit(number)
             except BaseException:
                 # failed commit: clear the marker so recovery can re-drive
-                STORAGE.abort_commit(number)
                 with self._lock:
                     self._committing.discard(number)
                     self._committing_thread = None

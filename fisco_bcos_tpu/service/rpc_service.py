@@ -59,10 +59,6 @@ class RpcFacade:
         self.server.register("fleet", self._fleet, concurrent=True)
         self.server.register("round", self._round, concurrent=True)
         self.server.register("rounds", self._rounds, concurrent=True)
-        # concurrent, the /profile lesson: a slow observability GET must
-        # not stall JSON-RPC dispatch (the snapshot walks the whole block
-        # ring + shard samples under the recorder lock)
-        self.server.register("storage", self._storage, concurrent=True)
         self.host, self.port = self.server.host, self.server.port
 
     def start(self) -> None:
@@ -123,15 +119,6 @@ class RpcFacade:
         from ..observability.device import device_doc
 
         return json.dumps(device_doc(), default=str).encode()
-
-    def _storage(self, _payload: bytes) -> bytes:
-        """The node core's storage-observatory document (codec ledger,
-        per-block copy amplification, shard 2PC attribution) — the split
-        deployment's GET /storage source: the commit path runs where the
-        scheduler lives."""
-        from ..observability.storagelog import storage_doc
-
-        return json.dumps(storage_doc(), default=str).encode()
 
     def _profile(self, payload: bytes) -> bytes:
         """Sample THIS process (the node core — where the pipeline burns
@@ -292,20 +279,6 @@ class RemoteTelemetry:
                 "error": f"facade unreachable: {e}",
                 "ledger": [],
                 "phase_ms": {},
-            }
-
-    def storage(self) -> dict:
-        """GET /storage over the split: the node core owns the commit-path
-        ledger; an unreachable core degrades to an explicit error doc."""
-        try:
-            return json.loads(self.client.call("storage", b""))
-        except Exception as e:
-            return {
-                "enabled": False,
-                "error": f"facade unreachable: {e}",
-                "codec": {},
-                "blocks": [],
-                "shards": {},
             }
 
     def profile(self, seconds=2.0) -> dict:

@@ -13,7 +13,6 @@ import threading
 from collections import OrderedDict
 from typing import Iterator
 
-from ..observability.storagelog import STORAGE as _OBS
 from .entry import Entry
 from .interfaces import (
     TransactionalStorage,
@@ -46,8 +45,6 @@ class CacheStorage(TransactionalStorage):
                 e = self._cache[k]
                 if e is None:
                     return None
-                if _OBS.enabled:
-                    _OBS.note_copy("cache.get_row", table)
                 return e.copy()
             self.misses += 1
             gen = self._gen
@@ -58,8 +55,6 @@ class CacheStorage(TransactionalStorage):
             # would serve stale state indefinitely. The generation counter
             # bumps on every commit; only same-generation reads may fill.
             if gen == self._gen:
-                if e is not None and _OBS.enabled:
-                    _OBS.note_copy("cache.fill", table)
                 self._cache[k] = None if e is None else e.copy()
                 while len(self._cache) > self.capacity:
                     self._cache.popitem(last=False)
@@ -87,8 +82,6 @@ class CacheStorage(TransactionalStorage):
 
     def _fill(self, table: str, key: bytes, entry: Entry) -> None:
         k = (table, bytes(key))
-        if not entry.deleted and _OBS.enabled:
-            _OBS.note_copy("cache.fill", table)
         with self._lock:
             self._cache[k] = None if entry.deleted else entry.copy()
             self._cache.move_to_end(k)

@@ -30,8 +30,8 @@ layer, so shard layout never leaks into them).
 from __future__ import annotations
 
 import hashlib
+import time
 
-from ..observability.storagelog import STORAGE as _OBS
 from ..resilience import HEALTH
 from ..service.rpc import ServiceConnectionError, ServiceRemoteError
 from ..service.storage_service import RemoteStorage
@@ -44,8 +44,26 @@ from ..storage.interfaces import (
     staged_rows,
 )
 from ..utils.log import get_logger
+from ..utils.metrics import REGISTRY
 
 _log = get_logger("dist-storage")
+
+# per-shard 2PC legs: sub-ms local sqlite staging up to multi-second
+# remote-shard round trips under faults
+SHARD_2PC_BUCKETS_MS = (
+    0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 100.0, 500.0, 2000.0,
+)
+
+
+def _observe_leg(op: str, shard: int, t0: float) -> None:
+    REGISTRY.observe(
+        "fisco_storage_shard_2pc_ms",
+        (time.perf_counter() - t0) * 1e3,
+        buckets=SHARD_2PC_BUCKETS_MS,
+        op=op,
+        shard=str(shard),
+        help="per-shard 2PC leg wall latency (shard attribution)",
+    )
 
 
 class DistributedStorage(TransactionalStorage):
@@ -165,23 +183,9 @@ class DistributedStorage(TransactionalStorage):
             )
         )
         for idx in range(len(self.shards)):
-            if not _OBS.enabled:
-                self.shards[idx].prepare(params, RowsView(parts[idx]))
-                continue
-            # staged-byte attribution by encode-delta across the leg: the
-            # RemoteStorage client encodes every row for the wire inside
-            # this call, so the codec counter's movement IS the shard's
-            # staged payload — no second encode pass
-            t0 = _OBS.clock()
-            b0 = _OBS.encode_bytes_now()
+            t0 = time.perf_counter()
             self.shards[idx].prepare(params, RowsView(parts[idx]))
-            _OBS.shard_note(
-                "prepare",
-                idx,
-                (_OBS.clock() - t0) * 1e3,
-                rows=len(parts[idx]),
-                n_bytes=_OBS.encode_bytes_now() - b0,
-            )
+            _observe_leg("prepare", idx, t0)
 
     def commit(self, params: TwoPCParams) -> None:
         # NEVER let recovery touch the number being committed: its slot is
@@ -190,12 +194,9 @@ class DistributedStorage(TransactionalStorage):
         # with empty slots, silently losing the block's writes
         self.recover_in_flight_if_needed(exclude=params.number)
         for idx in range(len(self.shards)):  # primary first
-            if not _OBS.enabled:
-                self.shards[idx].commit(params)
-                continue
-            t0 = _OBS.clock()
+            t0 = time.perf_counter()
             self.shards[idx].commit(params)
-            _OBS.shard_note("commit", idx, (_OBS.clock() - t0) * 1e3)
+            _observe_leg("commit", idx, t0)
         # retire the PREVIOUS block's witness: a commit of N proves N-1 is
         # fully resolved, so at most one live witness row remains instead
         # of one per block forever

@@ -11,7 +11,6 @@ from __future__ import annotations
 from enum import IntEnum
 
 from ..codec.flat import FlatReader, FlatWriter
-from ..observability.storagelog import STORAGE as _OBS
 
 
 class EntryStatus(IntEnum):
@@ -54,15 +53,10 @@ class Entry:
         w.u8(int(self.status))
         names = sorted(self.fields)
         w.seq(names, lambda w2, n: (w2.str_(n), w2.bytes_(self.fields[n])))
-        out = w.out()
-        if _OBS.enabled:
-            _OBS.note_encode(len(out))
-        return out
+        return w.out()
 
     @classmethod
     def decode(cls, buf: bytes) -> "Entry":
-        if _OBS.enabled:
-            _OBS.note_decode(len(buf))
         r = FlatReader(buf)
         status = EntryStatus(r.u8())
         fields: dict[str, bytes] = {}

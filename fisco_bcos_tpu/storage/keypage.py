@@ -23,12 +23,6 @@ import threading
 from typing import Iterator
 
 from ..codec.flat import FlatReader, FlatWriter
-from ..observability.storagelog import (
-    CTX_COMMIT,
-    CTX_INGRESS,
-    STORAGE as _OBS,
-    codec_ctx,
-)
 from .entry import Entry, EntryStatus
 from .interfaces import (
     RowsView,
@@ -110,11 +104,7 @@ class KeyPageStorage(TransactionalStorage):
         if cached is not None:
             return list(cached)  # shallow copy: callers mutate the list
         e = self.inner.get_row(PAGE_TABLE, self._page_key(table, start))
-        if e is not None and not e.deleted:
-            with codec_ctx(CTX_INGRESS, table):
-                items = _decode_page(e.get())
-        else:
-            items = []
+        items = _decode_page(e.get()) if e is not None and not e.deleted else []
         if len(self._page_cache) >= self._CACHE_MAX_PAGES:
             self._page_cache.clear()
         self._page_cache[pk] = list(items)
@@ -193,11 +183,7 @@ class KeyPageStorage(TransactionalStorage):
                 return None
             for k, e in self._load_page_locked(table, starts[idx]):
                 if k == key:
-                    if e.deleted:
-                        return None
-                    if _OBS.enabled:
-                        _OBS.note_copy("keypage.get_row", table)
-                    return e.copy()
+                    return None if e.deleted else e.copy()
         return None
 
     def set_row(self, table: str, key: bytes, entry: Entry) -> None:
@@ -220,10 +206,7 @@ class KeyPageStorage(TransactionalStorage):
                     starts.append(key)
                     meta_dirty = True
                 start = starts[self._page_for(starts, key)]
-                if _OBS.enabled:
-                    _OBS.note_copy("keypage.set_rows", table)
                 staged.setdefault(start, {})[key] = entry.copy()
-            pages_written = 0
             for start, pending in staged.items():
                 merged = {k: e for k, e in self._load_page_locked(table, start)}
                 merged.update(pending)
@@ -234,9 +217,6 @@ class KeyPageStorage(TransactionalStorage):
                         self._delete_page_row_locked(table, cstart)
                     else:
                         self._save_page_locked(table, cstart, chunk)
-                        pages_written += 1
-            if _OBS.enabled:
-                _OBS.note_pages(table, pages_written)
             if meta_dirty:
                 self._save_meta_locked(table, starts)
 
@@ -290,7 +270,6 @@ class KeyPageStorage(TransactionalStorage):
                 merged = {k: e for k, e in self._load_page_locked(table, start)}
                 merged.update(pending)
                 ops, _dirty = self._chunk_page(start, sorted(merged.items()), starts)
-                pages_written = 0
                 for cstart, chunk in ops:
                     if chunk is None:
                         rows.append(
@@ -301,18 +280,13 @@ class KeyPageStorage(TransactionalStorage):
                             )
                         )
                     else:
-                        with codec_ctx(CTX_COMMIT, table):
-                            page = _encode_page(chunk)
                         rows.append(
                             (
                                 PAGE_TABLE,
                                 self._page_key(table, cstart),
-                                Entry({"value": page}),
+                                Entry({"value": _encode_page(chunk)}),
                             )
                         )
-                        pages_written += 1
-                if _OBS.enabled:
-                    _OBS.note_pages(table, pages_written)
             for table, starts in metas.items():
                 rows.append(
                     (

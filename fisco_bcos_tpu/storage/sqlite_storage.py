@@ -14,7 +14,6 @@ import sqlite3
 import threading
 from typing import Iterator
 
-from ..observability.storagelog import CTX_INGRESS, codec_ctx
 from .entry import Entry
 from .interfaces import (
     TransactionalStorage,
@@ -29,11 +28,6 @@ class SQLiteStorage(TransactionalStorage):
     def __init__(self, path: str = ":memory:") -> None:
         self._conn = sqlite3.connect(path, check_same_thread=False)
         self._lock = threading.RLock()
-        # durable-write ground truth (tool/check_storage.py reconciles the
-        # storage observatory's codec ledger against these): value bytes
-        # staged by 2PC prepare, and value bytes applied to `kv` by commit
-        self.bytes_staged = 0
-        self.bytes_written = 0
         with self._lock:
             self._conn.execute("PRAGMA journal_mode=WAL")
             self._conn.execute(
@@ -59,8 +53,7 @@ class SQLiteStorage(TransactionalStorage):
             ).fetchone()
         if row is None:
             return None
-        with codec_ctx(CTX_INGRESS, table):
-            e = Entry.decode(row[0])
+        e = Entry.decode(row[0])
         return None if e.deleted else e
 
     def set_row(self, table: str, key: bytes, entry: Entry) -> None:
@@ -90,9 +83,7 @@ class SQLiteStorage(TransactionalStorage):
         with self._lock:
             rows = self._conn.execute("SELECT tbl, k, v FROM kv").fetchall()
         for t, k, v in rows:
-            with codec_ctx(CTX_INGRESS, t):
-                e = Entry.decode(v)
-            yield t, bytes(k), e
+            yield t, bytes(k), Entry.decode(v)
 
     # -- 2PC ------------------------------------------------------------
 
@@ -104,7 +95,6 @@ class SQLiteStorage(TransactionalStorage):
         lent, mode = staged_rows(writes)
         with self._lock:
             rows = [(params.number, t, k, e.encode()) for (t, k), e in lent.items()]
-            self.bytes_staged += sum(len(r[3]) for r in rows)
             self._conn.executemany(
                 "INSERT OR REPLACE INTO pending_2pc (num, tbl, k, v)"
                 " VALUES (?, ?, ?, ?)",
@@ -118,12 +108,6 @@ class SQLiteStorage(TransactionalStorage):
             # apply + clear the slot in ONE sqlite transaction: a crash
             # mid-commit leaves either the staged slot (re-commit resolves)
             # or the applied state, never half of each
-            staged = self._conn.execute(
-                "SELECT COALESCE(SUM(LENGTH(v)), 0) FROM pending_2pc"
-                " WHERE num=?",
-                (params.number,),
-            ).fetchone()
-            self.bytes_written += int(staged[0])
             self._conn.execute(
                 "INSERT OR REPLACE INTO kv (tbl, k, v)"
                 " SELECT tbl, k, v FROM pending_2pc WHERE num=?",

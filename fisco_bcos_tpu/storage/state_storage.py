@@ -22,7 +22,6 @@ import numpy as np
 
 from ..codec.flat import FlatWriter
 from ..crypto.suite import CryptoSuite
-from ..observability.storagelog import STORAGE as _OBS, codec_ctx
 from .entry import Entry, EntryStatus
 from .interfaces import StorageInterface, TraversableStorage
 
@@ -48,8 +47,6 @@ class StateStorage(TraversableStorage):
         if e is not None:
             if e.deleted:
                 return None
-            if _OBS.enabled:
-                _OBS.note_copy("state.get_row", table)
             return e.copy()
         if self.read_track is not None:
             self.read_track.add((table, key))
@@ -72,8 +69,6 @@ class StateStorage(TraversableStorage):
     # -- writes -------------------------------------------------------------
 
     def set_row(self, table: str, key: bytes, entry: Entry) -> None:
-        if _OBS.enabled:
-            _OBS.note_copy("state.set_row", table)
         with self._lock:
             self._data[(table, bytes(key))] = entry.copy()
 
@@ -91,10 +86,7 @@ class StateStorage(TraversableStorage):
     def traverse(self) -> Iterator[tuple[str, bytes, Entry]]:
         with self._lock:
             items = list(self._data.items())
-        track = _OBS.enabled
         for (t, k), e in items:
-            if track:
-                _OBS.note_copy("state.traverse", t)
             yield t, k, e.copy()
 
     def borrow_rows(self) -> dict[tuple[str, bytes], Entry]:
@@ -144,12 +136,11 @@ class StateStorage(TraversableStorage):
         Order-independent XOR root over dirty entries, hashed as one device
         batch (vs the reference's tbb loop, StateStorage.h:457-486)."""
         preimages = []
-        with codec_ctx("hash"):
-            for t, k, e in self.traverse():
-                w = FlatWriter()
-                w.str_(t)
-                w.bytes_(k)
-                preimages.append(w.out() + e.encode())
+        for t, k, e in self.traverse():
+            w = FlatWriter()
+            w.str_(t)
+            w.bytes_(k)
+            preimages.append(w.out() + e.encode())
         if not preimages:
             return lambda: _ZERO32
         resolve = suite.hash_batch_async(preimages)

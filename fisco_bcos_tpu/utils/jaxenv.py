@@ -4,7 +4,7 @@ the test tier and the ``tool/check_*.py`` smokes run under.
 
 One process owns a chip: whoever initialises a JAX backend first holds the
 device until it exits, and a child that needs it then fails or hangs. So
-orchestrating parents (``bench.py``, ``chip_smoke.py``,
+orchestrating parents (``chip_smoke.py``,
 ``__graft_entry__.dryrun_multichip``) never call anything here that touches
 a backend — only :func:`configure_compile_cache`, which is config-only.
 """

@@ -1492,7 +1492,7 @@ int fisco_ed25519_sign(const uint8_t seed[32], const uint8_t* msg,
     return 1;
 }
 
-// batch verify loops — the honest native CPU baselines for bench.py
+// batch verify loops — the suites' native CPU legs
 // (one call, n items, out[i] = 1/0). OpenMP-parallel when built with
 // -fopenmp (every lane is independent and the curve contexts are immutable
 // magic statics); ctypes releases the GIL for the call's duration, so these

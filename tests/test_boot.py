@@ -13,6 +13,7 @@ import socket
 import subprocess
 import sys
 import time
+import urllib.error
 import urllib.request
 
 import pytest
@@ -335,3 +336,56 @@ def test_build_node_selects_sm_transport(tmp_path):
     os.remove(opts.sm_node_cert)
     with pytest.raises(FileNotFoundError, match="SM dual"):
         build_node(opts)
+
+
+# -- GET /storage is gone: a dashboard that still asks gets a plain 404 -------
+
+
+def _air_front_door(tmp_path):
+    """The Air node's RPC server as the entry point wires it."""
+    from fisco_bcos_tpu.__main__ import build_node
+
+    dirs = build_chain(out_dir=str(tmp_path), count=1, ports=[(0, 0, 0)])
+    opts = load_chain_options(
+        os.path.join(dirs[0], "config.ini"), os.path.join(dirs[0], "config.genesis")
+    )
+    opts.rpc_listen_port = 0
+    _node, gw, server, _ws, _runtime, _stop = build_node(opts)
+    server.start()
+    return server.port, [server.stop, gw.stop]
+
+
+def _pro_front_door(_tmp_path):
+    """The Pro split's RPC front door over the node core's facade."""
+    from fisco_bcos_tpu.service.rpc_service import RpcFacade, RpcService
+
+    facade = RpcFacade(impl=None)
+    facade.start()
+    rpc = RpcService(facade.host, facade.port)
+    rpc.start()
+    return rpc.port, [rpc.stop, facade.stop]
+
+
+def _get(port: int, path: str) -> tuple[int, bytes]:
+    try:
+        with urllib.request.urlopen(
+            f"http://127.0.0.1:{port}{path}", timeout=10
+        ) as resp:
+            return resp.status, resp.read()
+    except urllib.error.HTTPError as e:
+        return e.code, e.read()
+
+
+@pytest.mark.parametrize("front_door", [_air_front_door, _pro_front_door],
+                         ids=["air", "pro-split"])
+def test_get_storage_answers_as_any_unknown_path_does(front_door, tmp_path):
+    port, stops = front_door(tmp_path)
+    try:
+        assert _get(port, "/health")[0] in (200, 503), "the server answers"
+        unknown = _get(port, "/no-such-document")
+        assert unknown[0] == 404
+        assert _get(port, "/storage") == unknown
+        assert _get(port, "/storage?last=8") == unknown
+    finally:
+        for stop in stops:
+            stop()

@@ -1,8 +1,9 @@
 """chip_smoke.py (the on-chip bring-up proof) — what can be pinned on a CPU:
 
-- the orchestrating parent imports neither JAX nor the package, and the other
-  parents that spawn chip children (``bench.py``, its ``--telemetry`` gate)
-  initialise no backend — one process per chip;
+- the orchestrating parent imports neither JAX nor the package, and what any
+  other parent may do before spawning a chip child (the package import, the
+  static-analysis gate, the jaxpr audit) initialises no backend — one process
+  per chip;
 - without a TPU the script refuses: non-zero exit, a one-line reason, no
   result line; the same in a directory that holds nothing of the repo;
 - each phase's pass/fail predicate on canned ``/device`` ``/health``
@@ -59,12 +60,13 @@ def test_parent_imports_neither_jax_nor_the_package():
     assert res.returncode == 0, res.stderr
 
 
-def test_bench_parent_and_telemetry_gate_initialise_no_backend():
-    """What bench.py's parent does before it spawns children — import the
-    package, the static-analysis gate, the one-program jaxpr audit — must
-    leave JAX without a backend, or the parent would hold the chip."""
+def test_package_import_and_static_gates_initialise_no_backend():
+    """What a parent may do before it spawns a chip child: import the
+    package, run the static-analysis gate and the one-program jaxpr audit.
+    All of it must leave JAX without a backend, or the parent would hold
+    the chip."""
     code = (
-        "import bench\n"
+        "import fisco_bcos_tpu\n"
         "from fisco_bcos_tpu.analysis import check_repo, progaudit\n"
         "check_repo()\n"
         "progaudit.audit(programs=["

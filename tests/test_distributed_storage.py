@@ -227,3 +227,65 @@ def test_witness_rows_are_retired():
     finally:
         for s in svcs:
             s.stop()
+
+
+def _leg_ms(op: str, shard: int) -> tuple[float, int]:
+    """(sum of ms, count) of one shard's 2PC leg on the process registry."""
+    from fisco_bcos_tpu.utils.metrics import REGISTRY
+
+    hist = REGISTRY._histograms.get("fisco_storage_shard_2pc_ms")  # no create
+    if hist is None:
+        return 0.0, 0
+    key = (("op", op), ("shard", str(shard)))
+    _cum, total, count = hist.snapshot().get(key, ((), 0.0, 0))
+    return total, count
+
+
+def test_shard_attribution_pins_an_injected_slow_shard():
+    """A FaultPlan-delayed shard must show up as THAT shard's prepare leg
+    in ``fisco_storage_shard_2pc_ms{op,shard}`` — the attribution the flat
+    2PC stage time can't provide."""
+    from fisco_bcos_tpu.resilience import (
+        FaultPlan,
+        clear_fault_plan,
+        install_fault_plan,
+    )
+    from fisco_bcos_tpu.storage.distributed import SHARD_2PC_BUCKETS_MS
+    from fisco_bcos_tpu.utils.metrics import REGISTRY
+
+    backings, svcs, dist = _cluster(3)
+    try:
+        rows = [
+            ("t", b"sh%02d" % i, Entry().set(b"v%d" % i)) for i in range(24)
+        ]
+        before = {
+            (op, i): _leg_ms(op, i)
+            for op in ("prepare", "commit") for i in range(3)
+        }
+        install_fault_plan(
+            FaultPlan(seed=19).rule(
+                "delay", "send", f"{svcs[1].port}/prepare", delay_ms=80
+            )
+        )
+        try:
+            dist.prepare(TwoPCParams(number=4), _Writes(rows))
+            dist.commit(TwoPCParams(number=4))
+        finally:
+            clear_fault_plan()
+        took = {}
+        for k, (ms0, n0) in before.items():
+            ms1, n1 = _leg_ms(*k)
+            assert n1 - n0 == 1, f"one observation a shard leg: {k} {n0}->{n1}"
+            took[k] = ms1 - ms0
+        delayed = took[("prepare", 1)]
+        others = max(took[("prepare", 0)], took[("prepare", 2)])
+        assert delayed >= 60.0, f"delayed shard not attributed: {took}"
+        assert delayed > others + 40.0, (delayed, others)
+        hist = REGISTRY.histogram("fisco_storage_shard_2pc_ms")
+        assert hist.buckets == tuple(float(b) for b in SHARD_2PC_BUCKETS_MS)
+        # the 2PC itself landed: every row reads back through routing
+        for t, k, e in rows:
+            assert dist.get_row(t, k).get() == e.get()
+    finally:
+        for s in svcs:
+            s.stop()

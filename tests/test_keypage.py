@@ -148,3 +148,32 @@ def test_head_page_rekey_on_split_keeps_rows_readable():
     # traverse must not resurrect tombstoned page rows
     seen = {k for _t, k, _e in kp3.traverse()}
     assert b"q5" in seen and len(seen) == 13
+
+
+def test_keypage_copy_in_copy_out_discipline_holds():
+    """KeyPage pages never alias caller-held entries: mutating the entry
+    handed to set_rows, or the entry returned by get_row, must never reach
+    the stored page. If this fails, keypage grew an aliasing leak and needs
+    a copy at the failing surface."""
+    kp = KeyPageStorage(MemoryStorage())
+    mine = Entry().set(b"original")
+    kp.set_rows("t_pin", [(b"k1", mine)])
+    # copy-in: the page holds another object than the caller's
+    got = kp.get_row("t_pin", b"k1")
+    assert got is not mine
+    mine.set(b"mutated-after-set")
+    assert kp.get_row("t_pin", b"k1").get() == b"original"
+    # copy-out: two reads hand out two objects, neither the page's own
+    again = kp.get_row("t_pin", b"k1")
+    assert again is not got
+    got.set(b"mutated-read")
+    assert kp.get_row("t_pin", b"k1").get() == b"original"
+    # the 2PC path stages the page's bytes, not the caller's object
+    writes = MemoryStorage()
+    staged = Entry().set(b"staged")
+    writes.set_row("t_pin", b"k2", staged)
+    kp.prepare(TwoPCParams(number=1), writes)
+    kp.commit(TwoPCParams(number=1))
+    staged.set(b"mutated-after-prepare")
+    assert kp.get_row("t_pin", b"k2").get() == b"staged"
+    assert kp.get_row("t_pin", b"k1").get() == b"original"

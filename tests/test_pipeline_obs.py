@@ -2,14 +2,11 @@
 injected clock, blocked-on attribution, watermark ring bounds, profiler
 determinism via injected frame snapshots, the /pipeline + /profile
 endpoints on both deployment splits, near-zero overhead when disabled,
-the /trace/tx miss-reason contract, the flood-window stage aggregation,
-and the check_perf artifact gate."""
+the /trace/tx miss-reason contract and the flood-window stage aggregation."""
 
 from __future__ import annotations
 
-import importlib.util
 import json
-import os
 import threading
 import urllib.request
 
@@ -22,8 +19,6 @@ from fisco_bcos_tpu.observability.pipeline import (
     PipelineRecorder,
     pipeline_doc,
 )
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def make_clock(step: float = 1.0):
@@ -483,51 +478,6 @@ def test_aggregate_stage_self_ms_dedups_shared_block_spans():
     finally:
         critical_path.reset()
         TRACER.clear()
-
-
-# -- check_perf gate ----------------------------------------------------------
-
-
-def _load_check_perf():
-    spec = importlib.util.spec_from_file_location(
-        "check_perf", os.path.join(_REPO, "tool", "check_perf.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_check_perf_flags_regression_and_passes_identity(tmp_path):
-    cp = _load_check_perf()
-    old = {"flood_tps": 100.0, "stage_self_ms": {"execute": 100.0, "seal": 40.0}}
-    bad = {"flood_tps": 100.0, "stage_self_ms": {"execute": 125.0, "seal": 40.0}}
-    regs, _ = cp.diff(old, bad, threshold=0.2, min_ms=5.0)
-    assert len(regs) == 1 and "execute" in regs[0]
-    regs, _ = cp.diff(old, old)
-    assert regs == []
-    # absolute floor: a tiny stage doubling is noise, not a regression
-    small_old = {"stage_self_ms": {"tiny": 0.5}}
-    small_new = {"stage_self_ms": {"tiny": 1.5}}
-    regs, _ = cp.diff(small_old, small_new, min_ms=5.0)
-    assert regs == []
-    # flood TPS drop trips the gate on its own
-    regs, _ = cp.diff({"flood_tps": 100.0}, {"flood_tps": 70.0})
-    assert len(regs) == 1 and "TPS" in regs[0]
-    # a stage idle last round (0 ms) must not regress for free
-    regs, _ = cp.diff(
-        {"stage_self_ms": {"notify": 0.0}},
-        {"stage_self_ms": {"notify": 500.0}},
-    )
-    assert len(regs) == 1 and "from zero" in regs[0]
-    # CLI round trip: exit 1 on regression, 0 on pass, 2 on garbage
-    a, b = tmp_path / "a.json", tmp_path / "b.json"
-    a.write_text(json.dumps(old))
-    b.write_text(json.dumps(bad))
-    assert cp.main([str(a), str(b)]) == 1
-    assert cp.main([str(a), str(a)]) == 0
-    g = tmp_path / "g.json"
-    g.write_text("{}")
-    assert cp.main([str(a), str(g)]) == 2
 
 
 # -- the wired pipeline end to end (single-node chain) ------------------------

@@ -37,7 +37,6 @@ from fisco_bcos_tpu.analysis.harnesses import (
     QuorumCollectorHarness,
     RacyCounterHarness,
     SchedulerHarness,
-    StorageObsHarness,
 )
 from fisco_bcos_tpu.analysis.interleave import (
     Explorer,
@@ -191,7 +190,7 @@ def test_deadlock_schedule_is_reported_not_hung():
     "cls",
     [DevicePlaneHarness, ProofPlaneHarness, AdmissionQuotasHarness,
      SchedulerHarness, PipelinedCommitHarness, PipelineObsHarness,
-     QuorumCollectorHarness, StorageObsHarness],
+     QuorumCollectorHarness],
     ids=lambda c: c.name,
 )
 def test_real_harness_seeded_sweep(cls):
@@ -204,7 +203,7 @@ def test_real_harnesses_registry_complete():
     assert set(HARNESSES) == {
         "device-plane", "proof-singleflight", "admission-quotas",
         "scheduler-commit", "pipelined-commit", "pipeline-obs",
-        "qc-collector", "fleet-obs", "torn-quorum", "storage-obs",
+        "qc-collector", "fleet-obs", "torn-quorum",
     }
 
 

@@ -175,8 +175,8 @@ def test_proof_storm_flood_is_deterministic():
 
 
 def test_proof_storm_is_a_bench_entry_point():
-    # bench.py routes --scenario proof-storm to run_proof_storm_bench even
-    # though it is not a catalog Scenario (it needs the three-leg runner)
+    # proof-storm is reached through run_proof_storm_bench, not the
+    # catalog: it is no Scenario (it needs the three-leg runner)
     from fisco_bcos_tpu.scenario import run_proof_storm_bench
 
     assert callable(run_proof_storm_bench)

@@ -127,6 +127,40 @@ def test_fixture_layering_under_the_seams(fixture_findings):
     assert not DeviceDispatchChecker().run([_src(leaf, "fisco_bcos_tpu/ops/ec.py")])
 
 
+def _imported_modules(src: Source) -> set[str]:
+    """Every module a file imports, as absolute dotted names; a
+    ``from x import a`` counts ``x`` and ``x.a`` (``a`` may be a module)."""
+    package = src.relpath[: -len(".py")].split("/")[:-1]
+    out: set[str] = set()
+    for node in ast.walk(src.tree):
+        if isinstance(node, ast.Import):
+            out.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            base = package[: len(package) - (node.level - 1)] if node.level else []
+            mod = ".".join(base + ([node.module] if node.module else []))
+            out.add(mod)
+            out.update(f"{mod}.{a.name}" for a in node.names)
+    return out
+
+
+@pytest.mark.parametrize("layer", ["storage", "codec", "protocol", "ledger"])
+def test_the_lowest_host_layers_import_nothing_of_observability(layer):
+    """Rows, codecs, protocol objects and the ledger know no recorder above
+    them: what is measured of them is measured where they are called."""
+    sources = [
+        s for s in load_sources()
+        if s.relpath.startswith(f"fisco_bcos_tpu/{layer}/")
+    ]
+    assert sources, layer
+    up = {
+        f"{s.relpath}: {m}"
+        for s in sources
+        for m in _imported_modules(s)
+        if (m + ".").startswith("fisco_bcos_tpu.observability.")
+    }
+    assert not up, sorted(up)
+
+
 def test_fixture_shape_bucket(fixture_findings):
     assert (
         "shape-bucket:tests/fixtures/analysis/bad_shape.py:feed:unbucketed-kernel"

@@ -1,6 +1,9 @@
 """Storage layers (overlay, 2PC backends) + ledger schema."""
 
+import threading
+
 import numpy as np
+import pytest
 
 from fisco_bcos_tpu.crypto.suite import ecdsa_suite
 from fisco_bcos_tpu.ledger import ConsensusNode, GenesisConfig, Ledger
@@ -49,6 +52,41 @@ def test_state_storage_overlay_and_root():
     assert base.get_row("t", b"k1") is None
     assert base.get_row("t", b"k2").get() == b"local2"
     assert s1.dirty_count() == 0
+
+
+def test_a_blocks_rows_take_no_lock_but_the_overlays_own(monkeypatch):
+    """A 1,000-row block through the overlay (write, read back, traverse and
+    encode for the root's preimages) acquires no lock of the process but
+    the StateStorage's own: nothing process-wide sits under a row."""
+    from fisco_bcos_tpu.analysis import lockorder
+
+    if not lockorder._installed:
+        pytest.skip("lockorder factory not installed (FISCO_LOCKORDER=0)")
+    base = MemoryStorage()
+    state = StateStorage(base)
+    assert isinstance(state._lock, lockorder.InstrumentedRLock)
+    rows = [(b"k%04d" % i, Entry().set(b"v%04d" % i)) for i in range(1000)]
+    me = threading.get_ident()
+    taken: list[str] = []
+    real = lockorder.RECORDER.on_acquire
+
+    def on_acquire(site):
+        if threading.get_ident() == me:
+            taken.append(site)
+        real(site)
+
+    monkeypatch.setattr(lockorder.RECORDER, "on_acquire", on_acquire)
+    for k, e in rows:
+        state.set_row("t", k, e)
+    for k, e in rows:
+        assert state.get_row("t", k) == e
+    n = sum(len(e.encode()) for _t, _k, e in state.traverse())
+    monkeypatch.undo()
+    assert n > 0 and state.dirty_count() == len(rows)
+    own = state._lock._site
+    assert own.startswith("fisco_bcos_tpu/storage/state_storage.py:")
+    assert taken, "the recorder saw the overlay's own lock"
+    assert set(taken) == {own}, sorted(set(taken) - {own})
 
 
 def test_two_pc_backends(tmp_path):

@@ -8,9 +8,7 @@ Floods a 4-node in-process PBFT chain, then asserts:
   (``<stage> blocked_on=<what>``), plus non-empty backpressure watermark
   timelines;
 - the sampling profiler's top self-time frame lands inside the package
-  while package code is the only thing running;
-- ``tool/check_perf.py`` flags a synthetic 30% stage self-time regression
-  between two artifacts, and passes an unchanged pair.
+  while package code is the only thing running.
 
 Runnable locally and from CI::
 
@@ -298,40 +296,6 @@ def check_profiler() -> None:
     )
 
 
-def check_perf_gate(tmpdir: str) -> None:
-    """check_perf.py must flag a synthetic 30% regression and pass an
-    unchanged pair."""
-    import subprocess
-
-    old = {
-        "flood_tps": 100.0,
-        "stage_self_ms": {"scheduler.execute_block": 100.0, "seal": 40.0},
-    }
-    regressed = {
-        "flood_tps": 98.0,
-        "stage_self_ms": {"scheduler.execute_block": 130.0, "seal": 40.0},
-    }
-    paths = {}
-    for name, doc in (("old", old), ("new", regressed), ("same", old)):
-        paths[name] = os.path.join(tmpdir, f"art_{name}.json")
-        with open(paths[name], "w") as f:
-            json.dump(doc, f)
-    tool = os.path.join(_REPO, "tool", "check_perf.py")
-    rc_bad = subprocess.run(
-        [sys.executable, tool, paths["old"], paths["new"]],
-        capture_output=True,
-    ).returncode
-    if rc_bad == 0:
-        fail("check_perf.py passed a 30% stage self-time regression")
-    rc_ok = subprocess.run(
-        [sys.executable, tool, paths["old"], paths["same"]],
-        capture_output=True,
-    ).returncode
-    if rc_ok != 0:
-        fail(f"check_perf.py failed an identical artifact pair (rc={rc_ok})")
-    print("check_perf ok: 30% synthetic regression flagged, identity passes")
-
-
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--txs", type=int, default=96)
@@ -340,10 +304,6 @@ def main() -> int:
     run_chain(args.txs, args.block_cap)
     check_pipeline_endpoint()
     check_profiler()
-    import tempfile
-
-    with tempfile.TemporaryDirectory() as tmp:
-        check_perf_gate(tmp)
     run_pipelined_flood()
     print("PASS: pipeline observatory + overlapped pipeline live end to end")
     return 0
