@@ -76,7 +76,8 @@ class BlockValidator:
 
         # QC checks gate block sync/commit: consensus lane of the plane
         with TRACER.span(
-            "qc.verify", scheme="signature_list", n=len(rows), headers=len(headers)
+            "qc.verify", scheme="signature_list", n=len(rows), headers=len(headers),
+            suite=self.suite.signature_impl.name,
         ), device_lane("consensus"):
             ok = np.asarray(self.suite.signature_impl.batch_verify(hashes, pubs, sigs))
         for (k, _h, _pub, _sig), good in zip(rows, ok):

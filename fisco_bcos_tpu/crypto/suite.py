@@ -747,6 +747,7 @@ def _build_tree(hasher: str, leaves) -> "merkle_ops.MerkleTree":
         "merkle_tree",
         len(leaves),
         shape_key=(hasher, merkle_ops.bucket_leaves(max(len(leaves), 1))),
+        hasher=hasher,
     ):
         return merkle_ops.MerkleTree(leaves, hasher=hasher)
 

@@ -8,7 +8,10 @@ crypto/admission):
 
 - ``fisco_device_batch_size{op=...}``      power-of-two batch histogram
 - ``fisco_device_op_latency_ms{op=...}``   wall latency per host call
-- ``fisco_device_items_total{op=...}``     items processed (rate = items/sec)
+- ``fisco_device_items_total{op=...}``     items processed (rate = items/sec);
+  the merkle programs, which run under whichever hash the suite has, add
+  ``hasher`` (``{op="merkle_root",hasher="sm3"}``), as their ``device.<op>``
+  record does
 - ``fisco_device_op_seconds_total{op=...}`` wall seconds (rate vs items =
   effective verifies/sec without histogram math)
 - ``fisco_device_compile_total{op=...}`` / ``fisco_device_cached_call_total``
@@ -820,12 +823,16 @@ class device_span:
     """
 
     __slots__ = (
-        "op", "batch", "key", "_t0", "_span", "_phases", "_frame", "_obs_s", "_tiled",
+        "op", "batch", "key", "hasher", "_t0", "_span", "_phases", "_frame", "_obs_s",
+        "_tiled",
     )
 
-    def __init__(self, op: str, batch: int, shape_key=None):
+    def __init__(self, op: str, batch: int, shape_key=None, hasher: str | None = None):
         self.op = op
         self.batch = int(batch)
+        # an op that runs under more than one hash (the merkle programs) says
+        # which: on the trace record and on the items series
+        self.hasher = hasher
         self.key = (
             shape_key if shape_key is not None
             else bucket_batch(max(int(batch), 1))
@@ -873,7 +880,8 @@ class device_span:
             self._obs_s += time.perf_counter() - t_obs
         else:
             self._frame = None
-        self._span = TRACER.span(f"device.{self.op}", batch=self.batch)
+        attrs = {"hasher": self.hasher} if self.hasher else {}
+        self._span = TRACER.span(f"device.{self.op}", batch=self.batch, **attrs)
         self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
@@ -894,8 +902,9 @@ class device_span:
                 help="device-crypto host-call wall latency per op",
                 op=self.op,
             )
+            by_hasher = f',hasher="{self.hasher}"' if self.hasher else ""
             reg.counter_add(
-                f'fisco_device_items_total{{op="{self.op}"}}',
+                f'fisco_device_items_total{{op="{self.op}"{by_hasher}}}',
                 float(self.batch),
                 help="items processed by device-crypto ops",
             )

@@ -370,7 +370,7 @@ def merkle_root_async(
     # is dispatched on the caller's thread and not through the plane
     n = len(leaves)
     key = (hasher, width, bucket_leaves(max(n, 1)))
-    with device_span("merkle_root", n, shape_key=key) as sp:
+    with device_span("merkle_root", n, shape_key=key, hasher=hasher) as sp:
         if (
             hasher == "keccak256"
             and len(leaves) >= 256

@@ -19,7 +19,7 @@ from fisco_bcos_tpu.executor import executor as executor_module
 from fisco_bcos_tpu.executor.precompiled import DAG_TRANSFER_ADDRESS
 from fisco_bcos_tpu.observability import TRACER
 from fisco_bcos_tpu.protocol.transaction import TransactionAttribute
-from fisco_bcos_tpu.utils.metrics import REGISTRY
+from fisco_bcos_tpu.utils.metrics import MetricsRegistry
 
 DAG = TransactionAttribute.DAG
 TRANSFER = "userTransfer(string,string,uint256)"
@@ -164,13 +164,20 @@ def test_a_contract_block_beside_a_thread_that_takes_the_interpreter_ends_on_the
 # -- (d) the record and the counters of a contract block --------------------------
 
 
-def pool_counters():
-    return {name: REGISTRY.counters_matching(name)
-            for name in ("fisco_executor_dag_pooled_txs_total",
-                         "fisco_executor_dag_pool_wait_seconds_total")}
+def test_a_contract_blocks_record_has_no_pool_in_it_and_the_pool_counters_never_move(
+    monkeypatch,
+):
+    # a registry of the test's own: what another test of this worker process wrote
+    # into the global one (test_contract_cell.py's snapshot test sets a pool counter
+    # by hand) is not the runner's doing, and which files share a worker changes
+    registry = MetricsRegistry()
+    monkeypatch.setattr(executor_module, "REGISTRY", registry)
 
+    def pool_counters():
+        return {name: registry.counters_matching(name)
+                for name in ("fisco_executor_dag_pooled_txs_total",
+                             "fisco_executor_dag_pool_wait_seconds_total")}
 
-def test_a_contract_blocks_record_has_no_pool_in_it_and_the_pool_counters_never_move():
     ex, txs = contract_block()
     ex.dag_execute_transactions(txs)  # registers what a first call registers
     before = pool_counters()
@@ -189,7 +196,7 @@ def test_a_contract_blocks_record_has_no_pool_in_it_and_the_pool_counters_never_
     # (tests/benchmark_checks) read these two: present, one series each, at 0
     assert pool_counters() == before
     assert all(list(series.values()) == [0.0] for series in before.values())
-    help_text = REGISTRY._help["fisco_executor_dag_pooled_txs_total"]
+    help_text = registry._help["fisco_executor_dag_pooled_txs_total"]
     assert "no thread pool since PR 41" in help_text
 
 
