@@ -52,6 +52,7 @@ OP_PROGRAMS = {
     "merkle_root": "fisco_bcos_tpu/ops/merkle.py:_device_root_fn.run",
     "merkle_tree": "fisco_bcos_tpu/ops/merkle.py:_device_root_fn.run",
     "merkle_tree.keccak256": "fisco_bcos_tpu/ops/merkle.py:_device_root_fn.run",
+    "merkle_tree.sm3": "fisco_bcos_tpu/ops/merkle.py:_device_tree_fn.tree",
     "admission": _ADMISSION,
     "admission_native": _ADMISSION,
     "admission_sharded": _ADMISSION,

@@ -748,8 +748,10 @@ def _build_tree(hasher: str, leaves) -> "merkle_ops.MerkleTree":
         len(leaves),
         shape_key=(hasher, merkle_ops.bucket_leaves(max(len(leaves), 1))),
         hasher=hasher,
-    ):
-        return merkle_ops.MerkleTree(leaves, hasher=hasher)
+    ) as sp:
+        tree = merkle_ops.MerkleTree(leaves, hasher=hasher)
+        sp.path("fused" if tree.fused else "levels")
+        return tree
 
 
 def ecdsa_suite() -> CryptoSuite:

@@ -34,6 +34,7 @@ from itertools import chain
 
 import numpy as np
 
+from ..observability.device import note_dispatch_path as _note_dispatch_path
 from ..utils.jaxenv import device_backend_is_cpu
 from .plane import get_plane, in_plane_executor, plane_wait
 
@@ -96,20 +97,6 @@ def use_native_batch(n: int, label: str = "") -> bool:
     if label == "admission" and os.environ.get("FISCO_FORCE_DEVICE_ADMISSION"):
         return False
     return 0 < n and (n < device_min_batch() or device_backend_is_cpu())
-
-
-def _note_dispatch_path(op: str, path: str) -> None:
-    """Labeled counter of which leg a batch actually took (native host loop
-    vs device program) — the observable form of the `use_native_batch`
-    policy, so a mistuned FISCO_DEVICE_MIN_BATCH shows up in /metrics
-    instead of as a silent latency cliff."""
-    from ..utils.metrics import REGISTRY
-
-    REGISTRY.counter_add(
-        f'fisco_device_dispatch_path_total{{op="{op}",path="{path}"}}',
-        1.0,
-        help="batch dispatches split by chosen leg (native host vs device)",
-    )
 
 
 # -- device-path circuit breaker (resilience/) -------------------------------

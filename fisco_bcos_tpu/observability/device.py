@@ -807,6 +807,19 @@ def device_phase(name: str, op: str | None = None):
     return _NOOP_PHASE
 
 
+def note_dispatch_path(op: str, path: str) -> None:
+    """Labeled counter of which way a call actually went: the leg of a batch
+    (native host loop vs device program: the observable form of
+    `device/dispatch.use_native_batch`, so a mistuned FISCO_DEVICE_MIN_BATCH
+    shows up in /metrics instead of as a silent latency cliff), the form of a
+    merkle tree (`device_span.path`)."""
+    _metrics.REGISTRY.counter_add(
+        f'fisco_device_dispatch_path_total{{op="{op}",path="{path}"}}',
+        1.0,
+        help="batch dispatches split by chosen leg (native host vs device)",
+    )
+
+
 class device_span:
     """Time one host-level device-batch call and emit the full signal set.
 
@@ -862,6 +875,13 @@ class device_span:
         ``fisco_device_tiled_items_total{op}`` as well."""
         self._span.set(tiles=tiles, tile_lanes=tile_lanes)
         self._tiled = tiles > 1
+
+    def path(self, name: str) -> None:
+        """Which way the call went where an op has more than one (a merkle
+        tree as one ``fused`` program or in ``levels``): ``path`` on the trace
+        record and one count on ``fisco_device_dispatch_path_total{op,path}``."""
+        self._span.set(path=name)
+        note_dispatch_path(self.op, name)
 
     def __enter__(self):
         reg = _metrics.REGISTRY

@@ -29,7 +29,7 @@ import numpy as np
 
 from .keccak import keccak256_batch_async, keccak256_blocks
 from .sha256 import sha256_batch_async
-from .sm3 import sm3_batch_async
+from .sm3 import sm3_absorb, sm3_batch_async
 
 HashBatchFn = Callable[[Sequence[bytes]], np.ndarray]
 
@@ -179,10 +179,17 @@ class MerkleTree:
         b = bucket_leaves(self.n)
         if b > self.n:  # zero-digest filler up to the bucket (see bucket_leaves)
             leaves = np.vstack([leaves, np.zeros((b - self.n, 32), np.uint8)])
-        self._hash_batch = (
-            _host_hash_batch(hasher) if _prefer_host_tree() else _HASHERS[hasher]
-        )
-        self.levels = _levels(leaves, width, self._hash_batch)
+        host = _prefer_host_tree()
+        # every level from one device program and one transfer (the fused
+        # tree, below), or a hash batch and a sync a level
+        self.fused = hasher in _FUSED_TREE and self.n >= _FUSED_MIN_LEAVES and not host
+        if self.fused:
+            rows = np.asarray(_device_tree_fn(hasher, b, width)(leaves))
+            self.levels = [leaves, *np.split(rows, _level_offsets(b, width))]
+        else:
+            self.levels = _levels(
+                leaves, width, _host_hash_batch(hasher) if host else _HASHERS[hasher]
+            )
 
     @property
     def padded_root(self) -> bytes:
@@ -263,12 +270,21 @@ class MerkleTree:
 # ---------------------------------------------------------------------------
 #
 # The generic MerkleTree path above does one host round trip per level with
-# Python per-group byte packing — fine for proofs and small blocks, but every
-# level is a dispatch, a transfer and a device sync, so a 10k-leaf root costs
-# ~4 syncs + host loops. The fused path packs keccak
-# sponge blocks with pure jnp reshapes and runs ALL levels in one jitted
-# device program: one transfer in, 32 bytes out. Bit-identical to the host
-# path (same grouping, same short-last-group semantics).
+# Python per-group byte packing — fine for small trees, but every level is a
+# dispatch, a transfer and a device sync, so a 10k-leaf root costs ~4 syncs +
+# host loops. The fused path packs a level's hash blocks with pure jnp
+# reshapes (keccak's sponge lanes, SM3's big-endian words) and runs ALL levels
+# in one jitted device program: one transfer in, and out either the root's 32
+# bytes (`_device_root_fn`, keccak) or every level above the leaves as one
+# array (`_device_tree_fn`: an SM3 root, and an SM3 proof tree, whose levels a
+# proof is cut from). Bit-identical to the host path (same grouping, same
+# short-last-group semantics).
+
+_FUSED_MIN_LEAVES = 256  # below it a tree is a few small hash batches
+_FUSED_ROOT = ("keccak256", "sm3")  # merkle_root_async: one program a root
+# MerkleTree: one program a proof tree. Keccak's proof trees stay level by
+# level (3.9 ms of device time a flood block: ROADMAP Queue 3)
+_FUSED_TREE = ("sm3",)
 
 _LANES = 17  # keccak rate 136 bytes = 17 64-bit lanes
 
@@ -333,16 +349,96 @@ def _device_level(cur, width: int):
     return _words_to_bytes(words)
 
 
+# A tree's batches are at most L/width lanes wide (64 at 1,024 leaves): a
+# compression costs the device ops it launches, not its arithmetic, so the
+# round scan is unrolled; and a batch of fewer than eight lanes (the short
+# last group, the top of the tree) is padded to eight, because the chip's
+# compiler runs narrower ones on its scalar core, 0.7 ms a 1,024-leaf tree
+# slower (PERF.md §6, PR 45 has the chip's table: 1.35 ms a tree, 7.2 as three
+# `sm3_blocks` programs; no loop at all is 0.19 ms, and XLA-CPU takes 30 s
+# a tree to compile it)
+_SM3_ROUND_UNROLL = 16
+_SM3_MIN_LANES = 8
+
+
+def _sm3_pad_words(msg_words: int) -> np.ndarray:
+    """SM3's padding of a message of msg_words 32-bit words (a group of
+    digests is whole words), as big-endian words: 0x80, zeros to the block's
+    last two words, the bit length."""
+    n = -(msg_words + 3) % 16
+    return np.array([0x80000000] + [0] * (n + 1) + [msg_words * 32], dtype=np.uint32)
+
+
+# analysis: allow(shape-bucket) — as _device_level: inside jit traces whose
+# leaf count _device_tree_fn's callers padded to bucket_leaves
+def _sm3_level(cur, width: int):
+    """One SM3 tree level on device: [L, 32] uint8 -> [ceil(L/width), 32].
+    The full groups are one batch and the short last group a second, each
+    lane of a batch absorbing the same number of blocks (a full group of 16
+    digests is eight data blocks and one constant padding block)."""
+    L = cur.shape[0]
+    b = cur.reshape(L, 8, 4).astype(jnp.uint32)
+    words = (b[..., 0] << 24) | (b[..., 1] << 16) | (b[..., 2] << 8) | b[..., 3]
+    gfull, rem = divmod(L, width)
+    digests = []
+    for groups, size, start in ((gfull, width, 0), (1, rem, gfull * width)):
+        if groups and size:
+            pad = _sm3_pad_words(size * 8)
+            msg = words[start : start + groups * size].reshape(groups, size * 8)
+            blocks = jnp.concatenate(
+                [msg, jnp.broadcast_to(jnp.asarray(pad), (groups, len(pad)))], axis=1
+            ).reshape(groups, -1, 16)
+            if groups < _SM3_MIN_LANES:
+                blocks = jnp.pad(blocks, ((0, _SM3_MIN_LANES - groups), (0, 0), (0, 0)))
+            digests.append(sm3_absorb(blocks, _SM3_ROUND_UNROLL)[:groups])
+    out = jnp.concatenate(digests, axis=0)
+    by = jnp.stack([(out >> s) & 0xFF for s in (24, 16, 8, 0)], axis=-1)
+    return by.reshape(out.shape[0], 32).astype(jnp.uint8)
+
+
+_DEVICE_LEVEL = {"keccak256": _device_level, "sm3": _sm3_level}
+
+
+def _device_levels(leaves, width: int, hasher: str) -> list:
+    """Every level above the leaves, inside a jit trace; the last is [1, 32]."""
+    levels = []
+    cur = leaves
+    while cur.shape[0] > 1:
+        cur = _DEVICE_LEVEL[hasher](cur, width)
+        levels.append(cur)
+    return levels
+
+
+def _level_offsets(n: int, width: int) -> list[int]:
+    """Where `_device_tree_fn`'s rows split into levels (for np.split)."""
+    offsets, at = [], 0
+    while n > 1:
+        n = -(-n // width)
+        at += n
+        offsets.append(at)
+    return offsets[:-1]
+
+
 @lru_cache(maxsize=64)
 def _device_root_fn(n: int, width: int):
     @jax.jit
     def run(leaves):
-        cur = leaves
-        while cur.shape[0] > 1:
-            cur = _device_level(cur, width)
-        return cur[0]
+        return _device_levels(leaves, width, "keccak256")[-1][0]
 
     return run
+
+
+@lru_cache(maxsize=64)
+def _device_tree_fn(hasher: str, n: int, width: int):
+    """The tree over n (bucketed) leaves as one program: [n, 32] uint8 ->
+    every level above the leaves, bottom-up, as the rows of one array (one
+    transfer back; `_level_offsets` splits them), the root the last row."""
+
+    @jax.jit
+    def tree(leaves):
+        return jnp.concatenate(_device_levels(leaves, width, hasher), axis=0)
+
+    return tree
 
 
 def merkle_root_async(
@@ -350,10 +446,12 @@ def merkle_root_async(
 ):
     """Dispatch the root computation, defer the device sync: () -> bytes.
 
-    Large keccak trees dispatch the fused single-program device path and
-    resolve on call (letting the sealing path queue tx root, receipts root
-    and state root before paying any device round trip); proofs, small
-    trees and other hashers compute eagerly inside this call."""
+    Large keccak and SM3 trees dispatch the fused single-program device path
+    and resolve on call (letting the sealing path queue tx root, receipts
+    root and state root before paying any device round trip); small trees,
+    the host route and other hashers compute eagerly inside this call.
+    Which of the two a root took is counted:
+    ``fisco_device_dispatch_path_total{op="merkle_root",path="fused"|"levels"}``."""
     from ..observability.device import device_phase, device_span
 
     if not isinstance(leaves, jax.Array):
@@ -372,10 +470,11 @@ def merkle_root_async(
     key = (hasher, width, bucket_leaves(max(n, 1)))
     with device_span("merkle_root", n, shape_key=key, hasher=hasher) as sp:
         if (
-            hasher == "keccak256"
-            and len(leaves) >= 256
+            hasher in _FUSED_ROOT
+            and n >= _FUSED_MIN_LEAVES
             and not _prefer_host_tree()
         ):
+            sp.path("fused")
             # jax.Array input stays on device — tx/receipt hashes come from
             # the batch hash kernels, so the hot sealing path never
             # round-trips the leaf tensor through the host. Padding to the
@@ -388,13 +487,19 @@ def merkle_root_async(
                 if b > n:
                     arr = jnp.concatenate([arr, jnp.zeros((b - n, 32), jnp.uint8)])
             with sp.phase("enqueue"):
-                dev = _device_root_fn(b, width)(arr)
+                if hasher == "keccak256":
+                    dev = _device_root_fn(b, width)(arr)
+                else:
+                    dev = _device_tree_fn(hasher, b, width)(arr)
 
             def resolve() -> bytes:
                 with device_phase("sync", op="merkle_root"):
-                    return bind_root(bytes(np.asarray(dev)), n, hasher)
+                    # the root's 32 bytes, or the tree's rows: the root last
+                    padded_root = np.asarray(dev).reshape(-1, 32)[-1]
+                    return bind_root(bytes(padded_root), n, hasher)
 
             return resolve
+        sp.path("levels")
         root = MerkleTree(
             np.asarray(leaves, dtype=np.uint8), width=width, hasher=hasher
         ).root
@@ -410,12 +515,17 @@ def merkle_root(
     return merkle_root_async(leaves, width=width, hasher=hasher)()
 
 
-# -- progaudit shape spec: the root program is a maker product — audit the
-# width-16 keccak tree at one ladder leaf count.
+# -- progaudit shape spec: the tree programs are maker products — audit the
+# width-16 keccak root and the width-16 SM3 tree at one ladder leaf count.
 PROGSPEC = {
     "_device_root_fn.run": {
         "bucket": 256,
         "call": lambda b: _device_root_fn(b, 16),
+        "inputs": lambda b: [((b, 32), "uint8")],
+    },
+    "_device_tree_fn.tree": {
+        "bucket": 256,
+        "call": lambda b: _device_tree_fn("sm3", b, 16),
         "inputs": lambda b: [((b, 32), "uint8")],
     },
 }

@@ -62,29 +62,68 @@ def _schedule(block):
     return w, w1
 
 
+def _round(carry, xs):
+    """One of the 64 rounds, on eight [B] registers: a `lax.scan` step."""
+    a, b, c, d, e, f, g, h = carry
+    tj, wt, w1t, j16 = xs
+    a12 = _rotl(a, 12)
+    ss1 = _rotl(a12 + e + tj, 7)
+    ss2 = ss1 ^ a12
+    ff_lin = a ^ b ^ c
+    ff_maj = (a & b) | (a & c) | (b & c)
+    gg_lin = e ^ f ^ g
+    gg_ch = (e & f) | (~e & g)
+    ff = jnp.where(j16, ff_maj, ff_lin)
+    gg = jnp.where(j16, gg_ch, gg_lin)
+    tt1 = ff + d + ss2 + w1t
+    tt2 = gg + h + ss1 + wt
+    return (tt1, a, _rotl(b, 9), c, _p0(tt2), e, _rotl(f, 19), g), None
+
+
+_J16 = np.arange(64) >= 16
+
+
 def _compress(state, block):
     """state [B, 8], block [B, 16] -> new state [B, 8]."""
     w, w1 = _schedule(block)
 
+    # a step function of this call's own, as it has always been: a trace that
+    # hashes more than once (the SM admission program, five times) then lowers
+    # to the module it always has, symbol for symbol, and finds its compiled
+    # program in the cache; `_round` itself as the step would share one body
     def rnd(carry, xs):
-        a, b, c, d, e, f, g, h = carry
-        tj, wt, w1t, j16 = xs
-        a12 = _rotl(a, 12)
-        ss1 = _rotl(a12 + e + tj, 7)
-        ss2 = ss1 ^ a12
-        ff_lin = a ^ b ^ c
-        ff_maj = (a & b) | (a & c) | (b & c)
-        gg_lin = e ^ f ^ g
-        gg_ch = (e & f) | (~e & g)
-        ff = jnp.where(j16, ff_maj, ff_lin)
-        gg = jnp.where(j16, gg_ch, gg_lin)
-        tt1 = ff + d + ss2 + w1t
-        tt2 = gg + h + ss1 + wt
-        return (tt1, a, _rotl(b, 9), c, _p0(tt2), e, _rotl(f, 19), g), None
+        return _round(carry, xs)
 
     init = tuple(state[:, i] for i in range(8))
-    j16 = np.arange(64) >= 16
-    out, _ = lax.scan(rnd, init, (jnp.asarray(_TJ), w[:64], w1, jnp.asarray(j16)))
+    out, _ = lax.scan(rnd, init, (jnp.asarray(_TJ), w[:64], w1, jnp.asarray(_J16)))
+    return state ^ jnp.stack(out, axis=1)
+
+
+def _compress_rolling(state, block, unroll: int):
+    """`_compress` with the message expanded as the rounds go: the scan
+    carries the sixteen words the next rounds read beside the registers, so a
+    compression is 64 / unroll loop steps and nothing else. For a batch a
+    vector register wide or less (a merkle level), whose time on the chip is
+    the device ops it launches (PERF.md §6, PR 45), and for XLA-CPU, which
+    takes 2 s to compile `_schedule`'s 68 stacked words wherever they are
+    traced; `_compress` keeps that one pass for the wide batches."""
+
+    def step(carry, xs):
+        regs, win = carry
+        tj, j16 = xs
+        regs, _ = _round(regs, (tj, win[0], win[0] ^ win[4], j16))
+        nxt = (
+            _p1(win[0] ^ win[7] ^ _rotl(win[13], 15)) ^ _rotl(win[3], 7) ^ win[10]
+        )
+        return (regs, (*win[1:], nxt)), None
+
+    init = (
+        tuple(state[:, i] for i in range(8)),
+        tuple(block[:, i] for i in range(16)),
+    )
+    (out, _), _ = lax.scan(
+        step, init, (jnp.asarray(_TJ), jnp.asarray(_J16)), unroll=unroll
+    )
     return state ^ jnp.stack(out, axis=1)
 
 
@@ -103,6 +142,21 @@ def sm3_blocks(blocks: jax.Array, nblocks: jax.Array) -> jax.Array:
         absorb,
         state0,
         (jnp.moveaxis(blocks, 1, 0), jnp.arange(m_max, dtype=jnp.int32)),
+    )
+    return state
+
+
+def sm3_absorb(blocks: jax.Array, unroll: int) -> jax.Array:
+    """SM3 inside a jit trace, every lane absorbing all of its blocks from
+    the IV, no mask: blocks [B, M, 16] padded big-endian words -> digests
+    [B, 8] uint32. The fused merkle tree's levels (ops/merkle) hash this way:
+    at most 64 lanes wide, their time is device ops launched and not
+    arithmetic, so they ask for the round scan unrolled ``unroll`` times."""
+    state0 = jnp.broadcast_to(jnp.asarray(_IV), (blocks.shape[0], 8))
+    state, _ = lax.scan(
+        lambda state, blk: (_compress_rolling(state, blk, unroll), None),
+        state0,
+        jnp.moveaxis(blocks, 1, 0),
     )
     return state
 

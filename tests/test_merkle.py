@@ -4,6 +4,8 @@ Reference model: bcos-crypto/test/unittests/testMerkle.cpp — roots and proofs
 across widths and leaf counts, negative proof cases.
 """
 
+import time
+
 import numpy as np
 import pytest
 
@@ -98,25 +100,81 @@ def test_truncated_proof_cannot_certify_internal_node():
     assert not MerkleTree.verify_proof(bytes(leaves[0]), 0, 256, padded, tree.root, width=16)
 
 
-@pytest.mark.parametrize("n", [256, 271, 400, 1000])
-def test_fused_device_root_matches_host_path(n, monkeypatch):
-    """merkle_root's >= 256-leaf fused single-program device path must be
-    bit-identical to the generic MerkleTree levels (consensus-critical:
-    tx/receipt roots) — including short last groups at every level, and for
-    device-resident (jax.Array) leaf input. The device route is FORCED here:
-    on CPU+native hosts merkle_root prefers the host tree (backend-aware
-    routing, r5), which would silently drop this cross-route identity
-    coverage."""
+# leaf counts of the fused SM3 tree: a full bucket (256, 1,024), one
+# filler-only group (257 pads to 272), a remainder group at each level (600
+# pads to 608: 38 groups, then 2 and a group of 6), a top group of one, and
+# the headline 10,000
+_FUSED_NS = [256, 257, 272, 600, 1000, 1024, 4097, 10_000]
+_FUSED_CASES = [("keccak256", n, 16) for n in (256, 271, 400, 1000)] + [
+    ("sm3", n, width) for width in (2, 16) for n in _FUSED_NS
+]
+
+
+def _leaves(n, width=0):
+    return np.random.default_rng(n + width).integers(0, 256, (n, 32), dtype=np.uint8)
+
+
+@pytest.mark.parametrize("hasher,n,width", _FUSED_CASES)
+def test_fused_device_root_matches_host_path(hasher, n, width, monkeypatch):
+    """merkle_root's >= 256-leaf fused single-program device path (keccak's
+    root program, SM3's tree program) must be bit-identical to the generic
+    MerkleTree levels (consensus-critical: tx/receipt roots) — including
+    short last groups at every level, and for device-resident (jax.Array)
+    leaf input. The device route is FORCED here: on CPU+native hosts
+    merkle_root prefers the host tree (backend-aware routing, r5), which
+    would silently drop this cross-route identity coverage."""
     import jax.numpy as jnp
 
     from fisco_bcos_tpu.ops import merkle as M
 
-    rng = np.random.default_rng(n)
-    leaves = rng.integers(0, 256, (n, 32), dtype=np.uint8)
-    want = MerkleTree(leaves, width=16).root  # host (native or XLA) route
+    leaves = _leaves(n, width)
+    want = MerkleTree(leaves, width=width, hasher=hasher).root  # host route
+    if hasher == "sm3" and width == 16 and n <= 1024:
+        # the benchmark's plain rule over the same digests (pure Python)
+        from benchmark import refsmroot
+
+        assert want == refsmroot.root([bytes(x) for x in leaves])
     monkeypatch.setattr(M, "_prefer_host_tree", lambda: False)
-    assert M.merkle_root(leaves) == want
-    assert M.merkle_root(jnp.asarray(leaves)) == want
+    assert M.merkle_root(leaves, width=width, hasher=hasher) == want
+    assert M.merkle_root(jnp.asarray(leaves), width=width, hasher=hasher) == want
+
+
+@pytest.mark.parametrize("width", [2, 16])
+@pytest.mark.parametrize("n", _FUSED_NS)
+def test_fused_sm3_tree_is_the_host_tree_level_for_level(n, width, monkeypatch):
+    """An SM3 proof tree of 256 leaves and more on the device route is ONE
+    program and one transfer: its levels are the host hasher's, row for row,
+    and a proof cut from them verifies (and a flipped byte does not)."""
+    from fisco_bcos_tpu.ops import merkle as M
+
+    leaves = _leaves(n, width)
+    host = MerkleTree(leaves, width=width, hasher="sm3")
+    assert not host.fused
+    monkeypatch.setattr(M, "_prefer_host_tree", lambda: False)
+    tree = MerkleTree(leaves, width=width, hasher="sm3")
+    assert tree.fused
+    want = M._levels(host.levels[0], width, M._host_hash_batch("sm3"))
+    assert len(tree.levels) == len(want)
+    for got, level in zip(tree.levels, want):
+        assert got.dtype == np.uint8 and np.array_equal(got, level)
+    assert tree.padded_root == host.padded_root and tree.root == host.root
+    for idx in (0, n // 2, n - 1):
+        proof = tree.proof(idx)
+        leaf = bytes(leaves[idx])
+        assert MerkleTree.verify_proof(leaf, idx, n, proof, tree.root, width=width, hasher="sm3")
+        flipped = bytes([leaf[0] ^ 1]) + leaf[1:]
+        assert not MerkleTree.verify_proof(
+            flipped, idx, n, proof, tree.root, width=width, hasher="sm3")
+
+
+def test_small_and_other_trees_keep_the_level_path(monkeypatch):
+    """Under 256 leaves, and under a hasher with no fused tree, MerkleTree is
+    the level-by-level path on the device route too."""
+    from fisco_bcos_tpu.ops import merkle as M
+
+    monkeypatch.setattr(M, "_prefer_host_tree", lambda: False)
+    assert not MerkleTree(_leaves(255), hasher="sm3").fused
+    assert not MerkleTree(_leaves(17), hasher="keccak256").fused
 
 
 def test_fused_device_root_input_validation():
@@ -129,12 +187,13 @@ def test_fused_device_root_input_validation():
         merkle_root(np.zeros((300, 64), dtype=np.uint8))
 
 
-def test_bucket_padding_reuses_device_program(monkeypatch):
+@pytest.mark.parametrize("hasher", ["keccak256", "sm3"])
+def test_bucket_padding_reuses_device_program(hasher, monkeypatch):
     """Block sizes within one bucket must hit the SAME compiled tree program
     (the per-leaf-count recompile churn fix), with padding overhead bounded
     by the 5-bit mantissa (<= 1/16). Device route forced (see above)."""
     import fisco_bcos_tpu.ops.merkle as M
-    from fisco_bcos_tpu.ops.merkle import _device_root_fn, bucket_leaves, merkle_root
+    from fisco_bcos_tpu.ops.merkle import bucket_leaves, merkle_root
 
     monkeypatch.setattr(M, "_prefer_host_tree", lambda: False)
 
@@ -149,9 +208,55 @@ def test_bucket_padding_reuses_device_program(monkeypatch):
         assert n <= b <= n + (n >> 4) + 16   # overhead bound
         assert bucket_leaves(b) == b         # buckets are fixed points
 
-    before = _device_root_fn.cache_info().currsize
+    maker = M._device_root_fn if hasher == "keccak256" else M._device_tree_fn
+    before = maker.cache_info().currsize
     rng = np.random.default_rng(3)
     for n in (497, 500, 505, 512):           # one bucket: 512
-        merkle_root(rng.integers(0, 256, (n, 32), dtype=np.uint8))
-    added = _device_root_fn.cache_info().currsize - before
+        merkle_root(rng.integers(0, 256, (n, 32), dtype=np.uint8), hasher=hasher)
+        if hasher == "sm3":                  # a proof tree shares the root's program
+            assert MerkleTree(_leaves(n), hasher=hasher).fused
+    added = maker.cache_info().currsize - before
     assert added <= 1  # one program for the whole bucket
+
+
+def test_a_merkle_root_counts_the_path_it_took(monkeypatch):
+    """The mechanism's counter: with the device route forced, one SM3 root of
+    1,000 leaves is one ``device.merkle_root`` span, one fused program, one
+    count on ``fisco_device_dispatch_path_total{op="merkle_root",path="fused"}``
+    and no `sm3_blocks` call; a 100-leaf one counts ``path="levels"``. A proof
+    tree through the suite counts under ``op="merkle_tree"``."""
+    import fisco_bcos_tpu.ops.merkle as M
+    from fisco_bcos_tpu.crypto.suite import sm_suite
+    from fisco_bcos_tpu.observability import TRACER
+    from fisco_bcos_tpu.utils.metrics import REGISTRY
+
+    monkeypatch.setattr(M, "_prefer_host_tree", lambda: False)
+    level_batches = []
+    monkeypatch.setitem(
+        M._HASHERS, "sm3",
+        lambda msgs, real=M._HASHERS["sm3"]: level_batches.append(len(msgs)) or real(msgs))
+    paths = "fisco_device_dispatch_path_total{"
+
+    def took(call):
+        before = REGISTRY.counters_matching(paths)
+        t0 = time.perf_counter()
+        call()
+        spans = [r for r in TRACER.spans()
+                 if r.name in ("device.merkle_root", "device.merkle_tree") and r.ts >= t0]
+        delta = {k[len(paths):-1]: v - before.get(k, 0.0)
+                 for k, v in REGISTRY.counters_matching(paths).items()
+                 if v - before.get(k, 0.0)}
+        return spans, delta
+
+    spans, delta = took(lambda: M.merkle_root(_leaves(1000), hasher="sm3"))
+    assert delta == {'op="merkle_root",path="fused"': 1.0}
+    assert [(r.name, r.attrs["path"]) for r in spans] == [("device.merkle_root", "fused")]
+    assert level_batches == []
+    spans, delta = took(lambda: M.merkle_root(_leaves(100), hasher="sm3"))
+    assert delta == {'op="merkle_root",path="levels"': 1.0}
+    assert len(spans) == 1 and level_batches == [7, 1]  # 112 padded leaves: two levels
+    spans, delta = took(lambda: sm_suite().merkle_tree(_leaves(1000)))
+    assert delta == {'op="merkle_tree",path="fused"': 1.0}
+    assert [(r.name, r.attrs["path"]) for r in spans] == [("device.merkle_tree", "fused")]
+    _, delta = took(lambda: sm_suite().merkle_tree(_leaves(100)))
+    assert delta == {'op="merkle_tree",path="levels"': 1.0}

@@ -192,6 +192,70 @@ def test_fast_subset_matches_committed_baseline():
     assert not diff["missing_spec"], diff["missing_spec"]
 
 
+# PR 45 gave ops/sm3 a second form of a compression for the fused SM3 merkle
+# tree (`_compress_rolling`, beside `_compress`, sharing `_round`): the
+# programs that hash through the first must be the ones the baseline holds
+# (how `sm3_blocks` is traced moves the SM admission program by tens of
+# milliseconds a block on the chip: ops/sm3.sm3_fixed), and the tree program
+# the one that was measured there.
+@pytest.mark.parametrize("key", [
+    "fisco_bcos_tpu/ops/sm3.py:sm3_blocks",
+    "fisco_bcos_tpu/ops/merkle.py:_device_root_fn.run",
+    "fisco_bcos_tpu/ops/merkle.py:_device_tree_fn.tree",
+])
+def test_the_sm3_and_merkle_programs_are_the_baselines(key):
+    result = progaudit.audit(programs=[key])
+    assert not result["failures"], result["failures"]
+    diff = progaudit.diff_audit(result, progaudit.load_jaxpr_baseline())
+    assert not diff["new"] and not diff["changed"], diff
+
+
+def test_sm3_blocks_round_scan_is_not_unrolled():
+    """The admission program's guard, said structurally: `sm3_blocks` runs
+    `_schedule`'s pass and then 64 rounds as a scan of 64 steps over the
+    schedule's rows, one round a step; the unrolled, rolling form is the
+    merkle tree's alone."""
+    from fisco_bcos_tpu.ops import sm3
+
+    jaxpr = jax.make_jaxpr(sm3.sm3_blocks)(
+        _aval((256, 2, 16), "uint32"), _aval((256,), "int32"))
+
+    def scans(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "scan":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from scans(sub)
+
+    rounds = [e for e in scans(jaxpr.jaxpr) if e.params["length"] == 64]
+    assert len(rounds) == 1
+    (eqn,) = rounds
+    assert eqn.params["unroll"] == 1
+    assert eqn.params["num_carry"] == 8  # the registers; the message is xs
+
+
+def test_two_hashes_of_one_trace_keep_a_step_function_each():
+    """What the fingerprints above cannot see. A program that hashes more
+    than once (the SM admission program: five times) lowers each
+    `_compress`'s round scan to a step function of its own, because
+    `_compress` hands `lax.scan` a function made in that call. With one
+    module-level step JAX shares the body between hashes of one lane shape:
+    the same arithmetic, but another module text, so another compile-cache
+    key (the 1,024-lane SM program compiled again, 242 s of set-up, on the
+    chip in PR 45) and a program nobody has timed (PR 26: how these hashes
+    are traced moved the SM block by 28 ms)."""
+    import re
+
+    from fisco_bcos_tpu.ops import sm3
+
+    text = jax.jit(lambda a, b: (sm3.sm3_fixed(a), sm3.sm3_fixed(b))).lower(
+        _aval((8, 1, 16), "uint32"), _aval((8, 2, 16), "uint32")).as_text()
+    # two `sm3_blocks` (one a block count), each with its absorb step and its
+    # round step
+    assert len(re.findall(r"func\.func private @sm3_blocks", text)) == 2
+    assert len(re.findall(r"func\.func private @closed_call", text)) == 4
+
+
 @pytest.mark.skipif(
     not os.path.exists(BASELINE_PATH), reason="baseline not generated yet"
 )

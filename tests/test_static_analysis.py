@@ -357,6 +357,7 @@ PINNED_JIT_PROGRAMS = [
     "fisco_bcos_tpu/ops/ed25519.py:_verify_xla",
     "fisco_bcos_tpu/ops/keccak.py:keccak256_blocks",
     "fisco_bcos_tpu/ops/merkle.py:_device_root_fn.run",
+    "fisco_bcos_tpu/ops/merkle.py:_device_tree_fn.tree",
     "fisco_bcos_tpu/ops/poseidon.py:poseidon_blocks",
     "fisco_bcos_tpu/ops/secp256k1.py:_recover_xla",
     "fisco_bcos_tpu/ops/secp256k1.py:_verify_xla",

@@ -38,7 +38,8 @@ FAST_SUBSET = (
     "fisco_bcos_tpu/ops/sha256.py:sha256_blocks,"
     "fisco_bcos_tpu/ops/sm3.py:sm3_blocks,"
     "fisco_bcos_tpu/ops/address.py:sender_address_device,"
-    "fisco_bcos_tpu/ops/merkle.py:_device_root_fn.run"
+    "fisco_bcos_tpu/ops/merkle.py:_device_root_fn.run,"
+    "fisco_bcos_tpu/ops/merkle.py:_device_tree_fn.tree"
 )
 
 

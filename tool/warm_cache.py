@@ -130,7 +130,10 @@ def _warm_merkle(bucket: int):
     if merkle._prefer_host_tree():
         return "host-tree policy on this backend (device tree never compiles)"
     leaves = np.ones((max(bucket, 256), 32), np.uint8)
-    merkle.merkle_root(leaves, hasher="keccak256")
+    # keccak's root program, and the SM3 tree an sm_crypto node's roots and
+    # proof trees share
+    for hasher in merkle._FUSED_ROOT:
+        merkle.merkle_root(leaves, hasher=hasher)
     return None
 
 
