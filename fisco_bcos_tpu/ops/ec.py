@@ -84,11 +84,15 @@ class CurveOps:
 
 def _make_curve_ops(c: Curve) -> CurveOps:
     # Pseudo-Mersenne fast path when p = 2^256 - small (secp256k1);
-    # generic Montgomery otherwise (SM2). SM2's prime is also a Solinas
-    # prime, and limb.SparseFoldField implements the shift-add fold with
-    # proven exactness — but its 8 carry-chain fold rounds have not shown
-    # a runtime win over REDC yet, so it stays opt-in (FISCO_SM2_SPARSE=1)
-    # until profiled on hardware.
+    # Montgomery otherwise (SM2), whose REDC multiplies by its two constants
+    # as shifted rows where the prime makes them short signed sums (SM2's p
+    # does: make_mont_field decides from the modulus, PR 46). The same prime
+    # has a plain-domain reduction, limb.SparseFoldField (a dense table fold
+    # and a signed shift-add round, five exact chains where REDC has three):
+    # exact; timed on the chip in PR 46 (PERF.md §6) it costs 82.8 / 170.0 /
+    # 241.9 ms a call at 1,024 / 4,096 / 10,240 lanes where REDC by products
+    # cost 119.5 / 245.9 / 339.5 and REDC by shifted rows costs 23.2 / 46.7 /
+    # 95.0, so it stays opt-in (FISCO_SM2_SPARSE=1) and nothing turns it on.
     import logging
     import os
 

@@ -47,7 +47,12 @@ bcos-crypto's secp256k1/SM2 paths) with a formulation shaped for the TPU VPU:
 - Modular reduction is **pseudo-Mersenne folding** (``FoldField``) for
   moduli of the form 2^256 − c with small c — secp256k1's p and n both
   qualify — and word Montgomery (``MontField``) for arbitrary odd moduli
-  (SM2). Both present the same field-ops protocol so the EC layer in
+  (SM2). REDC's two multiplications by constants (m' and m) are signed sums
+  of shifted rows where the modulus makes both constants short in signed
+  powers of two (SM2's p: seven and five terms, so a field multiplication is
+  one limb product; :func:`make_mont_field` reads this off the modulus), and
+  limb products for a dense modulus (SM2's n, BN254's Fr). Both fields
+  present the same field-ops protocol so the EC layer in
   :mod:`fisco_bcos_tpu.ops.ec` is generic over them.
 
 Everything here is plain ``jnp`` on values; integer semantics make every
@@ -196,9 +201,14 @@ def lane_plan(lanes: int) -> LanePlan:
     tile of 2,048 lanes costs 25.6 ms as a program of its own and 52.9 inside
     the loop, so no bucket over 2,560 lanes has a plan that beats it whole;
     three tiles of 1,024 lanes in ``[L, T]`` cost what three 1,024-lane calls
-    cost. The SM2 body has no cheap size to tile to (1,280 lanes alone 140.3
-    ms, 2,560 whole 213.5, two tiles of 1,280 261.8, 10,240 whole 339.6): it
-    runs :func:`whole_plan` at every size.
+    cost. The SM2 body runs :func:`whole_plan` at every size. While its REDC
+    ran three limb products a multiplication it had no cheap size at all
+    (1,024 lanes 119.5 ms, 1,280 140.3, 2,560 whole 213.5, two tiles of 1,280
+    261.8, 4,096 245.9, 10,240 339.6); with REDC's two constants as shifted
+    rows (PR 46, PERF.md §6) the same body is cheap an op at every size
+    measured, whole: 1,024 lanes 23.2 ms, 4,096 46.7, 10,240 95.0 (188,745 to
+    223,569 device ops a call, where it was 240,945 to 269,898); no tiled
+    plan of it has been timed.
 
     Not understood: the edge itself. Much the same device ops run at every
     size (114,000 to 125,000 a call), and past 1,024 lanes in ``[L, T]``, or
@@ -503,13 +513,75 @@ def add_widen(a: jax.Array, b: jax.Array) -> jax.Array:
 
 
 def cond_sub(x: jax.Array, m_limbs: np.ndarray) -> jax.Array:
-    """x - m if x >= m else x, for normalized x < 2m. Returns [16, T]."""
+    """x - m if x >= m else x, for normalized x < 2m. Returns [16, T]. m may
+    be as wide as x: a static offset both carry above limb 15 (x + a·R against
+    m + a·R) compares as x against m and leaves the low 16 limbs what they
+    were."""
     w = x.shape[0]
     m_pad = np.zeros(w, dtype=np.uint32)
-    m_pad[: LIMBS] = m_limbs
+    m_pad[: len(m_limbs)] = m_limbs
     mc = const_rows(m_pad, x)
     diff, borrow = sub_borrow(x, mc)
     return select(~borrow, diff, x)[:LIMBS]
+
+
+def signed_terms(c: int, width: int) -> tuple[tuple[int, int, int], ...]:
+    """c ≥ 0 as the shortest signed sum of powers of two, its non-adjacent
+    form, each term as (limb offset, shift inside the limb, sign): c ≡ Σ sign
+    · 2^shift · 2^(16·offset) mod 2^(16·width). A term at limb `width` or
+    above is a multiple of that modulus and is left out."""
+    terms, bit = [], 0
+    while c:
+        if c & 1:
+            sign = 2 - (c & 3)  # ±1, whichever leaves the next bit clear
+            c -= sign
+            if bit < LIMB_BITS * width:
+                terms.append((*divmod(bit, LIMB_BITS), sign))
+        c >>= 1
+        bit += 1
+    return tuple(terms)
+
+
+def signed_rows(
+    x: jax.Array,
+    terms: tuple[tuple[int, int, int], ...],
+    out: int,
+    x_max: int,
+    plus: jax.Array | None = None,
+    plus_max: int = 0,
+) -> tuple[jax.Array, int]:
+    """Column sums of x·c (+ `plus`) for a host constant c given as
+    :func:`signed_terms`: [n, ...] limbs ≤ x_max (and [out, ...] columns ≤
+    plus_max) -> ([out, ...] raw columns, a). No product: a term is x shifted
+    inside its limbs and placed at its offset, one pad; what a row places
+    past column `out` drops, as in :func:`mul_cols`.
+
+    Columns are unsigned, so the negative rows have no sum of their own to be
+    borrowed from: they are taken from a static bias, column by column at
+    least what the negative rows can reach there (bias_k = 2^16·a_k − a_{k−1},
+    a_k the least that covers column k: the sum telescopes), whose value is
+    a · 2^(16·out). The columns returned hold x·c + plus + a·2^(16·out), less
+    what dropped, each shown here to stay inside uint32."""
+    n = x.shape[0]
+    pos_max, neg_max = [plus_max] * out, [0] * out
+    pos = [] if plus is None else [plus]
+    neg = []
+    for offset, shift, sign in terms:
+        for k in range(offset, min(offset + n, out)):
+            (pos_max if sign > 0 else neg_max)[k] += x_max << shift
+        row = _placed(x << np.uint32(shift) if shift else x, offset, out)
+        (pos if sign > 0 else neg).append(row)
+    bias, a = [], 0
+    for need in neg_max:
+        above = -(-(need + a) >> LIMB_BITS)
+        bias.append((above << LIMB_BITS) - a)
+        a = above
+    if max(b + p for b, p in zip(bias, pos_max)) > 0xFFFFFFFF:
+        raise ValueError("signed_rows: a column would leave uint32")
+    if not neg:
+        return _sum_terms(pos), 0
+    cols = _sum_terms(pos + [const_rows(np.array(bias, dtype=np.uint32), x)])
+    return cols - _sum_terms(neg), a
 
 
 # ---------------------------------------------------------------------------
@@ -777,13 +849,26 @@ def make_sparse_fold_field(m: int) -> SparseFoldField:
 @dataclass(frozen=True)
 class MontField:
     """GF(m) for arbitrary odd m < 2^256: Montgomery-domain values (x·R mod m,
-    R = 2^256), word REDC reduction. The generic path (SM2's p and n)."""
+    R = 2^256), word REDC reduction: SM2's p, and the dense moduli (SM2's n,
+    BN254's Fr).
+
+    REDC multiplies by two constants, m' = −m^-1 mod R and m. Where the
+    modulus makes both short signed sums of powers of two
+    (:func:`make_mont_field` derives them and decides: `mprime_terms` and
+    `m_terms` are then set), the two multiplications are shifted rows added
+    and subtracted (:func:`signed_rows`) and a field multiplication is one
+    limb product, not three; for any other modulus they are limb products by
+    the constants' limbs (:func:`mul_cols`). One algorithm, the same exact
+    chains, the same canonical limbs out."""
 
     m_int: int
     m_limbs: np.ndarray = field(repr=False)
     mprime: np.ndarray = field(repr=False)  # -m^-1 mod 2^256
     r1: np.ndarray = field(repr=False)  # R mod m (the field's 1)
     r2: np.ndarray = field(repr=False)  # R^2 mod m
+    # m' mod R and m as signed_terms, or None for both: multiply by the limbs
+    mprime_terms: tuple[tuple[int, int, int], ...] | None = None
+    m_terms: tuple[tuple[int, int, int], ...] | None = None
 
     def __hash__(self):
         return hash(("mont", self.m_int))
@@ -797,22 +882,51 @@ class MontField:
     def one(self, t) -> jax.Array:
         return const_rows(self.r1, t)
 
-    def redc(self, t: jax.Array) -> jax.Array:
-        """t [32, ...] (limbs ≤ 2^16, canonical or loose; t < m*R) ->
+    def redc(self, t: jax.Array, t_max: int = 1 << LIMB_BITS) -> jax.Array:
+        """t [32, ...] (limbs or raw columns ≤ t_max of a value t < m*R) ->
         t*R^-1 mod m, [16, ...] canonical.
 
-        Two exact chains and the conditional subtract. t's limbs meet only
-        constant limbs (`· m'`: 2^16 · 0xFFFF < 2^32, and the columns past R
-        drop, so any low half congruent to t mod R serves) and an addition of
-        columns, so they may be loose; m_val multiplies m and has to be < R
-        for t + m_val·m < 2mR, so it is exact; m_val·m is never normalised on
-        its own: its raw columns (< 2^22) join t's."""
-        m_val = carry_norm(
-            mul_cols(t[:LIMBS], const_rows(self.mprime, t), out=LIMBS)
-        )[:LIMBS]
-        mm = mul_cols(m_val, const_rows(self.m_limbs, t))
-        s = carry_norm(t + mm)  # [33, ...]; low 16 limbs are zero
-        return cond_sub(s[LIMBS:], self.m_limbs)
+        Two exact chains and the conditional subtract: m_val = t·m' mod R (17
+        limbs), t + m_val·m (33), the subtract (17). t meets only constants
+        and an addition of columns, and the columns past R drop, so any low
+        half congruent to t mod R serves and t is never exact: loose limbs ≤
+        2^16 where they multiply the limbs of `m'` (2^16 · 0xFFFF < 2^32), a
+        product's raw columns where `m'` is a few shifted rows. m_val
+        multiplies m and has to be < R for t + m_val·m < 2mR, so it is exact;
+        m_val·m is never normalised on its own: its raw columns join t's.
+
+        In the shifted-sum form the columns carry a static bias under their
+        negative rows (:func:`signed_rows`). m_val's is a multiple of R and
+        drops with the limb above R. That of t + m_val·m is a · 2^512: the 33
+        limbs hold the sum (< 2mR < 2^513) with `a` more in the top limb, and
+        the subtract compares against m + a·R, so no chain grows and no limb
+        is repaired."""
+        if self.m_terms is None:
+            if t_max * 0xFFFF > 0xFFFFFFFF:
+                raise ValueError("redc: t · m' would leave uint32")
+            m_val = carry_norm(
+                mul_cols(t[:LIMBS], const_rows(self.mprime, t), out=LIMBS)
+            )[:LIMBS]
+            cols, a = t + mul_cols(m_val, const_rows(self.m_limbs, t)), 0
+        else:
+            m_val = carry_norm(
+                signed_rows(t[:LIMBS], self.mprime_terms, LIMBS, t_max)[0]
+            )[:LIMBS]
+            cols, a = signed_rows(
+                m_val, self.m_terms, 2 * LIMBS, 0xFFFF, plus=t, plus_max=t_max
+            )
+        s = carry_norm(cols)  # [33, ...]; low 16 limbs are zero
+        return cond_sub(s[LIMBS:], int_to_rows(self.m_int + a * _R, LIMBS + 1))
+
+    def _redc_cols(self, cols: jax.Array, col_max: int) -> jax.Array:
+        """Raw columns ≤ col_max of a product < m*R -> its REDC. Where t
+        multiplies the limbs of `m'` it is normalised first (loosely: two
+        split passes, because `m'` has limbs of 0xFFFF); shifted rows take
+        the columns as the product left them."""
+        if self.m_terms is None:
+            cols, col_max = carry_loose(cols, col_max)
+            cols = cols[: 2 * LIMBS]
+        return self.redc(cols, col_max)
 
     def from_plain(self, x: jax.Array) -> jax.Array:
         return self.mul(x, const_rows(self.r2, x))
@@ -821,10 +935,10 @@ class MontField:
         return self.redc(_placed(x, 0, 2 * LIMBS))
 
     def mul(self, a: jax.Array, b: jax.Array) -> jax.Array:
-        return self.redc(carry_loose(mul_cols(a, b), _MUL_COL_MAX)[0][: 2 * LIMBS])
+        return self._redc_cols(mul_cols(a, b), _MUL_COL_MAX)
 
     def sqr(self, a: jax.Array) -> jax.Array:
-        return self.redc(carry_loose(sqr_cols(a), _SQR_COL_MAX)[0][: 2 * LIMBS])
+        return self._redc_cols(sqr_cols(a), _SQR_COL_MAX)
 
     def mul_small(self, a: jax.Array, c: int) -> jax.Array:
         """a * c for tiny c via an addition chain (scaling commutes with the
@@ -861,16 +975,36 @@ class MontField:
         return pow_static(self, a, (self.m_int + 1) // 4)
 
 
+# The most terms a constant of REDC may have for the shifted-sum form. A
+# product by a constant's 16 limbs is 16 row products whose low and high
+# halves are 32 placed rows to add; a signed sum of n terms is n placed rows
+# and no product, so up to 16 terms it adds at most half the rows. Between
+# SM2's p (5 and 7 terms: 3.6 to 5.2 times faster a call on the chip than the
+# products, PERF.md §6 PR 46) and the dense moduli (SM2's n 43 and 88,
+# BN254's Fr 74 and 81) no modulus has been timed.
+_SHIFT_TERMS_MAX = 16
+
+
 @lru_cache(maxsize=None)
 def make_mont_field(m: int) -> MontField:
+    """The Montgomery field of m. The form of its REDC is read off the
+    modulus: the non-adjacent forms of m and of −m^-1 mod R, and the
+    shifted-sum form where both have at most `_SHIFT_TERMS_MAX` terms."""
     if m % 2 == 0 or not 2 < m < _R:
         raise ValueError("modulus must be odd and < 2^256")
+    mprime = (-pow(m, -1, _R)) % _R
+    mprime_terms = signed_terms(mprime, LIMBS)
+    m_terms = signed_terms(m, 2 * LIMBS)
+    if max(len(mprime_terms), len(m_terms)) > _SHIFT_TERMS_MAX:
+        mprime_terms = m_terms = None
     return MontField(
         m_int=m,
         m_limbs=int_to_rows(m),
-        mprime=int_to_rows((-pow(m, -1, _R)) % _R),
+        mprime=int_to_rows(mprime),
         r1=int_to_rows(_R % m),
         r2=int_to_rows(_R * _R % m),
+        mprime_terms=mprime_terms,
+        m_terms=m_terms,
     )
 
 

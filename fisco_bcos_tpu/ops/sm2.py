@@ -15,11 +15,17 @@ valid iff (e + x1) mod n == r.
 
 The EC plane is the lane-dense windowed ladder shared with secp256k1
 (:mod:`fisco_bcos_tpu.ops.ec`); SM2's prime has a 225-bit complement, so
-the field is the generic Montgomery path (``limb.MontField``) by default.
-The prime is also a Solinas prime (2^256 − p = 2^224 + 2^96 − 2^64 + 1),
-and ``limb.SparseFoldField`` implements the shift-add fold bit-exactly —
-opt in with FISCO_SM2_SPARSE=1 (kept off pending a measured win over
-REDC; see the note in :func:`fisco_bcos_tpu.ops.ec._make_curve_ops`).
+the field is the Montgomery one (``limb.MontField``). The prime is a Solinas
+prime, p = 2^256 − 2^224 − 2^96 + 2^64 − 1, and so is −p^-1 mod 2^256 = 1 +
+2^64 − 2^96 + 2^128 − 2^161 + 2^193 − 2^226: ``limb.make_mont_field`` finds
+both sums in the modulus, and REDC multiplies by them as twelve shifted rows
+where it ran two limb products (PR 46), so a field multiplication here is
+one limb product, as secp256k1's is. ``limb.SparseFoldField`` is another
+reduction for the same prime (plain domain, a dense table fold and a signed
+shift-add round), bit-exact; on the chip it beats REDC by products (241.9
+against 339.5 ms at 10,240 lanes) and loses to REDC by shifted rows (95.0)
+at every size (PERF.md §6, PR 46); FISCO_SM2_SPARSE=1 still selects it (see
+the note in :func:`fisco_bcos_tpu.ops.ec._make_curve_ops`).
 """
 
 from __future__ import annotations

@@ -7,8 +7,10 @@ Every case here runs the public batch-major ``[B, L]`` -> ``lane_dense`` ->
 field operation -> ``batch_major`` path at a lane count that pads (24), at
 whole multiples of 128 in ``[L, T]`` and at 1,152 lanes in ``[L, 9, 128]``, on
 random and edge values, for secp256k1's p and n (``FoldField``) and SM2's p
-(``MontField``); ed25519's 2p, SM2's n and the Solinas form of SM2's p at the
-lane counts that pad and that fill ``[L, 9, 128]``.
+(``MontField``, whose REDC multiplies by its two constants as signed shifted
+rows since PR 46); ed25519's 2p, P-256's prime (the shifted rows again), SM2's
+n and BN254's Fr (``MontField`` in the product form) and the Solinas form of
+SM2's p at the lane counts that pad and that fill ``[L, 9, 128]``.
 
 A field operation normalises exactly only where canonical limbs are consumed
 (PR 27): the adversarial cases below drive the loose limbs ≤ 2^16 that run
@@ -36,6 +38,11 @@ _N = ec.SECP256K1_OPS.curve.n
 _SM2P = ec.SM2_OPS.curve.p
 _SM2N = ec.SM2_OPS.curve.n
 _ED2P = 2 * ((1 << 255) - 19)  # ed25519 keeps its values mod 2p
+# BN254's scalar field (ops/poseidon.py): a dense modulus, REDC by products
+_BN254R = 21888242871839275222246405745257275088548364400416034343698204186575808495617
+# NIST P-256's prime: no cell runs it; a second modulus whose REDC constants
+# are short signed sums, so the form is seen to be derived and not SM2's alone
+_P256 = 2**256 - 2**224 + 2**192 + 2**96 - 1
 
 FIELDS = {
     "fold-p": limb.make_fold_field(_P),
@@ -43,6 +50,8 @@ FIELDS = {
     "mont-sm2p": limb.make_mont_field(_SM2P),
     "fold-ed2p": limb.make_fold_field(_ED2P),
     "mont-sm2n": limb.make_mont_field(_SM2N),
+    "mont-bn254r": limb.make_mont_field(_BN254R),
+    "mont-p256": limb.make_mont_field(_P256),
     "sparse-sm2p": limb.make_sparse_fold_field(_SM2P),
 }
 LANES = [24, 128, 512, 1024, 1152]
@@ -179,7 +188,8 @@ def _wide_cases(F, n):
         top = m * _R
         his = _adversarial(m, n, 14)
         los = _adversarial(_R, n, 15)
-        return [h * _R + (0 if i % 3 == 0 else l) for i, (h, l) in enumerate(zip(his, los))], top
+        wide = [h * _R + (0 if i % 3 == 0 else l) for i, (h, l) in enumerate(zip(his, los))]
+        return [(m - 1) ** 2, top - 1, (m - 1) * _R, _R - 1] + wide[: n - 4], top
     top = (_R - 1) ** 2 + 1
     his = _adversarial(_R - 2, n, 14)  # hi ≤ R − 3 leaves the low half free
     los = _adversarial(_R, n, 15, head=(_R - 1,))
@@ -189,17 +199,23 @@ def _wide_cases(F, n):
 ADVERSARIAL_OPS = ("mul", "sqr", "mul_small", "reduce", "plain")
 
 
+# every field in [16, 128]; the Montgomery fields, both forms of REDC, also
+# where a limb is more than one vreg ([16, 9, 128])
+ADVERSARIAL_FIELD_LANES = [
+    (f, n) for f in sorted(FIELDS) for n in (128, 1152) if n == 128 or f.startswith("mont-")
+]
+
+
 @pytest.mark.parametrize("op", ADVERSARIAL_OPS)
-@pytest.mark.parametrize("field", sorted(FIELDS))
-def test_field_ops_on_adversarial_raw_operands(field, op):
+@pytest.mark.parametrize("field,n", ADVERSARIAL_FIELD_LANES)
+def test_field_ops_on_adversarial_raw_operands(field, n, op):
     """mul, sqr, mul_small, the wide reduction (reduce_wide / redc) and the
     domain conversions against Python integers on raw canonical operands:
     0, 1, m − 1, limbs of 0xFFFF, the pair whose product's loose limbs reach
-    2^16, a REDC input whose low half is 0."""
+    2^16, a REDC input whose low half is 0, (m − 1)^2 and m·R − 1."""
     F = FIELDS[field]
     m = F.m_int
     r, rinv = _raw(F)
-    n = 128
     xs = _adversarial(m, n, 11, head=(_LOOSE_A, 1 << 128))
     ys = _adversarial(m, n, 12, head=(_LOOSE_B, 1 << 128))
     ys[5:10] = xs[5:10][::-1]  # the edges against each other
@@ -221,6 +237,149 @@ def test_field_ops_on_adversarial_raw_operands(field, op):
     else:
         assert _ints(F.from_plain(a), n) == [x * r % m for x in xs]
         assert _ints(F.to_plain(a), n) == [x * rinv % m for x in xs]
+
+
+# -- REDC by signed shifted rows where the modulus allows it (PR 46) ----------
+
+# the two sums make_mont_field must derive for SM2's p, as ISSUE 46 states
+# them: (limb offset, shift inside the limb, sign)
+_SM2P_TERMS = ((0, 0, -1), (4, 0, 1), (6, 0, -1), (14, 0, -1), (16, 0, 1))  # p
+_SM2P_MPRIME_TERMS = (  # 1 + 2^64 − 2^96 + 2^128 − 2·2^160 + 2·2^192 − 4·2^224
+    (0, 0, 1), (4, 0, 1), (6, 0, -1), (8, 0, 1), (10, 1, -1), (12, 1, 1), (14, 2, -1)
+)
+
+
+def _terms_value(terms) -> int:
+    return sum(sign << (16 * offset + shift) for offset, shift, sign in terms)
+
+
+# P-256: p = 2^256 − 2^224 + 2^192 + 2^96 − 1, m' = 1 + 2^96 + 2·2^192 − 2^224
+_P256_TERMS = ((0, 0, -1), (6, 0, 1), (12, 0, 1), (14, 0, -1), (16, 0, 1))
+_P256_MPRIME_TERMS = ((0, 0, 1), (6, 0, 1), (12, 1, 1), (14, 0, -1))
+
+
+@pytest.mark.parametrize(
+    "field,terms",
+    [
+        ("mont-sm2p", (_SM2P_TERMS, _SM2P_MPRIME_TERMS)),
+        ("mont-p256", (_P256_TERMS, _P256_MPRIME_TERMS)),
+        ("mont-sm2n", None),
+        ("mont-bn254r", None),
+    ],
+)
+def test_make_mont_field_reads_the_form_off_the_modulus(field, terms):
+    """The shifted-sum REDC for SM2's p, with exactly the two term lists of
+    the issue, and for P-256's prime; the product form for the dense moduli
+    (SM2's n: 43 and 88 terms; BN254's Fr: 74 and 81). Whatever the form, the
+    non-adjacent forms are the constants."""
+    F = FIELDS[field]
+    m = F.m_int
+    mprime = -pow(m, -1, _R) % _R
+    assert limb.rows_to_ints(F.mprime[:, None]) == [mprime]
+    assert _terms_value(limb.signed_terms(m, 32)) == m
+    assert _terms_value(limb.signed_terms(mprime, 16)) % _R == mprime
+    if terms is None:
+        assert F.m_terms is None and F.mprime_terms is None
+        assert len(limb.signed_terms(m, 32)) > limb._SHIFT_TERMS_MAX
+        assert len(limb.signed_terms(mprime, 16)) > limb._SHIFT_TERMS_MAX
+    else:
+        assert (F.m_terms, F.mprime_terms) == terms
+    if field == "mont-sm2p":
+        assert m == 2**256 - 2**224 - 2**96 + 2**64 - 1
+        assert mprime == 0xFFFFFFFC00000001FFFFFFFE00000000FFFFFFFF000000010000000000000001
+    assert limb.make_mont_field(m) is F  # one field a modulus
+
+
+def _dense_rows(rows):
+    """Explicit limb or column rows (lists of ints < 2^32) -> lane-dense."""
+    return limb.lane_dense(jnp.asarray(np.array(rows, dtype=np.uint32)))
+
+
+def _columns_under(top: int, col_max: int, n: int, seed: int) -> list[list[int]]:
+    """n rows of 32 columns ≤ col_max whose value stays under `top`: every
+    column at col_max as far up as the value allows, then seeded mixes of
+    col_max, 2^16, 0xFFFF and 0."""
+    rng = random.Random(seed)
+
+    def value(cols):
+        return sum(c << (16 * i) for i, c in enumerate(cols))
+
+    rows = []
+    while len(rows) < n:
+        pick = (lambda: col_max) if not rows else (
+            lambda: rng.choice((col_max, col_max, min(col_max, 1 << 16), 0xFFFF, 0)))
+        cols = [pick() for _ in range(32)]
+        k = 31
+        while value(cols) >= top:  # lower the top columns until the value fits
+            cols[k] = 0 if cols[k] == 0 or k > 30 else cols[k] >> 1
+            k = k - 1 if cols[k] == 0 else k
+        rows.append(cols)
+    return rows
+
+
+@pytest.mark.parametrize("lanes", [128, 1152])
+@pytest.mark.parametrize("field", ["mont-sm2p", "mont-p256", "mont-sm2n", "mont-bn254r"])
+def test_redc_takes_loose_limbs_and_raw_columns(field, lanes):
+    """REDC on inputs no canonical operand reaches: t with every limb at
+    carry_loose's bound 2^16 (either form), and a product's raw columns, up
+    to sqr_cols' bound in every column, which the shifted rows take as they
+    are and the product form refuses (its `· m'` would leave uint32)."""
+    F = FIELDS[field]
+    m = F.m_int
+    _, rinv = _raw(F)
+    top = m * _R
+    loose = _columns_under(top, 1 << 16, lanes, 46)
+    assert loose[0][:30] == [1 << 16] * 30
+    want = [sum(c << (16 * i) for i, c in enumerate(cols)) * rinv % m for cols in loose]
+    assert _ints(F.redc(_dense_rows(loose)), lanes) == want
+    raw = _columns_under(top, limb._SQR_COL_MAX, lanes, 47)
+    assert raw[0][:29] == [limb._SQR_COL_MAX] * 29
+    if F.m_terms is None:
+        with pytest.raises(ValueError):
+            F.redc(_dense_rows(raw), limb._SQR_COL_MAX)
+        return
+    want = [sum(c << (16 * i) for i, c in enumerate(cols)) * rinv % m for cols in raw]
+    assert _ints(F.redc(_dense_rows(raw), limb._SQR_COL_MAX), lanes) == want
+
+
+@pytest.mark.parametrize(
+    "c,width,fits",
+    [
+        pytest.param(_SM2P, 32, True, id="sm2p"),
+        pytest.param(-pow(_SM2P, -1, _R) % _R, 16, True, id="sm2p-mprime"),
+        pytest.param(0xFFFF, 16, True, id="one-limb"),
+        pytest.param(0x5555_5555, 16, True, id="sixteen-terms"),
+        pytest.param((1 << 255) + (1 << 20) - 1, 17, True, id="shift-15"),
+        pytest.param((1 << 255) + (1 << 47) + (1 << 31) - 1, 17, False, id="three-shifts-of-15"),
+    ],
+)
+def test_signed_rows_multiply_by_a_constant(c, width, fits):
+    """x · c by signed shifted rows against Python integers, modulo what
+    drops past the columns asked for; the bias under the negative rows is
+    a · 2^(16 · out); a sum that could leave uint32 raises when traced (three
+    rows shifted by 15 bits in one column; any constant on limbs of 2^31)."""
+    terms = limb.signed_terms(c, width)
+    assert _terms_value(terms) % (1 << (16 * width)) == c % (1 << (16 * width))
+    xs = _adversarial(_R, 128, 48, head=(_R - 1, _LOOSE_A))
+    x = _dense(xs)
+    x_max = 0xFFFF
+    with pytest.raises(ValueError):
+        limb.signed_rows(x, terms, width, 1 << 31)
+    if not fits:
+        with pytest.raises(ValueError):
+            limb.signed_rows(x, terms, width, x_max)
+        return
+    cols, a = limb.signed_rows(x, terms, width, x_max)
+    got = _ints(limb.carry_norm(cols), 128)
+    mod = 1 << (16 * width)
+    assert [g % mod for g in got] == [v * c % mod for v in xs]
+    if any(sign < 0 for _, _, sign in terms):
+        assert a > 0
+    # the same with columns added in: what REDC's second sum does with t
+    plus = _dense([v ^ 0x5A5A for v in xs], width)
+    cols, a = limb.signed_rows(x, terms, width, x_max, plus=plus, plus_max=0xFFFF)
+    got = _ints(limb.carry_norm(cols), 128)
+    assert [g % mod for g in got] == [(v * c + (v ^ 0x5A5A)) % mod for v in xs]
 
 
 @pytest.mark.parametrize("limbs", [16, 33, 48])
@@ -384,14 +543,20 @@ def _tool():
 
 
 # field -> (equations a multiplication may trace, limb-axis slices, the limbs
-# of its exact carry chains); before PR 27: 337 equations for p, 542 for n
-# (its nine-limb complement folds wider), 636 for REDC (three limb products:
-# 48 operand limbs), chains of 33, 20, 18, 17 limbs and 33, 17, 33, 33, 17
+# of its exact carry chains, its `mul` equations: a row product each, and a
+# fold's multiplications by the limbs of c); before PR 27: 337 equations for
+# p, 542 for n (its nine-limb complement folds wider), 636 for REDC (three
+# limb products: 48 operand limbs), chains of 33, 20, 18, 17 limbs and 33, 17,
+# 33, 33, 17. Since PR 46 a REDC whose constants are short signed sums
+# (SM2's p) holds one limb product, 16 row products where it held 48 (522
+# equations, 57 slices before); a dense modulus (SM2's n) keeps the three.
 MUL_CEILINGS = {
-    "fold-p": (265, 40, [17, 18]),
-    "fold-n": (440, 40, [17, 18]),
-    "mont-sm2p": (540, 64, [17, 17, 33]),
+    "fold-p": (265, 40, [17, 18], 20),
+    "fold-n": (440, 40, [17, 18], 43),
+    "mont-sm2p": (300, 32, [17, 17, 33], 16),
+    "mont-sm2n": (540, 64, [17, 17, 33], 48),
 }
+
 
 @pytest.mark.parametrize("field", sorted(MUL_CEILINGS))
 def test_field_mul_shuffles_no_limbs(field):
@@ -401,32 +566,46 @@ def test_field_mul_shuffles_no_limbs(field):
     placement is one pad. The op count cannot creep back unseen. Exact
     carry chains (``_carry_in``): the last fold and the conditional subtract
     of a fold (four before PR 27); m_val, t + m_val·m and the subtract of a
-    REDC (five before)."""
-    eqns, slices, chains = MUL_CEILINGS[field]
+    REDC (five before), in either form of it. The row products are pinned:
+    how often the shifted-sum REDC engages is static, not sampled."""
+    eqns, slices, chains, products = MUL_CEILINGS[field]
     a = jax.ShapeDtypeStruct((16, 8, 128), jnp.uint32)
     jaxpr = jax.make_jaxpr(FIELDS[field].mul)(a, a).jaxpr
     assert _count(jaxpr, ("concatenate",)) <= 8
     assert _count(jaxpr, ("slice",)) <= slices
     assert _count(jaxpr, _DYNAMIC_SHUFFLES) == 0
     assert len(jaxpr.eqns) <= eqns
+    assert _count(jaxpr, ("mul",)) == products
     tally = _tool().chain_tally(jaxpr)
     assert sorted(limbs for (_, limbs), n in tally.items() for _ in range(n)) == chains, tally
 
 
-@pytest.mark.parametrize("program,ceiling", [("secp", 16_500), ("sm", 43_500)])
-def test_admission_program_runs_few_exact_chains(program, ceiling):
+@pytest.mark.parametrize(
+    "program,ceiling,chains", [("secp", 16_500, 15_757), ("sm", 43_100, 37_480)]
+)
+def test_admission_program_runs_few_exact_chains(program, ceiling, chains):
     """Executed exact carry chains a call of the two admission programs,
     traced at 1,024 lanes (24,164 and 48,717 before PR 27), and their packed
     lookahead words, Σ ⌈limbs / 32⌉ (27,977 and 65,573): a later edit that
     puts a lookahead back where nothing consumes canonical limbs is seen here,
-    without a chip. Tracing only: nothing compiles."""
+    without a chip. PR 46 took REDC's two products by constants out of the SM
+    program and no chain in or out: 37,480 chains and 43,099 words as before
+    (5,618 REDCs a call, three chains each), and the secp program's 15,757.
+    Tracing only: nothing compiles."""
     from fisco_bcos_tpu.observability.device import LEDGER
 
     tool = _tool()
     LEDGER.reset()
-    totals = tool.chain_totals(tool.program_chains(program))
+    tally = tool.program_chains(program)
+    totals = tool.chain_totals(tally)
     assert LEDGER.cold_compile_count() == 0 and LEDGER.snapshot() == []
     assert 0 < totals["chains"] <= totals["words"] <= ceiling
+    assert totals["chains"] <= chains
+    if program == "sm":  # every REDC of the call is in the tally under its field operation
+        redcs = {c: n for (c, limbs), n in tally.items() if c.endswith("redc>carry_norm") and limbs == 33}
+        assert set(redcs) == {"MontField.mul>_redc_cols>redc>carry_norm",
+                              "MontField.sqr>_redc_cols>redc>carry_norm",
+                              "MontField.to_plain>redc>carry_norm"}, redcs
 
 
 def test_glv_window_step_stays_small():

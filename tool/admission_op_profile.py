@@ -106,7 +106,7 @@ def reduce_ops(path: str) -> dict:
 # -- the chain tally (tracing only) -------------------------------------------
 
 # frames of ops/limb.py between a field operation and _carry_in
-_CHAIN_HELPERS = ("_reduce_cols", "reduce_wide", "redc", "_table_fold")
+_CHAIN_HELPERS = ("_reduce_cols", "reduce_wide", "redc", "_redc_cols", "_table_fold")
 
 
 def _chain_caller(frames) -> str:
