@@ -1,25 +1,30 @@
 """The contract leg's counters, read for the window: what a deployed
-contract's call costs inside a block, which engine ran it, and what the
-thread that executes the block waited for its pool.
+contract's call costs inside a block, how many of them ran in the batch's
+contract frame, and which engine ran them.
 
 The driver (``drivers/air4_parallelok.py``) takes ``snapshot()`` at each edge
 of the window, beside ``dag_counters.py``'s; the readers
 ``layers/contract_tx_us_per_tx.py``, ``evm_call_us_per_tx.py``,
-``evm_native_call_share.py`` and ``dag_pool_wait_ms_per_block.py`` work on the
+``evm_native_call_share.py`` and ``contract_framed_tx_share.py`` work on the
 difference. The program adds to each once a batch (a DAG call, a serial
 batch), from sums its members carried back with their results:
 
 - ``fisco_executor_contract_txs_total`` / ``..._contract_tx_seconds_total``:
   block transactions whose callee is no registry precompile, and the seconds
-  inside ``_execute_one`` on the thread that ran each (a pool worker's
-  include its waits for the interpreter's lock);
+  each took, from the contract frame's entry to its receipt or inside
+  ``_execute_one``, on the thread that executes the block;
+  ``fisco_executor_contract_framed_txs_total``: those of them executed in the
+  batch's contract frame;
 - ``fisco_executor_evm_calls_total{engine="native"|"interpreter"}``: those
   transactions' top-level frames by the engine that finished them (the window's
   delta of ``native`` is one of ``correct``'s numbers: a call the Python
   interpreter ran or resumed is a wrong result there, not a slow one);
-  ``fisco_executor_evm_seconds_total``: their seconds inside the VM;
-- ``fisco_executor_dag_pool_wait_seconds_total``: the block-executing thread
-  inside ``fut.result()`` of a level's futures.
+  ``fisco_executor_evm_seconds_total``: their seconds inside the VM.
+
+``pool_wait_s`` (``fisco_executor_dag_pool_wait_seconds_total``: 0 since PR 41
+took the runner's pool out) has no reader here any more; the key stays in the
+snapshot because ``tests/test_contract_dag_block.py``, which is not the
+benchmark's to edit, reads it there, and goes with the program's counter.
 
 A counter the program does not have reads None here, and its reader None."""
 
@@ -27,6 +32,7 @@ from __future__ import annotations
 
 COUNTERS = {
     "contract_txs": "fisco_executor_contract_txs_total",
+    "contract_framed": "fisco_executor_contract_framed_txs_total",
     "contract_tx_s": "fisco_executor_contract_tx_seconds_total",
     "evm_native": 'fisco_executor_evm_calls_total{engine="native"}',
     "evm_interpreter": 'fisco_executor_evm_calls_total{engine="interpreter"}',

@@ -10,9 +10,8 @@ observation of ``fisco_executor_batch_latency_ms{mode="dag"}`` is one call):
 - ``fisco_executor_dag_levels_total``: dependent levels the blocks were cut into;
 - ``fisco_executor_dag_stage_seconds_total{stage}``: ``levelize`` (the
   conflict keys of every transaction and the levels), ``run`` (the levels'
-  execution, futures on the pool and inline), ``validate`` (the pairwise
-  check of a pooled level's read and write sets);
-- ``fisco_executor_dag_pooled_txs_total``: transactions executed as futures;
+  execution: framed, and member by member), ``validate`` (the pairwise check
+  of the read and write sets of every level wider than one);
 - ``fisco_executor_dag_conflict_reruns_total``: blocks executed again
   serially because a level's declarations lied (its window delta is one of
   ``correct``'s numbers);
@@ -49,7 +48,6 @@ def snapshot() -> dict:
     out = {
         "blocks": blocks, "loop_ms": loop_ms, "txs": txs,
         "levels": total("fisco_executor_dag_levels_total"),
-        "pooled_txs": total("fisco_executor_dag_pooled_txs_total"),
         "reruns": total("fisco_executor_dag_conflict_reruns_total") or 0.0,
     }
     for stage in STAGES:

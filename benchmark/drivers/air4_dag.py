@@ -316,7 +316,7 @@ class Cell(air4.Cell):
         if hasattr(self, "stage_lines"):
             logging.getLogger("scheduler").removeHandler(self.stage_lines)
         split = {}
-        for key in ("loop_ms", "levelize_ms", "run_ms", "validate_ms", "levels", "pooled_txs", "txs"):
+        for key in ("loop_ms", "levelize_ms", "run_ms", "validate_ms", "levels", "txs"):
             got = dag_counters.window(self, key)
             if got is not None:
                 split[key] = round(got[0] / got[1], 3)

@@ -5,12 +5,11 @@ replica) under calls to a Solidity contract and not to a registry precompile:
 ``ParallelOk.transfer(from, to, num)`` between accounts that exist, every
 transaction carrying the DAG attribute, the contract's ABI declaring
 ``conflictFields`` on the two names. So every member of a level is bytecode on
-the EVM engine through ``_execute_one``, and the members of a level wider than
-one are futures on the runner's thread pool.
+the EVM engine, executed on the thread that executes the block, in index
+order, in the batch's contract frame.
 
-The driver sets nothing in the node (no ``FISCO_DAG_WORKERS``, no
-``FISCO_NO_NATIVE_EVM``, no ``FISCO_DAG_SERIAL``): the node's own rules choose
-the pool and the engine.
+The driver sets nothing in the node (no ``FISCO_NO_NATIVE_EVM``, no
+``FISCO_DAG_SERIAL``): the node's own rules choose the frame and the engine.
 
 What differs from ``air4_dag``:
 
@@ -31,7 +30,11 @@ What differs from ``air4_dag``:
   ``fisco_executor_evm_calls_total{engine="native"}``, and the same delta of
   ``engine="interpreter"`` (``calls_the_python_interpreter_ran``). A block the
   Python interpreter quietly executed is then a wrong result and not a slow
-  one. One more control reads the native counter one call short.
+  one. One more control reads the native counter one call short;
+- the window's edges carry ``contract_counters.snapshot()``: the contract leg's
+  readers (``contract_tx_us_per_tx``, ``evm_call_us_per_tx``,
+  ``evm_native_call_share``, ``contract_framed_tx_share``) are entries of
+  ``BENCHMARK.json`` and say all of it in a ``--trace 1`` line.
 
 A checkout whose program does not say which engine finished a call cannot show
 that: it leaves at once with the harness's "no program" code, before any chain
@@ -41,17 +44,11 @@ from __future__ import annotations
 
 import random
 import sys
-import types
 
-from benchmark import contract_counters, manifest, refcontract
+from benchmark import contract_counters, refcontract
 from benchmark.drivers import air4_dag
 
 SAMPLE_CALLS = 256  # accounts read back through the read-only balanceOf
-# the contract leg's readers under benchmark/layers/. BENCHMARK.json cannot list
-# them yet (PERF.md, Open questions), so every run says them on standard error,
-# beside the DAG counters' line
-READINGS = ("contract_tx_us_per_tx", "evm_call_us_per_tx", "evm_native_call_share",
-            "dag_pool_wait_ms_per_block")
 
 
 class Cell(air4_dag.Cell):
@@ -237,11 +234,3 @@ class Cell(air4_dag.Cell):
             seen["native_calls"] -= 1
 
         return dict(super().controls(), one_call_short=one_call_short)
-
-    def close(self) -> None:
-        ctx = types.SimpleNamespace(cell=self)
-        split = {name: manifest.reader_of(name)(ctx) for name in READINGS}
-        for key in ("contract_txs", "evm_native", "evm_interpreter"):
-            split[key] = contract_counters.window(self, key)
-        print(f"contract leg, all replicas over the window: {split}", file=sys.stderr)
-        super().close()

@@ -35,10 +35,15 @@ What differs from ``air4``:
   ``benchmark/refsmroot.py`` over the plain SM3 of the bytes the replica
   stored. Balances are ``air4.compare``'s dict replay. Every limit is 0.
 
-Every run says once on standard error what the SM leg cost, from the
-program's own counters over the window: ``sm leg, all replicas over the
-window: {...}`` (the trace's device time by program is on the ``trace`` line
-of a ``--trace 1`` run: the harness hands the reduction to the readers only).
+The window's edges carry ``sm_counters.snapshot()``. What the SM leg cost is
+the manifest's to say (``device_leg_share.flood``, ``device_sync_ms_per_block.flood``,
+``sm_admission_sync_ms_per_call``, ``merkle_fused_call_share`` in a ``--trace 1``
+line). Every run still says once on standard error the whole counts no entry
+carries and ``tests/benchmark_checks/test_sm_chain_cell.py`` holds: what each
+op of the SM leg was given over the window, by the hasher its series names,
+and its calls by leg (``sm leg, counts by op over the window: {...}``: every
+merkle level under ``items_sm3``, no ``sm2_verify`` row, no
+``admission_native`` row). No milliseconds: those are the entries'.
 
 A checkout whose program has no fused SM admission leaves at once with the
 harness's "no program" code, before any chain or compile."""
@@ -52,8 +57,7 @@ import sys
 import threading
 import time
 
-from benchmark import refsm, refsmroot
-from benchmark.mesh_counters import _labels
+from benchmark import refsm, refsmroot, sm_counters
 from benchmark.drivers import air4
 from benchmark.drivers.air4 import WARM_BATCHES
 
@@ -62,11 +66,6 @@ from benchmark.drivers.air4 import WARM_BATCHES
 # some 6 s (a thousand plain SM3 a block): inside the 30 s the cell gives them
 SAMPLE_TXS = 256
 SAMPLE_ROOTS = 8  # window blocks whose transactions root is rebuilt in plain SM3
-# what the line on standard error splits: the admission seam's calls by leg,
-# the fused program, the SM3 programs by use (hash plane batches, merkle
-# levels), the QC's batch verification by leg
-_OPS = ("admission", "admission_sm", "admission_native", "sm3", "merkle_root", "merkle_tree",
-        "sm2_verify")
 
 
 @functools.lru_cache(maxsize=None)
@@ -86,39 +85,6 @@ def _plain_admits(data: bytes, sig: bytes, secret: int, ack_hash: bytes, ack_sen
 @functools.lru_cache(maxsize=4 * SAMPLE_ROOTS)
 def _plain_root(payloads: tuple) -> bytes:
     return refsmroot.txs_root(list(payloads))
-
-
-def snapshot() -> dict:
-    """The SM leg's counters as they stand, by op: the items
-    (``fisco_device_items_total``; ``items_<hasher>`` where the series names
-    one, so a merkle level hashed under keccak on this chain would show beside
-    ``items_sm3``), the host-call milliseconds, the measured
-    phases, and the dispatches by leg (``fisco_device_dispatch_path_total``:
-    ``calls_<path>``)."""
-    from fisco_bcos_tpu.observability.device import DEVICE_PHASE_BUCKETS_MS
-    from fisco_bcos_tpu.utils.metrics import REGISTRY
-
-    by_op: dict[str, dict[str, float]] = {}
-
-    def add(op: str, key: str, value: float) -> None:
-        if op in _OPS:
-            row = by_op.setdefault(op, {})
-            row[key] = row.get(key, 0.0) + value
-
-    for name, v in REGISTRY.counters_matching("fisco_device_items_total{").items():
-        labels = _labels(name)  # the merkle programs' series say their hasher
-        add(labels["op"], "_".join(filter(None, ("items", labels.get("hasher")))), v)
-    for name, v in REGISTRY.counters_matching("fisco_device_op_seconds_total{").items():
-        add(_labels(name)["op"], "ms", v * 1e3)
-    for name, v in REGISTRY.counters_matching("fisco_device_dispatch_path_total{").items():
-        labels = _labels(name)
-        add(labels["op"], "calls_" + labels["path"], v)
-    phases = REGISTRY.histogram(
-        "fisco_device_phase_ms", buckets=DEVICE_PHASE_BUCKETS_MS).snapshot()
-    for labels, (_cum, s, _n) in phases.items():
-        labels = dict(labels)
-        add(labels.get("op", ""), labels.get("phase", "") + "_ms", s)
-    return by_op
 
 
 class Cell(air4.Cell):
@@ -210,9 +176,9 @@ class Cell(air4.Cell):
     # -- the window ----------------------------------------------------------
 
     def window(self, seconds: float) -> None:
-        self.sm0 = snapshot()
+        self.sm0 = sm_counters.snapshot()
         super().window(seconds)
-        self.sm1 = snapshot()
+        self.sm1 = sm_counters.snapshot()
 
     # -- correct -------------------------------------------------------------
 
@@ -279,8 +245,8 @@ class Cell(air4.Cell):
             })
         return {"replicas": replicas, "picks": picks,
                 "acks": {k: list(v) for k, v in self.acks.items()},
-                "device_calls": self._delta("admission", "calls_device"),
-                "fused_lanes": self._delta("admission_sm", "items")}
+                "device_calls": sm_counters.window(self, "admission", "calls_device"),
+                "fused_lanes": sm_counters.window(self, "admission_sm", "items")}
 
     def compare(self, seen: dict) -> list[dict]:
         """``air4``'s eight numbers, the sample re-derived through plain
@@ -336,27 +302,19 @@ class Cell(air4.Cell):
 
     def close(self) -> None:
         if hasattr(self, "sm1"):
-            print(f"sm leg, all replicas over the window: {self._sm_leg()}", file=sys.stderr)
+            print(f"sm leg, counts by op over the window: {self._sm_counts()}", file=sys.stderr)
         if hasattr(self, "nodes"):
             super().close()
 
-    def _delta(self, op: str, key: str) -> float:
-        """The window's delta of one of ``snapshot``'s counters."""
-        return self.sm1.get(op, {}).get(key, 0.0) - self.sm0.get(op, {}).get(key, 0.0)
-
-    def _sm_leg(self) -> dict:
-        """The window's deltas of the SM leg's counters: milliseconds a
-        block, counts whole."""
-        blocks = max(self.window_blocks, 1)
+    def _sm_counts(self) -> dict:
+        """The window's deltas of the SM leg's whole counts by op: the items it
+        was given (by hasher where the series names one) and its calls by leg.
+        A count that did not move, and an op none of whose did, is left out."""
         out: dict = {"blocks": self.window_blocks}
         for op, after in sorted(self.sm1.items()):
-            row = {key: self._delta(op, key) for key in after}
-            moved = {k: round(v / blocks, 3) if k == "ms" or k.endswith("_ms") else v
-                     for k, v in row.items() if v}
+            moved = {key: sm_counters.window(self, op, key)
+                     for key in after if key.startswith(("items", "calls_"))}
+            moved = {k: v for k, v in moved.items() if v}
             if moved:
                 out[op] = moved
-        calls = self._delta("admission", "calls_device")
-        if calls:
-            out["admission_sm_sync_ms_per_call"] = round(
-                self._delta("admission_sm", "sync_ms") / calls, 3)
         return out

@@ -193,6 +193,15 @@ class ServingPeer:
 
 
 class Cell:
+    # The window admits nothing: the backlog entered the pool in set-up, and a
+    # downloaded block's transactions are re-verified by BlockSync, not
+    # submitted. So the window writes no ``txpool.submit_batch``, ``txsync.push``
+    # or ``txsync.maintain`` span, the ``admit_*`` quantities find nothing to read
+    # (``stage_parts.py``), and the manifest keeps this driver's cells off their
+    # lists: stated here once, read by ``tests/benchmark_checks/manifest_rules.py``.
+    # A driver that does not say so admits in its window.
+    window_admits = False
+
     def __init__(self, config: dict, traffic: dict, seed: int, spans):
         self.config, self.traffic, self.seed = config, traffic, seed
         self.spans = spans

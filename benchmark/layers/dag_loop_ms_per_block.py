@@ -1,7 +1,7 @@
 """Sealer, PBFT, scheduler, storage: one replica's whole ``dag_execute_transactions``
 call (the sum of ``fisco_executor_batch_latency_ms{mode="dag"}``), its mean a
 DAG block of the window: levelize + run + validate and what they leave out
-(the pool opened and joined, the shadow overlay merged).
+(the frames and the shadow overlay set up and merged).
 Rule: ``benchmark/dag_counters.py``."""
 
 from benchmark import dag_counters

@@ -1,5 +1,5 @@
-"""Sealer, PBFT, scheduler, storage: the pairwise check of every pooled level's
-read and write sets (``fisco_executor_dag_stage_seconds_total{stage="validate"}``),
+"""Sealer, PBFT, scheduler, storage: the pairwise check of the read and write
+sets of every level wider than one (``fisco_executor_dag_stage_seconds_total{stage="validate"}``),
 one replica's mean a DAG block of the window.
 Rule: ``benchmark/dag_counters.py``."""
 

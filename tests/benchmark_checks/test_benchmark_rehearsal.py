@@ -17,20 +17,14 @@ from benchmark import checks, manifest, run
 from benchmark.generators import signed_payloads, transfer_batches
 from benchmark.spans import Spans
 
-TINY = {
-    "flood": {"batch_txs": 8, "corpus_batches": 3},
-    "paced": {"batch_txs": 8, "tick_s": 0.3},
-    "stream": {"lanes": 16, "signers": 4, "rotations": 2},
-}
 CELLS = [w["name"] for w in manifest.load()["workloads"]]
 SEED = 2**31 + 12345  # the driver's seeds are large
 
 
 @pytest.fixture(autouse=True)
 def tiny_traffic(monkeypatch):
-    real = manifest.traffic_of
-    monkeypatch.setattr(
-        manifest, "traffic_of", lambda name, *a: dict(real(name, *a), **TINY[name]))
+    """Every mix at the tiny sizes its own file states (``tiny``)."""
+    monkeypatch.setattr(manifest, "traffic_of", manifest.tiny_traffic_of)
 
 
 def _run(workload, seconds=0.7):

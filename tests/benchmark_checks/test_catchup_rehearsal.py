@@ -20,7 +20,6 @@ from benchmark import manifest, refsync, run
 from benchmark.spans import Spans
 
 CELL = "air4-catchup.backlog"
-TINY = {"batch_txs": 8, "backlog_blocks": 6, "corpus_batches": 9, "trace_blocks": 3, "senders": 4}
 SEED = 2**31 + 30303  # the driver's seeds are large
 HERE = os.path.dirname(os.path.abspath(__file__))
 COMPARED = [
@@ -41,8 +40,7 @@ SYNC_LAYERS = [
 def tiny(monkeypatch):
     from fisco_bcos_tpu.sync import block_sync
 
-    real = manifest.traffic_of
-    monkeypatch.setattr(manifest, "traffic_of", lambda name, *a: dict(real(name, *a), **TINY))
+    monkeypatch.setattr(manifest, "traffic_of", manifest.tiny_traffic_of)
     monkeypatch.setattr(block_sync, "VERIFY_LANES_MAX", 24)  # three blocks of eight
 
 

@@ -4,6 +4,7 @@ the counter (the parent), and on a block committed through a scheduler here."""
 
 import pytest
 
+import manifest_rules as rules
 from benchmark import manifest
 from fisco_bcos_tpu.utils import metrics
 
@@ -26,19 +27,23 @@ def registry(monkeypatch):
     return fresh
 
 
+# the cells each list had when this file was written: still on it, at the front
+FRONT = {"commit_moved_row_share.flood": ["air4-transfer.flood", "air4-catchup.backlog",
+                                          "air4-dagtransfer.flood"],
+         "commit_moved_row_share.paced": ["air4-transfer.paced"]}
+
+
+def manifest_rule(doc):
+    for name, front in FRONT.items():
+        entry = rules.entry_of(doc, name)
+        assert {k: v for k, v in entry.items() if k != "workloads"} == {
+            "name": name, "unit": "%", "better": "higher", "source": "program_counter",
+            "layer": LAYER, "moves": rules.SUFFIX_MOVES[name.partition(".")[2]]}
+        rules.list_holds(doc, entry, front)
+
+
 def test_the_two_entries_are_counters_of_the_schedulers_layer_in_the_chain_cells():
-    entries = {m["name"]: m for m in manifest.load()["per_layer"]
-               if m["name"].startswith("commit_moved_row_share")}
-    common = {"unit": "%", "better": "higher", "source": "program_counter", "layer": LAYER}
-    assert entries == {
-        "commit_moved_row_share.flood": {
-            "name": "commit_moved_row_share.flood", **common, "moves": "committed_tps",
-            "workloads": ["air4-transfer.flood", "air4-catchup.backlog",
-                          "air4-dagtransfer.flood"]},
-        "commit_moved_row_share.paced": {
-            "name": "commit_moved_row_share.paced", **common, "moves": "commit_p50_ms",
-            "workloads": ["air4-transfer.paced"]},
-    }
+    manifest_rule(manifest.load())
     # one reader for both
     assert manifest.reader_path("commit_moved_row_share.flood") == \
         manifest.reader_path("commit_moved_row_share.paced")

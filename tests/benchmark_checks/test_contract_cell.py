@@ -1,6 +1,6 @@
 """The deployed-contract cell's own pieces, on the CPU: the configuration's
 file beside the precompile cell's, the manifest's lists the cell joined, the
-four readers of the contract leg on hand-made snapshots and on the program's
+four entries of the contract leg on hand-made snapshots and on the program's
 registry, and the driver ``air4_parallelok`` at the rehearsal's size: a block
 the Python interpreter executed is not correct, the engine counter read one
 call short is not correct, and a program that does not say which engine
@@ -11,16 +11,18 @@ import types
 
 import pytest
 
+import manifest_rules as rules
 from benchmark import contract_counters, manifest, run
 from benchmark.run import judge
 from benchmark.spans import Spans
 
 CELL = "air4-parallelok.flood"
 SIBLING = "air4-dagtransfer.flood"
-TINY = {"batch_txs": 8, "corpus_batches": 3}
 SEED = 2**31 + 40040  # the driver's seeds are large
+LAYER = "Sealer, PBFT, scheduler, storage"
+# the contract leg's own entries: this cell's alone when this file was written
 READERS = ("contract_tx_us_per_tx", "evm_call_us_per_tx", "evm_native_call_share",
-           "dag_pool_wait_ms_per_block")
+           "contract_framed_tx_share")
 
 
 def test_the_configuration_is_the_precompile_cells_where_the_deployment_is_the_same():
@@ -45,8 +47,7 @@ def test_the_configuration_is_the_precompile_cells_where_the_deployment_is_the_s
 JOINED = ("consensus_ms_per_block.flood", "seal_execute_ms_per_block.flood",
           "seal_commit_ms_per_block.flood", "admission_ms_per_block.flood",
           "device_idle_share.flood", "window_compiles.flood", "dag_levels_per_block",
-          "dag_loop_ms_per_block", "dag_pooled_tx_share", "dag_reruns_per_block")
-
+          "dag_loop_ms_per_block", "dag_reruns_per_block")
 
 @pytest.mark.parametrize("name", JOINED + ("committed_tps",))
 def test_the_cell_is_on_the_list_of_a_metric_its_sibling_reports(name):
@@ -55,42 +56,64 @@ def test_the_cell_is_on_the_list_of_a_metric_its_sibling_reports(name):
     assert CELL in entry["workloads"] and SIBLING in entry["workloads"]
 
 
-def test_every_list_the_cell_is_on_is_one_its_sibling_is_on():
+def reader_entry_holds(doc, name):
+    entry = rules.entry_of(doc, name)
+    assert (entry["source"], entry["moves"], entry["layer"]) == (
+        "program_counter", "committed_tps", LAYER)
+    rules.list_holds(doc, entry, [CELL])
+    assert manifest.reader_path(name).endswith(f"benchmark/layers/{name}.py")
+
+
+def manifest_rule(doc):
+    """The cell stands, behind its sibling, on the lists that split a block; the
+    contract leg's entries are counters of the scheduler's layer that it was the
+    first on; and every list it is on holds to ``manifest_rules``."""
+    for name in JOINED + rules.BLOCK_SPLIT:
+        listed = rules.entry_of(doc, name)["workloads"]
+        assert SIBLING in listed and listed.index(CELL) > listed.index(SIBLING), name
+    for name in READERS:
+        reader_entry_holds(doc, name)
+    for name in rules.listing(doc, CELL):
+        entry = rules.entry_of(doc, name)
+        assert entry["moves"] == "committed_tps"
+        rules.list_holds(doc, entry, ())
+
+
+def test_the_lists_the_cell_is_on_hold_to_the_manifests_rule():
+    manifest_rule(manifest.load())
+
+
+def test_no_entry_reads_the_pool_that_pr_41_took_out():
     doc = manifest.load()
-    joined = {m["name"] for m in doc["per_layer"] if CELL in m.get("workloads", ())}
-    siblings = {m["name"] for m in doc["per_layer"] if SIBLING in m.get("workloads", ())}
-    assert joined - siblings <= set(READERS)  # the contract leg's own, once they are entries
+    assert not [m["name"] for m in doc["per_layer"] if "pool" in m["name"]]
+    with pytest.raises(SystemExit):
+        manifest.reader_of("dag_pooled_tx_share")
+    with pytest.raises(SystemExit):
+        manifest.reader_of("dag_pool_wait_ms_per_block")
 
 
 @pytest.mark.parametrize("name", READERS)
-def test_a_reader_that_is_an_entry_is_a_counter_of_this_cell(name):
-    entries = [m for m in manifest.load()["per_layer"] if m["name"] == name]
-    if not entries:
-        pytest.skip("BENCHMARK.json does not list the reader yet (PERF.md, Open questions)")
-    (entry,) = entries
-    assert (entry["source"], entry["moves"]) == ("program_counter", "committed_tps")
-    assert CELL in entry["workloads"]
-    assert manifest.reader_path(name).endswith(f"benchmark/layers/{name}.py")
+def test_a_reader_of_the_contract_leg_is_an_entry_of_this_cell(name):
+    reader_entry_holds(manifest.load(), name)
 
 
 # -- the readers on hand-made snapshots -----------------------------------------
 
 
-def _snap(txs=0.0, tx_s=0.0, native=0.0, interpreted=0.0, evm_s=0.0, wait_s=0.0):
+def _snap(txs=0.0, tx_s=0.0, native=0.0, interpreted=0.0, evm_s=0.0, framed=0.0):
     return {"contract_txs": txs, "contract_tx_s": tx_s, "evm_native": native,
-            "evm_interpreter": interpreted, "evm_s": evm_s, "pool_wait_s": wait_s}
+            "evm_interpreter": interpreted, "evm_s": evm_s, "contract_framed": framed}
 
 
-def _ctx(before=None, after=None, dag_blocks=8):
+def _ctx(before=None, after=None):
     cell = types.SimpleNamespace()
     if before is not None:
         cell.contract0, cell.contract1 = before, after
-        cell.dag0, cell.dag1 = {"blocks": 0, "levels": 0.0}, {"blocks": dag_blocks, "levels": 9.0}
     return types.SimpleNamespace(cell=cell)
 
 
-WINDOW = _ctx(_snap(txs=4000, tx_s=1.0, native=4000, evm_s=0.5, wait_s=0.25),
-              _snap(txs=12000, tx_s=5.0, native=11000, interpreted=1000, evm_s=2.5, wait_s=2.25))
+WINDOW = _ctx(_snap(txs=4000, tx_s=1.0, native=4000, evm_s=0.5, framed=4000),
+              _snap(txs=12000, tx_s=5.0, native=11000, interpreted=1000, evm_s=2.5, framed=10000))
 NO_COUNTERS = _ctx(dict.fromkeys(_snap(), None), dict.fromkeys(_snap(), None))
 
 
@@ -98,7 +121,7 @@ NO_COUNTERS = _ctx(dict.fromkeys(_snap(), None), dict.fromkeys(_snap(), None))
     ("contract_tx_us_per_tx", 500.0),  # 4 s over 8,000 calls
     ("evm_call_us_per_tx", 250.0),
     ("evm_native_call_share", 87.5),  # 7,000 of 8,000
-    ("dag_pool_wait_ms_per_block", 250.0),  # 2 s over 8 DAG calls
+    ("contract_framed_tx_share", 75.0),  # 6,000 of 8,000: the frame stood aside for the rest
 ])
 def test_a_reader_gives_the_windows_delta(reader, want):
     assert manifest.reader_of(reader)(WINDOW) == pytest.approx(want)
@@ -108,7 +131,7 @@ def test_a_reader_gives_the_windows_delta(reader, want):
 @pytest.mark.parametrize("case,ctx", [
     ("a program without the counters", NO_COUNTERS),
     ("a driver that took no snapshots", _ctx()),
-    ("a window in which nothing ran", _ctx(_snap(), _snap(), dag_blocks=0)),
+    ("a window in which nothing ran", _ctx(_snap(), _snap())),
 ], ids=lambda v: v.replace(" ", "_") if isinstance(v, str) else None)
 def test_a_reader_gives_none_where_there_is_nothing_to_read(reader, case, ctx):
     assert manifest.reader_of(reader)(ctx) is None
@@ -117,6 +140,7 @@ def test_a_reader_gives_none_where_there_is_nothing_to_read(reader, case, ctx):
 def test_a_counter_first_seen_inside_the_window_counts_from_zero():
     ctx = _ctx(dict.fromkeys(_snap(), None), _snap(txs=10, tx_s=0.001, native=10, evm_s=0.0005))
     assert manifest.reader_of("evm_native_call_share")(ctx) == 100.0
+    assert manifest.reader_of("contract_framed_tx_share")(ctx) == 0.0  # seen, and did not move
     assert manifest.reader_of("contract_tx_us_per_tx")(ctx) == pytest.approx(100.0)
 
 
@@ -142,7 +166,7 @@ def test_snapshot_reads_the_programs_counters():
 def _cell():
     doc = manifest.load()
     config = manifest.config_of(doc, "air4-parallelok")
-    traffic = dict(manifest.traffic_of("flood"), **TINY)
+    traffic = manifest.tiny_traffic_of("flood")
     return manifest.driver_of(config).Cell(config, traffic, SEED, Spans())
 
 
@@ -164,8 +188,8 @@ def _mute(_msg):
     pass
 
 
-def test_a_sound_run_holds_every_number_and_prints_the_contract_legs_readings(monkeypatch, capsys):
-    for name in ("FISCO_NO_NATIVE_EVM", "FISCO_DAG_WORKERS", "FISCO_DAG_SERIAL"):
+def test_a_sound_run_holds_every_number_and_the_contract_legs_entries_read_it(monkeypatch, capsys):
+    for name in ("FISCO_NO_NATIVE_EVM", "FISCO_DAG_SERIAL"):
         monkeypatch.delenv(name, raising=False)
     cell = _cell()
     seen = _drive(cell)
@@ -175,10 +199,15 @@ def test_a_sound_run_holds_every_number_and_prints_the_contract_legs_readings(mo
     assert len(seen["replicas"][0]["balances"]) == 80 == len(cell.corpus.names)
     assert set(cell.controls()) == {"lost_write", "forked_root", "truncated_digest",
                                     "accepted_corrupt", "lost_update", "one_call_short"}
-    said = capsys.readouterr().err
-    readings = said.split("contract leg, all replicas over the window: ")[1].splitlines()[0]
-    assert all(f"'{name}': " in readings and f"'{name}': None" not in readings for name in READERS)
-    assert "'evm_native_call_share': 100.0" in readings and "'evm_interpreter': 0.0" in readings
+    # what the driver's line on standard error said is the entries' to say now
+    assert "contract leg" not in capsys.readouterr().err
+    ctx = types.SimpleNamespace(cell=cell)
+    readings = {name: manifest.reader_of(name)(ctx) for name in READERS}
+    assert None not in readings.values() and all(v > 0 for v in readings.values())
+    assert readings["evm_native_call_share"] == 100.0
+    assert readings["contract_framed_tx_share"] == 100.0
+    assert readings["evm_call_us_per_tx"] < readings["contract_tx_us_per_tx"]
+    assert contract_counters.window(cell, "evm_interpreter") == 0.0
     cell.controls()["one_call_short"](seen)
     assert _values(cell.compare(seen))["calls_not_on_the_native_engine"] == 1
     seen["native_calls"] += 2  # a block counted twice is no sounder than a call missed
@@ -203,8 +232,7 @@ def test_a_program_that_does_not_name_the_engine_leaves_at_once(monkeypatch, cap
     from fisco_bcos_tpu.executor.evm import EVMResult
 
     monkeypatch.delitem(EVMResult.__dataclass_fields__, "engine")
-    real = manifest.traffic_of
-    monkeypatch.setattr(manifest, "traffic_of", lambda name, *a: dict(real(name, *a), **TINY))
+    monkeypatch.setattr(manifest, "traffic_of", manifest.tiny_traffic_of)
     args = run.parse(["--workload", CELL, "--seed", str(SEED), "--seconds", "0.3", "--trace", "0"])
     with pytest.raises(SystemExit) as e:
         run.run(args, require_chip=False, out=io.StringIO())

@@ -17,7 +17,6 @@ from benchmark.run import judge
 from benchmark.spans import Spans
 
 CELL = "verify10k-quad.stream"
-TINY = {"lanes": 16, "signers": 4, "rotations": 2}
 SEED = 2**31 + 38038  # the driver's seeds are large
 
 
@@ -125,7 +124,7 @@ def test_snapshot_reads_the_programs_counters():
 def _cell(devices: int):
     doc = manifest.load()
     config = dict(manifest.config_of(doc, "verify10k-quad"), devices=devices)
-    traffic = dict(manifest.traffic_of("stream"), **TINY)
+    traffic = manifest.tiny_traffic_of("stream")
     return manifest.driver_of(config).Cell(config, traffic, SEED, Spans())
 
 
@@ -213,8 +212,7 @@ def test_a_program_without_the_counter_leaves_at_once(monkeypatch, capsys):
     from fisco_bcos_tpu.observability.device import CompileLedger
 
     monkeypatch.delattr(CompileLedger, "note_mesh_call")
-    real = manifest.traffic_of
-    monkeypatch.setattr(manifest, "traffic_of", lambda name, *a: dict(real(name, *a), **TINY))
+    monkeypatch.setattr(manifest, "traffic_of", manifest.tiny_traffic_of)
     args = run.parse(["--workload", CELL, "--seed", str(SEED), "--seconds", "0.3", "--trace", "0"])
     with pytest.raises(SystemExit) as e:
         run.run(args, require_chip=False, out=io.StringIO())

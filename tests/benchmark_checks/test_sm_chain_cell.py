@@ -5,7 +5,7 @@ driver ``air4_sm`` at the rehearsal's size with the device leg pinned
 (``FISCO_FORCE_DEVICE_ADMISSION=1``: the fused SM program on the CPU's XLA, a
 few seconds a call), traced through the harness and untraced through the
 driver: ``correct`` true with every number 0, the seal identity
-``test_program_spans.py`` holds the ``air4*`` cells to, each of the eight
+``test_program_spans.py`` holds every cell that seals blocks to, each of the eight
 controls not correct, a window the native loop answered for not correct, and a
 program without the fused SM admission leaving at once.
 
@@ -19,10 +19,12 @@ import contextlib
 import copy
 import io
 import json
+import types
 
 import pytest
 
-from benchmark import manifest, refsm, run
+import manifest_rules as rules
+from benchmark import manifest, refsm, run, sm_counters
 from benchmark.generators import sm_transfer_batches
 from benchmark.generators.sm_signed_payloads import BROKEN
 from benchmark.run import judge
@@ -30,9 +32,8 @@ from benchmark.spans import Spans
 
 CELL = "sm-air4-transfer.flood"
 SIBLING = "air4-parallelok.flood"
-TINY = {"batch_txs": 8, "corpus_batches": 3}
 SEED = 2**31 + 44044  # the driver's seeds are large
-# the 18 lists: what a chain cell under the flood reports and no test pins
+# the 18 lists the cell arrived on (PR 44): what a chain cell under the flood reports
 JOINED = (
     "admission_ms_per_block.flood", "consensus_ms_per_block.flood", "plane_queue_ms.flood",
     "device_leg_share.flood", "admission_us_per_sig.flood", "hash_device_ms_per_block.flood",
@@ -43,6 +44,8 @@ JOINED = (
     "device_sync_ms_per_block.flood", "gc_pause_ms_per_block.flood",
     "background_ms_per_block.flood",
 )
+# the SM leg's own entries: this cell's alone when this file was written
+OWN = {"merkle_fused_call_share": "Device programs", "sm_admission_sync_ms_per_call": "Crypto seam"}
 NUMBERS = (
     "valid_not_acknowledged", "acknowledged_not_committed", "balances_differing_from_replay",
     "sampled_txs_differing_from_plain_crypto", "corrupted_lanes_accepted",
@@ -61,15 +64,19 @@ def test_the_cell_is_one_chip_of_the_sm_chain_under_the_flood_as_it_is():
     doc = manifest.load()
     assert manifest.cell(doc, CELL) == dict(
         manifest.cell(doc, CELL), config="sm-air4-transfer", traffic="flood", chips=1)
-    assert doc["workloads"][-1]["name"] == CELL and doc["configs"][-1]["name"] == "sm-air4-transfer"
+    # the ninth cell and the eighth configuration, where PR 44 appended them
+    assert doc["workloads"][8]["name"] == CELL and doc["configs"][7]["name"] == "sm-air4-transfer"
+    # the mix as a run is given it: the parent's, key for key (its tiny sizes are left out)
     assert manifest.traffic_of("flood") == {
         "generator": "transfer_batches",
         "loop": "backlog: the next full block is offered as soon as the last one is "
                 "committed on all replicas",
         "batch_txs": 1000, "tick_s": 0, "senders": 64, "corpus_batches": 120, "trace_blocks": 1,
     }
+    assert manifest.tiny_traffic_of("flood") == dict(
+        manifest.traffic_of("flood"), batch_txs=8, corpus_batches=3)
     (tps,) = [m for m in doc["end_to_end"] if m["name"] == "committed_tps"]
-    assert tps["workloads"][-1] == CELL
+    assert tps["workloads"][:5][-1] == CELL
     assert {m["name"] for m in manifest.metrics_of(doc, "end_to_end", CELL)} == {
         "committed_tps", "setup_s"}
 
@@ -77,16 +84,34 @@ def test_the_cell_is_one_chip_of_the_sm_chain_under_the_flood_as_it_is():
 @pytest.mark.parametrize("name", JOINED)
 def test_the_cell_is_appended_to_a_list_its_sibling_is_on(name):
     (entry,) = [m for m in manifest.load()["per_layer"] if m["name"] == name]
-    assert entry["workloads"][-1] == CELL and SIBLING in entry["workloads"]
+    listed = entry["workloads"]
+    assert listed.index(CELL) == listed.index(SIBLING) + 1  # appended behind it, nothing moved
     assert entry["moves"] == "committed_tps"
 
 
-def test_the_cell_is_on_those_lists_and_no_other():
-    doc = manifest.load()
-    mine = {m["name"] for m in doc["per_layer"] if CELL in m.get("workloads", ())}
-    assert mine == set(JOINED) and len(JOINED) == 18
-    siblings = {m["name"] for m in doc["per_layer"] if SIBLING in m.get("workloads", ())}
-    assert siblings - mine == {m for m in siblings if m.startswith("dag_")}
+def manifest_rule(doc):
+    """The cell stands right behind its sibling on the 18 lists it arrived on and
+    on the 22 that split a block; the SM leg's two entries are counters it was
+    the first on; and every list it is on holds to ``manifest_rules``."""
+    assert len(JOINED) == 18 and len(rules.BLOCK_SPLIT) == 22
+    for name in JOINED + rules.BLOCK_SPLIT:
+        listed = rules.entry_of(doc, name)["workloads"]
+        assert listed.index(CELL) == listed.index(SIBLING) + 1, name
+    for name, layer in OWN.items():
+        entry = rules.entry_of(doc, name)
+        assert (entry["source"], entry["moves"], entry["layer"]) == (
+            "program_counter", "committed_tps", layer)
+        rules.list_holds(doc, entry, [CELL])
+    mine = set(rules.listing(doc, CELL))
+    assert set(JOINED) | set(rules.BLOCK_SPLIT) | set(OWN) <= mine
+    for name in mine:
+        entry = rules.entry_of(doc, name)
+        assert entry["moves"] == "committed_tps"
+        rules.list_holds(doc, entry, ())
+
+
+def test_the_cell_is_on_those_lists_and_they_hold_to_the_manifests_rule():
+    manifest_rule(manifest.load())
 
 
 def test_the_configuration_is_air4_transfers_under_sm_verify10ks_suite():
@@ -114,7 +139,7 @@ def test_the_configuration_is_air4_transfers_under_sm_verify10ks_suite():
 
 
 def _corpus(seed=SEED, batches=2):
-    corpus = sm_transfer_batches.Corpus(dict(manifest.traffic_of("flood"), **TINY), seed, 500)
+    corpus = sm_transfer_batches.Corpus(manifest.tiny_traffic_of("flood"), seed, 500)
     corpus.sign_until(batches)
     return corpus
 
@@ -157,7 +182,7 @@ def test_the_six_broken_lanes_are_sm_signed_payloads_and_the_reference_refuses_e
 def _cell():
     doc = manifest.load()
     config = manifest.config_of(doc, "sm-air4-transfer")
-    traffic = dict(manifest.traffic_of("flood"), **TINY)
+    traffic = manifest.tiny_traffic_of("flood")
     return manifest.driver_of(config).Cell(config, traffic, SEED, Spans())
 
 
@@ -174,8 +199,7 @@ def traced():
     """One ``--trace 1`` run through the harness -> (result line, log, standard error)."""
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("FISCO_FORCE_DEVICE_ADMISSION", "1")
-        real = manifest.traffic_of
-        mp.setattr(manifest, "traffic_of", lambda name, *a: dict(real(name, *a), **TINY))
+        mp.setattr(manifest, "traffic_of", manifest.tiny_traffic_of)
         args = run.parse(["--workload", CELL, "--seed", str(SEED), "--seconds", "0.4",
                           "--trace", "1"])
         out, err = io.StringIO(), io.StringIO()
@@ -212,8 +236,13 @@ def test_a_traced_run_is_correct_with_every_number_zero(traced):
 def test_a_traced_run_reports_the_cells_lists_and_the_device_leg(traced):
     line, _said, _err = traced
     m = line["metrics"]
-    # the CPU's trace has no device plane: the three device_trace readers find nothing
-    assert set(m) == set(JOINED) - {"admission_us_per_sig.flood", "hash_device_ms_per_block.flood",
+    doc = manifest.load()
+    listed = {e["name"]: e for e in manifest.metrics_of(doc, "per_layer", CELL)}
+    # the CPU's trace has no device plane: the device_trace readers find nothing
+    assert set(m) <= {name for name, e in listed.items() if e["source"] != "device_trace"}
+    assert set(JOINED) | set(rules.BLOCK_SPLIT) | set(OWN) <= set(m) | {
+        name for name, e in listed.items() if e["source"] == "device_trace"}
+    assert set(JOINED) - set(m) == {"admission_us_per_sig.flood", "hash_device_ms_per_block.flood",
                                     "device_idle_share.flood"}
     assert m["window_compiles.flood"]["value"] == 0.0
     assert m["device_leg_share.flood"]["value"] == 100.0
@@ -222,9 +251,35 @@ def test_a_traced_run_reports_the_cells_lists_and_the_device_leg(traced):
     assert m["marshal_span_ms_per_block.flood"]["value"] > 0.0
 
 
+def test_a_traced_run_reports_the_split_of_a_block_and_the_sm_legs_entries(traced):
+    """The 22 lists PR 47 appended the cell to, and its own two entries: every one
+    a number, none negative, the stage clock's identities holding."""
+    line, _said, err = traced
+    got = {k.split(".", 1)[0]: v["value"] for k, v in line["metrics"].items()}
+    split = [name.split(".", 1)[0] for name in rules.BLOCK_SPLIT]
+    assert all(got[q] >= 0.0 for q in split)
+    for quantity in ("exec_loop", "exec_state_root", "commit_prewrite", "commit_prepare",
+                     "commit_write", "admit_static", "admit_verify", "admit_insert"):
+        assert got[quantity + "_ms_per_block"] > 0.0, quantity
+    assert got["commit_moved_row_share"] == 100.0
+    (said,) = [ln for ln in err.splitlines() if ln.startswith("stage parts, ms per block: ")]
+    parts = ast.literal_eval(said.split(": ", 1)[1].rsplit(" (idle inside", 1)[0])
+    assert sum(v for k, v in parts.items() if k.startswith("exec_")) \
+        - parts["roots_under_commit"] == pytest.approx(parts["sum:scheduler.execute_block"])
+    assert sum(v for k, v in parts.items() if k.startswith("commit_")) \
+        == pytest.approx(parts["sum:scheduler.commit_block"])
+    outstanding = sum(v for k, v in parts.items() if k.startswith("outstanding:"))
+    assert sum(v for k, v in parts.items() if k.startswith("idle_")) <= parts["window"] * (1 + 1e-9)
+    assert outstanding > 0.0
+    # the SM leg's own: four device calls a block were waited for; a block of 8 leaves
+    # is under the fused tree's 256, so every merkle call of this window went level by level
+    assert got["sm_admission_sync_ms_per_call"] > 0.0
+    assert got["merkle_fused_call_share"] == 0.0
+
+
 def test_the_seal_span_splits_into_its_groups_and_what_the_spans_miss(traced):
-    """``test_program_spans.py`` holds every cell whose name starts with
-    ``air4`` to this; this cell's starts with ``sm-``."""
+    """``test_program_spans.py`` holds every cell that reports
+    ``consensus_ms_per_block`` to this on the native leg; here the device leg is pinned."""
     m = {k.split(".", 1)[0]: v["value"] for k, v in traced[0]["metrics"].items()}
     parts = sum(m[k] for k in ("seal_pbft_ms_per_block", "seal_execute_ms_per_block",
                                "seal_commit_ms_per_block", "seal_device_wait_ms_per_block"))
@@ -232,16 +287,15 @@ def test_the_seal_span_splits_into_its_groups_and_what_the_spans_miss(traced):
     assert parts + m["host_unattributed_ms_per_block"] >= m["consensus_ms_per_block"] * (1 - 1e-6)
 
 
-def test_a_run_says_the_rule_and_the_sm_legs_split_on_standard_error(traced):
+def test_a_run_says_the_rule_and_the_sm_legs_counts_on_standard_error(traced):
     _line, said, err = traced
     assert "sm leg: on cpu the program's rule sends a batch of 8 to the fused SM program: " \
            "4 device calls a block expected" in err
-    (leg,) = [ln for ln in err.splitlines() if ln.startswith("sm leg, all replicas over the window: ")]
+    (leg,) = [ln for ln in err.splitlines() if ln.startswith("sm leg, counts by op over the window: ")]
     split = ast.literal_eval(leg.split(": ", 1)[1])
     blocks = split["blocks"]
     assert blocks >= 1 and split["admission"] == {"calls_device": 4.0 * blocks}  # the seam's legs
-    assert split["admission_sm"]["items"] == 4 * blocks * 8  # the fused program's lanes
-    assert split["admission_sm"]["sync_ms"] > 0 and split["admission_sm_sync_ms_per_call"] > 0
+    assert split["admission_sm"] == {"items": 4 * blocks * 8}  # the fused program's lanes
     # the SM3 programs by use: the hash plane's batches apart from the merkle levels
     # and every merkle level of this chain under SM3, by the label its series carries
     assert split["sm3"]["items"] > 0 and split["merkle_root"]["items_sm3"] > 0
@@ -249,22 +303,51 @@ def test_a_run_says_the_rule_and_the_sm_legs_split_on_standard_error(traced):
                 if k.startswith("items") and k != "items_sm3"]
     # nothing went hash -> e -> verify -> address as programs of their own, or to the host loop
     assert "sm2_verify" not in split and "admission_native" not in split
+    # whole counts only: the milliseconds are the entries'
+    assert not [k for row in split.values() if isinstance(row, dict) for k in row
+                if k == "ms" or k.endswith("_ms")]
     assert json.loads(said.split("counters ", 1)[1].splitlines()[0])["admission_paths"] == {
         "device": 4.0 * blocks}
 
 
-def test_the_sm_legs_line_gives_milliseconds_a_block_and_counts_whole():
+def _ctx(cell):
+    return types.SimpleNamespace(cell=cell)
+
+
+def test_the_sm_legs_two_readers_give_the_windows_deltas():
     cell = _cell()
     cell.window_blocks = 4
-    cell.sm0 = {"admission": {"calls_device": 8.0}, "admission_sm": {"items": 64.0, "sync_ms": 100.0}}
+    cell.sm0 = {"admission": {"calls_device": 8.0}, "admission_sm": {"items": 64.0, "sync_ms": 100.0},
+                "merkle_root": {"calls_fused": 10.0, "calls_levels": 2.0}}
     cell.sm1 = {"admission": {"calls_device": 24.0},
                 "admission_sm": {"items": 192.0, "sync_ms": 900.0, "ms": 1000.0},
+                "merkle_root": {"calls_fused": 62.0, "calls_levels": 2.0, "items_sm3": 52000.0},
+                "merkle_tree": {"calls_fused": 20.0, "calls_levels": 12.0},  # first seen in the window
                 "sm2_verify": {"calls_native": 0.0}}
-    assert cell._sm_leg() == {
-        "blocks": 4, "admission": {"calls_device": 16.0},
-        "admission_sm": {"items": 128.0, "sync_ms": 200.0, "ms": 250.0},  # no sm2_verify: it stood
-        "admission_sm_sync_ms_per_call": 50.0,
+    assert manifest.reader_of("sm_admission_sync_ms_per_call")(_ctx(cell)) == 50.0  # 800 ms, 16 calls
+    assert manifest.reader_of("merkle_fused_call_share")(_ctx(cell)) == pytest.approx(
+        100.0 * 72 / 84)
+    assert cell._sm_counts() == {
+        "blocks": 4, "admission": {"calls_device": 16.0}, "admission_sm": {"items": 128.0},
+        "merkle_root": {"calls_fused": 52.0, "items_sm3": 52000.0},
+        "merkle_tree": {"calls_fused": 20.0, "calls_levels": 12.0},  # no sm2_verify: it stood
     }
+    assert sm_counters.window(cell, "admission_sm", "sync_ms") == 800.0
+    assert sm_counters.window(cell, "keccak256", "items") == 0.0  # never seen: it did not move
+
+
+@pytest.mark.parametrize("reader", sorted(OWN))
+@pytest.mark.parametrize("case,build", [
+    ("a driver that took no snapshots", lambda c: None),
+    ("a program without the counters", lambda c: setattr(c, "sm0", {}) or setattr(c, "sm1", {})),
+    ("a window on the native loop, no tree hashed",
+     lambda c: setattr(c, "sm0", {"admission": {"calls_native": 4.0}})
+     or setattr(c, "sm1", {"admission": {"calls_native": 8.0}, "admission_sm": {"sync_ms": 0.0}})),
+], ids=lambda v: v.replace(" ", "_").replace(",", "") if isinstance(v, str) else None)
+def test_an_sm_leg_reader_gives_none_where_there_is_nothing_to_read(reader, case, build):
+    cell = types.SimpleNamespace()
+    build(cell)
+    assert manifest.reader_of(reader)(_ctx(cell)) is None
 
 
 def test_an_untraced_run_is_correct_with_every_number_zero(driven):
@@ -338,8 +421,7 @@ def test_a_program_without_the_fused_sm_admission_leaves_at_once(monkeypatch, ca
     from fisco_bcos_tpu.crypto.suite import CryptoSuite
 
     monkeypatch.setattr(CryptoSuite, "fused_admission", lambda self: None)
-    real = manifest.traffic_of
-    monkeypatch.setattr(manifest, "traffic_of", lambda name, *a: dict(real(name, *a), **TINY))
+    monkeypatch.setattr(manifest, "traffic_of", manifest.tiny_traffic_of)
     args = run.parse(["--workload", CELL, "--seed", str(SEED), "--seconds", "0.3", "--trace", "0"])
     with pytest.raises(SystemExit) as e:
         run.run(args, require_chip=False, out=io.StringIO())

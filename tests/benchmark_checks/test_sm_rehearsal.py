@@ -14,14 +14,12 @@ from benchmark import manifest, refsm, run
 from benchmark.generators import sm_signed_payloads
 
 CELL = "sm-verify10k.stream"
-TINY = {"lanes": 16, "signers": 4, "rotations": 2}
 SEED = 2**31 + 54321  # the driver's seeds are large
 
 
 @pytest.fixture(autouse=True)
 def tiny_traffic(monkeypatch):
-    real = manifest.traffic_of
-    monkeypatch.setattr(manifest, "traffic_of", lambda name, *a: dict(real(name, *a), **TINY))
+    monkeypatch.setattr(manifest, "traffic_of", manifest.tiny_traffic_of)
 
 
 def _run():
